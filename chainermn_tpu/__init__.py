@@ -32,7 +32,6 @@ The dagger convention follows SURVEY.md: the reference mount was empty at
 survey time, so citations are to the public upstream layout.
 """
 
-from chainermn_tpu import _jax_compat  # noqa: F401  (import installs the gate)
 from chainermn_tpu.communicators import create_communicator
 from chainermn_tpu.communicators.base import ANY_SOURCE, CommunicatorBase
 from chainermn_tpu.optimizers import (

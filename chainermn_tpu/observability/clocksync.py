@@ -2,9 +2,9 @@
 (ISSUE 17): the honesty layer under cross-rank timeline merges.
 
 Every trace event stamps ``t`` from the local ``time.time()`` — two
-processes' epochs can disagree by milliseconds (or, over a tunnelled
-relay, much more), which is larger than the handoff latencies the
-journey merge wants to display. The classic two-way exchange bounds
+processes' epochs can disagree by milliseconds (more between hosts
+without a disciplined clock), which is larger than the handoff latencies
+the journey merge wants to display. The classic two-way exchange bounds
 it without any new transport: the client stamps ``t0``, the server
 answers with its own clock ``t_srv``, the client stamps ``t1``, and
 

@@ -42,9 +42,7 @@ _LANES = 128
 # sequential. Consumed only by the Mosaic lowering; interpret mode
 # ignores it, so the bench kernel sweep's on-chip numerics gate is the
 # check that this declaration is honest.
-_GRID_SEMANTICS = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)(
+_GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
 )
 
@@ -731,15 +729,30 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
 # Public op: BTHD custom_vjp
 # ---------------------------------------------------------------------------
 
+def interpret_on(platform: str) -> bool:
+    """Whether Pallas kernels are interpreted on ``platform``: Mosaic
+    compiles them on ``'tpu'``, the interpreter runs them on ``'cpu'``
+    (the test meshes). Any other accelerator is an error — interpret
+    mode there would train at emulator speed without saying so."""
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels compile for TPU only; platform {platform!r} "
+        "is neither 'tpu' nor the 'cpu' interpreter"
+    )
+
+
 def _use_interpret() -> bool:
-    """Mosaic-compile only when the computation will actually hit a TPU:
-    honour an explicit ``jax_default_device`` override (the test harness
-    pins CPU while a TPU plugin is also loaded) before the backend default."""
+    """:func:`interpret_on` the platform the computation will run on: an
+    explicit ``jax_default_device`` override (the test harness pins CPU)
+    before the backend default."""
     default = jax.config.jax_default_device
     if default is not None:
         # May be a Device object or a platform string (both accepted by JAX).
-        return getattr(default, "platform", default) != "tpu"
-    return jax.default_backend() not in ("tpu",)
+        return interpret_on(getattr(default, "platform", default))
+    return interpret_on(jax.default_backend())
 
 
 def _to_bhtd(x):

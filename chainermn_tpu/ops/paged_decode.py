@@ -55,7 +55,12 @@ slot):
 CPU tests run interpret mode per convention (``interpret=None`` auto-
 detects, same rule as flash attention); ALWAYS compile-check on a real
 chip before trusting a change — Mosaic rejects layouts interpret mode
-accepts (``tools/on_chip_capture.sh`` runs the check mechanically).
+accepts (``tools/kernel_compile_check.py``). It rejects THIS kernel on a
+TPU v5e under jax 0.9.0 (PR 21): the ``(1, bs, 1, D)`` K/V block's
+second-to-last dim is one of ``Hkv`` heads — neither a multiple of 8 nor
+the whole axis — so ``interpret=False`` raises at trace time until the
+block takes all kv heads or the pool is laid out ``[nb, Hkv, bs, D]``
+(ROADMAP S4).
 Numerics: fp32 accumulation throughout, so outputs are allclose (not
 bitwise) to the XLA paged path's fp32 softmax.
 """
@@ -79,21 +84,9 @@ _LANES = 128
 # rows (any order), the LAST carries the online-softmax accumulators and
 # must stay sequential. Interpret mode ignores this; the on-chip compile
 # check is what keeps the declaration honest.
-_GRID_SEMANTICS = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)(
+_GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"),
 )
-
-
-def fused_supported() -> bool:
-    """True when this image's Pallas carries the scalar-prefetch grid
-    specs the table-indexed gather rides on. The serving engine's
-    ``forced:jax-compat`` fallback (via
-    :func:`chainermn_tpu._jax_compat.pallas_paged_decode_supported`)
-    consults this before cloning a ``fused`` decode model."""
-    return (hasattr(pltpu, "PrefetchScalarGridSpec")
-            and _GRID_SEMANTICS is not None)
 
 
 def _decode_body(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
@@ -213,13 +206,6 @@ def paged_flash_decode(q, k_pool, v_pool, block_tables, positions, *,
             for s in range(k_pool.shape[0])
         ]
         return jnp.stack(outs)
-    if not fused_supported():  # pragma: no cover - gated in the engine
-        raise NotImplementedError(
-            "paged_flash_decode needs pltpu.PrefetchScalarGridSpec — "
-            "this jax's Pallas lacks it (the serving engine falls back "
-            "to decode_attend_impl='xla' with forced:jax-compat)"
-        )
-
     B, T, Hq, D = q.shape
     nb, bs, Hkv, Dk = k_pool.shape
     if Dk != D:

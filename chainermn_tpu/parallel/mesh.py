@@ -59,18 +59,17 @@ def best_mesh_shape(n: int, ndims: int = 2) -> tuple[int, ...]:
 
 
 def _device_array(devices: Sequence[jax.Device], shape: tuple[int, ...]) -> np.ndarray:
-    """Arrange devices into ``shape``, ICI-topology-aware when possible.
+    """Arrange devices into ``shape``, ICI-topology-aware on a TPU.
 
     ``mesh_utils.create_device_mesh`` understands TPU coords and lays the mesh
-    out so that neighbouring mesh indices are ICI neighbours; it refuses
-    non-TPU platforms' odd shapes sometimes, so fall back to a plain reshape
-    (fine for CPU test meshes — there is no topology to exploit).
+    out so that neighbouring mesh indices are ICI neighbours; a shape it
+    cannot lay out on the physical topology raises. CPU test meshes have no
+    topology to exploit and get a plain reshape.
     """
     devices = list(devices)
-    try:
+    if devices[0].platform == "tpu":
         return mesh_utils.create_device_mesh(shape, devices=devices)
-    except (ValueError, AssertionError, NotImplementedError):
-        return np.array(devices).reshape(shape)
+    return np.array(devices).reshape(shape)
 
 
 def make_mesh(

@@ -353,6 +353,7 @@ class ParallelPlan:
         compiles.
         """
         from chainermn_tpu import tuning
+        from chainermn_tpu.ops.flash_attention import interpret_on
         from chainermn_tpu.parallel.ring_attention import (
             seq_ring_attention_local,
         )
@@ -397,7 +398,7 @@ class ParallelPlan:
             self.axes["seq"],
             collectives=_ps.SEQ_IMPL_COLLECTIVES[winner],
         )
-        interpret = self.mesh.devices.flat[0].platform != "tpu"
+        interpret = interpret_on(self.mesh.devices.flat[0].platform)
 
         if winner == "ring":
             def attn_fn(q, k, v, *, causal=causal, scale=None, **kw):

@@ -45,6 +45,7 @@ from chainermn_tpu.ops.flash_attention import (
     _use_interpret,
     flash_block_bwd,
     flash_block_fwd,
+    interpret_on,
 )
 
 
@@ -911,8 +912,8 @@ def make_ring_attention(
     spec = P(batch_axis, axis_name, None, None)
     seg_spec = P(batch_axis, axis_name)
     # The mesh knows where this will execute; don't guess from the default
-    # backend (a TPU plugin may be loaded while this mesh is CPU).
-    interpret = mesh.devices.flat[0].platform != "tpu"
+    # backend (a CPU test mesh can sit beside a live TPU backend).
+    interpret = interpret_on(mesh.devices.flat[0].platform)
     n = mesh.shape[axis_name]
 
     def local(q, k, v, seg=None):

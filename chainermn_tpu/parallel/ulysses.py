@@ -21,7 +21,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from chainermn_tpu.ops.attention import blockwise_attention
-from chainermn_tpu.ops.flash_attention import flash_attention
+from chainermn_tpu.ops.flash_attention import flash_attention, interpret_on
 
 
 def check_ulysses_divisibility(q_heads: int, kv_heads: int, n: int,
@@ -152,7 +152,7 @@ def make_ulysses_attention(
 
     spec = P(batch_axis, axis_name, None, None)
     seg_spec = P(batch_axis, axis_name)
-    interpret = mesh.devices.flat[0].platform != "tpu"
+    interpret = interpret_on(mesh.devices.flat[0].platform)
     n = mesh.shape[axis_name]
 
     def local(q, k, v, seg=None):

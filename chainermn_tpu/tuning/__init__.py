@@ -20,8 +20,8 @@ every such choice one mechanism:
   bench.py's median-of-n>=3 + spread discipline; a spread-dominated
   comparison falls back to the table instead of adopting noise.
 - :mod:`~chainermn_tpu.tuning.cache` — the persistent JSON cache
-  (``.autotune_cache.json``), seedable OFFLINE from
-  ``BENCH_DETAILS.json`` / the carried TPU blob
+  (``.autotune_cache.json``), seedable OFFLINE from a bench run's
+  ``BENCH_DETAILS.json``
   (``python -m chainermn_tpu.tuning seed``) so on-chip sweep winners
   are adopted without re-measuring.
 

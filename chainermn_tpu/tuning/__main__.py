@@ -2,9 +2,8 @@
 
 - ``python -m chainermn_tpu.tuning seed [DETAILS.json]`` — seed the
   persistent cache offline from a bench artifact (default:
-  ``BENCH_DETAILS.json``; the ``last_good_tpu`` carried blob inside it
-  is seeded too, under its own device kind) — on-chip sweep winners get
-  adopted without re-measuring.
+  ``BENCH_DETAILS.json``), under the device kind it was measured on —
+  a chip run's winners get adopted without re-measuring.
 - ``python -m chainermn_tpu.tuning show`` — print the cache.
 
 Both are jax-free (cache + seeding are plain JSON).

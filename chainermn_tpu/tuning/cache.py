@@ -237,8 +237,8 @@ def _seed_one_result(result: dict, source: str, out: list,
                 {"busbw_gbps": by_mode})
 
     # Reduction schedule: the overlap phase's per-schedule step-time
-    # medians (ISSUE 3 — bench's ``overlap`` rows, carried TPU blob
-    # included, become the 'auto' schedule's evidence). The key must
+    # medians (ISSUE 3 — bench's ``overlap`` rows become the 'auto'
+    # schedule's evidence). The key must
     # reproduce resolve_schedule's exactly: world-shape + payload-MB
     # bucket, dtype tag 'sched' — bench records both alongside the rows.
     sched_ms = result.get("overlap_schedule_ms")
@@ -567,13 +567,9 @@ def seed_from_bench_details(
     details_path: str | None = None, cache_path: str | None = None
 ) -> list[str]:
     """Seed the cache from a bench artifact (``BENCH_DETAILS.json`` by
-    default, or the carried ``.bench_last_tpu.json`` blob directly).
-
-    Seeds decisions from the artifact's top level (whatever backend that
-    run measured — often the CPU proxy) AND from its ``last_good_tpu``
-    carried blob, each under its own ``device_kind``, so on-chip sweep
-    winners are adopted for the chip without re-measuring while the CPU
-    entries keep describing the CPU. Returns the list of seeded
+    default), under the ``device_kind`` that run measured on: a chip
+    run's winners are adopted for the chip without re-measuring, a CPU
+    proxy's entries describe the CPU. Returns the list of seeded
     ``name|key -> winner`` strings."""
     details_path = details_path or os.path.join(
         _REPO_ROOT, "BENCH_DETAILS.json"
@@ -583,11 +579,4 @@ def seed_from_bench_details(
     seeded: list[str] = []
     _seed_one_result(result, f"seeded:{os.path.basename(details_path)}",
                      seeded, cache_path)
-    carried = result.get("last_good_tpu")
-    if isinstance(carried, dict):
-        _seed_one_result(
-            carried,
-            f"seeded:{os.path.basename(details_path)}:last_good_tpu",
-            seeded, cache_path,
-        )
     return seeded

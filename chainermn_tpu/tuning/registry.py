@@ -25,8 +25,9 @@ from chainermn_tpu.tuning import measure as _measure
 
 #: Deterministic fallbacks, keyed ``decision -> device class -> winner``
 #: (``*`` = any). Each winner cites the measurement it rests on
-#: (BENCH_DETAILS.json r5 + the carried v5e blob), so the table is the
-#: documented crossover, not an opinion:
+#: (the CPU-proxy rows of 2026-08-07 and the one-chip v5e capture of
+#: 2026-08-01, both older than the code since PR 1 — PERF.md), so the
+#: table is the documented crossover, not an opinion:
 #:
 #: - ``moe_dispatch``: sort won BOTH measured points — 167.8x on the CPU
 #:   proxy (T2048xE8xD64) and 1.63x on TPU v5e at the production shape
@@ -314,13 +315,12 @@ def reset_decisions() -> None:
 def _trace_clean() -> bool:
     """Whether we are OUTSIDE any jax trace — measurement runs real
     device work and must never fire mid-trace (inside shard_map/jit the
-    table/cache answer is used instead)."""
-    try:
-        import jax.core
+    table/cache answer is used instead). jax 0.9.0 keeps the predicate
+    in ``jax._src.core`` only; an upgrade that moves it fails here
+    loudly instead of silently disabling measurement."""
+    from jax._src import core as jax_core
 
-        return bool(jax.core.trace_state_clean())
-    except Exception:
-        return False
+    return bool(jax_core.trace_state_clean())
 
 
 def _table_winner(name: str, key: str, candidates, table) -> str:

@@ -232,4 +232,7 @@ def _evaluate(eval_step, dataset, batchsize, comm):
 
 
 if __name__ == "__main__":
+    from chainermn_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -139,4 +139,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from chainermn_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -40,7 +40,7 @@ from bench import _fetch_scalar, _peak_flops
 
 from chainermn_tpu import create_communicator, create_multi_node_optimizer
 from chainermn_tpu.models import TransformerLM, lm_loss_fused
-from chainermn_tpu.ops.flash_attention import flash_attention
+from chainermn_tpu.ops.flash_attention import flash_attention, interpret_on
 
 
 def time_variant(comm, args, *, remat: str, n_chunks: int,
@@ -49,7 +49,7 @@ def time_variant(comm, args, *, remat: str, n_chunks: int,
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    interpret = jax.devices()[0].platform == "cpu"
+    interpret = interpret_on(jax.devices()[0].platform)
 
     def attn(q, k, v, *, causal, scale):
         return flash_attention(q, k, v, causal=causal, scale=scale,
@@ -213,4 +213,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from chainermn_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -49,6 +49,12 @@ def synthetic_tokens(rng, batch, seqlen):
     return x
 
 
+def _interpret(comm) -> bool:
+    from chainermn_tpu.ops.flash_attention import interpret_on
+
+    return interpret_on(comm.mesh.devices.flat[0].platform)
+
+
 def _make_optimizer(args, comm):
     """One builder for every training path in this example: local SGD
     (frequency lever) or the per-step multi-node wrapper (width/overlap
@@ -143,8 +149,11 @@ def main(argv=None):
     if comm.rank == 0:
         print(f"communicator: {comm}  sp={args.sequence_parallel}")
 
+    # bf16 with compiled kernels on the chip; f32 on the CPU test mesh,
+    # where Pallas kernels are interpreted. interpret_on raises for any
+    # other accelerator rather than training it through the interpreter.
     compute_dtype = (
-        jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+        jnp.float32 if _interpret(comm) else jnp.bfloat16
     )
     rng = np.random.default_rng(0)
 
@@ -192,7 +201,7 @@ def run_packed(args, comm, compute_dtype, rng):
     skips cross-document targets."""
     from chainermn_tpu.ops.flash_attention import flash_attention
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret(comm)
 
     def attn(q, k, v, *, causal, scale, segment_ids=None):
         # window composes with the packed-segment masks in the kernel
@@ -451,4 +460,7 @@ def run_sequence_parallel(args, comm, compute_dtype, rng):
 
 
 if __name__ == "__main__":
+    from chainermn_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

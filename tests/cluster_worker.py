@@ -24,7 +24,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from chainermn_tpu import _jax_compat  # noqa: E402,F401
 from chainermn_tpu.models.transformer import (  # noqa: E402
     TransformerLM,
     generate,

@@ -1,110 +1,12 @@
-"""Regression gates for the chip-pursuit shell tooling.
-
-The watcher/capture scripts gate 30-minute chip stages on
-``tools/capture_lib.sh``'s ``fresh_artifact`` predicate; a wrong answer
-either silently disables the round's capture (the ``find -exec grep``
-zero-match bug caught in review 2026-08-01) or burns scarce chip-up
-windows redoing finished stages. Exercised hermetically via a temp
-directory shaped like the repo root.
+"""Contract tests for the offline observability tools
+(``tools/trace_report.py`` and ``tools/metrics_dump.py``): golden
+traces in, pinned report fields out.
 """
 
 import os
-import shutil
 import subprocess
-import time
-
-import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-@pytest.fixture()
-def capture_root(tmp_path):
-    (tmp_path / "tools" / "capture_logs").mkdir(parents=True)
-    shutil.copy(
-        os.path.join(_REPO, "tools", "capture_lib.sh"),
-        tmp_path / "tools" / "capture_lib.sh",
-    )
-    return tmp_path
-
-
-def _fresh(root, glob, token, marker) -> bool:
-    proc = subprocess.run(
-        ["bash", "-c",
-         f". tools/capture_lib.sh && "
-         f"fresh_artifact '{glob}' '{token}' '{marker}'"],
-        cwd=root,
-    )
-    return proc.returncode == 0
-
-
-def test_zero_matching_files_is_not_fresh(capture_root):
-    """A fresh watch with NO artifacts must report nothing fresh —
-    `find -exec grep -l {} +` exits 0 on zero files, which read as
-    'capture complete' and would have disabled the whole round."""
-    marker = capture_root / "tools" / "capture_logs" / ".watch_start"
-    marker.touch()
-    assert not _fresh(capture_root, "resnet_sweep_*.log", "n_variants",
-                      "tools/capture_logs/.watch_start")
-
-
-def test_fresh_requires_token_and_recency(capture_root):
-    logs = capture_root / "tools" / "capture_logs"
-    marker = logs / ".watch_start"
-    stale = logs / "resnet_sweep_old.log"
-    stale.write_text('{"n_variants": 12}\n')
-    past = time.time() - 60
-    os.utime(stale, (past, past))
-    marker.touch()
-    m = "tools/capture_logs/.watch_start"
-
-    # older than the marker: belongs to a previous watch/round
-    assert not _fresh(capture_root, "resnet_sweep_*.log", "n_variants", m)
-
-    # newer but PARTIAL (step_ms rows, no completion line): not fresh.
-    # Explicit future mtime: `find -newer` is a strict comparison, and a
-    # same-second write on a coarse-timestamp filesystem would read as
-    # not-newer and flake.
-    future = time.time() + 60
-    partial = logs / "resnet_sweep_new.log"
-    partial.write_text('{"step_ms": 52.1}\n')
-    os.utime(partial, (future, future))
-    assert not _fresh(capture_root, "resnet_sweep_*.log", "n_variants", m)
-
-    # newer with the completion token: fresh
-    partial.write_text('{"step_ms": 52.1}\n{"best": {}, "n_variants": 12}\n')
-    os.utime(partial, (future, future))
-    assert _fresh(capture_root, "resnet_sweep_*.log", "n_variants", m)
-
-
-def test_whitespace_filename_is_handled(capture_root):
-    """ADVICE r5: the old `for f in $(find ...)` word-split paths; a log
-    name with whitespace must neither break the predicate nor hide a
-    fresh artifact."""
-    logs = capture_root / "tools" / "capture_logs"
-    marker = logs / ".watch_start"
-    marker.touch()
-    m = "tools/capture_logs/.watch_start"
-    spaced = logs / "resnet_sweep_two words.log"
-    spaced.write_text('{"n_variants": 12}\n')
-    future = time.time() + 60
-    os.utime(spaced, (future, future))
-    assert _fresh(capture_root, "resnet_sweep_*.log", "n_variants", m)
-
-
-def test_watch_capture_counter_persists_across_restarts():
-    """ADVICE r5: the re-fire cap must bound the ROUND, not the watcher
-    process — chip_watch.sh persists the attempt count beside
-    .watch_start (reset only when a fresh marker starts a new round)
-    and counts an attempt BEFORE launching the capture."""
-    src = open(os.path.join(_REPO, "tools", "chip_watch.sh")).read()
-    assert ".watch_captures" in src
-    assert 'captures=$(cat "$counter"' in src
-    # counter reset is tied to marker creation (fresh round)
-    assert 'touch "$marker"; echo 0 > "$counter"' in src
-    # the attempt is persisted before the capture launches
-    before = src.index('echo "$captures" > "$counter"')
-    assert before < src.index("on_chip_capture.sh")
 
 
 def _golden_trace_lines():
@@ -714,9 +616,8 @@ def test_journey_report_contract(tmp_path):
 def test_trace_report_roofline_scoped_to_device_plane(tmp_path):
     """Roofline floors apply only to device-plane ops, against the
     device kinds they actually ran on — a host-plane pickle transfer
-    has no HBM roofline, and a mixed cpu+TPU trace (bench's accel child
-    + cpu fallback in one file) must not cross-product (code-review
-    finding)."""
+    has no HBM roofline, and a mixed cpu+TPU trace (a chip run and its
+    CPU children in one file) must not cross-product."""
     import json as _json
     import sys
 
@@ -811,11 +712,3 @@ def test_metrics_dump_label_validation_and_down_endpoint(capsys):
                     "--timeout", "0.2"]) == 1
     err = capsys.readouterr().err
     assert "unreachable" in err
-
-
-def test_missing_marker_is_never_fresh(capture_root):
-    logs = capture_root / "tools" / "capture_logs"
-    (logs / "bench_2.log").write_text('{"source": "live"}\n')
-    assert not _fresh(capture_root, "bench_2*.log", '"source": "live"', "")
-    assert not _fresh(capture_root, "bench_2*.log", '"source": "live"',
-                      "tools/capture_logs/.no_such_marker")

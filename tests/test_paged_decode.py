@@ -26,16 +26,9 @@ from jax.sharding import Mesh
 from chainermn_tpu.models.transformer import TransformerLM, generate
 from chainermn_tpu.ops.paged_decode import (
     dense_flash_decode,
-    fused_supported,
     paged_flash_decode,
 )
 from chainermn_tpu.serving import Request, Scheduler, ServingEngine
-
-pytestmark = pytest.mark.skipif(
-    not fused_supported(),
-    reason="this jax's Pallas lacks scalar-prefetch grid specs "
-    "(the engine falls back with forced:jax-compat)",
-)
 
 VOCAB = 32
 

@@ -172,8 +172,8 @@ class TestRegistry:
 # ---------------------------------------------------------------------------
 
 
-_FAKE_DETAILS = {
-    # CPU-proxy top level (the r5 shape of BENCH_DETAILS.json)
+_FAKE_CPU_DETAILS = {
+    # a CPU-proxy run's rows (`bench.py --run cpu`)
     "device_kind": "cpu", "n_devices": 8,
     "moe_dispatch_shape": "T2048xE8xD64_cap320_top2",
     "moe_dispatch_einsum_ms": 96.063, "moe_dispatch_sort_ms": 0.572,
@@ -185,37 +185,44 @@ _FAKE_DETAILS = {
     "overlap_schedule_ms": {"flat": 11.3, "two_level": 11.8, "zero": 9.4},
     "overlap_schedule_spread_pct": 8.5,
     "overlap_world_shape": [8], "overlap_payload_mb": 1,
-    "last_good_tpu": {
-        # a 4-chip-shaped blob so the wire seeding (gated on a real
-        # multi-member axis) is exercised
-        "device_kind": "TPU v5 lite", "n_devices": 4,
-        "measured_at": "2026-08-01T08:46:00Z",
-        "moe_dispatch_shape": "T16384xE16xD512_cap1280_top2",
-        "moe_dispatch_einsum_ms": 11.362, "moe_dispatch_sort_ms": 6.981,
-        "attn_shape": "B4xT4096xH8xD128_bf16_causal",
-        "flash_fwdbwd_ms": 13.605, "xla_fwdbwd_ms": 41.08,
-        "double_buffer_speedup": 0.85,
-        "overlap_schedule_ms": {"flat": 5.0, "two_level": 3.9,
-                                "zero": 4.4},
-        "overlap_schedule_spread_pct": 2.0,
-        "overlap_world_shape": [4], "overlap_payload_mb": 128,
-        "allreduce_curve": [
-            {"mib": 128, "dtype": "bfloat16", "mode": "fused",
-             "busbw_gbps": 101.6},
-            {"mib": 512, "dtype": "bfloat16", "mode": "bucketed",
-             "busbw_gbps": 99.0},
-            {"mib": 256, "dtype": "float32", "mode": "int8",
-             "busbw_gbps": 55.0},
-        ],
-    },
+}
+
+_FAKE_TPU_DETAILS = {
+    # a chip run's rows (`python bench.py`), 4-chip-shaped so the wire
+    # seeding (gated on a real multi-member axis) is exercised
+    "device_kind": "TPU v5 lite", "n_devices": 4,
+    "measured_at": "2026-08-01T08:46:00Z",
+    "moe_dispatch_shape": "T16384xE16xD512_cap1280_top2",
+    "moe_dispatch_einsum_ms": 11.362, "moe_dispatch_sort_ms": 6.981,
+    "attn_shape": "B4xT4096xH8xD128_bf16_causal",
+    "flash_fwdbwd_ms": 13.605, "xla_fwdbwd_ms": 41.08,
+    "double_buffer_speedup": 0.85,
+    "overlap_schedule_ms": {"flat": 5.0, "two_level": 3.9,
+                            "zero": 4.4},
+    "overlap_schedule_spread_pct": 2.0,
+    "overlap_world_shape": [4], "overlap_payload_mb": 128,
+    "allreduce_curve": [
+        {"mib": 128, "dtype": "bfloat16", "mode": "fused",
+         "busbw_gbps": 101.6},
+        {"mib": 512, "dtype": "bfloat16", "mode": "bucketed",
+         "busbw_gbps": 99.0},
+        {"mib": 256, "dtype": "float32", "mode": "int8",
+         "busbw_gbps": 55.0},
+    ],
 }
 
 
 class TestSeeding:
-    def _seed(self, tmp_path, details=None):
-        p = tmp_path / "details.json"
-        p.write_text(json.dumps(details or _FAKE_DETAILS))
-        return tuning.seed_from_bench_details(str(p))
+    def _seed(self, tmp_path):
+        """Seed one cache from a CPU-proxy artifact and a chip artifact:
+        each lands under its own device kind."""
+        seeded = []
+        for name, doc in (("cpu.json", _FAKE_CPU_DETAILS),
+                          ("tpu.json", _FAKE_TPU_DETAILS)):
+            p = tmp_path / name
+            p.write_text(json.dumps(doc))
+            seeded += tuning.seed_from_bench_details(str(p))
+        return seeded
 
     def test_seeding_adopts_onchip_choice_cpu_measurement_picks_sort(
         self, tmp_path
@@ -335,13 +342,14 @@ class TestSeeding:
         assert winner == "zero"
         assert rec["source"].startswith("cache:seeded")
 
-    def test_seeding_from_repo_details_is_self_consistent(self):
-        """The REAL BENCH_DETAILS.json seeds without error and its
-        on-chip MoE row reproduces the einsum-competitive choice."""
+    def test_seeding_from_recorded_details_is_self_consistent(self):
+        """A row bench.py really wrote (the 2026-08-01 one-chip capture,
+        tests/data/) seeds without error and its on-chip MoE row
+        reproduces the einsum-competitive choice."""
         import os
 
-        details = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "BENCH_DETAILS.json")
+        details = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               "data", "bench_details_moe_tpu.json")
         seeded = tuning.seed_from_bench_details(details)
         moe = [s for s in seeded if s.startswith("moe_dispatch|TPU")]
         assert moe, seeded

@@ -266,8 +266,8 @@ def summarize(events: list[dict]) -> dict:
 
     # Roofline floors where the device kind names a known HBM peak:
     # device-plane ops only, floored against the kinds THEY actually ran
-    # on (a multi-backend trace — bench's accel child + cpu fallback in
-    # one file — must not cross-product ops against foreign devices, and
+    # on (a multi-backend trace — e.g. a chip run and its CPU children
+    # in one file — must not cross-product ops against foreign devices, and
     # a host-plane pickle transfer has no HBM roofline at all).
     floors = []
     for entry in ops:
