@@ -1,0 +1,15 @@
+"""Per step and device, device time of the Mosaic custom calls inside the
+program's ``flash_bwd_dkv`` scope: flash attention's backward kernel for dK and dV.
+With its two siblings it adds up to ``flash_ms``."""
+
+LAYER = "kernels"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "device_trace"
+MOVES = "step_ms"
+
+
+def read(ctx):
+    import scopes
+
+    return scopes.scope_ms(ctx, (scopes.FLASH_BWD_DKV,), category="mosaic")

@@ -20,6 +20,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from chainermn_tpu.observability import train_path
 from chainermn_tpu.ops.attention import blockwise_attention
 
 
@@ -828,7 +829,9 @@ class TransformerLM(nn.Module):
         x = nn.LayerNorm(dtype=self.compute_dtype, param_dtype=jnp.float32)(x)
         if self.return_hidden:
             return x
-        logits = emb.attend(x.astype(jnp.float32))  # weight-tied output head
+        with jax.named_scope(train_path.LM_HEAD):
+            # weight-tied output head
+            logits = emb.attend(x.astype(jnp.float32))
         return logits
 
 
@@ -898,6 +901,12 @@ def lm_loss_fused(hidden, emb_table, tokens, *, n_chunks=8,
       n_chunks: token-dimension split; ``B*(T-1)`` need not divide evenly —
         the tail partial chunk is padded and masked out.
     """
+    with jax.named_scope(train_path.LM_HEAD):
+        return _lm_loss_fused(hidden, emb_table, tokens, n_chunks,
+                              compute_dtype)
+
+
+def _lm_loss_fused(hidden, emb_table, tokens, n_chunks, compute_dtype):
     B, T, D = hidden.shape
     h = hidden[:, :-1].reshape(-1, D)
     t = tokens[:, 1:].reshape(-1)

@@ -34,9 +34,15 @@ The LIVE plane (ISSUE 6) sits beside the post-hoc trace:
   percentile rule (``ceil(q*n)``) behind both the serving rollup and
   the histogram quantiles.
 
+The DEVICE side of the train path (ISSUE 23) is named from the inside:
+:mod:`~chainermn_tpu.observability.train_path` spells every
+``jax.named_scope``, host span and counter the training step and its
+set-up carry (docs/observability.md, "Training on the device").
+
 The pre-existing ``jax.profiler`` wrappers stay in
-:mod:`chainermn_tpu.utils.observability`; ``profile()`` now records its
-start/stop into this event stream as well.
+:mod:`chainermn_tpu.utils.observability`; ``profile()`` records its
+start/stop into this event stream as well, and ``annotate()`` is how
+:func:`~chainermn_tpu.observability.trace.span` reaches the profiler.
 """
 
 from chainermn_tpu.observability.trace import (

@@ -162,6 +162,12 @@ class Gauge(_Family):
             v = self._children.get(_labels_key(labels))
             return None if v is None else float(v)
 
+    def clear(self) -> None:
+        """Drop every labelled child: for a gauge whose label set is
+        replaced as a whole (a stale label must not outlive its value)."""
+        with self._lock:
+            self._children.clear()
+
 
 class Histogram(_Family):
     """Fixed-bucket streaming histogram: per child, cumulative-ready

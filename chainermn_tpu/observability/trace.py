@@ -329,23 +329,32 @@ def active() -> Optional[Recorder]:
 
 @contextlib.contextmanager
 def span(name: str, kind: str = "span", **fields: Any):
-    """Timed span event (recorded at exit, with ``dur_s`` and ``ok``).
+    """Timed span, on two clocks at once: always a
+    ``jax.profiler.TraceAnnotation`` (so it lands in a live profiler
+    session's host plane, beside the device's ops), and with a recorder
+    on also an event (recorded at exit, with ``dur_s`` and ``ok``).
     Yields a mutable dict merged into the event — callers may attach
-    results discovered inside the block. No-op when tracing is off."""
+    results discovered inside the block. With no recorder and no
+    profiler session it costs the annotation's no-op."""
+    # Imported here, not at the top: tools/trace_report.py loads this
+    # file with no package and no jax, and never opens a span.
+    from chainermn_tpu.utils.observability import annotate
+
     rec = active()
-    if rec is None:
-        yield {}
-        return
-    extra: dict = {}
-    t0 = time.perf_counter()
-    try:
-        yield extra
-    except BaseException:
-        rec.event(kind, name=name, dur_s=round(time.perf_counter() - t0, 9),
-                  ok=False, **{**fields, **extra})
-        raise
-    rec.event(kind, name=name, dur_s=round(time.perf_counter() - t0, 9),
-              ok=True, **{**fields, **extra})
+    with annotate(name):
+        if rec is None:
+            yield {}
+            return
+        extra: dict = {}
+        t0 = time.perf_counter()
+        ok = False
+        try:
+            yield extra
+            ok = True
+        finally:
+            rec.event(kind, name=name,
+                      dur_s=round(time.perf_counter() - t0, 9),
+                      ok=ok, **{**fields, **extra})
 
 
 def sync_point(x: Any) -> Any:

@@ -1,0 +1,71 @@
+"""The train path's names: every device scope, host span and counter the
+program puts on a training step and its set-up, spelled once
+(docs/observability.md, "Training on the device").
+
+Device scopes are ``jax.named_scope`` names. They reach the compiled
+step as ``op_name`` metadata only: the program XLA compiles is the same
+with and without them. JAX wraps a scope in its own markers when it
+differentiates or rematerialises the code inside
+(``transpose(jvp(lm_head))``, ``checkpoint/rematted_computation``), so a
+reader looks for a name anywhere in an ``op_name`` and tells forward,
+backward and recomputation apart by :data:`BACKWARD_MARKER` and
+:data:`REMAT_MARKER`.
+
+Host spans go through :func:`chainermn_tpu.observability.trace.span`:
+into the profiler's own trace whenever a ``jax.profiler`` session is
+live, and into the JSONL when a recorder is on.
+
+Counters live in the :mod:`~chainermn_tpu.observability.metrics`
+registry and are written off the step's path: while the step is traced,
+when a program compiles, or by a scrape-time hook.
+"""
+
+from __future__ import annotations
+
+# -- device scopes ------------------------------------------------------
+#: round ``value_and_grad`` of the loss: forward, backward, recomputation
+LOSS_AND_GRAD = "loss_and_grad"
+#: casts to and from the wire, packing, scaling and the collectives
+GRAD_REDUCE = "grad_reduce"
+#: the inner optimizer's sweep and ``optax.apply_updates``
+OPTIMIZER_UPDATE = "optimizer_update"
+#: the language-model head, fused (chunked loop) or not
+LM_HEAD = "lm_head"
+#: the three flash-attention kernels; also each ``pallas_call``'s ``name``
+FLASH_FWD = "flash_fwd"
+FLASH_BWD_DQ = "flash_bwd_dq"
+FLASH_BWD_DKV = "flash_bwd_dkv"
+
+#: how JAX marks the transposed (backward) and the recomputed code of a
+#: scope in ``op_name``
+BACKWARD_MARKER = "transpose("
+REMAT_MARKER = "rematted_computation"
+
+
+def bucket_scope(index: int) -> str:
+    """Sub-scope of :data:`GRAD_REDUCE` for one packed bucket."""
+    return f"bucket{index}"
+
+
+# -- host spans ---------------------------------------------------------
+FEED_NEXT = "chainermn.feed.next"
+FEED_PUT = "chainermn.feed.put"
+#: ``jax.profiler.StepTraceAnnotation`` name of a ``Trainer`` iteration
+TRAINER_STEP = "train"
+TRAINER_DATA_WAIT = "chainermn.trainer.data_wait"
+TRAINER_H2D = "chainermn.trainer.h2d"
+TRAINER_LOG = "chainermn.trainer.log"
+
+# -- counters -----------------------------------------------------------
+JAX_TRACE_SECONDS = "jax_trace_seconds_total"
+JAX_LOWER_SECONDS = "jax_lower_seconds_total"
+JAX_BACKEND_COMPILE_SECONDS = "jax_backend_compile_seconds_total"
+PROGRAMS_COMPILED = "programs_compiled_total"
+COMPILE_CACHE_HITS = "compile_cache_hits_total"
+COMPILE_CACHE_MISSES = "compile_cache_misses_total"
+GRAD_WIRE_BYTES = "grad_wire_bytes_per_step"
+GRAD_REDUCE_BUCKETS = "grad_reduce_buckets"
+FEED_BATCHES = "feed_batches_total"
+FEED_BYTES = "feed_bytes_total"
+FEED_NOT_READY = "feed_not_ready_total"
+

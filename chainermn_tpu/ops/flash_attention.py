@@ -31,6 +31,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from chainermn_tpu.observability import train_path
 from chainermn_tpu.ops.attention import NEG_INF
 
 _LANES = 128
@@ -372,27 +373,30 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
         _fwd_body(refs[0], refs[1], refs[2], seg_refs, bias_ref,
                   o_ref, lse_ref, acc, m, l, **params)
 
-    return pl.pallas_call(
-        kernel,
-        grid=(B, H, nq, grid_k),
-        compiler_params=_GRID_SEMANTICS,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b, h, iq, ik: (b, h, iq, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),      # acc
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # m
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
-        ],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope(train_path.FLASH_FWD):
+        return pl.pallas_call(
+            kernel,
+            name=train_path.FLASH_FWD,
+            grid=(B, H, nq, grid_k),
+            compiler_params=_GRID_SEMANTICS,
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, block_q, D),
+                             lambda b, h, iq, ik: (b, h, iq, 0)),
+                pl.BlockSpec((1, 1, block_q, 1),
+                             lambda b, h, iq, ik: (b, h, iq, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
+                jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, D), jnp.float32),      # acc
+                pltpu.VMEM((block_q, _LANES), jnp.float32),  # m
+                pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
+            ],
+            interpret=interpret,
+        )(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -619,16 +623,18 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
         _bwd_dq_body(refs[0], refs[1], refs[2], refs[3], refs[4], refs[5],
                      seg_refs, bias_ref, dq_ref, dq_acc, **dq_params)
 
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(B, H, nq, grid_k),
-        compiler_params=_GRID_SEMANTICS,
-        in_specs=dq_in_specs,
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-    )(*dq_args)
+    with jax.named_scope(train_path.FLASH_BWD_DQ):
+        dq = pl.pallas_call(
+            dq_kernel,
+            name=train_path.FLASH_BWD_DQ,
+            grid=(B, H, nq, grid_k),
+            compiler_params=_GRID_SEMANTICS,
+            in_specs=dq_in_specs,
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            interpret=interpret,
+        )(*dq_args)
 
     # dk/dv grid iterates Q heads; with GQA each q head writes its own
     # [B, H, Tk, D] slot (no cross-head accumulation inside the grid) and
@@ -694,19 +700,21 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
                       seg_refs, bias_ref, dk_ref, dv_ref, dbias_ref,
                       dk_acc, dv_acc, **dkv_params)
 
-    res = pl.pallas_call(
-        dkv_kernel,
-        grid=(B, H, nk, grid_q),
-        compiler_params=_GRID_SEMANTICS,
-        in_specs=dkv_in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*dkv_args)
+    with jax.named_scope(train_path.FLASH_BWD_DKV):
+        res = pl.pallas_call(
+            dkv_kernel,
+            name=train_path.FLASH_BWD_DKV,
+            grid=(B, H, nk, grid_q),
+            compiler_params=_GRID_SEMANTICS,
+            in_specs=dkv_in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[
+                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, D), jnp.float32),
+            ],
+            interpret=interpret,
+        )(*dkv_args)
     if want_dbias:
         dk, dv, dbias = res
     else:

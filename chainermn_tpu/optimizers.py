@@ -36,6 +36,7 @@ import optax
 from jax import lax
 
 from chainermn_tpu.communicators.base import CommunicatorBase
+from chainermn_tpu.observability import train_path
 
 PyTree = Any
 
@@ -82,8 +83,13 @@ def allreduce_gradients(
         # reduce-scatter -> inter-allreduce -> all-gather).
         return comm.reduce_gradients_in_jit(grads, compress_dtype=compress_dtype)
 
+    from chainermn_tpu.parallel.collectives import publish_grad_wire
+
     int8_wire = (compress_dtype is not None
                  and jnp.dtype(compress_dtype) == jnp.dtype(jnp.int8))
+    # one collective a leaf as the program writes it (XLA combines them)
+    leaves = jax.tree.leaves(grads)
+    publish_grad_wire(leaves, compress_dtype, axis_names, len(leaves))
 
     def reduce_leaf(g):
         if int8_wire and jnp.issubdtype(g.dtype, jnp.floating):
@@ -567,20 +573,26 @@ class MultiNodeOptimizer:
             zero_composition,
         )
 
+        from chainermn_tpu.parallel.collectives import publish_grad_wire
+
         pre, post = zero_composition(names).split_update()
-        gchunks = jax.tree.map(
-            lambda g: run_reduce_prefix(
-                g, pre, total=n_tot, wire_dtype=compress
-            ),
-            grads,
-        )
+        leaves = jax.tree.leaves(grads)
+        publish_grad_wire(leaves, compress, names, len(leaves))
+        with jax.named_scope(train_path.GRAD_REDUCE):
+            gchunks = jax.tree.map(
+                lambda g: run_reduce_prefix(
+                    g, pre, total=n_tot, wire_dtype=compress
+                ),
+                grads,
+            )
         pchunks = (jax.tree.map(
             lambda p: lax.dynamic_index_in_dim(
                 _chunk_rows(p, n), idx, keepdims=False
             ), params,
         ) if params is not None else None)
         schunk = jax.tree.map(lambda e: e[0], state.inner)
-        uchunks, schunk = inner.update(gchunks, schunk, pchunks)
+        with jax.named_scope(train_path.OPTIMIZER_UPDATE):
+            uchunks, schunk = inner.update(gchunks, schunk, pchunks)
         inner_state = jax.tree.map(lambda e: e[None], schunk)
 
         updates = jax.tree.map(
@@ -683,10 +695,12 @@ class MultiNodeOptimizer:
             axes_bound,
             int8_allreduce_mean_with_feedback,
             int8_two_level_allreduce_mean_with_feedback,
+            publish_grad_wire,
         )
 
         axes = self.communicator.grad_axes
         if not axes_bound(axes):
+            publish_grad_wire(jax.tree.leaves(grads), jnp.int8, axes, 0)
             return grads, residual  # pjit/eager: identity, residual kept
 
         axes2 = getattr(self.communicator, "two_level_axes", None)
@@ -705,6 +719,8 @@ class MultiNodeOptimizer:
         sizes = [g.size for g in leaves]
         buckets = _float_bucket_partition(float_idx, sizes,
                                           self._bucket_bytes)
+        publish_grad_wire(leaves, jnp.int8, axes,
+                          len(buckets) + len(leaves) - len(float_idx))
 
         if axes2 is not None:
             # Shard-level EF: residual is a tuple of per-bucket shard
@@ -766,9 +782,10 @@ class MultiNodeOptimizer:
         reduced = None
         if self.error_feedback:
             ef_state, state = state, state.inner
-            reduced, new_residual = self._reduce_with_feedback(
-                grads, ef_state.residual
-            )
+            with jax.named_scope(train_path.GRAD_REDUCE):
+                reduced, new_residual = self._reduce_with_feedback(
+                    grads, ef_state.residual
+                )
         else:
             schedule = self._effective_schedule(grads)
             if schedule == "zero":
@@ -776,10 +793,12 @@ class MultiNodeOptimizer:
 
         if not self.double_buffering:
             if reduced is None:
-                reduced = self._reduce_scheduled(grads, schedule)
-            updates, inner = self.actual_optimizer.update(
-                reduced, state, params
-            )
+                with jax.named_scope(train_path.GRAD_REDUCE):
+                    reduced = self._reduce_scheduled(grads, schedule)
+            with jax.named_scope(train_path.OPTIMIZER_UPDATE):
+                updates, inner = self.actual_optimizer.update(
+                    reduced, state, params
+                )
         else:
             # OVERLAPPED mode (reference staleness-1, made explicit):
             # apply last step's BANKED buckets first, then dispatch this
@@ -790,11 +809,13 @@ class MultiNodeOptimizer:
             # with donation (make_train_step's default) the bank buffer
             # is reused in place. Per-bucket wire events carry
             # overlapped=True for trace_report's comm-hidden fraction.
-            updates, inner_inner = self.actual_optimizer.update(
-                state.communicated_grads, state.inner, params
-            )
+            with jax.named_scope(train_path.OPTIMIZER_UPDATE):
+                updates, inner_inner = self.actual_optimizer.update(
+                    state.communicated_grads, state.inner, params
+                )
             if reduced is None:
-                reduced = self._reduce_scheduled(grads, schedule)
+                with jax.named_scope(train_path.GRAD_REDUCE):
+                    reduced = self._reduce_scheduled(grads, schedule)
             inner = _DoubleBufferState(
                 inner=inner_inner, communicated_grads=reduced,
                 step=state.step + 1,

@@ -169,6 +169,48 @@ def axes_bound(axis_names) -> bool:
     return True
 
 
+def grad_wire_bytes(leaves, compress_dtype=None) -> dict[str, int]:
+    """Bytes ``leaves`` put on the gradient wire, by wire dtype name:
+    floating leaves at ``compress_dtype`` (one byte an element on the
+    int8 wire), everything else at its own dtype. The one computation
+    behind the ``pack`` trace event's ``nbytes`` and the
+    ``grad_wire_bytes_per_step`` gauge."""
+    out: dict[str, int] = {}
+    for g in leaves:
+        dt = jnp.dtype(g.dtype)
+        if compress_dtype is not None and jnp.issubdtype(dt, jnp.floating):
+            dt = jnp.dtype(compress_dtype)
+        out[dt.name] = out.get(dt.name, 0) + int(g.size) * dt.itemsize
+    return out
+
+
+def publish_grad_wire(leaves, compress_dtype, axis_names,
+                      buckets: int) -> None:
+    """Publish what the gradient reduction being traced puts on the wire
+    each step (``observability.train_path``: ``grad_wire_bytes_per_step``
+    by wire dtype, ``grad_reduce_buckets``): 0 where ``axis_names`` are
+    unbound or span one device, since nothing then leaves it. Trace-time
+    only, never on a running step's path; the last reduction traced is
+    the one a scrape sees."""
+    from chainermn_tpu.observability import train_path
+    from chainermn_tpu.observability.metrics import registry
+
+    live = axes_bound(axis_names) and axes_size(axis_names) > 1
+    reg = registry()
+    wire = reg.gauge(
+        train_path.GRAD_WIRE_BYTES,
+        "bytes one device sends into the gradient reduction each step, "
+        "by wire dtype (0: axes unbound or one device)",
+    )
+    wire.clear()
+    for name, nbytes in grad_wire_bytes(leaves, compress_dtype).items():
+        wire.set(float(nbytes if live else 0), wire=name)
+    reg.gauge(
+        train_path.GRAD_REDUCE_BUCKETS,
+        "buffers the gradient reduction hands the collectives each step",
+    ).set(float(buckets if live else 0))
+
+
 #: wire-name -> compress dtype for the gradient allreduce ("auto"
 #: resolution target; None = uncompressed f32 master wire).
 WIRE_DTYPES = {"f32": None, "bf16": jnp.bfloat16, "int8": jnp.int8}

@@ -59,6 +59,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from chainermn_tpu.observability import trace as _trace
+from chainermn_tpu.observability import train_path
 
 PyTree = Any
 
@@ -289,8 +290,10 @@ def reduce_tree(
     attribute comm time per composition stage.
     """
     from chainermn_tpu.parallel.collectives import (
+        grad_wire_bytes,
         int8_allreduce_mean,
         int8_decomposed_allreduce_mean,
+        publish_grad_wire,
         _names_tuple,
     )
     from chainermn_tpu.parallel.composition import (
@@ -403,29 +406,26 @@ def reduce_tree(
                 out[i] = exact_mean(leaves[i])
         n_buckets_total += len(buckets)
         for bidx in buckets:
-            flat = jnp.concatenate(
-                [leaves[i].astype(dt).ravel() for i in bidx]
-            )
-            red = reduce_bucket(flat, dt)
-            off = 0
-            for i in bidx:
-                n = leaves[i].size
-                out[i] = (
-                    red[off: off + n]
-                    .reshape(leaves[i].shape)
-                    .astype(leaves[i].dtype)
+            with jax.named_scope(train_path.bucket_scope(len(bucket_meta))):
+                flat = jnp.concatenate(
+                    [leaves[i].astype(dt).ravel() for i in bidx]
                 )
-                off += n
+                red = reduce_bucket(flat, dt)
+                off = 0
+                for i in bidx:
+                    n = leaves[i].size
+                    out[i] = (
+                        red[off: off + n]
+                        .reshape(leaves[i].shape)
+                        .astype(leaves[i].dtype)
+                    )
+                    off += n
             bucket_meta.append(
                 (flat.size * wire_item, jnp.dtype(dt).name, flat.size)
             )
 
+    publish_grad_wire(leaves, compress_dtype, names, n_buckets_total)
     if rec is not None:
-        def wire_itemsize(g):
-            if int8_wire and jnp.issubdtype(g.dtype, jnp.floating):
-                return 1
-            return jnp.dtype(cast_dtype(g)).itemsize
-
         wire_name = ("int8" if int8_wire else
                      (jnp.dtype(compress_dtype).name
                       if compress_dtype is not None else "none"))
@@ -454,7 +454,7 @@ def reduce_tree(
                 )
         rec.event(
             "pack", op=(op or f"scheduled_reduce[{label}]"),
-            nbytes=sum(g.size * wire_itemsize(g) for g in leaves),
+            nbytes=sum(grad_wire_bytes(leaves, compress_dtype).values()),
             bucket_bytes=(bucket_bytes if bucket_bytes is not None
                           else DEFAULT_BUCKET_BYTES),
             n_buckets=n_buckets_total,

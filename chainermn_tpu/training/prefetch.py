@@ -23,7 +23,38 @@ from typing import Any, Iterable, Iterator, Optional
 
 import jax
 
+from chainermn_tpu.observability import train_path
+from chainermn_tpu.observability.trace import span
+
 PyTree = Any
+
+
+class _FeedTotals:
+    """What every feed of this process has handed over: plain integers
+    that a generator adds to with no lock (the step's path), published
+    to the metrics registry by :func:`_collect_feed` when it is read."""
+
+    def __init__(self) -> None:
+        self.batches = 0
+        self.nbytes = 0
+        self.not_ready = 0
+
+
+_FEED = _FeedTotals()
+
+
+def _collect_feed(reg) -> None:
+    for name, help_, now in (
+        (train_path.FEED_BATCHES, "batches the device feed handed over",
+         _FEED.batches),
+        (train_path.FEED_BYTES, "bytes of the batches handed over",
+         _FEED.nbytes),
+        (train_path.FEED_NOT_READY,
+         "batches whose transfer had not finished when handed over",
+         _FEED.not_ready),
+    ):
+        counter = reg.counter(name, help_)
+        counter.inc(max(0.0, now - counter.value()))
 
 
 def prefetch_to_device(
@@ -58,17 +89,32 @@ def prefetch_to_device(
             batch,
         )
 
+    def hand_over(batch: PyTree) -> PyTree:
+        leaves = jax.tree.leaves(batch)
+        _FEED.batches += 1
+        _FEED.nbytes += sum(leaf.nbytes for leaf in leaves)
+        _FEED.not_ready += not all(leaf.is_ready() for leaf in leaves)
+        return batch
+
     def gen() -> Iterator[PyTree]:
         queue: collections.deque = collections.deque()
         it = iter(iterator)
-        try:
-            while True:
-                while len(queue) < size:
-                    queue.append(put(next(it)))
-                yield queue.popleft()
-        except StopIteration:
-            while queue:
-                yield queue.popleft()
+        end = object()
+        while True:
+            while len(queue) < size:
+                with span(train_path.FEED_NEXT):
+                    host = next(it, end)
+                if host is end:
+                    while queue:
+                        yield hand_over(queue.popleft())
+                    return
+                with span(train_path.FEED_PUT):
+                    queue.append(put(host))
+            yield hand_over(queue.popleft())
+
+    from chainermn_tpu.observability.metrics import registry
+
+    registry().register_collect(_collect_feed)
 
     # Validate eagerly at the call site (a generator function would defer
     # the ValueError to the first next(), far from the faulty argument).

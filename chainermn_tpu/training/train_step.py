@@ -23,6 +23,7 @@ from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from chainermn_tpu.communicators.base import CommunicatorBase
+from chainermn_tpu.observability import train_path
 from chainermn_tpu.optimizers import (
     MultiNodeOptimizer,
     _ErrorFeedbackState,
@@ -314,9 +315,10 @@ def make_train_step(
 
     def _grads_single(state, batch):
         grad_fn = jax.value_and_grad(_loss_with_aux, has_aux=True)
-        (loss, (metrics, model_state)), grads = grad_fn(
-            state.params, batch, state.model_state
-        )
+        with jax.named_scope(train_path.LOSS_AND_GRAD):
+            (loss, (metrics, model_state)), grads = grad_fn(
+                state.params, batch, state.model_state
+            )
         return grads, loss, metrics, model_state
 
     def _grads_accumulated(state, batch):
@@ -335,9 +337,10 @@ def make_train_step(
 
         def body(carry, mb):
             gsum, model_state = carry
-            (loss, (metrics, model_state)), g = grad_fn(
-                state.params, mb, model_state
-            )
+            with jax.named_scope(train_path.LOSS_AND_GRAD):
+                (loss, (metrics, model_state)), g = grad_fn(
+                    state.params, mb, model_state
+                )
             gsum = jax.tree.map(jnp.add, gsum, g)
             return (gsum, model_state), (loss, metrics)
 
@@ -358,7 +361,8 @@ def make_train_step(
                 state, batch
             )
         if reduce_in_step:
-            grads = allreduce_gradients(grads, comm)
+            with jax.named_scope(train_path.GRAD_REDUCE):
+                grads = allreduce_gradients(grads, comm)
         opt_in = state.opt_state
         if ef:
             # Hand the optimizer its single supported layout: this
@@ -373,7 +377,8 @@ def make_train_step(
                 residual=jax.tree.map(lambda e: e[None],
                                       opt_state.residual)
             )
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(train_path.OPTIMIZER_UPDATE):
+            params = optax.apply_updates(state.params, updates)
         metrics = {"loss": loss, **metrics}
         metrics = lax.pmean(metrics, axes)
         # model_state (e.g. BN stats) must not drift across shards:

@@ -20,6 +20,7 @@ from chainermn_tpu.communicators.base import CommunicatorBase
 from chainermn_tpu.observability import flight as _flight
 from chainermn_tpu.observability import metrics as _metrics
 from chainermn_tpu.observability import trace as _trace
+from chainermn_tpu.observability import train_path
 
 PyTree = Any
 
@@ -157,9 +158,10 @@ class Trainer:
             # the step whose data interval paid for them, so the loop
             # drains the accumulator once per step.
             t_h2d = time.perf_counter()
-            out = host_local_batch_to_global(
-                collated, self.comm, self.batch_spec
-            )
+            with _trace.span(train_path.TRAINER_H2D):
+                out = host_local_batch_to_global(
+                    collated, self.comm, self.batch_spec
+                )
             self._h2d_pending += time.perf_counter() - t_h2d
             yield out
 
@@ -240,96 +242,104 @@ class Trainer:
 
             batches = prefetch_to_device(_place(batches), self.prefetch)
         it = iter(batches)
+        end = object()
         while True:
-            # --- data-wait: pulling the next collated global batch
-            # (collate + epoch restarts; with prefetch, also the queue
-            # wait). The generator accumulates its h2d sub-spans into
-            # ``_h2d_pending``; draining it here keeps the two phases
-            # disjoint even when one pull runs several assemblies
-            # (prefetch queue fill).
-            self._h2d_pending = 0.0
-            t_data = time.perf_counter()
-            try:
-                collated = next(it)
-            except StopIteration:
-                break
-            h2d = self._h2d_pending
-            data_wait = time.perf_counter() - t_data - h2d
+            # one iteration, one step of a profile: a live jax.profiler
+            # session groups the host spans and device ops below by it
+            # (numbered as the ``step`` event numbers it)
+            with jax.profiler.StepTraceAnnotation(
+                train_path.TRAINER_STEP, step_num=self.iteration + 1
+            ):
+                # --- data-wait: pulling the next collated global batch
+                # (collate + epoch restarts; with prefetch, also the queue
+                # wait). The generator accumulates its h2d sub-spans into
+                # ``_h2d_pending``; draining it here keeps the two phases
+                # disjoint even when one pull runs several assemblies
+                # (prefetch queue fill).
+                self._h2d_pending = 0.0
+                t_data = time.perf_counter()
+                with _trace.span(train_path.TRAINER_DATA_WAIT):
+                    collated = next(it, end)
+                if collated is end:
+                    break
+                h2d = self._h2d_pending
+                data_wait = time.perf_counter() - t_data - h2d
 
-            # --- compute: the jitted step. Dispatch-to-return under
-            # async dispatch; a sync-mode recorder blocks on the metrics
-            # for true wall time (measurement mode — serialises overlap).
-            t_step = time.perf_counter()
-            self.state, metrics = self.step_fn(self.state, collated)
-            rec = _trace.active()
-            if rec is not None and rec.sync:
-                jax.block_until_ready(metrics)
-            compute = time.perf_counter() - t_step
-            self.iteration += 1
-            # Hang-watchdog heartbeat + the direct step-counter gauge
-            # (ISSUE 6): the trainer's state plane has no trace event of
-            # its own until the step event below — the beat and gauge
-            # stay live even with tracing off. One slot store; the gauge
-            # guards on the registry existing at all.
-            _flight.beat(self.iteration)
-            reg = _metrics.active_registry()
-            if reg is not None:
-                reg.gauge(
-                    "train_iteration", "last completed trainer iteration"
-                ).set(float(self.iteration))
+                # --- compute: the jitted step. Dispatch-to-return under
+                # async dispatch; a sync-mode recorder blocks on the metrics
+                # for true wall time (measurement mode — serialises overlap).
+                t_step = time.perf_counter()
+                self.state, metrics = self.step_fn(self.state, collated)
+                rec = _trace.active()
+                if rec is not None and rec.sync:
+                    jax.block_until_ready(metrics)
+                compute = time.perf_counter() - t_step
+                self.iteration += 1
+                # Hang-watchdog heartbeat + the direct step-counter gauge
+                # (ISSUE 6): the trainer's state plane has no trace event of
+                # its own until the step event below — the beat and gauge
+                # stay live even with tracing off. One slot store; the gauge
+                # guards on the registry existing at all.
+                _flight.beat(self.iteration)
+                reg = _metrics.active_registry()
+                if reg is not None:
+                    reg.gauge(
+                        "train_iteration", "last completed trainer iteration"
+                    ).set(float(self.iteration))
 
-            log_s = 0.0
-            if self.iteration % self.log_interval == 0 or self.iteration == max_iterations:
-                t_log = time.perf_counter()
-                host_metrics = {
-                    k: float(jax.device_get(v)) for k, v in metrics.items()
+                log_s = 0.0
+                if self.iteration % self.log_interval == 0 or self.iteration == max_iterations:
+                    t_log = time.perf_counter()
+                    with _trace.span(train_path.TRAINER_LOG):
+                        self._log_metrics(metrics, max_iterations, t0)
+                    log_s = time.perf_counter() - t_log
+
+                # Window accumulation BEFORE extensions run, so a straggler
+                # monitor firing as an extension sees this step included.
+                phases = {
+                    "data_wait": data_wait,
+                    "h2d": h2d,
+                    "compute": compute,
+                    "logging": log_s,
                 }
-                # Cross-rank aggregation so EVERY rank holds the global
-                # metrics (one host collective per log point; all ranks
-                # reach this branch at the same iteration). Rank-0's
-                # pretty-print keeps its LOCAL values, unchanged.
-                agg = self._obs_agg(host_metrics)
-                self.observation = (
-                    agg if agg is not None else dict(host_metrics)
-                )
-                dt = time.perf_counter() - t0
-                rate = self.iteration / dt
-                pretty = " ".join(f"{k}={v:.4f}" for k, v in host_metrics.items())
-                self._log(
-                    f"iter {self.iteration}/{max_iterations} {pretty} "
-                    f"({rate:.1f} it/s)"
-                )
-                log_s = time.perf_counter() - t_log
+                for k, v in phases.items():
+                    self._phase_sums[k] = self._phase_sums.get(k, 0.0) + v
+                self._phase_steps += 1
 
-            # Window accumulation BEFORE extensions run, so a straggler
-            # monitor firing as an extension sees this step included.
-            phases = {
-                "data_wait": data_wait,
-                "h2d": h2d,
-                "compute": compute,
-                "logging": log_s,
-            }
-            for k, v in phases.items():
-                self._phase_sums[k] = self._phase_sums.get(k, 0.0) + v
-            self._phase_steps += 1
-
-            t_ext = time.perf_counter()
-            for interval, ext in self._extensions:
-                if self.iteration % interval == 0:
-                    ext(self)
-            ext_s = time.perf_counter() - t_ext
-            self._phase_sums["extensions"] = (
-                self._phase_sums.get("extensions", 0.0) + ext_s
-            )
-
-            if rec is not None:
-                rec.event(
-                    "step", iteration=self.iteration,
-                    phases={k: round(v, 6)
-                            for k, v in {**phases,
-                                         "extensions": ext_s}.items()},
+                t_ext = time.perf_counter()
+                for interval, ext in self._extensions:
+                    if self.iteration % interval == 0:
+                        ext(self)
+                ext_s = time.perf_counter() - t_ext
+                self._phase_sums["extensions"] = (
+                    self._phase_sums.get("extensions", 0.0) + ext_s
                 )
+
+                if rec is not None:
+                    rec.event(
+                        "step", iteration=self.iteration,
+                        phases={k: round(v, 6)
+                                for k, v in {**phases,
+                                             "extensions": ext_s}.items()},
+                    )
         return self.state
+
+    def _log_metrics(self, metrics, max_iterations: int, t0: float) -> None:
+        host_metrics = {
+            k: float(jax.device_get(v)) for k, v in metrics.items()
+        }
+        # Cross-rank aggregation so EVERY rank holds the global
+        # metrics (one host collective per log point; all ranks
+        # reach this branch at the same iteration). Rank-0's
+        # pretty-print keeps its LOCAL values, unchanged.
+        agg = self._obs_agg(host_metrics)
+        self.observation = agg if agg is not None else dict(host_metrics)
+        rate = self.iteration / (time.perf_counter() - t0)
+        pretty = " ".join(f"{k}={v:.4f}" for k, v in host_metrics.items())
+        self._log(
+            f"iter {self.iteration}/{max_iterations} {pretty} "
+            f"({rate:.1f} it/s)"
+        )
 
     def consume_phase_window(self) -> dict[str, float]:
         """Mean seconds per step-timeline phase (data_wait / h2d /

@@ -1,0 +1,417 @@
+"""The train path named from the inside (ISSUE 23): device scopes in the
+lowered step, the gradient-wire gauge, compile and feed counters, and host
+spans on the profiler's clock. Everything here is structural (names and
+counts), on the CPU; times come from the chip (PERF.md)."""
+
+import functools
+import glob
+import json
+import os
+import re
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+import chainermn_tpu
+from chainermn_tpu import create_multi_node_optimizer as mno
+from chainermn_tpu.models import TransformerLM, lm_loss_fused
+from chainermn_tpu.observability import metrics, trace, train_path
+from chainermn_tpu.ops.flash_attention import flash_attention
+from chainermn_tpu.training import make_train_step
+from chainermn_tpu.training.prefetch import prefetch_to_device
+from chainermn_tpu.training.train_step import create_train_state
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+OPTIMIZERS = {
+    "default": dict(),
+    "flat": dict(reduction_schedule="flat"),
+    "double_buffered": dict(double_buffering=True),
+    "error_feedback": dict(allreduce_grad_dtype=jnp.int8,
+                           error_feedback=True),
+}
+STEP_SCOPES = (train_path.LOSS_AND_GRAD, train_path.GRAD_REDUCE,
+               train_path.OPTIMIZER_UPDATE)
+
+
+def _op_names(hlo_text):
+    return set(re.findall(r'op_name="([^"]*)"', hlo_text))
+
+
+def _params():
+    rng = np.random.default_rng(0)
+    return {"w": jnp.asarray(rng.normal(size=(16, 8)), jnp.float32),
+            "b": jnp.zeros((8,), jnp.float32)}
+
+
+def _loss(params, batch):
+    out = batch @ params["w"] + params["b"]
+    return jnp.mean(out ** 2)
+
+
+@functools.lru_cache(maxsize=None)
+def _lowered(kind, n):
+    """``(op_names of the lowered step, registry snapshot after tracing
+    it)`` for one optimizer on ``n`` virtual devices."""
+    metrics.reset()
+    comm = chainermn_tpu.create_communicator(
+        "naive", devices=jax.devices("cpu")[:n],
+        allreduce_grad_dtype=jnp.bfloat16)
+    opt = mno(optax.adam(1e-3), comm, **OPTIMIZERS[kind])
+    state = create_train_state(_params(), opt, comm)
+    step = make_train_step(_loss, opt, comm)
+    if not hasattr(step, "lower"):  # error feedback: a checking wrapper
+        step = jax.jit(step)
+    lowered = step.lower(state, jnp.ones((4 * n, 16), jnp.float32))
+    names = _op_names(lowered.as_text(dialect="hlo", debug_info=True))
+    return names, metrics.registry().snapshot()
+
+
+@pytest.mark.parametrize("n", [1, 4])
+@pytest.mark.parametrize("kind", list(OPTIMIZERS))
+def test_step_scopes_in_the_lowered_step(kind, n):
+    names, _ = _lowered(kind, n)
+    for scope in STEP_SCOPES:
+        assert any(scope in name for name in names), (scope, kind, n)
+    # forward and backward of the loss come apart by JAX's own marker
+    inside = [x for x in names if train_path.LOSS_AND_GRAD in x]
+    assert any(train_path.BACKWARD_MARKER in x for x in inside)
+    assert any(train_path.BACKWARD_MARKER not in x for x in inside)
+    # a packed schedule names its buckets inside the reduction
+    if kind in ("flat", "double_buffered") and n > 1:
+        assert any(f"{train_path.GRAD_REDUCE}/{train_path.bucket_scope(0)}"
+                   in x for x in names)
+    # nothing of the optimizer's sweep sits inside the reduction's scope
+    assert not any(train_path.GRAD_REDUCE in x
+                   and train_path.OPTIMIZER_UPDATE in x for x in names)
+
+
+def _wire_bytes(snapshot):
+    rows = snapshot[train_path.GRAD_WIRE_BYTES]["values"]
+    return {r["labels"]["wire"]: r["value"] for r in rows}
+
+
+@pytest.mark.parametrize("n", [1, 4])
+@pytest.mark.parametrize("kind", list(OPTIMIZERS))
+def test_grad_wire_bytes_are_the_leaves_at_the_wire_dtype(kind, n):
+    _, snap = _lowered(kind, n)
+    wire = "int8" if kind == "error_feedback" else "bfloat16"
+    expect = {wire: (16 * 8 + 8) * jnp.dtype(wire).itemsize}
+    got = _wire_bytes(snap)
+    assert set(got) == set(expect)
+    if n == 1:  # nothing leaves the device
+        assert set(got.values()) == {0.0}
+        assert snap[train_path.GRAD_REDUCE_BUCKETS]["values"][0]["value"] \
+            == 0.0
+    else:
+        assert got == expect
+        assert snap[train_path.GRAD_REDUCE_BUCKETS]["values"][0]["value"] \
+            >= 1.0
+
+
+def test_grad_wire_bytes_zero_outside_any_axis():
+    metrics.reset()
+    comm = chainermn_tpu.create_communicator(
+        "naive", devices=jax.devices("cpu")[:4])
+    chainermn_tpu.optimizers.allreduce_gradients(
+        _params(), comm, compress_dtype=jnp.bfloat16)  # eager: unbound
+    assert set(_wire_bytes(metrics.registry().snapshot()).values()) == {0.0}
+
+
+def test_pack_event_and_gauge_share_one_computation():
+    """The ``pack`` trace event's ``nbytes`` is the gauge's sum."""
+    metrics.reset()
+    rec = trace.enable(None)
+    try:
+        _lowered.cache_clear()
+        _, snap = _lowered("flat", 4)
+    finally:
+        trace.disable()
+        _lowered.cache_clear()
+    packs = [e for e in rec.events if e["kind"] == "pack"]
+    assert packs and packs[-1]["nbytes"] == sum(_wire_bytes(snap).values())
+
+
+# -- the model's and the kernels' scopes --------------------------------
+
+def _flash(q, k, v, *, causal, scale):
+    return flash_attention(q, k, v, causal=causal, scale=scale)
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_lm_head_scope(fused):
+    model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
+                          d_model=32, d_ff=64, max_len=16,
+                          return_hidden=fused)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    params = model.init(jax.random.key(0), tokens)["params"]
+
+    def loss(p):
+        out = model.apply({"params": p}, tokens)
+        if fused:
+            return lm_loss_fused(out, p["tok_emb"]["embedding"], tokens,
+                                 n_chunks=2)
+        return out.sum()
+
+    names = _op_names(jax.jit(jax.grad(loss)).lower(params).as_text(
+        dialect="hlo", debug_info=True))
+    head = [x for x in names if train_path.LM_HEAD in x]
+    assert any(train_path.BACKWARD_MARKER in x for x in head)
+    assert any(train_path.BACKWARD_MARKER not in x for x in head)
+    if fused:  # the chunks are recomputed in the backward pass (inside
+        # the loop's body the lowering may drop the enclosing names)
+        assert any(train_path.REMAT_MARKER in x for x in names)
+
+
+def test_flash_kernels_are_scoped_and_named_forward_and_backward():
+    q = jnp.ones((1, 128, 2, 32), jnp.float32)
+
+    def f(q):
+        return _flash(q, q, q, causal=True, scale=1.0).sum()
+
+    fwd = _op_names(jax.jit(f).lower(q).as_text(dialect="hlo",
+                                                debug_info=True))
+    assert any(train_path.FLASH_FWD in x for x in fwd)
+    assert not any(train_path.FLASH_BWD_DQ in x or
+                   train_path.FLASH_BWD_DKV in x for x in fwd)
+    bwd = _op_names(jax.jit(jax.grad(f)).lower(q).as_text(
+        dialect="hlo", debug_info=True))
+    for scope in (train_path.FLASH_FWD, train_path.FLASH_BWD_DQ,
+                  train_path.FLASH_BWD_DKV):
+        assert any(scope in x for x in bwd), scope
+    # the Mosaic kernels themselves carry the same names
+    kernels = set(re.findall(r"name=(\w+)", str(jax.make_jaxpr(
+        jax.grad(f))(q))))
+    assert {train_path.FLASH_FWD, train_path.FLASH_BWD_DQ,
+            train_path.FLASH_BWD_DKV} <= kernels
+
+
+def test_ring_attention_blocks_carry_the_flash_scopes():
+    from chainermn_tpu.ops.flash_attention import (
+        flash_block_bwd,
+        flash_block_fwd,
+    )
+
+    q = jnp.ones((1, 128, 2, 32), jnp.float32)  # BTHD
+    kw = dict(causal=False, scale=1.0, block_q=128, block_k=128,
+              interpret=True)
+
+    def both(q):
+        o, lse = flash_block_fwd(q, q, q, **kw)
+        delta = jnp.sum(o * o, axis=-1).transpose(0, 2, 1)  # [B, H, Tq]
+        return flash_block_bwd(q, q, q, o, lse, delta, **kw)
+
+    names = _op_names(jax.jit(both).lower(q).as_text(dialect="hlo",
+                                                     debug_info=True))
+    for scope in (train_path.FLASH_FWD, train_path.FLASH_BWD_DQ,
+                  train_path.FLASH_BWD_DKV):
+        assert any(scope in x for x in names), scope
+
+
+def test_scopes_are_metadata_only():
+    """The recorder changes nothing of the lowered step (the
+    ``test_trace.py`` certificate, on the scoped step), and the scopes sit
+    only in metadata: stripped of it the text names none of them."""
+    def lower(kind):
+        _lowered.cache_clear()
+        comm = chainermn_tpu.create_communicator(
+            "naive", devices=jax.devices("cpu")[:4],
+            allreduce_grad_dtype=jnp.bfloat16)
+        opt = mno(optax.adam(1e-3), comm, **OPTIMIZERS[kind])
+        state = create_train_state(_params(), opt, comm)
+        step = make_train_step(_loss, opt, comm)
+        return step.lower(state, jnp.ones((16, 16), jnp.float32))
+
+    off = lower("flat")
+    trace.enable(None)
+    try:
+        on = lower("flat")
+    finally:
+        trace.disable()
+    bare = off.as_text(dialect="hlo")
+    assert on.as_text(dialect="hlo") == bare
+    assert _op_names(on.as_text(dialect="hlo", debug_info=True)) == \
+        _op_names(off.as_text(dialect="hlo", debug_info=True))
+    assert not any(scope in bare for scope in STEP_SCOPES)
+
+
+# -- compile counters ----------------------------------------------------
+
+def _counter(name):
+    fam = metrics.registry().snapshot().get(name)
+    return sum(r["value"] for r in fam["values"]) if fam else 0.0
+
+
+def test_compile_counters_rise_on_a_compile():
+    from chainermn_tpu.utils import compile_cache
+
+    compile_cache._count_compiles()
+    compile_cache._count_compiles()  # once a process: no second listener
+    x = jnp.ones((7, 3))
+    before = {n: _counter(n) for n in (
+        train_path.JAX_TRACE_SECONDS, train_path.JAX_LOWER_SECONDS,
+        train_path.JAX_BACKEND_COMPILE_SECONDS,
+        train_path.PROGRAMS_COMPILED)}
+    jax.jit(lambda x: jnp.tanh(x) * 3.25 + 0.125)(x)
+    for name, was in before.items():
+        assert _counter(name) > was, name
+    assert _counter(train_path.PROGRAMS_COMPILED) == \
+        before[train_path.PROGRAMS_COMPILED] + 1
+
+
+def test_nested_trace_spans_count_once():
+    from jax import monitoring
+
+    from chainermn_tpu.utils import compile_cache
+
+    compile_cache._count_compiles()
+    event = compile_cache._TRACE_EVENT
+    was = _counter(train_path.JAX_TRACE_SECONDS)
+    t = time.time() + 1000.0  # after every span JAX itself has reported
+    # as JAX emits them: the inner spans close before the outer one
+    monitoring.record_event_time_span(event, t + 1.0, t + 2.0)
+    monitoring.record_event_time_span(event, t + 3.0, t + 3.5)
+    assert _counter(train_path.JAX_TRACE_SECONDS) == pytest.approx(was + 1.5)
+    monitoring.record_event_time_span(event, t, t + 4.0)
+    assert _counter(train_path.JAX_TRACE_SECONDS) == pytest.approx(was + 4.0)
+    monitoring.record_event_time_span(event, t + 5.0, t + 6.0)  # a sibling
+    assert _counter(train_path.JAX_TRACE_SECONDS) == pytest.approx(was + 5.0)
+
+
+_CACHE_CHILD = """
+import json, sys
+import jax, jax.numpy as jnp
+from chainermn_tpu.utils.compile_cache import use_compile_cache
+from chainermn_tpu.observability.metrics import registry
+assert use_compile_cache() == sys.argv[1]
+jax.jit(lambda x: jnp.sin(x) @ x.T + 2.5)(jnp.ones((8, 8))).block_until_ready()
+snap = registry().snapshot()
+print(json.dumps({k: sum(r["value"] for r in v["values"])
+                  for k, v in snap.items()}))
+"""
+
+
+def test_a_second_process_on_a_warm_cache_counts_hits(tmp_path):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path),
+           "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+           "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1",
+           "PYTHONPATH": REPO}
+
+    def child():
+        out = subprocess.run(
+            [sys.executable, "-c", _CACHE_CHILD, str(tmp_path)], env=env,
+            capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    cold, warm = child(), child()
+    assert cold[train_path.COMPILE_CACHE_MISSES] >= 1
+    assert cold.get(train_path.COMPILE_CACHE_HITS, 0) == 0
+    assert warm[train_path.COMPILE_CACHE_HITS] >= 1
+    assert warm[train_path.COMPILE_CACHE_HITS] == \
+        cold[train_path.COMPILE_CACHE_MISSES]
+    assert warm[train_path.PROGRAMS_COMPILED] == \
+        cold[train_path.PROGRAMS_COMPILED]
+
+
+# -- the feed ------------------------------------------------------------
+
+def test_feed_counters_on_a_host_iterator():
+    metrics.reset()
+    from chainermn_tpu.training import prefetch
+
+    base = (prefetch._FEED.batches, prefetch._FEED.nbytes)
+    batches = [{"x": np.full((4, 3), i, np.float32),
+                "y": np.arange(4, dtype=np.int32)} for i in range(5)]
+    got = list(prefetch_to_device(iter(batches), size=2))
+    assert [int(b["x"][0, 0]) for b in got] == [0, 1, 2, 3, 4]
+    assert prefetch._FEED.batches - base[0] == 5
+    assert prefetch._FEED.nbytes - base[1] == 5 * (4 * 3 * 4 + 4 * 4)
+    # a scrape publishes the process's totals; a second one adds nothing
+    for _ in range(2):
+        snap = metrics.registry().snapshot()
+        assert snap[train_path.FEED_BATCHES]["values"][0]["value"] == \
+            prefetch._FEED.batches
+        assert snap[train_path.FEED_BYTES]["values"][0]["value"] == \
+            prefetch._FEED.nbytes
+        assert 0 <= snap[train_path.FEED_NOT_READY]["values"][0]["value"] \
+            <= prefetch._FEED.batches
+
+
+# -- host spans on the profiler's clock ----------------------------------
+
+def _host_events(logdir):
+    from jax.profiler import ProfileData
+
+    pb = sorted(glob.glob(os.path.join(
+        logdir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    return [(e.name, int(e.start_ns), int(e.duration_ns),
+             # only the step annotation's stats are read (step_num)
+             dict(e.stats) if e.name == train_path.TRAINER_STEP else {})
+            for plane in ProfileData.from_file(pb).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+@pytest.mark.parametrize("recorder", [False, True])
+def test_span_lands_in_a_cpu_profile(tmp_path, recorder):
+    rec = trace.enable(None) if recorder else None
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        with trace.span("chainermn.test.phase", detail=7):
+            jnp.ones((4,)).block_until_ready()
+        jax.profiler.stop_trace()
+    finally:
+        trace.disable()
+    named = [e for e in _host_events(str(tmp_path))
+             if e[0] == "chainermn.test.phase"]
+    assert len(named) == 1 and named[0][2] > 0
+    if recorder:
+        spans = [e for e in rec.events if e["kind"] == "span"]
+        assert [e["name"] for e in spans] == ["chainermn.test.phase"]
+        assert spans[0]["ok"] and spans[0]["detail"] == 7
+    else:
+        assert trace.active() is None
+
+
+def test_feed_spans_in_a_profile(tmp_path):
+    batches = [np.ones((2, 2), np.float32)] * 3
+    jax.profiler.start_trace(str(tmp_path))
+    list(prefetch_to_device(iter(batches), size=1))
+    jax.profiler.stop_trace()
+    names = [e[0] for e in _host_events(str(tmp_path))]
+    assert names.count(train_path.FEED_PUT) == 3
+    assert names.count(train_path.FEED_NEXT) == 4  # the last finds the end
+
+
+def test_trainer_profile_groups_by_step(tmp_path):
+    from chainermn_tpu.training.trainer import Trainer
+
+    comm = chainermn_tpu.create_communicator(
+        "naive", devices=jax.devices("cpu")[:2])
+    opt = mno(optax.sgd(0.1), comm)
+    state = create_train_state(
+        {"w": jnp.ones((16, 8)), "b": jnp.zeros((8,))}, opt, comm)
+    step = make_train_step(_loss, opt, comm)
+    data = [np.ones((4, 16), np.float32)] * 3
+    trainer = Trainer(step, state, data, comm,
+                      collate=lambda b: b, log_interval=1,
+                      out=open(os.devnull, "w"))
+    jax.profiler.start_trace(str(tmp_path))
+    trainer.run(3)
+    jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    steps = sorted(int(stats["step_num"]) for name, _, _, stats in events
+                   if name == train_path.TRAINER_STEP)
+    assert steps[:3] == [1, 2, 3]
+    names = [e[0] for e in events]
+    for span in (train_path.TRAINER_DATA_WAIT, train_path.TRAINER_H2D,
+                 train_path.TRAINER_LOG):
+        assert names.count(span) >= 3, span
