@@ -68,4 +68,8 @@ GRAD_REDUCE_BUCKETS = "grad_reduce_buckets"
 FEED_BATCHES = "feed_batches_total"
 FEED_BYTES = "feed_bytes_total"
 FEED_NOT_READY = "feed_not_ready_total"
+#: gauge, labels ``kernel`` (a flash kernel's name) and ``kind``
+#: (``total`` / ``visited`` / ``masked``): the tile geometry of the last
+#: call of the op (for a jitted step: the last one traced)
+FLASH_TILES = "flash_tiles"
 

@@ -16,6 +16,32 @@ kernels power the sequence-parallel ring attention
 (:mod:`chainermn_tpu.parallel.ring_attention`), which rotates K/V blocks via
 ``ppermute`` and calls them per arriving block.
 
+Iteration geometry, the same for the three kernels (:func:`_geometry`
+derives it from the lengths and the mask kind unless the caller passes
+``block_q`` / ``block_k``): a grid ``(B, H, space, reduce)`` of tiles that
+are long along the axis a kernel walks (1024 keys for the forward and dq,
+1024 queries for dk/dv) and 512 across it. Under a causal mask a tile is
+one of three kinds (:func:`_tile_class`): wholly above the diagonal — no
+matmul, no vector work and, because the index maps repeat the last live
+block there, no DMA; wholly below — computed with no mask at all; crossed
+by the diagonal. In the forward and dq a crossed tile is walked in
+sub-tiles of 512 keys inside the grid step, of which those above the
+diagonal are skipped, the one it crosses builds the iota/compare/select
+mask and the rest are computed without one (:func:`_pieces`); dk/dv masks
+a crossed tile whole, and where nothing but the causal mask acts on the
+scores (and a head's row has at most 512 bytes) its tile is 1024 x 1024.
+A kernel whose walked axis is one tile long (T <= 1024) keeps no running
+statistics or accumulators between steps. What that saves depends on the
+lengths: at T 1024 the forward and dq visit 3 of the square's 4 sub-tiles
+and mask 2, dk/dv computes its one tile; at T 2048, 10 of 16 and 4 (dk/dv
+3 of 4 and 2); at T 4096, 36 of 64 and 8 (dk/dv 10 of 16 and 4); under the
+former 512 x 1024 default 2 of 2, 6 of 8 and 20 of 32, every one masked.
+A window, or a ``q_offset`` off the sub-tile grid, masks a crossed tile
+whole in every kernel; segment ids and a bias keep their work on every
+visited sub-tile, and with either, or a window, dk/dv keeps the 512 x 1024
+tile. The gauge ``flash_tiles`` (labels ``kernel``, ``kind``) says what
+the last call of the op does.
+
 Layout: BTHD at the API (framework convention), BHTD inside the kernel grid;
 LSE/delta rows are ``[B, H, T]``.
 """
@@ -27,6 +53,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -48,39 +75,50 @@ _GRID_SEMANTICS = pltpu.CompilerParams(
 )
 
 
-def _causal_mask(iq, ik, block_q, block_k, shape, window=None,
-                 q_offset=0):
-    """Causal mask, optionally banded to a sliding window: query at
-    GLOBAL position ``i + q_offset`` sees keys ``j`` with
-    ``i + q_offset - window < j <= i + q_offset`` (``window=None`` → full
-    causal). ``q_offset`` aligns Q against a K axis that starts earlier —
-    the sequence-parallel neighbour-tail layout."""
-    q_pos = q_offset + iq * block_q + lax.broadcasted_iota(
-        jnp.int32, shape, 0
-    )
-    k_pos = ik * block_k + lax.broadcasted_iota(jnp.int32, shape, 1)
+def _causal_mask(q0, k0, shape, window=None):
+    """Causal mask of a score tile whose first query and first key sit at
+    GLOBAL positions ``q0`` and ``k0``, optionally banded to a sliding
+    window: a query at ``i`` sees keys ``j`` with ``i - window < j <= i``
+    (``window=None`` → full causal). A caller folds ``q_offset`` (Q
+    aligned against a K axis that starts earlier — the sequence-parallel
+    neighbour-tail layout) into ``q0``."""
+    q_pos = q0 + lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_pos = k0 + lax.broadcasted_iota(jnp.int32, shape, 1)
     mask = q_pos >= k_pos
     if window is not None:
         mask &= q_pos - k_pos < window
     return mask
 
 
-def _live(ik, iq, block_q, block_k, causal, window=None, q_offset=0):
-    """Causal: blocks strictly above the diagonal contribute nothing — skip
-    their matmuls entirely (≈2x for long sequences). A sliding window
-    additionally kills blocks entirely BELOW the band (every pair with
-    ``q_pos - k_pos >= window``). With the band-narrowed grids
-    (``_band_k``/``_band_q``) this predicate only handles the clipped
-    edge slots; the grid itself no longer visits far-out-of-band
-    blocks."""
+def _tile_class(ik, iq, block_q, block_k, causal, window=None, q_offset=0):
+    """``(live, full)`` of tile ``(iq, ik)`` under the mask, for traced
+    ints in a kernel and for numpy index grids alike.
+
+    ``live`` is false for a tile that contributes nothing: wholly above
+    the causal diagonal (every key after every query), or wholly below a
+    sliding window's band (every pair with ``q_pos - k_pos >= window``).
+    Such a tile is skipped whole; how many there are follows from the
+    tiles alone (a k tile as long as the sequence is never above the
+    diagonal). ``full`` is true where every pair of the tile is allowed,
+    so the causal mask need not be built: every key at or before every
+    query, and the farthest pair still inside the window. A live tile
+    that is not full is crossed by the diagonal or a window edge and is
+    the only kind that pays for iota, compare and select. Without
+    ``causal`` every tile is live and full. With the band-narrowed grids
+    (``_band_k``/``_band_q``) the predicate only sorts the slots the
+    grid still visits."""
     if not causal:
-        return True
-    q0 = q_offset + iq * block_q  # min global q position in the block
-    alive = ik * block_k <= q0 + block_q - 1
+        return True, True
+    q0 = q_offset + iq * block_q  # first and last global q position
+    q1 = q0 + block_q - 1
+    k0 = ik * block_k
+    k1 = k0 + block_k - 1
+    live = k0 <= q1
+    full = k1 <= q0
     if window is not None:
-        # max k_pos in block = (ik+1)·bk - 1.
-        alive &= q0 - ((ik + 1) * block_k - 1) < window
-    return alive
+        live &= q0 - k1 < window
+        full &= q1 - k0 < window
+    return live, full
 
 
 def _band_k(block_q: int, block_k: int, window: int, nk: int,
@@ -147,13 +185,43 @@ def _band_q(block_q: int, block_k: int, window: int, nq: int,
     return span, lo
 
 
-def _clipped_slot(lo, n):
-    """Slot→true-block mapper for index maps: identity when un-banded,
-    else ``clip(lo(i) + j, 0, n - 1)`` (dead slots land on a valid,
-    unused block — the body's liveness predicate skips them)."""
-    if lo is None:
-        return lambda i, j: j
-    return lambda i, j: jnp.clip(lo(i) + j, 0, n - 1)
+def _k_slot(band_lo, nk, block_q, block_k, causal, q_offset):
+    """Slot→k-block mapper for the index maps of the kernels that walk K
+    per Q block (forward, dq): slot ``j`` of q block ``iq`` is true block
+    ``band_lo(iq) + j`` (``j`` itself un-banded), held inside ``[0, nk)``
+    and, under a causal mask, at or before the last block a query of
+    ``iq`` can see. A dead slot so names the block of the slot before it,
+    which Pallas does not fetch again; the body's ``live`` skips it."""
+    if band_lo is None and not causal:
+        return lambda iq, j: j
+
+    def k_block(iq, j):
+        ik = j if band_lo is None else band_lo(iq) + j
+        hi = nk - 1
+        if causal:
+            hi = jnp.minimum(
+                hi, (q_offset + (iq + 1) * block_q - 1) // block_k
+            )
+        return jnp.maximum(jnp.minimum(ik, hi), 0)
+
+    return k_block
+
+
+def _q_slot(band_lo, nq, block_q, block_k, causal, q_offset):
+    """:func:`_k_slot`'s mirror for the dk/dv kernel, which walks Q per K
+    block: its dead slots come first (queries before the k block), so a
+    slot is held at or after the first q block that sees ``ik``."""
+    if band_lo is None and not causal:
+        return lambda ik, j: j
+
+    def q_block(ik, j):
+        iq = j if band_lo is None else band_lo(ik) + j
+        lo = 0
+        if causal:
+            lo = jnp.maximum(lo, (ik * block_k - q_offset) // block_q)
+        return jnp.minimum(jnp.maximum(iq, lo), nq - 1)
+
+    return q_block
 
 
 def _pick_block(requested: int, T: int) -> int:
@@ -166,101 +234,255 @@ def _pick_block(requested: int, T: int) -> int:
     return b if T % b == 0 else T
 
 
+#: ``(block_q, block_k)`` where the caller names none, and for dk/dv where
+#: nothing but the causal mask acts on the scores and a row of an operand
+#: (``D`` elements) has at most ``_DKV_CAUSAL_ROW_BYTES``.
+_TILES = (512, 1024)
+_DKV_CAUSAL_TILES = (1024, 1024)
+_DKV_CAUSAL_ROW_BYTES = 512
+
+
+def _geometry(Tq, Tk, *, walks, causal, bare=True, row_bytes=0, window=None,
+              q_offset=0, block_q=None, block_k=None):
+    """``(block_q, block_k, sub)`` of a kernel, from what a call shows:
+    the lengths, the mask kind (``bare``: no segment ids and no bias),
+    the bytes of an operand's row (head width times item size; only
+    dk/dv's tile depends on ``bare`` and ``row_bytes``) and which axis
+    the kernel walks (``walks='k'``: forward and dq; ``walks='q'``:
+    dk/dv).
+
+    On the v5e what a grid step costs hardly falls with its tile
+    (PERF.md, PR 24: at T 1024 sixteen steps of 256 x 256 a head take
+    2.3 times as long as two of 512 x 1024 that compute the whole
+    square), so the tiles stay long and, where K is walked, the triangle
+    is walked *inside* a tile, in sub-tiles of ``sub`` keys: one grid
+    step computes the sub-tiles the mask leaves alive and builds a mask
+    for the one the diagonal crosses. ``sub`` is ``block_q``, which is
+    what confines the diagonal to one sub-tile; where that cannot be (a
+    window, a ``q_offset`` that is no multiple of it, a ``block_k`` it
+    does not divide) the tile is its own single sub-tile and is masked
+    whole, as every tile once was. dk/dv measured fastest on tiles it
+    does not cut up (in the LM cells a 1024 x 512 tile walked in halves
+    took 1.01 times the whole 1024 x 1024 at B 4 and 1.35 times at B 16),
+    so its ``sub`` is the tile: it skips and unmasks whole tiles only.
+    That tile is 1024 x 1024 under a bare causal mask and no window, for
+    rows of up to 512 bytes (heads of 256 in bf16, of 128 in f32).
+    Segment ids, a bias (and its gradient's tile), a window or wider
+    rows add to what a tile holds in VMEM: Mosaic refuses 1024 x 1024
+    with a bias gradient or with f32 heads of 256 (PERF.md, PR 24).
+    There, and with no mask to skip by, the tile stays 512 x 1024. A
+    caller's ``block_q`` / ``block_k`` are taken as given
+    (``_pick_block`` still makes them divide the lengths)."""
+    whole = (walks == "q" and causal and bare and window is None
+             and row_bytes <= _DKV_CAUSAL_ROW_BYTES)
+    derived = _DKV_CAUSAL_TILES if whole else _TILES
+    block_q = _pick_block(block_q or derived[0], Tq)
+    block_k = _pick_block(block_k or derived[1], Tk)
+    nests = (walks == "k" and causal and window is None
+             and block_k % block_q == 0 and q_offset % block_q == 0)
+    return block_q, block_k, block_q if nests else block_k
+
+
+#: which axis each kernel walks, for :func:`_geometry`
+_WALKS = {train_path.FLASH_FWD: "k", train_path.FLASH_BWD_DQ: "k",
+          train_path.FLASH_BWD_DKV: "q"}
+
+
+def _publish_tiles(kernels, Tq, Tk, *, causal, window=None, q_offset=0,
+                   **geometry):
+    """Set the ``flash_tiles`` gauge for each of ``kernels`` from a call's
+    static arguments, in the sub-tiles the kernel skips and masks by:
+    those of one head's square (``total``), those computed (``visited``)
+    and those of them that build the causal mask (``masked``). The public
+    entry points call it outside any jit of this module, so every call
+    of theirs sets it, which for a jitted step is while the step is
+    traced; the last call is what a scrape sees."""
+    from chainermn_tpu.observability.metrics import registry
+
+    gauge = registry().gauge(
+        train_path.FLASH_TILES,
+        "sub-tiles of one head's score square in the last call of each "
+        "flash kernel: total, visited (computed) and masked (visited and "
+        "crossed by the diagonal or a window edge)",
+    )
+    for kernel in kernels:
+        unit_q, _, unit_k = _geometry(
+            Tq, Tk, walks=_WALKS[kernel], causal=causal, window=window,
+            q_offset=q_offset, **geometry)
+        nq, nk = Tq // unit_q, Tk // unit_k
+        live, full = _tile_class(
+            np.arange(nk)[None, :], np.arange(nq)[:, None], unit_q, unit_k,
+            causal, window, q_offset,
+        )
+        live = np.broadcast_to(live, (nq, nk))
+        masked = live & ~np.broadcast_to(full, (nq, nk))
+        for kind, n in (("total", nq * nk), ("visited", live.sum()),
+                        ("masked", masked.sum())):
+            gauge.set(float(n), kernel=kernel, kind=kind)
+
+
+def _pieces(index, sub, block_k):
+    """The ``(start, stop, masked)`` stretches of a tile's keys that
+    branch ``index`` computes. Branch 0 is the tile every pair of which
+    is allowed: all of it, unmasked. Branch ``n >= 1`` is the tile the
+    diagonal crosses in its ``n``-th sub-tile, which is masked; the
+    sub-tiles before it make one unmasked stretch and those after it are
+    skipped."""
+    if index == 0:
+        return [(0, block_k, False)]
+    lo = (index - 1) * sub
+    return ([(0, lo, False)] if lo else []) + [(lo, lo + sub, True)]
+
+
+def _visit(live, index, branches, causal, tile):
+    """Run ``tile(pieces)`` with the pieces of the branch ``index`` picks
+    (:func:`_pieces`), on a live tile."""
+    if not causal:
+        tile(branches[0])
+        return
+    pl.when(live)(lambda: lax.switch(
+        index, [functools.partial(tile, pieces) for pieces in branches]
+    ))
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
-def _seg_mask(sq_ref, sk_ref):
-    """Segment mask from the per-block segment-id refs: attention is
-    allowed only within the same packed segment.
+def _scores(q, k, bias_ref, seg_refs, masked, q0, k0, cols, *, scale, window):
+    """One head's masked f32 scores of a piece of a tile: ``q k^T``
+    scaled, plus the bias, ``NEG_INF`` where the segment ids differ and,
+    on a ``masked`` piece, where the causal mask forbids. ``cols`` is the
+    piece's slice of the tile's keys (for the bias and the segment ids,
+    which arrive tile-sized), ``q0`` / ``k0`` the tile's first global
+    positions.
 
-    The q-ids ref is ``[1, block_q, 1]`` and the kv-ids ref
-    ``[1, 1, block_k]`` — the host side stores ids as ``[B, T, 1]`` /
-    ``[B, 1, T]`` so every Mosaic tile is (major divisible-by-8-or-full,
-    minor 1-or-divisible-by-128)-legal AND arrives already column/row
-    shaped: the mask is one VPU broadcast-compare, no in-kernel
-    transpose. A flat ``[B, T]`` layout with ``(1, block)`` tiles is
-    rejected by the Mosaic lowering (sublane dim 1 ≠ B) — caught on
-    hardware by the bench kernel sweep; interpret mode accepts it."""
-    sq = sq_ref[0]  # [block_q, 1]
-    sk = sk_ref[0]  # [1, block_k]
-    return sq == sk
+    The segment-id refs are ``[1, block_q, 1]`` and ``[1, 1, block_k]``
+    — the host side stores ids as ``[B, T, 1]`` / ``[B, 1, T]`` so every
+    Mosaic tile is (major divisible-by-8-or-full, minor
+    1-or-divisible-by-128)-legal AND arrives already column/row shaped:
+    the mask is one VPU broadcast-compare, no in-kernel transpose. A flat
+    ``[B, T]`` layout with ``(1, block)`` tiles is rejected by the Mosaic
+    lowering (sublane dim 1 ≠ B) — caught on hardware by the bench kernel
+    sweep; interpret mode accepts it."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    if bias_ref is not None:
+        s = s + bias_ref[0, 0, :, cols].astype(jnp.float32)
+    if seg_refs is not None:
+        sq_ref, sk_ref = seg_refs
+        s = jnp.where(sq_ref[0] == sk_ref[0, :, cols], s, NEG_INF)
+    if masked:
+        s = jnp.where(
+            _causal_mask(q0, k0 + (cols.start or 0), s.shape, window),
+            s, NEG_INF)
+    return s
+
+
+def _walk(iq, ik, block_q, block_k, sub, causal, window, q_offset):
+    """``(q0, k0, live, index, branches)`` of tile ``(iq, ik)``: its first
+    global positions, whether anything of it is computed, and the branch
+    (a list of :func:`_pieces`) ``index`` picks: 0 where every pair is
+    allowed, else the number of sub-tiles up to and with the one the
+    diagonal crosses (1, the whole tile masked, where the tile is its
+    own sub-tile)."""
+    q0 = q_offset + iq * block_q
+    k0 = ik * block_k
+    n_sub = block_k // sub
+    live, full = _tile_class(ik, iq, block_q, block_k, causal, window,
+                             q_offset)
+    index = 0
+    if causal:
+        crossed = jnp.clip((q0 + block_q - 1 - k0) // sub + 1, 1, n_sub)
+        index = jnp.where(full, 0, crossed)
+    branches = [_pieces(i, sub, block_k) for i in range(n_sub + 1)]
+    return q0, k0, live, index, branches
 
 
 def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
-              acc_ref, m_ref, l_ref, *,
-              scale: float, causal: bool, block_q: int, block_k: int,
-              num_k_blocks: int, window=None, band_lo=None, nk_total=None,
-              q_offset: int = 0):
+              acc_ref, m_ref, l_ref, *, scale: float, causal: bool,
+              block_q: int, block_k: int, sub: int, num_k_blocks: int,
+              window=None, band_lo=None, nk_total=None, q_offset: int = 0):
     iq = pl.program_id(2)
     j = pl.program_id(3)
     # Banded grid: slot j covers TRUE k block band_lo(iq) + j; slots
     # falling outside [0, nk_total) are dead padding.
     ik = j if band_lo is None else band_lo(iq) + j
+    # One k slot a row: nothing to merge, so no running statistics are
+    # kept (the same sums, with the factor exp(-inf) = 0 on nothing).
+    single = num_k_blocks == 1
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _emit(acc, m, l):
+        o_ref[0, 0] = jnp.where(
+            l > 0, acc / jnp.maximum(l, 1e-37), 0.0
+        ).astype(o_ref.dtype)
+        # LSE in the scaled-score domain; fully-masked rows stay NEG_INF.
+        lse_ref[0, 0] = jnp.where(
+            l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), NEG_INF
+        )
 
-    live = _live(ik, iq, block_q, block_k, causal, window, q_offset)
+    if not single:
+        @pl.when(j == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+    q0, k0, live, index, branches = _walk(
+        iq, ik, block_q, block_k, sub, causal, window, q_offset)
     if band_lo is not None:
         live &= (ik >= 0) & (ik < nk_total)
 
-    @pl.when(live)
-    def _accumulate():
-        q = q_ref[0, 0]  # [block_q, D]
-        k = k_ref[0, 0]  # [block_k, D]
-        v = v_ref[0, 0]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [block_q, block_k]
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-
-        mask = None
-        if causal:
-            mask = _causal_mask(iq, ik, block_q, block_k, s.shape, window,
-                                q_offset)
-        if seg_refs is not None:
-            sm = _seg_mask(*seg_refs)
-            mask = sm if mask is None else mask & sm
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[:, 0:1]  # [block_q, 1]
-        l_prev = l_ref[:, 0:1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # Guard fully-masked ROWS: with every score NEG_INF, exp(s - m_new)
-        # would be exp(0) = 1 per entry; the mask re-zeroes them.
-        p = jnp.exp(s - m_new)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
+    def _accumulate(pieces):
+        q = q_ref[0, 0]
+        scores = [
+            _scores(q, k_ref[0, 0, a:b], bias_ref, seg_refs, masked, q0, k0,
+                    slice(a, b), scale=scale, window=window)
+            for a, b, masked in pieces
+        ]
+        m_new = functools.reduce(jnp.maximum, [
+            jnp.max(s, axis=1, keepdims=True) for s in scores
+        ])  # [block_q, 1]
+        if not single:
+            m_prev = m_ref[:, 0:1]
+            m_new = jnp.maximum(m_prev, m_new)
+        # A masked score is NEG_INF and exp(NEG_INF - m) is exactly 0,
+        # except in a row that has seen nothing yet: there m is NEG_INF
+        # too and the difference 0. Subtract 0 in such a row.
+        m_sub = jnp.where(m_new > NEG_INF, m_new, 0.0)
+        l_new = acc = 0.0
+        for s, (a, b, _) in zip(scores, pieces):
+            p = jnp.exp(s - m_sub)
+            l_new += jnp.sum(p, axis=1, keepdims=True)
+            v = v_ref[0, 0, a:b]
+            acc += jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        if single:
+            _emit(acc, m_new, l_new)
+            return
         corr = jnp.exp(m_prev - m_new)  # [block_q, 1]
-        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] = acc_ref[...] * corr + acc
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_ref[:, 0:1] * corr + l_new,
+                                      l_ref.shape)
+
+    _visit(live, index, branches, causal, _accumulate)
+
+    if single:
+        if causal:
+            @pl.when(jnp.logical_not(live))
+            def _nothing_visible():
+                o_ref[...] = jnp.zeros_like(o_ref)
+                lse_ref[...] = jnp.full_like(lse_ref, NEG_INF)
+        return
 
     @pl.when(j == num_k_blocks - 1)
     def _finalize():
-        m = m_ref[:, 0:1]
-        l = l_ref[:, 0:1]
-        o_ref[0, 0] = jnp.where(
-            l > 0, acc_ref[...] / jnp.maximum(l, 1e-37), 0.0
-        ).astype(o_ref.dtype)
-        # LSE in the scaled-score domain; fully-masked rows stay NEG_INF.
-        lse = jnp.where(
-            l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), NEG_INF
-        )  # [block_q, 1]
-        lse_ref[0, 0] = lse
+        _emit(acc_ref[...], m_ref[:, 0:1], l_ref[:, 0:1])
 
 
 def _group(Hq: int, Hkv: int) -> int:
@@ -291,18 +513,18 @@ def _bias_spec(bias, block_q, block_k, swap=False, k_of=None, q_of=None):
     """BlockSpec for an additive bias ``[B|1, H|1, Tq, Tk]`` — size-1
     leading dims broadcast via the index map. ``swap=True`` for grids
     whose 3rd/4th program ids are (ik, iq) instead of (iq, ik).
-    ``k_of(iq, j)`` / ``q_of(ik, j)`` translate a banded-grid slot to the
-    true (clipped) block index."""
+    ``k_of(iq, j)`` / ``q_of(ik, j)`` translate a grid slot to the true
+    (clipped) block index."""
     bb = 0 if bias.shape[0] == 1 else None
     bh = 0 if bias.shape[1] == 1 else None
 
     def idx(b, h, i, j):
         if swap:
             ik = i
-            iq = q_of(i, j) if q_of is not None else j
+            iq = q_of(i, j)
         else:
             iq = i
-            ik = k_of(i, j) if k_of is not None else j
+            ik = k_of(i, j)
         return (bb if bb is not None else b,
                 bh if bh is not None else h, iq, ik)
 
@@ -312,18 +534,21 @@ def _bias_spec(bias, block_q, block_k, swap=False, k_of=None, q_of=None):
 def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
                     scale, block_q, block_k, interpret, window=None,
                     q_offset=0):
-    """BHTD forward → (out [B,H,Tq,D], lse [B,H,Tq]).
+    """BHTD forward → (out [B,H,Tq,D], lse [B,H,Tq,1]).
 
     ``k``/``v`` may carry FEWER heads than ``q`` (GQA/MQA): kv head
     ``h // g`` serves q head ``h`` via the BlockSpec index map — no
-    materialized ``jnp.repeat``. ``seg_q``/``seg_k`` are optional
-    ``[B, T]`` int32 packed-segment ids; ``bias`` an optional additive
-    ``[B|1, H|1, Tq, Tk]`` score bias (ALiBi etc.), tiled per block."""
+    materialized ``jnp.repeat``. ``seg_q``/``seg_k`` are optional ``[B, T]``
+    int32 packed-segment ids; ``bias`` an optional additive
+    ``[B|1, H|1, Tq, Tk]`` score bias (ALiBi etc.), tiled per block.
+    ``block_q``/``block_k`` of None are derived (:func:`_geometry`)."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     g = _group(H, k.shape[1])
-    block_q = _pick_block(block_q, Tq)
-    block_k = _pick_block(block_k, Tk)
+    block_q, block_k, sub = _geometry(
+        Tq, Tk, walks="k", causal=causal, window=window, q_offset=q_offset,
+        block_q=block_q, block_k=block_k,
+    )
     nq, nk = Tq // block_q, Tk // block_k
 
     # Banded grid: with a sliding window, only `span` k-block slots per
@@ -336,18 +561,20 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
         if span < nk:
             band_lo, grid_k = lo, span
 
-    k_block = _clipped_slot(band_lo, nk)
+    k_block = _k_slot(band_lo, nk, block_q, block_k, causal, q_offset)
 
-    params = dict(scale=scale, causal=causal,
-                  block_q=block_q, block_k=block_k, num_k_blocks=grid_k,
+    params = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, sub=sub, num_k_blocks=grid_k,
                   window=window, band_lo=band_lo, nk_total=nk,
                   q_offset=q_offset)
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_k, D),
+        lambda b, h, iq, j: (b, h // g, k_block(iq, j), 0),
+    )
     in_specs = [
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, h, iq, j: (b, h // g, k_block(iq, j), 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, h, iq, j: (b, h // g, k_block(iq, j), 0)),
+        kv_spec,
+        kv_spec,
     ]
     has_segments = seg_q is not None
     has_bias = bias is not None
@@ -369,9 +596,9 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
         seg_refs, bias_ref, rest = _split_refs(
             refs, 3, has_segments, has_bias
         )
-        o_ref, lse_ref, acc, m, l = rest
+        o_ref, lse_ref, *scratch = rest
         _fwd_body(refs[0], refs[1], refs[2], seg_refs, bias_ref,
-                  o_ref, lse_ref, acc, m, l, **params)
+                  o_ref, lse_ref, *(scratch or (None,) * 3), **params)
 
     with jax.named_scope(train_path.FLASH_FWD):
         return pl.pallas_call(
@@ -390,7 +617,7 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
                 jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
                 jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
             ],
-            scratch_shapes=[
+            scratch_shapes=[] if grid_k == 1 else [
                 pltpu.VMEM((block_q, D), jnp.float32),      # acc
                 pltpu.VMEM((block_q, _LANES), jnp.float32),  # m
                 pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
@@ -404,58 +631,61 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
-                 bias_ref, dq_ref, dq_acc, *,
-                 scale: float, causal: bool, block_q: int, block_k: int,
-                 num_k_blocks: int, window=None, band_lo=None,
-                 nk_total=None, q_offset: int = 0):
+                 bias_ref, dq_ref, dq_acc, *, scale: float, causal: bool,
+                 block_q: int, block_k: int, sub: int, num_k_blocks: int,
+                 window=None, band_lo=None, nk_total=None,
+                 q_offset: int = 0):
     iq = pl.program_id(2)
     j = pl.program_id(3)
     ik = j if band_lo is None else band_lo(iq) + j
+    single = num_k_blocks == 1  # one k slot a row: nothing to add up
 
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+    if not single:
+        @pl.when(j == 0)
+        def _init():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    live = _live(ik, iq, block_q, block_k, causal, window, q_offset)
+    q0, k0, live, index, branches = _walk(
+        iq, ik, block_q, block_k, sub, causal, window, q_offset)
     if band_lo is not None:
         live &= (ik >= 0) & (ik < nk_total)
 
-    @pl.when(live)
-    def _accumulate():
+    def _accumulate(pieces):
         q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
         do = do_ref[0, 0].astype(jnp.float32)
         lse = lse_ref[0, 0]    # [block_q, 1]
         delta = delta_ref[0, 0]
+        dq = 0.0
+        for a, b, masked in pieces:
+            k = k_ref[0, 0, a:b]
+            s = _scores(q, k, bias_ref, seg_refs, masked, q0, k0,
+                        slice(a, b), scale=scale, window=window)
+            # p from the saved LSE: exp(NEG_INF - lse) underflows to
+            # exactly 0, so masked/never-attended entries contribute
+            # nothing.
+            p = jnp.exp(s - lse)
+            dp = jax.lax.dot_general(
+                do, v_ref[0, 0, a:b], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [block_q, b - a]
+            ds = p * (dp - delta) * scale
+            dq += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        if single:
+            dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+        else:
+            dq_acc[...] += dq
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        mask = None
+    _visit(live, index, branches, causal, _accumulate)
+
+    if single:
         if causal:
-            mask = _causal_mask(iq, ik, block_q, block_k, s.shape, window,
-                                q_offset)
-        if seg_refs is not None:
-            sm = _seg_mask(*seg_refs)
-            mask = sm if mask is None else mask & sm
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        # p from the saved LSE: exp(NEG_INF - lse) underflows to exactly 0,
-        # so masked/never-attended entries contribute nothing.
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, block_k]
-        ds = p * (dp - delta) * scale
-        dq_acc[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            @pl.when(jnp.logical_not(live))
+            def _nothing_visible():
+                dq_ref[...] = jnp.zeros_like(dq_ref)
+        return
 
     @pl.when(j == num_k_blocks - 1)
     def _finalize():
@@ -474,13 +704,16 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
     ik = pl.program_id(2)
     j = pl.program_id(3)
     iq = j if band_lo is None else band_lo(ik) + j
+    single = num_q_blocks == 1  # one q slot a column: nothing to add up
 
-    @pl.when(j == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+    if not single:
+        @pl.when(j == 0)
+        def _init():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    live = _live(ik, iq, block_q, block_k, causal, window, q_offset)
+    q0, k0, live, index, branches = _walk(
+        iq, ik, block_q, block_k, block_k, causal, window, q_offset)
     if band_lo is not None:
         # With q_offset > 0 the low end can undershoot too.
         live &= (iq >= 0) & (iq < nq_total)
@@ -491,35 +724,19 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
         # are not pre-zeroed.
         @pl.when(jnp.logical_not(live))
         def _zero_dbias():
-            dbias_ref[0, 0] = jnp.zeros_like(dbias_ref[0, 0])
+            dbias_ref[...] = jnp.zeros_like(dbias_ref)
 
-    @pl.when(live)
-    def _accumulate():
+    def _accumulate(pieces):
+        (_, _, masked), = pieces  # the whole tile, masked or not
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]    # [block_q, 1]
-        delta = delta_ref[0, 0]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        mask = None
-        if causal:
-            mask = _causal_mask(iq, ik, block_q, block_k, s.shape, window,
-                                q_offset)
-        if seg_refs is not None:
-            sm = _seg_mask(*seg_refs)
-            mask = sm if mask is None else mask & sm
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)  # [block_q, block_k]
+        s = _scores(q, k, bias_ref, seg_refs, masked, q0, k0, slice(None),
+                    scale=scale, window=window)
+        p = jnp.exp(s - lse_ref[0, 0])  # [block_q, block_k]
         # dv += p^T @ do
-        dv_acc[...] += jax.lax.dot_general(
+        dv = jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -527,17 +744,33 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds_unscaled = p * (dp - delta)  # d loss / d s_total
+        ds_unscaled = p * (dp - delta_ref[0, 0])  # d loss / d s_total
         if dbias_ref is not None:
             # dbias tile == ds before the qk-scale factor (the bias adds
             # AFTER the scale multiplies q·k).
             dbias_ref[0, 0] = ds_unscaled.astype(dbias_ref.dtype)
         ds = ds_unscaled * scale  # [block_q, block_k]
         # dk += ds^T @ q
-        dk_acc[...] += jax.lax.dot_general(
+        dk = jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if single:
+            dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+            dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+        else:
+            dk_acc[...] += dk
+            dv_acc[...] += dv
+
+    _visit(live, index, branches, causal, _accumulate)
+
+    if single:
+        if causal:
+            @pl.when(jnp.logical_not(live))
+            def _nothing_visible():
+                dk_ref[...] = jnp.zeros_like(dk_ref)
+                dv_ref[...] = jnp.zeros_like(dv_ref)
+        return
 
     @pl.when(j == num_q_blocks - 1)
     def _finalize():
@@ -560,68 +793,61 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
     B, H, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     g = _group(H, Hkv)
-    block_q = _pick_block(block_q, Tq)
-    block_k = _pick_block(block_k, Tk)
-    nq, nk = Tq // block_q, Tk // block_k
     has_segments = seg_q is not None
     has_bias = bias is not None
     assert not (want_dbias and not has_bias)
+    geometry = functools.partial(
+        _geometry, Tq, Tk, causal=causal, window=window, q_offset=q_offset,
+        bare=not (has_segments or has_bias),
+        row_bytes=D * q.dtype.itemsize, block_q=block_q, block_k=block_k,
+    )
+    seg_args = ((seg_q[:, :, None], seg_k[:, None, :]) if has_segments
+                else ())
+    bias_args = (bias,) if has_bias else ()
 
-    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0))
-
+    # -- dq: K blocks per Q block -------------------------------------
+    block_q, block_k, sub = geometry(walks="k")
+    nq, nk = Tq // block_q, Tk // block_k
     # Banded grids (see _flash_fwd_bhtd): dq iterates only the k blocks in
     # the window band; dk/dv only the q blocks that can see this k block.
-    # want_dbias forces the full grid — its output tiles every (iq, ik).
-    k_band_lo = None
+    band_lo = None
     grid_k = nk
-    q_band_lo = None
-    grid_q = nq
     if causal and window is not None:
         span_k, lo_k = _band_k(block_q, block_k, window, nk, q_offset)
         if span_k < nk:
-            k_band_lo, grid_k = lo_k, span_k
-        if not want_dbias:
-            span_q, lo_q = _band_q(block_q, block_k, window, nq, q_offset)
-            if span_q < nq:
-                q_band_lo, grid_q = lo_q, span_q
-
-    k_block = _clipped_slot(k_band_lo, nk)
-    q_block = _clipped_slot(q_band_lo, nq)
-
-    dq_params = dict(scale=scale, causal=causal,
-                     block_q=block_q, block_k=block_k, num_k_blocks=grid_k,
-                     window=window, band_lo=k_band_lo, nk_total=nk,
-                     q_offset=q_offset)
-    dq_in_specs = [
-        q_spec,
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, h, i, j: (b, h // g, k_block(i, j), 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, h, i, j: (b, h // g, k_block(i, j), 0)),
-        q_spec,
-        row_spec,
-        row_spec,
-    ]
-    dq_args = (q, k, v, do, lse, delta)
+            band_lo, grid_k = lo_k, span_k
+    k_block = _k_slot(band_lo, nk, block_q, block_k, causal, q_offset)
+    dq_params = dict(scale=scale, causal=causal, block_q=block_q,
+                     block_k=block_k, sub=sub, window=window,
+                     q_offset=q_offset, num_k_blocks=grid_k,
+                     band_lo=band_lo, nk_total=nk)
+    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
+    row_spec = pl.BlockSpec((1, 1, block_q, 1),
+                            lambda b, h, i, j: (b, h, i, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_k, D),
+        lambda b, h, i, j: (b, h // g, k_block(i, j), 0),
+    )
+    dq_in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
     if has_segments:
         dq_in_specs += [
             pl.BlockSpec((1, block_q, 1), lambda b, h, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_k),
                          lambda b, h, i, j: (b, 0, k_block(i, j))),
         ]
-        dq_args += (seg_q[:, :, None], seg_k[:, None, :])
     if has_bias:
-        dq_in_specs.append(_bias_spec(bias, block_q, block_k, k_of=k_block))
-        dq_args += (bias,)
+        dq_in_specs.append(
+            _bias_spec(bias, block_q, block_k, k_of=k_block)
+        )
 
     def dq_kernel(*refs):
         seg_refs, bias_ref, rest = _split_refs(
             refs, 6, has_segments, has_bias
         )
-        dq_ref, dq_acc = rest
+        dq_ref, *dq_acc = rest
         _bwd_dq_body(refs[0], refs[1], refs[2], refs[3], refs[4], refs[5],
-                     seg_refs, bias_ref, dq_ref, dq_acc, **dq_params)
+                     seg_refs, bias_ref, dq_ref, *(dq_acc or (None,)),
+                     **dq_params)
 
     with jax.named_scope(train_path.FLASH_BWD_DQ):
         dq = pl.pallas_call(
@@ -632,46 +858,52 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
             in_specs=dq_in_specs,
             out_specs=q_spec,
             out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            scratch_shapes=[] if grid_k == 1 else [
+                pltpu.VMEM((block_q, D), jnp.float32)],
             interpret=interpret,
-        )(*dq_args)
+        )(q, k, v, do, lse, delta, *seg_args, *bias_args)
 
-    # dk/dv grid iterates Q heads; with GQA each q head writes its own
+    # -- dk/dv: Q blocks per K block ----------------------------------
+    # The grid iterates Q heads; with GQA each q head writes its own
     # [B, H, Tk, D] slot (no cross-head accumulation inside the grid) and
     # the group sum happens below. Grid program ids here are (ik, iq).
+    # want_dbias forces the full grid — its output tiles every (iq, ik),
+    # written at its grid slot.
+    block_q, block_k, _ = geometry(walks="q")
+    nq, nk = Tq // block_q, Tk // block_k
+    band_lo = None
+    grid_q = nq
+    if causal and window is not None and not want_dbias:
+        span_q, lo_q = _band_q(block_q, block_k, window, nq, q_offset)
+        if span_q < nq:
+            band_lo, grid_q = lo_q, span_q
+    q_block = _q_slot(band_lo, nq, block_q, block_k,
+                      causal and not want_dbias, q_offset)
+    dkv_params = dict(scale=scale, causal=causal, block_q=block_q,
+                      block_k=block_k, window=window,
+                      q_offset=q_offset, num_q_blocks=grid_q,
+                      band_lo=band_lo, nq_total=nq)
     k_spec_in = pl.BlockSpec((1, 1, block_k, D),
                              lambda b, h, i, j: (b, h // g, i, 0))
     k_spec_out = pl.BlockSpec((1, 1, block_k, D),
                               lambda b, h, i, j: (b, h, i, 0))
-    dkv_params = dict(scale=scale, causal=causal,
-                      block_q=block_q, block_k=block_k, num_q_blocks=grid_q,
-                      window=window, band_lo=q_band_lo, nq_total=nq,
-                      q_offset=q_offset)
-    dkv_in_specs = [
-        pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, h, i, j: (b, h, q_block(i, j), 0)),
-        k_spec_in,
-        k_spec_in,
-        pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, h, i, j: (b, h, q_block(i, j), 0)),
-        pl.BlockSpec((1, 1, block_q, 1),
-                     lambda b, h, i, j: (b, h, q_block(i, j), 0)),
-        pl.BlockSpec((1, 1, block_q, 1),
-                     lambda b, h, i, j: (b, h, q_block(i, j), 0)),
-    ]
-    dkv_args = (q, k, v, do, lse, delta)
+    q_spec_in = pl.BlockSpec((1, 1, block_q, D),
+                             lambda b, h, i, j: (b, h, q_block(i, j), 0))
+    row_spec_in = pl.BlockSpec((1, 1, block_q, 1),
+                               lambda b, h, i, j: (b, h, q_block(i, j), 0))
+    dkv_in_specs = [q_spec_in, k_spec_in, k_spec_in, q_spec_in,
+                    row_spec_in, row_spec_in]
     if has_segments:
         dkv_in_specs += [
             pl.BlockSpec((1, block_q, 1),
                          lambda b, h, i, j: (b, q_block(i, j), 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, i)),
         ]
-        dkv_args += (seg_q[:, :, None], seg_k[:, None, :])
     if has_bias:
         dkv_in_specs.append(
-            _bias_spec(bias, block_q, block_k, swap=True, q_of=q_block)
+            _bias_spec(bias, block_q, block_k, swap=True,
+                       q_of=q_block)
         )
-        dkv_args += (bias,)
 
     out_specs = [k_spec_out, k_spec_out]
     out_shape = [
@@ -691,14 +923,11 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
         seg_refs, bias_ref, rest = _split_refs(
             refs, 6, has_segments, has_bias
         )
-        if want_dbias:
-            dk_ref, dv_ref, dbias_ref, dk_acc, dv_acc = rest
-        else:
-            dk_ref, dv_ref, dk_acc, dv_acc = rest
-            dbias_ref = None
+        dk_ref, dv_ref, *rest = rest
+        dbias_ref = rest.pop(0) if want_dbias else None
         _bwd_dkv_body(refs[0], refs[1], refs[2], refs[3], refs[4], refs[5],
                       seg_refs, bias_ref, dk_ref, dv_ref, dbias_ref,
-                      dk_acc, dv_acc, **dkv_params)
+                      *(rest or (None, None)), **dkv_params)
 
     with jax.named_scope(train_path.FLASH_BWD_DKV):
         res = pl.pallas_call(
@@ -709,12 +938,12 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
             in_specs=dkv_in_specs,
             out_specs=out_specs,
             out_shape=out_shape,
-            scratch_shapes=[
+            scratch_shapes=[] if grid_q == 1 else [
                 pltpu.VMEM((block_k, D), jnp.float32),
                 pltpu.VMEM((block_k, D), jnp.float32),
             ],
             interpret=interpret,
-        )(*dkv_args)
+        )(q, k, v, do, lse, delta, *seg_args, *bias_args)
     if want_dbias:
         dk, dv, dbias = res
     else:
@@ -829,6 +1058,14 @@ def _flash_core_bwd(has_seg, has_bias, bias_grad, causal, scale, block_q,
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
+# A model calls the op once a layer with the same shapes. Under a jit of
+# its own those calls share one trace and one lowering of the kernels
+# (tracing a kernel body and lowering it for Mosaic is what a call costs
+# the host: without it 72 kernels a step, traced again by every program
+# that holds the model); XLA inlines the calls, so the compiled step is
+# the same program, each kernel under its call site's ``op_name``.
+_flash_call = jax.jit(_flash_core, static_argnums=tuple(range(5, 14)))
+
 
 def flash_attention(
     q: jax.Array,
@@ -841,8 +1078,8 @@ def flash_attention(
     bias: Optional[jax.Array] = None,
     bias_grad: bool = False,
     window: Optional[int] = None,
-    block_q: int = 512,
-    block_k: int = 1024,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Flash attention on ``[B, T, H, D]`` inputs, Pallas forward AND
@@ -877,6 +1114,10 @@ def flash_attention(
     exception: ``bias_grad=True`` forces the dk/dv kernel back to the
     full grid (its dbias output must tile every (iq, ik)).
 
+    ``block_q`` / ``block_k`` of None (the default) are derived from the
+    lengths and the mask kind (:func:`_geometry`); numbers are taken as
+    given, halved until they divide the lengths.
+
     On TPU the kernels compile via Mosaic; elsewhere (CPU tests) they run in
     Pallas interpreter mode unless ``interpret=False``.
     """
@@ -906,7 +1147,14 @@ def flash_attention(
     seg = (segment_ids.astype(jnp.int32) if has_seg
            else jnp.zeros((0,), jnp.int32))
     b = bias if has_bias else jnp.zeros((0,), q.dtype)
-    return _flash_core(q, k, v, seg, b, has_seg, has_bias, bias_grad,
+    # Here and not in the kernels' builders, which `_flash_call` traces
+    # once a shape: the backward's entries are what this call's gradient
+    # runs, whether or not one is taken.
+    _publish_tiles(_WALKS, q.shape[1], k.shape[1], causal=causal,
+                   window=window, bare=not (has_seg or has_bias),
+                   row_bytes=q.shape[3] * q.dtype.itemsize,
+                   block_q=block_q, block_k=block_k)
+    return _flash_call(q, k, v, seg, b, has_seg, has_bias, bias_grad,
                        causal, scale, block_q, block_k, interpret, window)
 
 
@@ -923,6 +1171,9 @@ def flash_block_fwd(q, k_blk, v_blk, *, causal, scale, block_q, block_k,
     (:func:`chainermn_tpu.parallel.ring_attention.merge_partials`).
     ``seg_q``/``seg_kv`` are the per-shard segment-id slices (the kv ids
     travel with their block around the ring)."""
+    _publish_tiles((train_path.FLASH_FWD,), q.shape[1], k_blk.shape[1],
+                   causal=causal, window=window, q_offset=q_offset,
+                   block_q=block_q, block_k=block_k)
     out, lse = _flash_fwd_bhtd(
         _to_bhtd(q), _to_bhtd(k_blk), _to_bhtd(v_blk), seg_q, seg_kv,
         causal=causal, window=window, q_offset=q_offset,
@@ -936,6 +1187,11 @@ def flash_block_bwd(q, k_blk, v_blk, do, lse, delta, *, causal, scale,
                     window=None, q_offset=0):
     """One ring step's backward: (dq, dk_blk, dv_blk) contributions for one
     K/V block, f32, BTHD (lse/delta are ``[B, H, Tq]``)."""
+    _publish_tiles((train_path.FLASH_BWD_DQ, train_path.FLASH_BWD_DKV),
+                   q.shape[1], k_blk.shape[1], causal=causal, window=window,
+                   q_offset=q_offset, bare=seg_q is None,
+                   row_bytes=q.shape[3] * q.dtype.itemsize,
+                   block_q=block_q, block_k=block_k)
     dq, dk, dv = _flash_bwd_bhtd(
         _to_bhtd(q), _to_bhtd(k_blk), _to_bhtd(v_blk), _to_bhtd(do),
         lse[..., None], delta[..., None], seg_q, seg_kv,

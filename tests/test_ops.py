@@ -426,3 +426,254 @@ def test_flash_window_with_trainable_bias():
         ),
         gf, gr,
     )
+
+
+# ---------------------------------------------------------------------------
+# Derived iteration geometry: the causal triangle, not the square
+# ---------------------------------------------------------------------------
+
+_FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+def _traced_tiles(T, D, H=16, causal=True, **kw):
+    """``{kernel: {kind: tiles}}`` of the ``flash_tiles`` gauge after
+    tracing (never running) a forward + backward at ``[1, T, H, D]``."""
+    from chainermn_tpu.observability import train_path
+    from chainermn_tpu.observability.metrics import registry
+
+    x = jax.ShapeDtypeStruct((1, T, H, D), jnp.bfloat16)
+    jax.eval_shape(
+        jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, interpret=True, **kw
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2)),
+        x, x, x,
+    )
+    gauge = registry().gauge(train_path.FLASH_TILES)
+    return {k: {kind: gauge.value(kernel=k, kind=kind)
+                for kind in ("total", "visited", "masked")}
+            for k in _FLASH_KERNELS}
+
+
+# (total, visited, masked) sub-tiles: of the kernels that walk K per Q
+# block (512 x 512 inside tiles of 512 x 1024), and of dk/dv (whole tiles
+# of 1024 x 1024). At the LM cells' length that is two sub-tiles a side
+# and one dk/dv tile: finer ones measured slower on the chip (PERF.md,
+# PR 24), which is why the shares there are 0.75 and 1 and not 0.625.
+@pytest.mark.parametrize("T,D,share,walks_k,walks_q", [
+    (1024, 64, 0.75, (4, 3, 2), (1, 1, 1)),
+    (2048, 128, 0.65, (16, 10, 4), (4, 3, 2)),
+    (4096, 128, 0.60, (64, 36, 8), (16, 10, 4)),
+    # 512 does not divide 768: sub-tiles of 256 in a tile of 768
+    (768, 64, 0.70, (9, 6, 3), (1, 1, 1)),
+])
+def test_flash_derived_geometry_walks_the_triangle(T, D, share, walks_k,
+                                                   walks_q):
+    got = {k: (v["total"], v["visited"], v["masked"])
+           for k, v in _traced_tiles(T, D).items()}
+    assert got == {"flash_fwd": walks_k, "flash_bwd_dq": walks_k,
+                   "flash_bwd_dkv": walks_q}
+    total, visited, masked = walks_k
+    assert visited / total <= share
+    # the masked sub-tiles are those the diagonal crosses: one a row
+    for total, visited, masked in (walks_k, walks_q):
+        assert masked ** 2 == total
+        assert visited == masked * (masked + 1) // 2
+
+
+def test_flash_tiles_follow_the_last_call_not_the_last_trace():
+    """The op sits in a jit of its own; a call whose trace that jit
+    already holds still sets the gauge."""
+    first = _traced_tiles(2048, 64)
+    assert _traced_tiles(1024, 64) != first
+    assert _traced_tiles(2048, 64) == first
+
+
+@pytest.mark.parametrize("name,walks_k,walks_q", [
+    # segment ids and a bias add tile-sized work to every visited tile,
+    # so dk/dv keeps the 512 x 1024 tile; K is walked in sub-tiles still
+    ("segments", (16, 10, 4), (8, 6, 4)),
+    ("bias", (16, 10, 4), (8, 6, 4)),
+    # a window: every kernel masks its crossed 512 x 1024 tiles whole
+    ("window", (8, 5, 5), (8, 5, 5)),
+])
+def test_flash_dkv_tile_is_whole_only_under_a_bare_causal_mask(
+        name, walks_k, walks_q):
+    T = 2048
+    kw = {"segments": dict(segment_ids=jnp.zeros((1, T), jnp.int32)),
+          "bias": dict(bias=jnp.zeros((1, 1, T, T), jnp.bfloat16)),
+          "window": dict(window=300)}[name]
+    got = {k: (v["total"], v["visited"], v["masked"])
+           for k, v in _traced_tiles(T, 64, H=2, **kw).items()}
+    assert got == {"flash_fwd": walks_k, "flash_bwd_dq": walks_k,
+                   "flash_bwd_dkv": walks_q}
+
+
+@pytest.mark.parametrize("kernel", _FLASH_KERNELS)
+def test_flash_noncausal_visits_every_tile_unmasked(kernel):
+    got = _traced_tiles(1024, 64, causal=False)[kernel]
+    assert got["visited"] == got["total"] >= 1
+    assert got["masked"] == 0
+
+
+@pytest.mark.parametrize("bq,bk,walks_k,walks_q", [
+    # the former default: K per Q block in halves of its 1024 keys, the
+    # dk/dv tile whole
+    (512, 1024, (4, 3, 2), (2, 2, 2)),
+    (128, 256, (64, 36, 8), (32, 20, 8)),
+    (256, 128, (32, 20, 8), (32, 20, 8)),
+    # no divisor of 1024: one whole-T tile
+    (48, 48, (1, 1, 1), (1, 1, 1)),
+])
+def test_flash_explicit_blocks_are_honoured(bq, bk, walks_k, walks_q):
+    """The tiles are the caller's; where K is walked the triangle is
+    walked inside them in sub-tiles of ``block_q`` keys, if that divides
+    ``block_k``."""
+    got = {k: (v["total"], v["visited"], v["masked"]) for k, v in
+           _traced_tiles(1024, 64, block_q=bq, block_k=bk).items()}
+    assert got == {"flash_fwd": walks_k, "flash_bwd_dq": walks_k,
+                   "flash_bwd_dkv": walks_q}
+
+
+def test_flash_geometry_from_the_shapes():
+    from chainermn_tpu.ops.flash_attention import _geometry
+
+    def geo(T, walks, **kw):
+        kw.setdefault("causal", True)
+        return _geometry(T, T, walks=walks, **kw)
+
+    # (block_q, block_k, sub): where K is walked, long along K with the
+    # triangle inside the tile in sub-tiles of block_q; dk/dv whole
+    assert geo(1024, "k") == (512, 1024, 512)
+    assert geo(1024, "q") == (1024, 1024, 1024)
+    assert geo(4096, "k") == (512, 1024, 512)
+    assert geo(4096, "q") == (1024, 1024, 1024)
+    assert geo(768, "k") == (256, 768, 256)
+    # a window or an offset off the sub-tile grid: the tile is masked
+    # whole
+    assert geo(1024, "k", window=300) == (512, 1024, 1024)
+    assert geo(1024, "k", q_offset=100) == (512, 1024, 1024)
+    assert geo(1024, "k", q_offset=512) == (512, 1024, 512)
+    # segment ids, a bias or a window: dk/dv keeps the former tile
+    assert geo(1024, "q", bare=False) == (512, 1024, 1024)
+    assert geo(1024, "q", window=300) == (512, 1024, 1024)
+    assert geo(1024, "k", bare=False) == (512, 1024, 512)
+    # rows wider than 512 bytes (f32 heads of 256): the same
+    assert geo(1024, "q", row_bytes=512) == (1024, 1024, 1024)
+    assert geo(1024, "q", row_bytes=1024) == (512, 1024, 1024)
+    # no mask to skip by: the fewest, largest steps
+    assert geo(4096, "k", causal=False) == (512, 1024, 1024)
+    assert geo(4096, "q", causal=False) == (512, 1024, 1024)
+    # a caller's numbers are taken as given
+    assert geo(4096, "k", block_q=256, block_k=512) == (256, 512, 256)
+    assert geo(4096, "q", block_q=256, block_k=512) == (256, 512, 512)
+
+
+def _dense_block(q, k, v, *, causal, scale, seg_q=None, seg_kv=None,
+                 window=None, q_offset=0):
+    """XLA reference of one flash block call: ``(out, lse)``, with the
+    mask handed to ``ops.attention`` as a bias."""
+    Tq, Tk = q.shape[1], k.shape[1]
+    i = q_offset + np.arange(Tq)[:, None]
+    j = np.arange(Tk)[None, :]
+    ok = np.ones((Tq, Tk), bool)
+    if causal:
+        ok &= j <= i
+        if window is not None:
+            ok &= i - j < window
+    ok = jnp.asarray(ok)[None, None]
+    if seg_q is not None:
+        ok = ok & (seg_q[:, None, :, None] == seg_kv[:, None, None, :])
+    bias = jnp.where(ok, 0.0, -1e30).astype(jnp.float32)
+    out = dot_product_attention(q, k, v, scale=scale, bias=bias)
+    g = q.shape[2] // k.shape[2]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, g, axis=2),
+                   precision="highest") * scale + bias
+    return out, jax.nn.logsumexp(s, axis=-1)
+
+
+_T1K = 1024
+
+
+def _block_case(name):
+    """Operands of one ``flash_block_fwd`` call at T 1024, D 64, B 1,
+    H 4 and the keywords of the variant ``name``."""
+    ks = jax.random.split(jax.random.PRNGKey(24), 4)
+    kv_heads = 2 if name == "gqa" else 4
+    Tk = _T1K + 256 if name == "q_offset" else _T1K
+    q = jax.random.normal(ks[0], (1, _T1K, 4, 64))
+    k = jax.random.normal(ks[1], (1, Tk, kv_heads, 64))
+    v = jax.random.normal(ks[2], (1, Tk, kv_heads, 64))
+    do = jax.random.normal(ks[3], (1, _T1K, 4, 64))
+    kw = dict(causal=True, scale=0.125)
+    if name == "segments":
+        seg = jnp.asarray(np.repeat([0, 1, 2, 3], [300, 212, 412, 100])
+                          [None].astype(np.int32))
+        kw.update(seg_q=seg, seg_kv=seg)
+    elif name == "window":
+        kw.update(window=300)
+    elif name == "q_offset":
+        kw.update(q_offset=256)
+    elif name == "noncausal":
+        kw.update(causal=False)
+    return (q, k, v, do), kw
+
+
+@pytest.mark.parametrize(
+    "name", ["causal", "gqa", "segments", "window", "q_offset",
+             "noncausal"])
+def test_flash_derived_geometry_matches_reference_and_former_tiles(name):
+    """Output, LSE and dq/dk/dv of the derived geometry (K walked in
+    sub-tiles of 512 inside tiles of 1024, dk/dv on one whole tile)
+    against XLA's attention and against the same
+    call under the former explicit 512 x 1024 tiles."""
+    from chainermn_tpu.ops.flash_attention import (
+        flash_block_bwd,
+        flash_block_fwd,
+    )
+
+    (q, k, v, do), kw = _block_case(name)
+
+    def flash(block_q, block_k):
+        blocks = dict(block_q=block_q, block_k=block_k, interpret=True)
+        out, lse = flash_block_fwd(q, k, v, **kw, **blocks)
+        delta = jnp.einsum("bqhd,bqhd->bhq", do, out)
+        return (out, lse) + flash_block_bwd(q, k, v, do, lse, delta, **kw,
+                                            **blocks)
+
+    (ref_out, ref_lse), vjp = jax.vjp(
+        lambda *a: _dense_block(*a, **kw), q, k, v)
+    ref = (ref_out, ref_lse) + vjp((do, jnp.zeros_like(ref_lse)))
+    derived = flash(None, None)
+    former = flash(512, 1024)
+    for what, a, b, c in zip(("out", "lse", "dq", "dk", "dv"), derived,
+                             former, ref):
+        np.testing.assert_allclose(a, c, rtol=2e-4, atol=2e-4,
+                                   err_msg=f"{what} against XLA")
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5,
+                                   err_msg=f"{what} against 512x1024")
+
+
+def test_flash_derived_geometry_bias_grad_matches_reference():
+    ks = jax.random.split(jax.random.PRNGKey(25), 4)
+    q, k, v = (jax.random.normal(kk, (1, _T1K, 4, 64)) for kk in ks[:3])
+    bias = 0.1 * jax.random.normal(ks[3], (1, 4, _T1K, _T1K))
+
+    def grads(attn):
+        return jax.grad(lambda *a: (attn(*a) ** 2).sum(),
+                        argnums=(0, 1, 2, 3))(q, k, v, bias)
+
+    def flash(**blocks):
+        return grads(lambda q, k, v, b: flash_attention(
+            q, k, v, causal=True, bias=b, bias_grad=True, interpret=True,
+            **blocks))
+
+    ref = grads(lambda q, k, v, b: dot_product_attention(
+        q, k, v, causal=True, bias=b))
+    derived = flash()
+    former = flash(block_q=512, block_k=1024)
+    for what, a, b, c in zip(("dq", "dk", "dv", "dbias"), derived, former,
+                             ref):
+        np.testing.assert_allclose(a, c, rtol=2e-4, atol=2e-4,
+                                   err_msg=f"{what} against XLA")
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5,
+                                   err_msg=f"{what} against 512x1024")
