@@ -14,7 +14,12 @@ from chainermn_tpu.models.seq2seq import (
     seq2seq_loss,
 )
 from chainermn_tpu.models.transformer import (
+    MODEL_CONFIGS,
+    Architecture,
     TransformerLM,
+    head_table,
+    lm_from_config,
+    lm_loss_moe,
     mlm_corrupt,
     mlm_loss,
     beam_search,
@@ -48,6 +53,11 @@ __all__ = [
     "greedy_decode",
     "seq2seq_loss",
     "TransformerLM",
+    "Architecture",
+    "MODEL_CONFIGS",
+    "lm_from_config",
+    "head_table",
+    "lm_loss_moe",
     "mlm_corrupt",
     "mlm_loss",
     "lm_loss",

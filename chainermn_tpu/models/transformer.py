@@ -14,6 +14,7 @@ ring_attention_local(q, k, v, 'seq', causal=causal, scale=scale)``).
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -22,6 +23,117 @@ import jax.numpy as jnp
 
 from chainermn_tpu.observability import train_path
 from chainermn_tpu.ops.attention import blockwise_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class Architecture:
+    """What kind of block a model is built from: everything about a
+    decoder layer that is a choice between published designs and not a
+    size. :class:`TransformerBlock` and :class:`TransformerLM` read it; the
+    sizes (layers, width, heads, vocabulary, positions) stay their own
+    fields. The defaults are the block this module always built (GPT-2's,
+    with the departures ``benchmark/configs/gpt2-medium.json`` lists), so a
+    model without a description is that instance and its parameter tree
+    and compiled step are what they were. :meth:`from_config` reads a
+    Hugging Face ``config.json``-style dict."""
+
+    #: ``'layernorm'`` (scale and bias) or ``'rmsnorm'`` (scale only);
+    #: statistics in float32 either way
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    #: ``'gelu'``: up, tanh GELU, down, both with a bias; ``'gated_silu'``:
+    #: ``down(silu(gate(x)) * up(x))`` without biases
+    ffn: str = "gelu"
+    #: RMSNorm over the whole query and key projections, before the split
+    #: into heads (OLMoE's QK-norm)
+    qk_norm: bool = False
+    #: ``'learned'`` absolute table or ``'rope'`` at ``rope_base``
+    positions: str = "learned"
+    rope_base: float = 10000.0
+    #: the head shares the embedding table, or has a ``lm_head`` of its own
+    tied_head: bool = True
+    #: dropless top-``experts_per_token`` of ``n_experts`` routing in every
+    #: block's feed-forward (0: dense), experts of ``ffn``'s kind and
+    #: ``expert_width``, gates the chosen experts' softmax probabilities,
+    #: renormalised over the chosen or not
+    n_experts: int = 0
+    experts_per_token: int = 0
+    expert_width: int = 0
+    renormalise_gates: bool = False
+
+    def __post_init__(self):
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got "
+                             f"{self.norm!r}")
+        if self.ffn not in ("gelu", "gated_silu"):
+            raise ValueError(f"ffn must be 'gelu' or 'gated_silu', got "
+                             f"{self.ffn!r}")
+        if self.positions not in ("learned", "rope"):
+            raise ValueError(f"positions must be 'learned' or 'rope', got "
+                             f"{self.positions!r}")
+        if self.n_experts and not (
+                0 < self.experts_per_token <= self.n_experts
+                and self.expert_width > 0 and self.ffn == "gated_silu"):
+            raise ValueError(
+                "a mixture of experts needs 0 < experts_per_token <= "
+                "n_experts, an expert_width and gated SiLU experts")
+
+    @classmethod
+    def from_config(cls, config: dict) -> "Architecture":
+        kind = config.get("model_type", "gpt2")
+        if kind == "gpt2":
+            return cls()
+        if kind == "olmoe":
+            if config.get("hidden_act", "silu") != "silu" or \
+                    config.get("attention_bias") or \
+                    config.get("clip_qkv") is not None or \
+                    config.get("rope_scaling") is not None:
+                raise ValueError(
+                    "an olmoe config with another activation, attention "
+                    "biases, clipped qkv or scaled RoPE is not built here")
+            return cls(
+                norm="rmsnorm", norm_eps=float(config["rms_norm_eps"]),
+                ffn="gated_silu", qk_norm=True, positions="rope",
+                rope_base=float(config["rope_theta"]),
+                tied_head=bool(config["tie_word_embeddings"]),
+                n_experts=int(config["num_experts"]),
+                experts_per_token=int(config["num_experts_per_tok"]),
+                expert_width=int(config["intermediate_size"]),
+                renormalise_gates=bool(config["norm_topk_prob"]),
+            )
+        raise ValueError(f"no block is described for model_type {kind!r}")
+
+
+#: collection a dropless MoE block sows its router's auxiliary losses and
+#: load into (``apply(..., mutable=[MOE_AUX])``; :func:`lm_loss_moe`)
+MOE_AUX = "moe_aux"
+
+#: ``config.json``-style descriptions of the models the examples name
+#: (:func:`lm_from_config` builds them); sources in the README
+MODEL_CONFIGS = {
+    "gpt2-medium": {
+        "model_type": "gpt2", "n_layer": 24, "n_embd": 1024, "n_head": 16,
+        "n_inner": 4096, "n_positions": 1024, "vocab_size": 50257,
+    },
+    "olmoe-1b-7b": {
+        "model_type": "olmoe", "num_hidden_layers": 16, "hidden_size": 2048,
+        "num_attention_heads": 16, "num_key_value_heads": 16,
+        "intermediate_size": 1024, "num_experts": 64,
+        "num_experts_per_tok": 8, "norm_topk_prob": False,
+        "hidden_act": "silu", "rms_norm_eps": 1e-5, "rope_theta": 10000,
+        "rope_scaling": None, "attention_bias": False, "clip_qkv": None,
+        "tie_word_embeddings": False, "vocab_size": 50304,
+        "max_position_embeddings": 4096,
+    },
+}
+
+
+def _norm_layer(arch: Architecture, dtype, name=None):
+    """A normalisation layer of the description's kind; unnamed ones take
+    flax's running names (``LayerNorm_0``, ``RMSNorm_0``, ...)."""
+    cls = nn.RMSNorm if arch.norm == "rmsnorm" else nn.LayerNorm
+    return cls(epsilon=arch.norm_eps, dtype=dtype, param_dtype=jnp.float32,
+               name=name)
 
 
 def apply_rope(x, positions, base: float = 10000.0):
@@ -143,6 +255,9 @@ class TransformerBlock(nn.Module):
     #: sharder's sliced leaves; ``n_experts`` itself stays GLOBAL (the
     #: router scores every expert).
     moe_experts_local: Optional[int] = None
+    #: the kind of block (:class:`Architecture`); the default instance is
+    #: the GPT-2 block
+    arch: Architecture = Architecture()
 
     @staticmethod
     def _lora_delta(name, adapters, inp, out):
@@ -466,6 +581,42 @@ class TransformerBlock(nn.Module):
         full = jax.lax.psum(full, ax)
         return full[:rows].reshape(B, T, D)
 
+    def _moe_dropless(self, h, arch):
+        """Dropless top-k mixture of gated-SiLU experts (the training
+        path of an :class:`Architecture` with experts): route, sort the
+        ``tokens * k`` rows by expert, two grouped matmuls round the SiLU
+        gate, weighted sum back. No capacity, so no token is dropped and
+        none is padded; the router's auxiliary losses and load are sown
+        into :data:`MOE_AUX`."""
+        from chainermn_tpu.ops.grouped_matmul import grouped_matmul
+        from chainermn_tpu.parallel import moe as _moe
+
+        E, F = arch.n_experts, arch.expert_width
+        B, T, D = h.shape
+        kern = nn.initializers.variance_scaling(
+            1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
+            batch_axis=(0,),
+        )
+        router = self.param("moe_router", nn.initializers.normal(0.02),
+                            (D, E), jnp.float32)
+        # gate and up of an expert are one matrix, gate's columns first:
+        # one grouped matmul makes both
+        w_gate_up = self.param("moe_w_gate_up", kern, (E, D, 2 * F),
+                               jnp.float32)
+        w_down = self.param("moe_w_down", kern, (E, F, D), jnp.float32)
+
+        tokens = h.reshape(B * T, D)
+        routing = _moe.dropless_topk(tokens, router, arch.experts_per_token,
+                                     arch.renormalise_gates)
+        for name, value in _moe.dropless_aux(routing).items():
+            self.sow(MOE_AUX, name, value)
+        rows = _moe.dispatch(tokens, routing)
+        gate_up = grouped_matmul(rows, w_gate_up, routing.group_sizes)
+        with jax.named_scope(train_path.MOE_EXPERTS):
+            act = nn.silu(gate_up[:, :F]) * gate_up[:, F:]
+        out = grouped_matmul(act, w_down, routing.group_sizes)
+        return _moe.combine(out, routing).reshape(B, T, D)
+
     @nn.compact
     def __call__(self, x, segment_ids=None, rope_positions=None,
                  train: bool = True, decode: bool = False,
@@ -483,13 +634,14 @@ class TransformerBlock(nn.Module):
         head_dim = self.head_dim or D // self.num_heads
         kv_heads = self.num_kv_heads or self.num_heads
         attn = self.attention_fn or blockwise_attention
+        arch = self.arch
         if self.tp_axis is not None:
             from chainermn_tpu.parallel.tensor import (
                 copy_to_tp,
                 reduce_from_tp,
             )
 
-        h = nn.LayerNorm(dtype=self.compute_dtype, param_dtype=jnp.float32)(x)
+        h = _norm_layer(arch, self.compute_dtype)(x)
         if self.tp_axis is not None:
             h = copy_to_tp(h, self.tp_axis)
         qkv = nn.Dense(
@@ -507,14 +659,17 @@ class TransformerBlock(nn.Module):
             axis=-1,
         )
         B, T = q.shape[:2]
+        if arch.qk_norm:
+            q = _norm_layer(arch, self.compute_dtype, "q_norm")(q)
+            k = _norm_layer(arch, self.compute_dtype, "k_norm")(k)
 
         def heads(t, n):
             return t.reshape(B, T, n, head_dim)
 
         qh, kh = heads(q, self.num_heads), heads(k, kv_heads)
         if rope_positions is not None:
-            qh = apply_rope(qh, rope_positions)
-            kh = apply_rope(kh, rope_positions)
+            qh = apply_rope(qh, rope_positions, arch.rope_base)
+            kh = apply_rope(kh, rope_positions, arch.rope_base)
         if decode:
             if not self.causal:
                 raise ValueError("decode=True requires a causal block")
@@ -563,7 +718,17 @@ class TransformerBlock(nn.Module):
             o = nn.Dropout(self.dropout_rate, deterministic=not train)(o)
         x = x + o
 
-        h = nn.LayerNorm(dtype=self.compute_dtype, param_dtype=jnp.float32)(x)
+        h = _norm_layer(arch, self.compute_dtype)(x)
+        if arch.n_experts > 0:
+            if decode or adapters is not None or self.tp_axis is not None:
+                raise ValueError(
+                    "the dropless mixture of experts is the training "
+                    "path: no decode, adapters or tensor parallelism yet")
+            h = self._moe_dropless(h, arch)
+            if self.dropout_rate > 0.0:
+                h = nn.Dropout(self.dropout_rate,
+                               deterministic=not train)(h)
+            return x + h
         if self.n_experts > 0:
             if adapters is not None and (
                 "ff_up" in adapters or "ff_down" in adapters
@@ -579,14 +744,25 @@ class TransformerBlock(nn.Module):
             return x + h
         if self.tp_axis is not None:
             h = copy_to_tp(h, self.tp_axis)
+        gated = arch.ffn == "gated_silu"
         up = nn.Dense(
-            self.d_ff, dtype=self.compute_dtype, param_dtype=jnp.float32,
+            self.d_ff, use_bias=not gated,
+            dtype=self.compute_dtype, param_dtype=jnp.float32,
             name="ff_up",
         )(h)
         # Column-parallel (B sharded with the ff_up kernel's d_ff split).
-        h = nn.gelu(self._lora_delta("ff_up", adapters, h, up))
+        up = self._lora_delta("ff_up", adapters, h, up)
+        if gated:
+            # column-parallel like ff_up: the product stays local
+            h = nn.silu(nn.Dense(
+                self.d_ff, use_bias=False, dtype=self.compute_dtype,
+                param_dtype=jnp.float32, name="ff_gate",
+            )(h)) * up
+        else:
+            h = nn.gelu(up)
         down = nn.Dense(
-            D, dtype=self.compute_dtype, param_dtype=jnp.float32, name="ff_down",
+            D, use_bias=not gated,
+            dtype=self.compute_dtype, param_dtype=jnp.float32, name="ff_down",
         )(h)
         # Row-parallel (A sharded with the ff_down kernel's d_ff rows;
         # the partial delta rides the layer's second psum).
@@ -657,7 +833,8 @@ class TransformerLM(nn.Module):
     #: ``'learned'`` (reference-style absolute table) or ``'rope'``
     #: (rotary — no position parameters; relative by construction, the
     #: natural choice under sequence parallelism where a learned table
-    #: would need per-shard rolling).
+    #: would need per-shard rolling). The positions of a model without a
+    #: description (``arch``); with one, its ``positions`` are read.
     pos_encoding: str = "learned"
     #: causal sliding-window width (see ``TransformerBlock.window``):
     #: training requires a window-honouring ``attention_fn``; the decode
@@ -716,6 +893,11 @@ class TransformerLM(nn.Module):
     #: (``TransformerBlock.moe_experts_local``; the engine's TP clone
     #: sets ``n_experts // tp``).
     moe_experts_local: Optional[int] = None
+    #: the kind of block, of positions and of head (:class:`Architecture`;
+    #: :func:`lm_from_config` builds a model from a ``config.json``).
+    #: ``None`` is the GPT-2 instance with ``pos_encoding``'s positions;
+    #: a description given here is read alone and ``pos_encoding`` is not.
+    arch: Optional[Architecture] = None
 
     @nn.compact
     def __call__(self, tokens, *, segment_ids=None, positions=None,
@@ -748,10 +930,18 @@ class TransformerLM(nn.Module):
                 "attention_fn=flash_attention (the default blockwise "
                 "reference does not take segment masks)"
             )
-        if self.pos_encoding not in ("learned", "rope"):
+        arch = self.arch
+        if arch is None:
+            if self.pos_encoding not in ("learned", "rope"):
+                raise ValueError(
+                    f"pos_encoding must be 'learned' or 'rope', got "
+                    f"{self.pos_encoding!r}"
+                )
+            arch = Architecture(positions=self.pos_encoding)
+        if arch.n_experts > 0 and self.n_experts > 0:
             raise ValueError(
-                f"pos_encoding must be 'learned' or 'rope', got "
-                f"{self.pos_encoding!r}"
+                "n_experts selects the top-1 serving form, the "
+                "description's experts the dropless one: give one"
             )
         if decode and not self.causal:
             raise ValueError(
@@ -774,9 +964,18 @@ class TransformerLM(nn.Module):
             self.vocab_size, self.d_model, param_dtype=jnp.float32,
             dtype=self.compute_dtype, name="tok_emb",
         )
-        x = emb(tokens)
+        if arch.tied_head:
+            x = emb(tokens)
+        else:
+            # A table that only the lookup reads takes its whole gradient
+            # from the scatter-add of one row a token. Gathering the
+            # float32 rows and casting them (the same values as casting
+            # the table and gathering) makes that sum float32: in bf16 a
+            # frequent token's hundreds of rows lose 5% of it.
+            x = jnp.take(emb.embedding, tokens, axis=0).astype(
+                self.compute_dtype)
         rope_positions = None
-        if self.pos_encoding == "rope":
+        if arch.positions == "rope":
             if positions is None:
                 positions = self.pos_offset + jnp.arange(T, dtype=jnp.int32)
             rope_positions = positions
@@ -822,17 +1021,65 @@ class TransformerLM(nn.Module):
                 expert_axis=self.expert_axis,
                 moe_dispatch_impl=self.moe_dispatch_impl,
                 moe_experts_local=self.moe_experts_local,
+                arch=arch,
                 name=f"block_{i}",
             )(x, segment_ids, rope_positions, train, decode,
               decode_positions, block_tables, decode_slots,
               adapters[i] if adapters is not None else None)
-        x = nn.LayerNorm(dtype=self.compute_dtype, param_dtype=jnp.float32)(x)
+        x = _norm_layer(arch, self.compute_dtype)(x)
+        if arch.tied_head:
+            head = emb
+        else:
+            # a table of its own, laid out as the embedding's so that
+            # ``lm_loss_fused`` takes either (:func:`head_table`)
+            head = nn.Embed(
+                self.vocab_size, self.d_model, param_dtype=jnp.float32,
+                dtype=self.compute_dtype, name="lm_head",
+            )
         if self.return_hidden:
+            if not arch.tied_head and self.is_initializing():
+                head.attend(x[:, :1])  # creates the table
             return x
         with jax.named_scope(train_path.LM_HEAD):
-            # weight-tied output head
-            logits = emb.attend(x.astype(jnp.float32))
+            logits = head.attend(x.astype(jnp.float32))
         return logits
+
+
+def head_table(params, arch: Optional[Architecture] = None):
+    """The ``[vocab, d_model]`` table the head multiplies by: the
+    embedding's when tied, ``lm_head``'s when not."""
+    tied = arch is None or arch.tied_head
+    return params["tok_emb" if tied else "lm_head"]["embedding"]
+
+
+def lm_from_config(config: dict, *, num_layers: Optional[int] = None,
+                   **kwargs) -> "TransformerLM":
+    """A :class:`TransformerLM` from a ``config.json``-style dict (GPT-2's
+    keys or OLMoE's; :data:`MODEL_CONFIGS` holds two), at the published
+    sizes but for ``num_layers`` where given. ``kwargs`` are the model's
+    other fields (``compute_dtype``, ``attention_fn``, ``remat``, ...)."""
+    arch = Architecture.from_config(config)
+    if config.get("model_type", "gpt2") == "gpt2":
+        sizes = dict(
+            vocab_size=config["vocab_size"], num_layers=config["n_layer"],
+            num_heads=config["n_head"], d_model=config["n_embd"],
+            d_ff=config.get("n_inner") or 4 * config["n_embd"],
+            max_len=config["n_positions"],
+        )
+    else:
+        sizes = dict(
+            vocab_size=config["vocab_size"],
+            num_layers=config["num_hidden_layers"],
+            num_heads=config["num_attention_heads"],
+            num_kv_heads=config["num_key_value_heads"],
+            d_model=config["hidden_size"],
+            d_ff=config["intermediate_size"],
+            max_len=config["max_position_embeddings"],
+        )
+    if num_layers is not None:
+        sizes["num_layers"] = num_layers
+    sizes.update(kwargs)
+    return TransformerLM(arch=arch, **sizes)
 
 
 def lm_loss(logits, tokens, mask=None):
@@ -939,6 +1186,48 @@ def _lm_loss_fused(hidden, emb_table, tokens, n_chunks, compute_dtype):
          valid.reshape(n_chunks, chunk)),
     )
     return total / n
+
+
+def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
+                load_balance_coef=0.01, z_loss_coef=0.001):
+    """Loss of a model whose description has experts (build it with
+    ``return_hidden=True``): next-token cross-entropy through the fused
+    head plus the router's two auxiliary losses, each the mean over the
+    layers: ``load_balance_coef`` x the load-balancing loss and
+    ``z_loss_coef`` x the router z-loss
+    (:func:`chainermn_tpu.parallel.moe.dropless_aux`).
+
+    Returns ``(loss, metrics)`` as :func:`~chainermn_tpu.training.
+    make_train_step` takes it: ``moe/load_balance``, ``moe/z_loss``,
+    ``moe/expert_load_max_over_mean`` (the busiest expert's rows over the
+    mean, all layers together), ``moe/dropped`` (rows that lie in no
+    expert's group, counted from each layer's group sizes: 0 while the
+    dropless path keeps its word) and the vector ``moe/expert_load`` (rows
+    an expert received, summed over the layers), which ``Trainer`` hands
+    to ``record_moe_dispatch``."""
+    hidden, sown = model.apply({"params": params}, tokens,
+                               mutable=[MOE_AUX])
+    ce = lm_loss_fused(hidden, head_table(params, model.arch), tokens,
+                       n_chunks=n_chunks, compute_dtype=model.compute_dtype)
+    # one entry a block, each a 1-tuple (sow appends)
+    layers = [{k: v[0] for k, v in sown[MOE_AUX][f"block_{i}"].items()}
+              for i in range(model.num_layers)]
+
+    def over_layers(name, reduce):
+        return reduce(jnp.stack([layer[name] for layer in layers]), axis=0)
+
+    load_balance = over_layers("load_balance", jnp.mean)
+    z_loss = over_layers("z_loss", jnp.mean)
+    load = over_layers("expert_load", jnp.sum)
+    metrics = {
+        "moe/load_balance": load_balance,
+        "moe/z_loss": z_loss,
+        "moe/expert_load_max_over_mean": load.max() / load.mean(),
+        "moe/dropped": over_layers("dropped", jnp.sum),
+        "moe/expert_load": load,
+    }
+    loss = ce + load_balance_coef * load_balance + z_loss_coef * z_loss
+    return loss, metrics
 
 
 def init_cache(model: TransformerLM, params, batch_size: int):

@@ -35,6 +35,15 @@ LM_HEAD = "lm_head"
 FLASH_FWD = "flash_fwd"
 FLASH_BWD_DQ = "flash_bwd_dq"
 FLASH_BWD_DKV = "flash_bwd_dkv"
+#: a dropless mixture-of-experts layer, in the order a token meets them:
+#: router matmul, softmax, top-k and the auxiliary losses; the sort by
+#: expert, the group sizes and the gather into expert order; the grouped
+#: matmuls and the SiLU gate (also each grouped-matmul kernel's ``name``);
+#: the weighted sum back into token order
+MOE_ROUTE = "moe_route"
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+MOE_COMBINE = "moe_combine"
 
 #: how JAX marks the transposed (backward) and the recomputed code of a
 #: scope in ``op_name``
@@ -72,4 +81,9 @@ FEED_NOT_READY = "feed_not_ready_total"
 #: (``total`` / ``visited`` / ``masked``): the tile geometry of the last
 #: call of the op (for a jitted step: the last one traced)
 FLASH_TILES = "flash_tiles"
+#: gauges set while a dropless MoE layer is traced (the last layer traced
+#: is what a scrape sees): (token, slot) rows the layer routes in one call
+#: (tokens x experts per token), and the experts it chooses among
+MOE_ROWS_PER_STEP = "moe_rows_per_step"
+MOE_EXPERTS_TOTAL = "moe_experts_total"
 

@@ -1,31 +1,46 @@
-"""Expert parallelism (MoE) — all_to_all token routing over an ``'expert'``
-mesh axis.
+"""Expert parallelism (MoE) — token routing to experts, in two forms.
 
 Absent from the reference (SURVEY.md section 2.2 lists EP as the optional
-TPU-era extension). Mechanism: each shard hosts one (or more) experts; a
-top-1 router scores tokens, tokens travel to their expert's shard via
-``all_to_all``, the expert MLP runs, and a second ``all_to_all`` returns
-outputs — the same two-collective shape as Ulysses sequence parallelism,
-with capacity-bounded dispatch making every shape static for XLA.
+TPU-era extension).
 
-Capacity discipline (the TPU answer to ragged routing): each expert
-processes at most ``capacity = ceil(tokens/experts * capacity_factor)``
-tokens per shard; overflow tokens are dropped (standard Switch-style
-routing) and their outputs fall back to zero — callers add the residual
-path so dropped tokens pass through unchanged.
+**Capacity-bounded, over an ``'expert'`` mesh axis** (:func:`moe_layer_local`
+and what it is built from; the serving engine's and the plan's path): each
+shard hosts one or more experts; a top-1 or top-k router scores tokens,
+tokens travel to their expert's shard via ``all_to_all``, the expert MLP
+runs, and a second ``all_to_all`` returns outputs — the same two-collective
+shape as Ulysses sequence parallelism, with fixed-size queues making every
+shape static for XLA. Each expert processes at most ``capacity =
+ceil(tokens/experts * capacity_factor)`` tokens per shard; **this path
+drops what overflows** (standard Switch-style routing) and a dropped
+token's output falls back to zero — callers add the residual path so it
+passes through unchanged. ``capacity_factor=None`` sets ``capacity =
+tokens`` and drops nothing, at a queue of ``tokens`` rows an expert.
+
+**Dropless, sorted and ragged** (:func:`dropless_topk`, :func:`dispatch`,
+:func:`combine`; the training path of a model with many small experts):
+every token's top-k (token, slot) rows are sorted by expert, the experts
+run as grouped matmuls over ragged groups
+(:func:`chainermn_tpu.ops.grouped_matmul.grouped_matmul`), and the rows
+are summed back with their gates. **This path drops nothing** and pads
+nothing: exactly ``tokens * k`` rows pass through the experts whatever the
+routing. On one chip there is no collective; an expert axis puts its two
+``all_to_all``s between :func:`dispatch` and the experts and between the
+experts and :func:`combine`.
 
 Differentiable end to end: routing uses straight-through softmax gating
-(gradient flows through the gate probability), and ``all_to_all`` has an
-exact transpose.
+(gradient flows through the gate probability, not the indices), and
+``all_to_all`` has an exact transpose.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from chainermn_tpu.observability import train_path
 
 PyTree = Any
 
@@ -93,12 +108,15 @@ def topk_route(
 
 
 def load_balancing_loss(
-    logits: jax.Array, axis_name=None
+    logits: jax.Array, axis_name=None, k: int = 1
 ) -> jax.Array:
     """Switch/GShard auxiliary load-balancing loss:
-    ``n_experts * mean_e(fraction_of_tokens_e * mean_router_prob_e)``
-    (top-1 assignment fraction, the standard estimator for any k) —
-    1.0 at perfect balance, grows as routing collapses onto few experts.
+    ``n_experts * sum_e(fraction_of_tokens_e * mean_router_prob_e)``,
+    ``fraction_of_tokens_e`` the share of tokens that hold expert ``e``
+    among their top ``k`` (so the fractions sum to ``k``: the form of the
+    OLMoE paper and of Hugging Face's ``load_balancing_loss_func``; with
+    the default ``k=1`` the top-1 assignment fraction of Switch) —
+    ``k`` at perfect balance, grows as routing collapses onto few experts.
     Add ``aux_weight * load_balancing_loss(logits)`` to the task loss.
 
     ``axis_name``: when the token dim is SHARDED over mesh axes, pass
@@ -111,9 +129,15 @@ def load_balancing_loss(
     """
     n_experts = logits.shape[-1]
     probs = jax.nn.softmax(logits, axis=-1)
-    # fraction of tokens whose top-1 choice is each expert
-    top1 = jax.nn.one_hot(jnp.argmax(probs, -1), n_experts, dtype=probs.dtype)
-    frac = top1.mean(axis=0)
+    if k == 1:
+        # fraction of tokens whose top-1 choice is each expert
+        held = jax.nn.one_hot(jnp.argmax(probs, -1), n_experts,
+                              dtype=probs.dtype)
+    else:
+        # ... that hold each expert among their k (ties: the lower index)
+        held = jax.nn.one_hot(lax.top_k(probs, k)[1], n_experts,
+                              dtype=probs.dtype).sum(-2)
+    frac = held.mean(axis=0)
     mean_prob = probs.mean(axis=0)
     if axis_name is not None:
         frac = lax.pmean(frac, axis_name)
@@ -501,3 +525,150 @@ def make_expert_params(init_fn: Callable, rng: jax.Array, n_experts: int):
     rngs = jax.random.split(rng, n_experts)
     trees = [init_fn(r) for r in rngs]
     return jax.tree.map(lambda *ls: jnp.stack(ls), *trees)
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing: sorted, ragged, nothing dropped and nothing padded
+# ---------------------------------------------------------------------------
+
+class Routing(NamedTuple):
+    """What :func:`dropless_topk` decides for ``T`` tokens and ``k`` slots.
+
+    ``order[j]`` is the (token, slot) row (``token * k + slot``) that sits
+    at position ``j`` of the expert-sorted rows, ``inverse`` its inverse
+    permutation; rows of one expert are contiguous, experts ascending, and
+    within an expert in token order. ``logits`` are the router's float32
+    scores, kept for the auxiliary losses."""
+
+    gates: jax.Array        # [T, k] float32
+    experts: jax.Array      # [T, k] int32
+    order: jax.Array        # [T * k] int32
+    inverse: jax.Array      # [T * k] int32
+    group_sizes: jax.Array  # [E] int32, sums to T * k
+    logits: jax.Array       # [T, E] float32
+
+
+def dropless_topk(u, router_w, k: int, renormalise: bool = False) -> Routing:
+    """Route every row of ``u [T, D]`` to its ``k`` best of ``E`` experts.
+
+    The scores are ``softmax(u @ router_w)`` in float32 at full matmul
+    precision (a TPU's default would round the operands to bf16 and flip
+    near-tied experts), the gates the chosen experts' probabilities, not
+    renormalised over the ``k`` unless asked; ties go to the lower expert
+    index. Differentiable in the gates, not in the choice."""
+    from chainermn_tpu.observability.metrics import registry
+
+    n_experts = router_w.shape[-1]
+    if k > n_experts:
+        raise ValueError(f"k={k} exceeds n_experts={n_experts}")
+    # set while the caller's program is traced; the last layer traced is
+    # what a scrape sees
+    registry().gauge(
+        train_path.MOE_ROWS_PER_STEP,
+        "(token, slot) rows a dropless MoE layer routes in one call "
+        "(tokens x experts per token), at the last call traced",
+    ).set(float(u.shape[0] * k))
+    registry().gauge(
+        train_path.MOE_EXPERTS_TOTAL,
+        "experts a dropless MoE layer routes among, at the last call "
+        "traced",
+    ).set(float(n_experts))
+    with jax.named_scope(train_path.MOE_ROUTE):
+        logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, experts = lax.top_k(probs, k)
+        if renormalise:
+            gates = gates / gates.sum(-1, keepdims=True)
+    with jax.named_scope(train_path.MOE_DISPATCH):
+        flat = experts.reshape(-1).astype(jnp.int32)
+        rows = jnp.arange(flat.shape[0], dtype=jnp.int32)
+        # a stable sort by expert keeps token order within an expert
+        by_expert, order = lax.sort((flat, rows), num_keys=1, is_stable=True)
+        _, inverse = lax.sort((order, rows), num_keys=1)
+        # where each expert's rows end in the sorted keys (cheaper on a
+        # TPU than a scatter-add of one a row into the experts' counters)
+        ends = jnp.searchsorted(
+            by_expert, jnp.arange(1, n_experts + 1, dtype=jnp.int32))
+        group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    return Routing(gates, experts.astype(jnp.int32), order, inverse,
+                   group_sizes, logits)
+
+
+@jax.custom_vjp
+def _rows_to_experts(x, order, inverse):
+    k = order.shape[0] // x.shape[0]
+    return x[order // k]
+
+
+def _rows_to_experts_fwd(x, order, inverse):
+    return _rows_to_experts(x, order, inverse), (order, inverse, x.shape[0])
+
+
+def _rows_to_experts_bwd(res, g):
+    # the transpose of the gather is a scatter-add of k rows into each
+    # token; through the inverse permutation it is a gather and a sum
+    order, inverse, tokens = res
+    back = g[inverse].reshape(tokens, -1, g.shape[-1])
+    return back.sum(1, dtype=jnp.float32).astype(g.dtype), None, None
+
+
+_rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
+
+
+@jax.custom_vjp
+def _rows_from_experts(y, order, inverse):
+    return y[inverse]
+
+
+def _rows_from_experts_fwd(y, order, inverse):
+    return y[inverse], (order,)
+
+
+def _rows_from_experts_bwd(res, g):
+    return g[res[0]], None, None  # a permutation's transpose: its inverse
+
+
+_rows_from_experts.defvjp(_rows_from_experts_fwd, _rows_from_experts_bwd)
+
+
+def dispatch(x, routing: Routing):
+    """Gather the rows of ``x [T, D]`` into expert order: ``[T * k, D]``,
+    row ``j`` the token of ``routing.order[j]``; the groups of
+    ``routing.group_sizes`` are the operands of the experts' grouped
+    matmuls. Differentiable in ``x``."""
+    with jax.named_scope(train_path.MOE_DISPATCH):
+        return _rows_to_experts(x, routing.order, routing.inverse)
+
+
+def combine(y, routing: Routing):
+    """Sum the experts' outputs ``y [T * k, D]`` (expert order) back into
+    ``[T, D]``, each row weighted by its gate, accumulated in float32.
+    Differentiable in ``y`` and in the gates."""
+    tokens, k = routing.gates.shape
+    with jax.named_scope(train_path.MOE_COMBINE):
+        back = _rows_from_experts(y, routing.order, routing.inverse)
+        back = back.reshape(tokens, k, y.shape[-1])
+        out = jnp.einsum("tkd,tk->td", back, routing.gates.astype(y.dtype),
+                         preferred_element_type=jnp.float32)
+        return out.astype(y.dtype)
+
+
+def dropless_aux(routing: Routing) -> dict:
+    """The router's auxiliary losses and statistics of one layer:
+    ``load_balance`` (:func:`load_balancing_loss` over the top ``k``),
+    ``z_loss`` (``mean(logsumexp(logits)^2)``), ``expert_load`` (rows an
+    expert received, float32 ``[E]``) and ``dropped`` (the (token, slot)
+    rows that lie in no expert's group, ``tokens * k -
+    sum(group_sizes)``, counted from the routing the experts are given:
+    0 while this path keeps its word, since it has no capacity)."""
+    tokens, k = routing.gates.shape
+    with jax.named_scope(train_path.MOE_ROUTE):
+        return {
+            "load_balance": load_balancing_loss(routing.logits, k=k),
+            "z_loss": jnp.mean(
+                jax.nn.logsumexp(routing.logits, axis=-1) ** 2),
+            "expert_load": routing.group_sizes.astype(jnp.float32),
+            "dropped": (tokens * k - routing.group_sizes.sum()).astype(
+                jnp.float32),
+        }

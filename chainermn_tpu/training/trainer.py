@@ -325,6 +325,18 @@ class Trainer:
         return self.state
 
     def _log_metrics(self, metrics, max_iterations: int, t0: float) -> None:
+        metrics = dict(metrics)
+        load = metrics.pop("moe/expert_load", None)
+        if load is not None:
+            # the per-expert vector of a dropless MoE loss
+            # (models.lm_loss_moe) is no scalar to print: it goes to the
+            # ``moe_dispatch`` trace event and the ``moe_expert_load``
+            # gauges; that path has no capacity and pads nothing
+            from chainermn_tpu.parallel.moe import record_moe_dispatch
+
+            record_moe_dispatch({
+                "expert_load": load, "padded": 0.0, "capacity": 0.0,
+                "dropped": metrics.get("moe/dropped", 0.0)})
         host_metrics = {
             k: float(jax.device_get(v)) for k, v in metrics.items()
         }

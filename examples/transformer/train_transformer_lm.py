@@ -12,6 +12,9 @@ parallelism for long context (``--sequence-parallel``).
         --communicator naive --sequence-parallel --seq-len 512
     python examples/transformer/train_transformer_lm.py \
         --communicator naive --packed --num-kv-heads 2
+    python examples/transformer/train_transformer_lm.py \
+        --communicator xla --model olmoe-1b-7b --layers 1 \
+        --batchsize 4 --seq-len 4096      # one v5e chip, published widths
 """
 
 from __future__ import annotations
@@ -30,7 +33,15 @@ sys.path.insert(0, __file__.rsplit("/examples/", 1)[0])
 
 import chainermn_tpu
 from chainermn_tpu import global_except_hook
-from chainermn_tpu.models import TransformerLM, lm_loss
+from chainermn_tpu.models import (
+    MODEL_CONFIGS,
+    TransformerLM,
+    head_table,
+    lm_from_config,
+    lm_loss,
+    lm_loss_fused,
+    lm_loss_moe,
+)
 from chainermn_tpu.training import make_train_step
 from chainermn_tpu.training.train_step import create_train_state
 
@@ -109,6 +120,14 @@ def main(argv=None):
                    help="absolute learned table (reference-style) or "
                         "rotary (no position parameters)")
     p.add_argument("--num-layers", type=int, default=6)
+    p.add_argument("--model", default=None, choices=sorted(MODEL_CONFIGS),
+                   help="train a published architecture at its own widths "
+                        "through the model description (models."
+                        "lm_from_config): flash attention, fused head, and "
+                        "for an expert model dropless routing with its "
+                        "auxiliary losses; --layers cuts the depth")
+    p.add_argument("--layers", type=int, default=None, metavar="N",
+                   help="with --model: layers to build (default: all)")
     p.add_argument("--d-model", type=int, default=512)
     p.add_argument("--generate", type=int, default=0, metavar="N",
                    help="after training, greedy-decode N tokens from a "
@@ -133,6 +152,12 @@ def main(argv=None):
     if args.mlm and (args.generate or args.beam):
         p.error("--mlm is an encoder: no autoregressive decode "
                 "(--generate/--beam)")
+    if args.model and (args.mlm or args.sequence_parallel or args.packed
+                       or args.window or args.generate):
+        p.error("--model trains the published block data-parallel; it "
+                "composes with the optimizer flags only")
+    if args.layers is not None and not args.model:
+        p.error("--layers cuts the depth of a --model")
     if args.mlm and (args.window or args.sequence_parallel or args.packed):
         p.error("--mlm composes with the plain data-parallel path only "
                 "(windows/SP/packing are causal-LM features here)")
@@ -163,7 +188,9 @@ def main(argv=None):
             "example (ring attention does accept segment_ids — see "
             "ring_attention_local — but this CLI keeps the modes separate)"
         )
-    if args.sequence_parallel:
+    if args.model:
+        run_named_model(args, comm, compute_dtype, rng)
+    elif args.sequence_parallel:
         run_sequence_parallel(args, comm, compute_dtype, rng)
     elif args.packed:
         run_packed(args, comm, compute_dtype, rng)
@@ -254,6 +281,65 @@ def run_packed(args, comm, compute_dtype, rng):
     jax.block_until_ready(state.params)
     if comm.rank == 0:
         print("done (packed)")
+
+
+def run_named_model(args, comm, compute_dtype, rng):
+    """A published architecture from its ``config.json`` (``--model``),
+    through the same front door as everything else here."""
+    from chainermn_tpu.ops.flash_attention import flash_attention
+
+    def attention_fn(q, k, v, *, causal, scale):
+        return flash_attention(q, k, v, causal=causal, scale=scale)
+
+    model = lm_from_config(
+        MODEL_CONFIGS[args.model], num_layers=args.layers,
+        compute_dtype=compute_dtype, attention_fn=attention_fn,
+        return_hidden=True,
+    )
+    if args.seq_len > model.max_len:
+        raise SystemExit(f"{args.model} has {model.max_len} positions")
+    global_batch = args.batchsize * comm.size
+    tokens0 = synthetic_tokens(rng, global_batch, args.seq_len)
+    params = jax.jit(model.init)(
+        jax.random.key(0), jnp.asarray(tokens0[:1])
+    )["params"]
+
+    if model.arch.n_experts:
+
+        def loss_fn(params, tokens):
+            return lm_loss_moe(model, params, tokens)
+    else:
+
+        def loss_fn(params, tokens):
+            hidden = model.apply({"params": params}, tokens)
+            return lm_loss_fused(hidden, head_table(params, model.arch),
+                                 tokens, compute_dtype=compute_dtype)
+
+    optimizer = _make_optimizer(args, comm)
+    state = create_train_state(params, optimizer, comm)
+    step = make_train_step(loss_fn, optimizer, comm)
+    t0 = time.perf_counter()
+    for it in range(args.iterations):
+        batch = jnp.asarray(
+            synthetic_tokens(rng, global_batch, args.seq_len))
+        state, metrics = step(state, batch)
+        if comm.rank == 0 and (it + 1) % 10 == 0:
+            jax.block_until_ready(metrics["loss"])
+            tps = global_batch * args.seq_len * (it + 1) / (
+                time.perf_counter() - t0
+            )
+            extra = "".join(
+                f" {k.split('/')[1]}={float(v):.3f}"
+                for k, v in sorted(metrics.items())
+                if k.startswith("moe/") and jnp.ndim(v) == 0)
+            print(
+                f"iter {it + 1}/{args.iterations} "
+                f"loss={float(metrics['loss']):.4f}{extra} "
+                f"({tps:,.0f} tok/s, {args.model})"
+            )
+    jax.block_until_ready(state.params)
+    if comm.rank == 0:
+        print(f"done ({args.model}, {model.num_layers} layers)")
 
 
 def run_data_parallel(args, comm, compute_dtype, rng):

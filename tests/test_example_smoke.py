@@ -3,6 +3,7 @@ tiny configs, a handful of iterations — proof every documented CLI still
 runs end to end on the 8-way CPU mesh (the reference ran its examples
 under MPI as its de-facto integration suite, SURVEY.md section 2.8)."""
 
+import pytest
 from conftest import load_example as _load_example
 
 
@@ -133,3 +134,24 @@ def test_mnist_example_local_sgd_smoke():
         "--communicator", "naive", "--iterations", "12",
         "--local-sgd", "3", "--batchsize", "64",
     ])
+
+
+@pytest.mark.parametrize("model", ["olmoe-tiny", "gpt2-tiny"])
+def test_transformer_example_named_model_smoke(model, monkeypatch, capsys):
+    """``--model <name> --layers N`` builds a published architecture
+    through the model description (ISSUE 25); here two tiny stand-ins
+    under the published ones' ``model_type``s."""
+    ex = _load_example("transformer", "train_transformer_lm.py")
+    monkeypatch.setitem(ex.MODEL_CONFIGS, "olmoe-tiny", dict(
+        ex.MODEL_CONFIGS["olmoe-1b-7b"], num_hidden_layers=4,
+        hidden_size=32, num_attention_heads=2, num_key_value_heads=2,
+        intermediate_size=16, num_experts=4, num_experts_per_tok=2,
+        vocab_size=1024, max_position_embeddings=64))
+    monkeypatch.setitem(ex.MODEL_CONFIGS, "gpt2-tiny", dict(
+        ex.MODEL_CONFIGS["gpt2-medium"], n_layer=4, n_embd=32, n_head=2,
+        n_inner=64, n_positions=64, vocab_size=1024))
+    ex.main(["--iterations", "10", "--batchsize", "8", "--seq-len", "32",
+             "--model", model, "--layers", "1"])
+    out = capsys.readouterr().out
+    assert f"done ({model}, 1 layers)" in out
+    assert ("load_balance=" in out) == (model == "olmoe-tiny")

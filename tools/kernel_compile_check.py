@@ -26,6 +26,14 @@ Checked kernels:
   an extended, tile-padded K axis, ``q_offset`` and wrap-sentinel
   segment ids) — the two variants Mosaic rejected on 2026-08-01, at the
   shapes it rejected them
+- flash attention forward + backward at the OLMoE cell's shape (B 4,
+  T 4096, 16 heads of 128)
+- the grouped matmul of the dropless mixture of experts
+  (``ops/grouped_matmul.py``): forward and both gradients on uneven groups
+  with empty ones, run against a masked loop over the groups (``rel_err``
+  under ``_TOL``), and compiled at the OLMoE cell's shapes (131,072 rows,
+  64 experts, 2048 x 2048 and 1024 x 2048); ``timings`` carries its
+  forward and forward + backward there
 - fused paged decode (ISSUE 19): plain tick T=1, verify span T>1,
   window, and the dense-cache wrapper — the ``(1, bs, 1, D)`` KV block
   (second-to-last dim 1 over the kv-head axis) is exactly the kind of
@@ -54,7 +62,22 @@ sys.path.insert(0, _HERE)
 
 #: largest ``rel_err`` against XLA's attention a flash case may show:
 #: three times the worst measured with bf16 operands (0.0029, PERF.md).
+#: The grouped-matmul cases are held to the same: bf16 operands and a bf16
+#: result against the same products in float32 (0.0025-0.0026 measured).
 _TOL = 1e-2
+
+
+def _group_sizes(noise, rows: int):
+    """Uneven group sizes summing to ``rows`` from a float vector, every
+    fifth group empty: what a skewed router hands the grouped matmul."""
+    import jax
+    import jax.numpy as jnp
+
+    n = noise.shape[0]
+    share = jax.nn.softmax(2.0 * noise.astype(jnp.float32))
+    share = jnp.where(jnp.arange(n) % 5 == 3, 0.0, share)
+    sizes = jnp.floor(share / share.sum() * rows).astype(jnp.int32)
+    return sizes.at[0].add(rows - sizes.sum())
 
 
 def _note(msg: str) -> None:
@@ -76,6 +99,7 @@ def _cases():
         flash_attention,
         flash_block_fwd,
     )
+    from chainermn_tpu.ops.grouped_matmul import grouped_matmul
     from chainermn_tpu.ops.paged_decode import (
         dense_flash_decode,
         paged_flash_decode,
@@ -128,6 +152,44 @@ def _cases():
     # benchmark's LM cells' (gpt2-medium at 4 sequences a chip).
     q_lm = jax.ShapeDtypeStruct((16, 2048, 16, 64), dt)
     q_cell = jax.ShapeDtypeStruct((4, 1024, 16, 64), dt)
+    # the OLMoE cell's: 4 sequences of 4096, 16 heads of 128
+    q_olmoe = jax.ShapeDtypeStruct((4, 4096, 16, 128), dt)
+
+    # The grouped matmul: group sizes are made from a float vector inside
+    # the case, uneven and with empty groups; the reference multiplies
+    # every row by every group's matrix and keeps the group's own rows.
+    def gmm(lhs, rhs, noise):
+        return grouped_matmul(lhs, rhs, _group_sizes(noise, lhs.shape[0]))
+
+    def gmm_loop(lhs, rhs, noise):
+        sizes = _group_sizes(noise, lhs.shape[0])
+        ends = jnp.cumsum(sizes)
+        rows = jnp.arange(lhs.shape[0])[:, None]
+        lhs32 = lhs.astype(jnp.float32)
+
+        def one(acc, group):
+            w, lo, hi = group
+            mine = (rows >= lo) & (rows < hi)
+            prod = jnp.dot(lhs32, w.astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST)
+            return acc + jnp.where(mine, prod, 0.0), ()
+
+        out, _ = jax.lax.scan(
+            one, jnp.zeros((lhs.shape[0], rhs.shape[2]), jnp.float32),
+            (rhs, ends - sizes, ends))
+        return out.astype(lhs.dtype)
+
+    def gmm_grads(fn):
+        return jax.grad(
+            lambda a, b, n_: fn(a, b, n_).astype(jnp.float32).sum(),
+            argnums=(0, 1))
+
+    def gmm_specs(rows, experts, k_dim, n_dim):
+        return (jax.ShapeDtypeStruct((rows, k_dim), dt),
+                jax.ShapeDtypeStruct((experts, k_dim, n_dim), jnp.float32),
+                jax.ShapeDtypeStruct((experts,), jnp.float32))
+
+    gmm_small = gmm_specs(8192, 16, 1024, 512)
 
     # The two variants Mosaic rejected, at bench's kernel-sweep shape.
     Bs, Ts, Hs, Ds = 2, 2048, 8, 128
@@ -191,6 +253,14 @@ def _cases():
         ("flash_bwd", grads(flash), (q, kv, kv), grads(xla)),
         ("flash_lm_fwdbwd", grads(flash), (q_lm,) * 3, grads(xla)),
         ("flash_cell_fwdbwd", grads(flash), (q_cell,) * 3, grads(xla)),
+        ("flash_olmoe_fwdbwd", grads(flash), (q_olmoe,) * 3, grads(xla)),
+        ("grouped_matmul_fwd", gmm, gmm_small, gmm_loop),
+        ("grouped_matmul_grads", gmm_grads(gmm), gmm_small,
+         gmm_grads(gmm_loop)),
+        ("grouped_matmul_olmoe_gate_up", gmm_grads(gmm),
+         gmm_specs(131072, 64, 2048, 2048), None),
+        ("grouped_matmul_olmoe_down", gmm_grads(gmm),
+         gmm_specs(131072, 64, 1024, 2048), None),
         ("flash_bwd_window", grads(functools.partial(flash, window=1024)),
          (q, kv, kv), grads(xla_window)),
         ("flash_bwd_bias_grad",
@@ -281,6 +351,59 @@ def _timings():
             samples.append((time.perf_counter() - t0) / iters * 1e3)
         rows.append({"shape": f"B{B}xT{T}xH{H}xD{D}_bf16_causal",
                      "flash_fwdbwd_ms": [round(x, 4) for x in samples]})
+    return rows + _grouped_matmul_timings()
+
+
+def _grouped_matmul_timings(iters: int = 5):
+    """The grouped matmul at the OLMoE cell's shapes (131,072 rows of
+    uneven groups over 64 experts, bf16 rows, float32 master weights):
+    forward, and forward + both gradients, ms a call, chained in one
+    program like the flash timings."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu.ops.grouped_matmul import grouped_matmul
+
+    def timed(fn, *args):
+        float(fn(*args))  # compile + warm
+        samples = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            float(fn(*args))
+            samples.append((time.perf_counter() - t0) / iters * 1e3)
+        return [round(x, 4) for x in samples]
+
+    rows = []
+    for k_dim, n_dim in ((2048, 2048), (1024, 2048)):
+        keys = jax.random.split(jax.random.PRNGKey(25), 3)
+        lhs = jax.random.normal(keys[0], (131072, k_dim), jnp.bfloat16)
+        rhs = jax.random.normal(keys[1], (64, k_dim, n_dim), jnp.float32)
+        sizes = _group_sizes(jax.random.normal(keys[2], (64,)), 131072)
+
+        # the weights and the sizes are arguments: closed over they would
+        # be a gigabyte of constants in the program
+        def fwd(x, w, gs):
+            def step(x, _):
+                out = grouped_matmul(x, w, gs)
+                # the next iteration's rows: the result's first columns
+                return (out[:, :k_dim] * 1e-2).astype(x.dtype), ()
+            return jax.lax.scan(step, x, None, length=iters)[0] \
+                .astype(jnp.float32).sum()
+
+        def fwdbwd(x, w, gs):
+            def step(x, _):
+                out, vjp = jax.vjp(
+                    lambda a, b: grouped_matmul(a, b, gs), x, w)
+                dl, dr = vjp(out)  # the forward's result is needed: kept
+                return (x + 1e-4 * dl + 1e-9 * dr[0, 0, 0]).astype(
+                    x.dtype), ()
+            return jax.lax.scan(step, x, None, length=iters)[0] \
+                .astype(jnp.float32).sum()
+
+        rows.append({"shape": f"M131072xE64xK{k_dim}xN{n_dim}_bf16",
+                     "gmm_fwd_ms": timed(jax.jit(fwd), lhs, rhs, sizes),
+                     "gmm_fwdbwd_ms": timed(jax.jit(fwdbwd), lhs, rhs,
+                                            sizes)})
     return rows
 
 
