@@ -777,23 +777,34 @@ class TransformerBlock(nn.Module):
         return x + h
 
 
+#: what ``jax.checkpoint`` keeps of a block for the backward, by the name
+#: ``remat_policy`` takes. ``'dots'``: the matmul-class results, XLA's
+#: dots and, by the names its wrapper gives them, what the flash forward
+#: kernel made (a Pallas call is no dot; an ``attention_fn`` without such
+#: names saves its dots alone). ``'nothing'``: jax.checkpoint's default.
+_REMAT_POLICIES = {
+    "dots": jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        jax.checkpoint_policies.save_only_these_names(
+            *train_path.FLASH_RESIDUALS),
+    ),
+    "nothing": None,
+}
+
+
 def _remat_block(remat_policy: str):
     """``nn.remat``-wrapped :class:`TransformerBlock` for the given save
     policy — ONE construction shared by :class:`TransformerLM` and
     :class:`chainermn_tpu.models.vit.VisionTransformer` so the
     policy-name surface cannot drift between the families."""
-    if remat_policy == "dots":
-        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    elif remat_policy == "nothing":
-        policy = None  # jax.checkpoint default: save nothing
-    else:
+    if remat_policy not in _REMAT_POLICIES:
         raise ValueError(
             f"remat_policy must be 'dots' or 'nothing', got "
             f"{remat_policy!r}"
         )
     return nn.remat(
         TransformerBlock,
-        policy=policy,
+        policy=_REMAT_POLICIES[remat_policy],
         static_argnums=(4, 5),  # (self, x, seg, rope_pos, train, dec)
     )
 
@@ -812,17 +823,21 @@ class TransformerLM(nn.Module):
     #: global position offset of the local sequence shard (sequence-parallel
     #: runs pass ``axis_index * T_local`` so learned positions line up).
     pos_offset: int = 0
-    #: rematerialize each block in the backward pass (keep only the matmul
-    #: outputs that feed the MXU — ``dots_with_no_batch_dims_saveable``);
+    #: rematerialize each block in the backward pass (keep only what
+    #: ``remat_policy`` names: by default the matmul-class results);
     #: trades ~1/3 more FLOPs for activation memory, the standard TPU move
     #: for fitting larger B*T (SURVEY.md "use jax.checkpoint to trade FLOPs
     #: for memory").
     remat: bool = False
-    #: remat save policy (with ``remat=True``): ``'dots'`` — keep matmul
-    #: outputs, recompute elementwise/norm chains (the default; cheapest
-    #: recompute); ``'nothing'`` — save only block inputs, recompute
-    #: everything (max memory saving, ~1/3 extra FLOPs: the knob the MFU
-    #: sweep explores for HBM-bound configs).
+    #: remat save policy (with ``remat=True``): ``'dots'`` — keep the
+    #: matmul-class results, recompute elementwise/norm chains (the
+    #: default; cheapest recompute). Matmul-class: XLA's dots, and with
+    #: ``flash_attention`` as ``attention_fn`` what its forward kernel
+    #: made (output and log-sum-exp, by their checkpoint names), so the
+    #: backward calls no attention forward again; another
+    #: ``attention_fn`` saves its dots alone. ``'nothing'`` — save only
+    #: block inputs, recompute everything (max memory saving, ~1/3 extra
+    #: FLOPs: the knob the MFU sweep explores for HBM-bound configs).
     remat_policy: str = "dots"
     #: skip the weight-tied LM head and return the final (post-LN) hidden
     #: states; pair with :func:`lm_loss_fused` to avoid materializing the

@@ -50,6 +50,18 @@ MOE_COMBINE = "moe_combine"
 BACKWARD_MARKER = "transpose("
 REMAT_MARKER = "rematted_computation"
 
+# -- checkpoint names -----------------------------------------------------
+#: ``jax.ad_checkpoint.checkpoint_name`` tags on what the flash forward
+#: kernel made and its backward takes: the attention output as the kernel
+#: leaves it (``[B, H, T, D]``) and the log-sum-exp of a row's scores
+#: (``[B, H, T]``: without the kernel's unit minor dimension, which a TPU
+#: pads to 128 lanes). A name is an identity outside ``jax.checkpoint``;
+#: a remat policy that saves these names keeps the kernel's results, and
+#: the recomputation no longer calls the kernel.
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
+FLASH_RESIDUALS = (FLASH_OUT, FLASH_LSE)
+
 
 def bucket_scope(index: int) -> str:
     """Sub-scope of :data:`GRAD_REDUCE` for one packed bucket."""

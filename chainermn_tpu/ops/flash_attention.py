@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1022,12 +1023,20 @@ def _flash_core_fwd(q, k, v, seg, bias, has_seg, has_bias, bias_grad,
         block_q=block_q, block_k=block_k, interpret=interpret,
         window=window,
     )
-    return _to_bhtd(out), (q, k, v, seg, bias, out, lse)  # out in BHTD
+    # What the backward takes from the kernel, by name: a remat policy
+    # that saves these names (models/transformer.py, 'dots') keeps them,
+    # and the recomputation has no use for the kernel. The log-sum-exp
+    # goes without its unit minor dimension: as the kernel writes it a
+    # value occupies 128 lanes, and a saved one would hold 128 times its bytes.
+    out = checkpoint_name(out, train_path.FLASH_OUT)  # in BHTD
+    lse = checkpoint_name(lax.squeeze(lse, (3,)), train_path.FLASH_LSE)
+    return _to_bhtd(out), (q, k, v, seg, bias, out, lse)
 
 
 def _flash_core_bwd(has_seg, has_bias, bias_grad, causal, scale, block_q,
                     block_k, interpret, window, res, g):
     q, k, v, seg, bias, out_bhtd, lse = res
+    lse = lax.expand_dims(lse, (3,))  # [B, H, Tq, 1] (kernel layout)
     do = _to_bhtd(g)
     # delta_i = sum_d dO_i . O_i — the rowwise correction term of the flash
     # backward (re-derives softmax jacobian contributions without P).

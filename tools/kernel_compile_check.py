@@ -28,6 +28,10 @@ Checked kernels:
   shapes it rejected them
 - flash attention forward + backward at the OLMoE cell's shape (B 4,
   T 4096, 16 heads of 128)
+- the gradient of two remat'ed blocks (``remat_policy='dots'``) at the
+  memory-full GPT-2 cell's shape (B 16, T 1024, 16 heads of 64): the
+  policy keeps what the flash forward made, so the compiled gradient
+  holds three Mosaic calls a layer and not four (``mosaic_calls``)
 - the grouped matmul of the dropless mixture of experts
   (``ops/grouped_matmul.py``): forward and both gradients on uneven groups
   with empty ones, run against a masked loop over the groups (``rel_err``
@@ -65,6 +69,10 @@ sys.path.insert(0, _HERE)
 #: The grouped-matmul cases are held to the same: bf16 operands and a bf16
 #: result against the same products in float32 (0.0025-0.0026 measured).
 _TOL = 1e-2
+
+#: Mosaic custom calls the compiled program of a case has to hold: two
+#: remat'ed layers of forward, dq and dk/dv, the forward not run again
+_MOSAIC_CALLS = {"remat_dots_block_grads": 2 * 3}
 
 
 def _group_sizes(noise, rows: int):
@@ -246,6 +254,25 @@ def _cases():
     dense_cache = jax.ShapeDtypeStruct((S, L, Hkv, D), dt)
     qd1 = jax.ShapeDtypeStruct((S, 1, Hq, D), dt)
 
+    # Two remat'ed GPT-2 medium blocks at the memory-full cell's batch.
+    from chainermn_tpu.models import TransformerLM
+
+    remat_lm = TransformerLM(
+        vocab_size=512, num_layers=2, num_heads=16, d_model=1024, d_ff=4096,
+        max_len=1024, compute_dtype=dt, remat=True, remat_policy="dots",
+        return_hidden=True,
+        attention_fn=lambda q_, k_, v_, *, causal, scale: flash_attention(
+            q_, k_, v_, causal=causal, scale=scale, interpret=False),
+    )
+    remat_tokens = jax.ShapeDtypeStruct((16, 1024), jnp.int32)
+    remat_params = jax.eval_shape(
+        lambda: remat_lm.init(jax.random.PRNGKey(0),
+                              jnp.zeros((1, 1024), jnp.int32))["params"])
+
+    def remat_block_grads(params, tokens):
+        return jax.grad(lambda p: remat_lm.apply(
+            {"params": p}, tokens).astype(jnp.float32).sum())(params)
+
     return [
         ("flash_fwd", flash, (q, kv, kv), xla),
         ("flash_fwd_window", functools.partial(flash, window=1024),
@@ -284,6 +311,8 @@ def _cases():
         ("dense_decode",
          functools.partial(dense_flash_decode, interpret=False),
          (qd1, dense_cache, dense_cache, pos), None),
+        ("remat_dots_block_grads", remat_block_grads,
+         (remat_params, remat_tokens), None),
     ]
 
 
@@ -425,6 +454,10 @@ def main() -> int:
             compiled = jax.jit(fn).lower(*specs).compile()
             row["compile_s"] = round(time.perf_counter() - t0, 2)
             row["ok"] = True
+            if name in _MOSAIC_CALLS:
+                row["mosaic_calls"] = compiled.as_text().count(
+                    'custom_call_target="tpu_custom_call"')
+                row["ok"] = row["mosaic_calls"] == _MOSAIC_CALLS[name]
             if ref is not None:
                 _note(f"running {name} against XLA's attention")
                 inputs = _seeded(specs)
