@@ -98,8 +98,7 @@ class CommunicatorBase:
         #: choice). ``"auto"`` resolves the wire variant device-aware
         #: through the autotune registry (decision ``allreduce_wire``
         #: keyed on this mesh's device kind + size — table default
-        #: bf16; an int8 cache entry must earn its rounding stages with
-        #: a measured busbw win; see chainermn_tpu.tuning).
+        #: bf16; see chainermn_tpu.tuning).
         #: autotune decision record behind an ``'auto'`` wire resolution
         #: (name/winner/source/key) — attached to this communicator's
         #: ``allreduce_grad`` wire events so every auto collective in a

@@ -282,8 +282,8 @@ class MetricsRegistry:
     def snapshot(self) -> dict:
         """JSON-able view of every family: counters/gauges as values,
         histograms as count/sum/cumulative buckets + the SLO quantiles.
-        This is the peer-merge payload (exporter) and the bench
-        artifact (``metrics_snapshot`` in BENCH_DETAILS.json)."""
+        This is the peer-merge payload (exporter) and what the
+        benchmark's loop reads its counters from."""
         self._run_collect()
         out: dict = {}
         with self._lock:

@@ -36,8 +36,8 @@ per step, per process. Three properties are load-bearing:
 
 Enable programmatically (:func:`enable`) or by environment:
 ``CHAINERMN_TPU_TRACE=<path.jsonl>`` turns the recorder on at first use
-in any process — which is how ``bench.py``'s child processes and the
-chip-capture path inherit tracing without plumbing.
+in any process — which is how child processes inherit tracing without
+plumbing.
 """
 
 from __future__ import annotations

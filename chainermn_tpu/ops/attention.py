@@ -146,17 +146,11 @@ def finalize_online_softmax(o: jax.Array, l: jax.Array, dtype) -> jax.Array:
 
 
 def resolve_attention_impl(q_shape, dtype, *, windowed: bool = False) -> str:
-    """Device-aware attention variant, through the autotune registry
+    """Device-aware attention variant, through the decision registry
     (:mod:`chainermn_tpu.tuning`), keyed on ``(device_kind,
-    bucket(T, H, D), dtype)``.
-
-    The measured inversion the default table encodes (r5 bench
-    artifacts, B4xT4096xH8xD128 bf16 causal): the flash kernel is 3.0x
-    XLA attention fwd+bwd on TPU v5e but 0.56x under CPU interpret mode
-    — so ``flash`` (or ``windowed``, when a sliding window is asked
-    for) on accelerators and ``xla`` on CPU, with the persistent cache
-    (live-measured or seeded from on-chip captures) overriding per
-    shape bucket."""
+    bucket(T, H, D), dtype)``: ``flash`` (or ``windowed``, when a
+    sliding window is asked for) on accelerators, ``xla`` on a CPU,
+    where the kernel would run in the interpreter."""
     from chainermn_tpu import tuning
 
     B, T, H, D = q_shape

@@ -243,7 +243,7 @@ def paged_flash_decode(q, k_pool, v_pool, block_tables, positions, *,
         # Dead slots (past the row's horizon / below its window band)
         # re-target one fixed block: consecutive unchanged block indices
         # revisit the resident copy, so the DMA bill is live blocks
-        # only — the "one live-KV read" in byte_audit's decode floor.
+        # only: one read of the live KV.
         live = j * bs <= pos_ref[b] + (T - 1)
         if window is not None:
             live &= (j + 1) * bs - 1 > pos_ref[b] - window

@@ -220,9 +220,8 @@ class MultiNodeOptimizer:
       (``make_train_step`` does this automatically). Incompatible with
       ``double_buffering``, ``error_feedback`` and the int8 wire.
     - ``'auto'``: resolved once per optimizer instance through the
-      autotune registry (decision ``'reduction_schedule'``, keyed
-      device_kind x world-shape x payload-MB bucket), seedable offline
-      from bench's ``overlap`` phase rows.
+      decision registry (decision ``'reduction_schedule'``, keyed
+      device_kind x world-shape x payload-MB bucket).
 
     ``double_buffering=True`` is the OVERLAPPED mode: the update
     consumes the PREVIOUS step's banked buckets while this step's
@@ -352,11 +351,11 @@ class MultiNodeOptimizer:
         self._schedule_provenance: dict | None = None
         # One resolution per optimizer instance: init's residual
         # allocation and update's reduction must see the same bucket
-        # layout even if the autotune cache changes mid-process. The
-        # table-default 64 MB resolves to None — _float_bucket_partition
-        # then reads the module's _EF_BUCKET_BYTES at call time, keeping
-        # that constant the single default (and test seam); only a
-        # non-default cache/forced decision pins an explicit size here.
+        # layout. The table-default 64 MB resolves to None —
+        # _float_bucket_partition then reads the module's
+        # _EF_BUCKET_BYTES at call time, keeping that constant the single
+        # default (and test seam); only a forced decision pins an
+        # explicit size here.
         from chainermn_tpu import tuning
 
         mb = tuning.choice(
@@ -369,45 +368,6 @@ class MultiNodeOptimizer:
             else (1 << 62) if mb == "none"
             else int(mb) << 20
         )
-        if double_buffering:
-            self._advise_double_buffering()
-
-    def _advise_double_buffering(self) -> None:
-        """Warn-and-record when the autotune cache says the
-        double-buffering flag LOSES on this backend (measured 0.752x on
-        the CPU proxy, 0.85x on a single chip — the grad-sized bank is
-        pure cost with no collective to overlap). The flag stays
-        honoured with faithful staleness-1 semantics — this is an
-        advisory, not an override — and the decision is recorded either
-        way so bench/dryrun artifacts show the provenance. The blanket
-        table fallback does NOT warn: on an unmeasured topology (e.g. a
-        real multi-chip pod, exactly where the flag is designed to pay)
-        there is no evidence to cite, and a warning claiming a
-        measurement would be false."""
-        import warnings
-
-        from chainermn_tpu import tuning
-
-        comm = self.communicator
-        key = tuning.decision_key(comm.device_kind, shape=(comm.size,),
-                                  dtype="step")
-        verdict = tuning.choice("double_buffering", ("on", "off"), key)
-        rec = next((d for d in tuning.decisions_taken()
-                    if d["name"] == "double_buffering"
-                    and d["key"] == key), {})
-        evidenced = rec.get("source", "").startswith(("cache", "measured"))
-        if verdict == "off" and evidenced:
-            warnings.warn(
-                "double_buffering=True, but the autotune record for "
-                f"this backend (key {key!r}, {rec.get('source')}) says "
-                "the flag loses here — with no collective to overlap "
-                "the grad-sized bank is pure cost (measured 0.85x "
-                "on-chip, 0.752x CPU proxy; see docs/benchmarks.md). "
-                "Keeping the requested staleness-1 semantics; enable "
-                "it where a real inter-chip allreduce sits on the "
-                "critical path.",
-                stacklevel=4,
-            )
 
     def _int8_wire(self) -> bool:
         return (self.compress_dtype is not None

@@ -219,11 +219,10 @@ WIRE_DTYPES = {"f32": None, "bf16": jnp.bfloat16, "int8": jnp.int8}
 def tuned_bucket_bytes(device_kind: str | None = None,
                        n_devices: int = 1) -> int:
     """Gradient-pack bucket size for the two-level allreduce pipeline,
-    through the autotune registry (decision ``allreduce_bucket_mb``,
+    through the decision registry (decision ``allreduce_bucket_mb``,
     candidates 16/64/256 MB or ``none`` = one fused buffer). The ~64 MB
     table default keeps the inter (DCN) level bandwidth-bound while
-    bounding the transient flat-copy in HBM; a cache entry seeded from
-    an on-chip busbw curve can move it. Deterministic per
+    bounding the transient flat-copy in HBM. Deterministic per
     (device_kind, n_devices) within a process — the EF residual
     allocation and the reduction path both call this and must agree."""
     from chainermn_tpu import tuning
@@ -239,12 +238,10 @@ def tuned_bucket_bytes(device_kind: str | None = None,
 def resolve_allreduce_wire(device_kind: str | None = None,
                            n_devices: int = 1):
     """The ``allreduce_grad_dtype="auto"`` resolution: wire variant
-    (f32 / bf16 / the int8 two-phase wire) through the autotune registry
+    (f32 / bf16 / the int8 two-phase wire) through the decision registry
     (decision ``allreduce_wire``), returning the compress dtype the
-    communicator stores. Table default is bf16 — the measured default
-    (halved bytes, zero rounding risk); int8 is adopted only when a
-    cache entry (live-measured or seeded from a busbw curve) shows its
-    two rounding stages paying on this topology."""
+    communicator stores. The table says bf16 (halved bytes, one
+    rounding); int8 has two rounding stages."""
     from chainermn_tpu import tuning
 
     key = tuning.decision_key(device_kind, shape=(max(1, n_devices),),

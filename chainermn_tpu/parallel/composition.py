@@ -974,24 +974,6 @@ def schedule_candidates(n_axes: int) -> tuple[str, ...]:
     return tuple(SCHEDULES) + derived
 
 
-def normalize_schedule_name(schedule: str, n_axes: int) -> str:
-    """Map a menu-instance SIGNATURE back to its menu name — the
-    spelling :func:`schedule_candidates` (and therefore the registry's
-    candidate matching) uses. A composed sweep times every derived
-    pipeline by signature, and ``flat``/``two_level`` are among them as
-    ``ar(all)`` / ``rs(fast)>ar(rest)>ag(fast)``: adopting such a
-    winner under its signature would store a cache entry the candidate
-    list never matches (silently discarded, table default wins).
-    Non-menu signatures and menu names pass through unchanged."""
-    names = canonical_axis_names(max(1, int(n_axes)))
-    table = {
-        flat_composition(names).signature(): "flat",
-        two_level_composition(names).signature(): "two_level",
-        zero_composition(names).signature(): "zero",
-    }
-    return table.get(schedule, schedule)
-
-
 def signature_for(schedule, n_axes: int) -> str:
     """Canonical-token signature for a winner string (menu name or
     signature) — the provenance spelling ``resolve_schedule`` reports,
@@ -1353,7 +1335,6 @@ __all__ = [
     "effective_slices",
     "expand_slices",
     "flat_composition",
-    "normalize_schedule_name",
     "parse_signature",
     "predicted_collectives",
     "reduce_composed",

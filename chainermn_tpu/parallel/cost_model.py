@@ -46,16 +46,16 @@ NEVER TRUSTED BLIND: :func:`rank_compositions` with ``model=None``
 (no rows for this mesh shape) returns mode ``exhaustive`` with
 provenance ``forced:uncalibrated`` — rank on a default-initialized
 model is the failure mode this module refuses by construction — and
-every top-k adoption records its predicted-vs-measured error as cache
-evidence (``tuning.record_measurement(extra_evidence=...)``), so a
-model that drifts past the measurement spread is audited in the cache
-and the bench falls back to exhaustive coverage.
+:func:`emit_sched_search_event` puts every top-k ranking's
+predicted-vs-measured error into the trace, so a model that drifts past
+the measurement spread shows.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import json
+import statistics
 import time
 from typing import Mapping, Optional, Sequence
 
@@ -268,7 +268,11 @@ def load_from_bench_details(
     mode the predicted-vs-measured audit cannot see (the audited arms
     ARE the fit rows). Refusing keeps the cadence honest: a top-k
     capture is followed by one exhaustive sweep that restores full
-    coverage, then top-k resumes."""
+    coverage, then top-k resumes.
+
+    No program writes these rows since ``bench.py`` left (PR 28): the
+    reader and ``tests/data/bench_details_composed_cpu.json`` stay with
+    the rest of this module for ROADMAP D6 to adjudicate."""
     try:
         with open(path) as f:
             data = json.load(f)
@@ -316,7 +320,6 @@ def calibrate(
     from chainermn_tpu.parallel.reduction_schedule import (
         MeasuredComposedReducer,
     )
-    from chainermn_tpu.tuning.measure import repeat_median
 
     axes = comm.grad_axes
     axes = axes if isinstance(axes, tuple) else (axes,)
@@ -337,8 +340,8 @@ def calibrate(
             red.reduce(stacked)
             return (time.perf_counter() - t0) * 1000.0
 
-        med, _ = repeat_median(sample, repeats=repeats)
-        rows[canonical_signature(sig, len(shape))] = med
+        rows[canonical_signature(sig, len(shape))] = statistics.median(
+            sample() for _ in range(max(1, repeats)))
     model = fit_pipeline_rows(
         rows, shape, n_elems * WIRE_ITEMSIZE, source="fit:calibration")
     return model
@@ -455,8 +458,8 @@ def model_error_pct(
 ) -> Optional[float]:
     """Max relative predicted-vs-measured error (percent) over the
     signatures present in BOTH maps — the audit number every top-k
-    adoption records as cache evidence and the bench publishes as
-    ``cost_model_err_pct``. None when the maps share nothing."""
+    ranking's ``sched_search`` event carries. None when the maps share
+    nothing."""
     errs = [
         abs(predicted_ms[s] - measured_ms[s]) / max(abs(measured_ms[s]),
                                                     1e-12)

@@ -306,17 +306,10 @@ def resolve_dispatch_impl(
     tokens: int, n_experts: int, d_model: int, dtype,
     impl: str = "auto",
 ) -> str:
-    """Device-aware dispatch choice, through the autotune registry
+    """Dispatch choice through the decision registry
     (:mod:`chainermn_tpu.tuning`), keyed on ``(device_kind,
-    bucket(T, E, d), dtype)``.
-
-    Measured crossover the default table encodes (r5 bench artifacts):
-    sort is 167.8x the einsum path on the CPU proxy (T2048xE8xD64) but
-    only 1.63x on TPU v5e at the production shape (T16384xE16xD512) —
-    einsum-competitive there, dominant nowhere measured, so the table
-    says ``sort`` for every backend and the persistent cache (seeded
-    from on-chip sweeps) owns any shape bucket where the dense form
-    wins. ``impl`` other than ``"auto"`` short-circuits (explicit
+    bucket(T, E, d), dtype)``; the table says ``sort`` for every
+    backend. ``impl`` other than ``"auto"`` short-circuits (explicit
     caller choice is never overridden).
     """
     if impl != "auto":
@@ -359,13 +352,10 @@ def resolve_expert_parallel(
     """``'on'``/``'off'`` — whether this MoE workload should spread over
     an ``'expert'`` mesh axis (two all_to_alls per layer, experts
     sharded) or stay replicated-local (every shard hosts every expert,
-    zero collectives). Resolved through the autotune registry (decision
+    zero collectives). Resolved through the decision registry (decision
     ``expert_parallel``, keyed like ``moe_dispatch``); the table says
-    ``off`` everywhere — spreading must EARN adoption through bench's
-    ``moe`` phase step-time rows (spread-gated, the spec_tokens
-    precedent), because on a single host the a2a pair is pure overhead
-    and only a real multi-chip capture can price the HBM-per-expert win
-    honestly. ``choice`` other than ``'auto'`` short-circuits."""
+    ``off`` everywhere until a multi-chip MoE cell has measured both
+    sides. ``choice`` other than ``'auto'`` short-circuits."""
     if choice != "auto":
         return choice
     from chainermn_tpu import tuning

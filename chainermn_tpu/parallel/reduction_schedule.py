@@ -35,10 +35,9 @@ not a fixed menu. The three named, equivalence-tested strategies
   :class:`chainermn_tpu.optimizers.MultiNodeOptimizer`, which calls the
   chunk/scatter/gather building blocks here.
 
-Schedule choice is a first-class decision in the autotune registry
+Schedule choice is a decision in the registry
 (:mod:`chainermn_tpu.tuning`, decision ``'reduction_schedule'``), keyed
-(device_kind x world-shape x payload-MB bucket) and seedable offline
-from ``bench.py``'s ``overlap`` phase rows — :func:`resolve_schedule`.
+(device_kind x world-shape x payload-MB bucket) — :func:`resolve_schedule`.
 
 Double buffering (the reference's ``double_buffering_optimizer.py``
 (dagger) staleness-1 semantics) composes with the bucketed schedules:
@@ -75,10 +74,8 @@ DECISION = "reduction_schedule"
 #: Registry decision name for the bucket-slice count a composed
 #: schedule interleaves over (ISSUE 15): ∈ {1, 2, 4, 8}, table default
 #: 1 — slicing multiplies per-stage collective dispatches S× (at 1/S
-#: payload each), so the interleave must EARN adoption through the
-#: bench ``composed`` phase's sliced arms (spread-gated, the
-#: spec_tokens/prefill_chunk precedent). Keyed beside ``DECISION`` on
-#: world-shape x payload-MB so one capture adjudicates both.
+#: payload each). Keyed beside ``DECISION`` on world-shape x payload-MB
+#: so one cell adjudicates both.
 SLICES_DECISION = "comp_slices"
 
 #: The ``comp_slices`` candidate set (registry spellings are strings).
@@ -141,9 +138,7 @@ def resolve_comp_slices(
     """The ``comp_slices`` resolution (ISSUE 15): how many bucket
     slices a composed reduction interleaves over, through the autotune
     registry — keyed exactly like :func:`resolve_schedule` (world-shape
-    x payload-MB, dtype tag ``'slices'``), table default 1 (slicing
-    must earn adoption; a cache entry seeded from bench's
-    ``composed_sliced_ms`` rows moves it)."""
+    x payload-MB, dtype tag ``'slices'``), table default 1."""
     from chainermn_tpu import tuning
 
     mb = max(1, int(payload_bytes) >> 20)
@@ -176,10 +171,7 @@ def resolve_schedule(
     schedule_candidates`): the menu names plus every composition the
     deriver generates for a ``len(world_shape)``-level mesh, keyed by
     signature string — the autotuner searches generated schedules, not
-    a fixed menu. Table default is ``'flat'``; a cache entry seeded
-    from bench's ``overlap``/``composed`` phase rows
-    (``python -m chainermn_tpu.tuning seed``) moves it where a measured
-    comparison shows another pipeline paying (spread-gated, as always).
+    a fixed menu. Table default is ``'flat'``.
 
     ``slices='auto'`` (ISSUE 15) additionally consults the
     ``comp_slices`` decision (:func:`resolve_comp_slices`) and, when it

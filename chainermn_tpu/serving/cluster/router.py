@@ -136,7 +136,7 @@ class Router:
                 if recs:
                     self.decisions.append(dict(recs[-1]))
                 if mode == "colocated_chunked" and not chunked_engines:
-                    # The cache says chunking wins this shape, but THIS
+                    # The decision says chunking wins this shape, but THIS
                     # replica set was built monolithic — route as plain
                     # colocated (honest provenance) rather than promise
                     # a mixed step nobody compiled.

@@ -24,8 +24,7 @@ honestly) to serving:
   tokens, and the cache is DONATED through the decode jit so occupancy
   changes never reallocate. ``'dense'`` keeps the classic
   ``[slots, max_len]`` ring; ``'auto'`` resolves through the tuning
-  registry (decisions ``decode_impl`` / ``kv_block_size``, seeded
-  offline from bench's ``serving`` rows).
+  registry (decisions ``decode_impl`` / ``kv_block_size``).
 - **Tensor-parallel decode.** Pass a ``mesh`` with a ``'model'`` axis:
   weights are head/width-sharded through
   :mod:`chainermn_tpu.parallel.tensor`'s adjoint pairs — exactly one
@@ -67,8 +66,7 @@ KV_BLOCK_SIZES = ("16", "32", "64", "128")
 #: gather → einsum attend; 'fused' = the flash-decoding Pallas kernel
 #: (:mod:`chainermn_tpu.ops.paged_decode`) — one HBM pass over the live
 #: blocks, table-indexed in-kernel gather, no dense view. Table default
-#: 'xla': the kernel must EARN adoption through bench's
-#: ``serving_decode_kernel`` rows (spread-gated).
+#: 'xla'.
 DECODE_ATTEND_IMPLS = ("xla", "fused")
 #: speculation lengths the ``spec_tokens`` decision chooses among
 #: (ISSUE 5): 0 = plain one-token decode; K > 0 = draft-and-verify with
@@ -86,7 +84,7 @@ MIN_SHARED_BLOCKS = ("1", "2", "4")
 #: (ISSUE 11): 0 = monolithic bucketed prefill (``prefill_join``); C > 0
 #: = admitted prompts write C tokens of KV per tick INSIDE the mixed
 #: step while the remaining active slots decode — the long-prompt
-#: TPOT-freeze fix, priced by the bench's bursty goodput-under-SLO rows.
+#: TPOT-freeze fix.
 PREFILL_CHUNKS = ("0", "16", "32", "64", "128")
 #: sequence-parallel long-prompt prefill over the replica's ``model``
 #: partition (ISSUE 13): 'off' = the TP (or single-device) monolithic
@@ -104,8 +102,7 @@ PREFILL_SEQ_PARALLEL = ("off", "on")
 #: tenant churn is host metadata only); 'merged' = the tenant's delta
 #: is folded into the base weights at construction (zero per-step
 #: delta cost — single-tenant-dominant traffic; other tenants refused
-#: loudly). Table default 'gather': merging must EARN adoption through
-#: the bench's ``serving_tenants`` rows. ONE definition, in
+#: loudly). Table default 'gather'. ONE definition, in
 #: adapters.py — the ctor's validation and the tuning candidates must
 #: never disagree.
 from chainermn_tpu.serving.adapters import ADAPTER_IMPLS  # noqa: E402
@@ -114,8 +111,7 @@ from chainermn_tpu.serving.adapters import ADAPTER_IMPLS  # noqa: E402
 def resolve_adapter_impl(d_model: int, num_heads: int, max_len: int) -> str:
     """Resolve ``adapter_impl`` ('gather' | 'merged') via the registry
     (decision ``adapter_impl``, same key as the other serving
-    decisions; bench's ``serving_tenants`` phase measures both arms
-    under Zipf-skewed multi-tenant traffic and seeds it)."""
+    decisions)."""
     from chainermn_tpu import tuning
 
     return tuning.choice(
@@ -138,10 +134,8 @@ def _gather_adapter_rows(stacks, rows):
 
 def serving_decision_key(d_model: int, num_heads: int, max_len: int,
                          device_kind: Optional[str] = None) -> str:
-    """The ONE key both serving decisions resolve under —
-    device_kind x model-shape bucket x max-seq bucket. bench's
-    ``serving`` phase records the same dims (``serving_model_shape``)
-    so offline seeding rebuilds this key exactly."""
+    """The ONE key the serving decisions resolve under —
+    device_kind x model-shape bucket x max-seq bucket."""
     from chainermn_tpu import tuning
 
     return tuning.decision_key(
@@ -172,9 +166,7 @@ def resolve_kv_block_size(d_model: int, num_heads: int, max_len: int) -> int:
 def resolve_decode_attend_impl(d_model: int, num_heads: int,
                                max_len: int) -> str:
     """Resolve ``decode_attend_impl`` ('xla' | 'fused') via the registry
-    (same key as the other serving decisions; bench's
-    ``serving_decode_kernel`` phase measures both attends per shape and
-    seeds it — table default 'xla', the kernel earns adoption)."""
+    (same key as the other serving decisions; table default 'xla')."""
     from chainermn_tpu import tuning
 
     return tuning.choice(
@@ -185,8 +177,7 @@ def resolve_decode_attend_impl(d_model: int, num_heads: int,
 
 def resolve_spec_tokens(d_model: int, num_heads: int, max_len: int) -> int:
     """Resolve the speculation length K via the registry (decision
-    ``spec_tokens``, same key as the other serving decisions — bench's
-    ``serving`` phase measures spec-vs-plain per shape and seeds it)."""
+    ``spec_tokens``, same key as the other serving decisions)."""
     from chainermn_tpu import tuning
 
     return int(tuning.choice(
@@ -220,8 +211,7 @@ def resolve_prefill_chunk(d_model: int, num_heads: int,
                           max_len: int) -> int:
     """Resolve the chunked-prefill width via the registry (decision
     ``prefill_chunk``, same key as the other serving decisions — table
-    default 0: chunking must EARN adoption through the bench's bursty
-    goodput-under-SLO rows, the spec_tokens precedent)."""
+    default 0)."""
     from chainermn_tpu import tuning
 
     return int(tuning.choice(
@@ -234,8 +224,7 @@ def resolve_prefill_seq_parallel(d_model: int, num_heads: int,
                                  max_len: int) -> str:
     """Resolve ``prefill_seq_parallel`` ('off' | 'on') via the registry
     (decision ``prefill_seq_parallel``, same key as the other serving
-    decisions; table default 'off' — the wide prefill must EARN adoption
-    through bench's ``seq_parallel`` long-prompt TTFT rows)."""
+    decisions; table default 'off')."""
     from chainermn_tpu import tuning
 
     return tuning.choice(
@@ -434,8 +423,7 @@ class ServingEngine:
         emitted token is the model's own argmax — or counter-keyed
         sample — at its true position). ``'auto'`` resolves through the
         registry (decision
-        ``prefill_chunk``, table default 0 — chunking must earn
-        adoption via the bursty bench rows).
+        ``prefill_chunk``, table default 0).
       prefill_seq_parallel: sequence-parallel long-prompt prefill over
         the mesh's ``model`` partition (ISSUE 13): ``'on'`` shards a
         cache-MISS prompt's forward over the TP devices — each shard
@@ -456,9 +444,7 @@ class ServingEngine:
         ``window``, and ``prefill_chunk == 0`` (chunked admission takes
         precedence) — explicit ``'on'`` violating these is rejected; an
         ``'auto'`` resolution is forced off with provenance. ``'auto'``
-        resolves via the registry (table default ``off`` — the wide
-        prefill must earn adoption through bench's ``seq_parallel``
-        long-prompt TTFT rows).
+        resolves via the registry (table default ``off``).
       adapter_bank: multi-tenant low-rank delta store (ISSUE 14,
         :class:`~chainermn_tpu.serving.adapters.AdapterBank`): each
         slot carries a host-side tenant row, every serving program
@@ -666,7 +652,7 @@ class ServingEngine:
         # ---- prefix sharing (ISSUE 7): trie + COW over the paged pool.
         # Dense rows are slot-private by layout — nothing to share, so
         # the decision is forced off there without consulting the
-        # registry (an 'on' cache entry for a dense shape would be a
+        # registry (an 'on' record for a dense shape would be a
         # lie about what ran). Validate BEFORE the dense force: a typo
         # must raise identically whichever decode impl it rides with.
         if prefix_cache != "auto" and prefix_cache not in PREFIX_CACHE:

@@ -1,7 +1,7 @@
 """Where compiled programs are kept between runs.
 
-One helper for every entry point (``chip_smoke.py``, ``bench.py``, the
-example CLIs), so that processes which share compiles share one cache.
+One helper for every entry point (``chip_smoke.py``, ``benchmark/run.py``,
+the example CLIs), so that processes which share compiles share one cache.
 """
 
 from __future__ import annotations

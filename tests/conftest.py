@@ -9,7 +9,6 @@ tests are hermetic on any machine, TPU present or not.
 """
 
 import os
-import tempfile
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -19,20 +18,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
-# Hermeticity for the autotune registry (chainermn_tpu.tuning): the
-# repo-root .autotune_cache.json is a bench-mutated artifact — a prior
-# `python bench.py` on this machine could flip which code path the
-# "hermetic" suite exercises. Pin the suite to pure-table resolution
-# (deterministic) and point the cache at an untracked temp path so no
-# test write touches the repo file. tests/test_tuning.py overrides both
-# per-test via monkeypatch to exercise cache/measurement behaviour.
-os.environ["CHAINERMN_TPU_AUTOTUNE"] = "off"
-os.environ.setdefault(
-    "CHAINERMN_TPU_AUTOTUNE_CACHE",
-    os.path.join(tempfile.gettempdir(), f"autotune_test_{os.getpid()}.json"),
-)
-
-# Same hermeticity rule for the observability recorder: a developer
+# Hermeticity for the observability recorder: a developer
 # shell (or a capture-script run) exporting CHAINERMN_TPU_TRACE must not
 # make the suite write trace files — tests that need a recorder enable
 # one explicitly (tests/test_trace.py).

@@ -647,9 +647,30 @@ def test_trace_report_roofline_scoped_to_device_plane(tmp_path):
     assert [(f["op"], f["device"]) for f in floors] == [
         ("allreduce", "TPU v5 lite")
     ], floors
-    assert floors[0]["hbm_peak_gbps"] == 819.0  # v5e table via bench
+    assert floors[0]["hbm_peak_gbps"] == 819.0  # benchmark/peaks.py
     # no internal bookkeeping leaks into the contract
     assert all("_devices" not in c for c in summary["collectives"])
+
+
+def test_trace_report_reads_its_peaks_from_the_benchmarks_table():
+    """The report's HBM floor is ``benchmark/peaks.py``'s row for the
+    device kind, and a kind with no published row there has no floor."""
+    import importlib.util
+
+    def load(name, *parts):
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(_REPO, *parts))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    report = load("_tr_peaks", "tools", "trace_report.py")
+    peaks = load("_bench_peaks_t", "benchmark", "peaks.py")
+    for kind in ("TPU v5 lite", "TPU v5e"):
+        assert report._hbm_peak(kind) == \
+            peaks.lookup(kind)["hbm_bytes_per_s"]
+    assert report._hbm_peak("cpu") is None
+    assert report._hbm_peak("TPU v99") is None
 
 
 def _metrics_dump_mod():

@@ -335,10 +335,7 @@ class TestErrorPathAndProvenance:
             reduce_tree({"w": jnp.ones((4,))}, schedule="ring",
                         axes=comm3.grad_axes)
 
-    def test_resolve_schedule_provenance_names_composition(
-        self, monkeypatch
-    ):
-        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE", "table")
+    def test_resolve_schedule_provenance_names_composition(self):
         winner, rec = resolve_schedule("cpu", 3 << 20, (2, 2, 2))
         assert winner == "flat"  # table default, still a candidate
         assert rec["composition"] == "ar(a0+a1+a2)"

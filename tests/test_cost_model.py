@@ -16,10 +16,7 @@ never trusted blind — so the tests pin exactly that contract:
   candidate measured — never a ranking off a default model;
 - on a recorded composed sweep's rows (tests/data/) the predicted winner
   lands inside the measured spread gate of the measured best (the
-  acceptance criterion);
-- offline seeding adopts ``topk`` when the recorded model error sits
-  inside the spread and ``exhaustive`` when it does not, with the
-  predicted rows carried as evidence.
+  acceptance criterion).
 """
 
 import json
@@ -28,7 +25,6 @@ import random
 
 import pytest
 
-from chainermn_tpu import tuning
 from chainermn_tpu.parallel.composition import (
     canonical_axis_names,
     derive_compositions,
@@ -362,66 +358,3 @@ class TestSchedSearchTraceEvent:
         err = emit_sched_search_event(
             rank, {s: rank.predicted_ms[s] for s in rank.measured})
         assert err == 0.0
-
-
-class TestSchedSearchSeeding:
-    """Offline seeding of the sched_search decision from the bench's
-    model-audit keys — topk inside the spread, exhaustive past it."""
-
-    @pytest.fixture(autouse=True)
-    def _isolated_cache(self, tmp_path, monkeypatch):
-        # conftest pins AUTOTUNE=off for hermeticity; re-enable cache
-        # resolution against a tmp cache so choice() can hit the seed
-        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE_CACHE",
-                           str(tmp_path / "cache.json"))
-        monkeypatch.delenv("CHAINERMN_TPU_AUTOTUNE", raising=False)
-        monkeypatch.delenv("CHAINERMN_TPU_AUTOTUNE_FORCE", raising=False)
-
-    def _seed(self, tmp_path, err, spread=32.1):
-        details = {
-            "device_kind": "cpu", "n_devices": 8,
-            "measured_at": "2026-08-07T00:00:00Z",
-            "composed_world_shape": [2, 2, 2],
-            "composed_payload_mb": 1,
-            "composed_spread_pct": spread,
-            "cost_model_err_pct": err,
-            "sched_search_selected": "topk",
-            "sched_search_predicted_ms": {"ar(a0+a1+a2)": 3.23,
-                                          "rs(a2)>ag(a2)": 4.0},
-            "sched_search_skipped": ["rs(a2)>ag(a2)"],
-        }
-        p = tmp_path / "details.json"
-        p.write_text(json.dumps(details))
-        return tuning.seed_from_bench_details(str(p))
-
-    def test_error_inside_spread_seeds_topk(self, tmp_path):
-        seeded = self._seed(tmp_path, err=21.08)
-        assert any(s.startswith("sched_search|") and s.endswith("topk")
-                   for s in seeded)
-        key = tuning.decision_key("cpu", shape=(2, 2, 2, 1),
-                                  dtype="search")
-        assert tuning.choice("sched_search", ("topk", "exhaustive"),
-                             key) == "topk"
-        rec = [r for r in tuning.decisions_taken()
-               if r["key"] == key][-1]
-        assert rec["source"].startswith("cache:seeded")
-        # the full audit rides the cache ENTRY as evidence
-        from chainermn_tpu.tuning.cache import lookup_entry
-
-        ev = lookup_entry("sched_search", key)
-        assert ev["cost_model_err_pct"] == pytest.approx(21.08)
-        assert ev["spread_pct"] == pytest.approx(32.1)
-        assert ev["predicted_ms"]["ar(a0+a1+a2)"] == pytest.approx(3.23)
-        assert ev["skipped"] == ["rs(a2)>ag(a2)"]
-        assert ev["selected"] == "topk"
-
-    def test_error_past_spread_seeds_exhaustive(self, tmp_path):
-        seeded = self._seed(tmp_path, err=55.0)
-        assert any(s.startswith("sched_search|")
-                   and s.endswith("exhaustive") for s in seeded)
-
-    def test_no_audit_keys_seeds_nothing(self, tmp_path):
-        p = tmp_path / "details.json"
-        p.write_text(json.dumps({"device_kind": "cpu", "n_devices": 8}))
-        assert not any(s.startswith("sched_search|")
-                       for s in tuning.seed_from_bench_details(str(p)))

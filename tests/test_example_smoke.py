@@ -87,36 +87,6 @@ def test_imagenet_example_native_loader(tmp_path):
     ])
 
 
-def test_transformer_sweep_tool_smoke():
-    """The MFU sweep tool (perf methodology for the tracked
-    transformer_mfu metric) runs a two-variant grid on the CPU mesh —
-    the legacy 'true' remat spelling (compat) and the round-4 'nothing'
-    granularity — and reports step_ms + tokens/s."""
-    ex = _load_example("transformer", "sweep_mfu.py")
-    results = ex.main([
-        "--communicator", "naive", "--layers", "2", "--d-model", "64",
-        "--heads", "2", "--d-ff", "128", "--seq-len", "128",
-        "--batch", "1", "--steps", "2", "--chunks", "2",
-        "--blocks", "64x128", "--remat", "true,nothing",
-    ])
-    assert len(results) == 2
-    assert all(r["tokens_per_sec"] > 0 for r in results)
-    assert {r["remat"] for r in results} == {"dots", "nothing"}
-
-
-def test_resnet_sweep_tool_smoke():
-    """The ResNet MFU sweep tool (stage 2 of the on-chip capture; the
-    remat-byte-reduction methodology behind the docs/benchmarks.md
-    roofline) runs a one-variant grid on the CPU mesh."""
-    ex = _load_example("imagenet", "sweep_mfu.py")
-    results = ex.main([
-        "--communicator", "naive", "--batches", "1", "--steps", "1",
-        "--stems", "standard", "--remat", "conv",
-    ])
-    assert results and results[0]["images_per_sec"] > 0
-    assert results[0]["remat"] == "conv"
-
-
 def test_transformer_example_mlm_smoke():
     """--mlm: the bidirectional-encoder pretraining mode (round 5)."""
     ex = _load_example("transformer", "train_transformer_lm.py")

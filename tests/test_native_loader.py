@@ -107,52 +107,3 @@ def test_open_rejects_bad_record_size(dataset):
     path, _, _ = dataset
     with pytest.raises(RuntimeError, match="dl_open failed"):
         NativeDataLoader(path, [("x", np.uint8, (9,))], batch_size=4)
-
-def test_bench_native_loop_child_mode(tmp_path):
-    """``bench.py --run native-loop`` (the fresh-process end-to-end input
-    benchmark child) runs loader → prefetch_to_device → jitted train step
-    and prints a wall-time JSON line; its timed region holds no
-    device→host transfer until the one sync that ends it
-    (docs/benchmarks.md, input-pipeline section)."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, repo)
-    try:
-        from _driver_env import cpu_scrubbed_env
-    finally:
-        sys.path.pop(0)
-
-    # Match bench._resnet_setup(on_accel=False) INSIDE THE CHILD: hw=32,
-    # batch = 8 * mesh size, where the child's mesh is pinned to 8 by
-    # cpu_scrubbed_env(8) below — NOT this process's device count (which
-    # an externally-set XLA_FLAGS could make different).
-    hw = 32
-    batch = 8 * 8
-    rng = np.random.default_rng(0)
-    images = rng.integers(0, 256, size=(batch * 3, hw, hw, 3), dtype=np.uint8)
-    labels = rng.integers(0, 10, size=(batch * 3,)).astype(np.int32)
-    path = str(tmp_path / "records.bin")
-    write_fixed_records(path, images, labels)
-
-    env = cpu_scrubbed_env(8, cache_dir=os.path.join(repo, ".jax_cache"))
-    env.update(
-        CMN_NATIVE_STEPS="2",
-        CMN_NATIVE_RECORDS=path,
-        CMN_NATIVE_HW=str(hw),
-        CMN_NATIVE_BATCH=str(batch),
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--run",
-         "native-loop"],
-        env=env, cwd=repo, capture_output=True, text=True, timeout=300,
-    )
-    assert proc.returncode == 0, proc.stderr[-1500:]
-    line = [l for l in proc.stdout.splitlines() if l.startswith("{")][-1]
-    out = json.loads(line)
-    assert out["steps"] == 2
-    assert out["batch"] == batch
-    assert out["wall_s"] > 0

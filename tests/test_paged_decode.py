@@ -347,8 +347,7 @@ class TestEngineEquivalence:
             )
 
     def test_table_default_resolves_xla(self, lm, monkeypatch):
-        # conftest pins CHAINERMN_TPU_AUTOTUNE=off → DEFAULT_TABLE: the
-        # kernel must EARN adoption, so 'auto' resolves 'xla' here.
+        # DEFAULT_TABLE says 'xla' until a cell adjudicates the kernel.
         model, params = lm
         engine = ServingEngine(
             model, params, num_slots=2, max_len=32, decode_impl="paged",

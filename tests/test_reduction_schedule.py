@@ -505,8 +505,7 @@ class TestStructural:
 
 
 class TestAutoResolution:
-    def test_table_default_is_flat_with_provenance(self, comm, monkeypatch):
-        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE", "table")
+    def test_table_default_is_flat_with_provenance(self, comm):
         winner, rec = resolve_schedule("cpu", 3 << 20, (8,))
         assert winner == "flat"
         assert rec["name"] == "reduction_schedule"
@@ -854,12 +853,11 @@ class TestSlicedEagerReducers:
 
 
 class TestCompSlicesDecision:
-    def test_table_default_is_one(self, monkeypatch):
+    def test_table_default_is_one(self):
         from chainermn_tpu.parallel.reduction_schedule import (
             resolve_comp_slices,
         )
 
-        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE", "table")
         assert resolve_comp_slices("cpu", 3 << 20, (2, 2, 2)) == 1
         # ...and the auto schedule resolution stays unsliced
         winner, rec = resolve_schedule("cpu", 3 << 20, (2, 2, 2),
@@ -868,7 +866,6 @@ class TestCompSlicesDecision:
         assert "comp_slices" not in (rec or {})
 
     def test_forced_slices_slice_the_auto_winner(self, monkeypatch):
-        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE", "table")
         monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE_FORCE",
                            "comp_slices=4")
         winner, rec = resolve_schedule("cpu", 3 << 20, (2, 2, 2),
@@ -890,7 +887,6 @@ class TestCompSlicesDecision:
         """End to end: a forced comp_slices=2 'auto' optimizer reduces
         a dyadic tree identically to the flat schedule — the sliced
         winner compiles and runs through the standard update path."""
-        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE", "table")
         monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE_FORCE",
                            "comp_slices=2")
         opt = create_multi_node_optimizer(

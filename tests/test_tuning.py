@@ -1,24 +1,18 @@
-"""Device-aware dispatch + autotune cache (chainermn_tpu.tuning).
+"""Device-aware dispatch (chainermn_tpu.tuning).
 
 Covers the subsystem's contracts hermetically (no hardware):
 
-- cache round-trip / corrupt-file tolerance / shape-bucket keying;
-- offline seeding from a BENCH_DETAILS-shaped artifact — the on-chip
-  MoE entry (einsum-competitive, 1.63x) is adopted for the TPU device
-  kind while LIVE measurement on the CPU mesh picks sort (the 167.8x
-  side of the crossover) — the acceptance demo for the whole mechanism;
+- every decision resolves from its :data:`DEFAULT_TABLE` entry, through
+  its own call site where it has one, for a TPU key and a CPU key, and
+  neither a file nor a mode variable in the environment can change that;
+  ``CHAINERMN_TPU_AUTOTUNE_FORCE`` is the one override;
 - dist==single equivalence (values AND grads) for BOTH sides of every
-  tuned choice (MoE dispatch impls, attention variants, wire dtypes,
-  double-buffering semantics);
+  tuned choice (MoE dispatch impls, attention variants, wire dtypes);
 - a structural assertion that the auto-selected MoE path on the CPU
   mesh is the sort path (scatter in the lowering, decision recorded).
-
-Every test pins the cache to a tmp path — the repo's own seeded
-``.autotune_cache.json`` must never leak into hermetic assertions.
 """
 
 import json
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -40,12 +34,8 @@ D = 8
 
 
 @pytest.fixture(autouse=True)
-def _isolated_cache(tmp_path, monkeypatch):
-    """Every test gets its own cache file and a clean decision log."""
-    monkeypatch.setenv(
-        "CHAINERMN_TPU_AUTOTUNE_CACHE", str(tmp_path / "cache.json")
-    )
-    monkeypatch.delenv("CHAINERMN_TPU_AUTOTUNE", raising=False)
+def _clean_decisions(monkeypatch):
+    """Every test starts with no override and a clean decision log."""
     monkeypatch.delenv("CHAINERMN_TPU_AUTOTUNE_FORCE", raising=False)
     tuning.reset_decisions()
     yield
@@ -66,36 +56,11 @@ def _expert_init(rng):
 
 
 # ---------------------------------------------------------------------------
-# Registry + cache mechanics
+# Registry mechanics
 # ---------------------------------------------------------------------------
 
 
 class TestRegistry:
-    def test_cache_round_trip(self):
-        key = tuning.decision_key("TPU v5 lite", shape=(4096, 8), dtype="bf16")
-        tuning.store_entry(
-            "moe_dispatch", key,
-            {"winner": "einsum", "source": "test",
-             "candidates_ms": {"einsum": 1.0, "sort": 2.0}},
-        )
-        got = tuning.choice("moe_dispatch", ("sort", "einsum"), key)
-        assert got == "einsum"
-        d = {(r["name"], r["key"]): r for r in tuning.decisions_taken()}
-        assert d[("moe_dispatch", key)]["source"] == "cache:test"
-        # and the file itself is well-formed JSON with provenance
-        doc = tuning.load_cache()
-        entry = doc["decisions"][f"moe_dispatch|{key}"]
-        assert entry["source"] == "test" and "measured_at" in entry
-
-    def test_corrupt_cache_is_empty_not_fatal(self, tmp_path, monkeypatch):
-        bad = tmp_path / "bad.json"
-        bad.write_text("{nope")
-        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE_CACHE", str(bad))
-        key = tuning.decision_key("cpu", shape=(8,), dtype="grad")
-        # falls through to the table, never raises
-        assert tuning.choice("allreduce_wire", ("f32", "bf16", "int8"),
-                             key) == "bf16"
-
     def test_shape_bucket_keying(self):
         # nearby shapes share a bucket; far shapes do not
         assert tuning.shape_bucket((2000, 8, 60)) == "2048x8x64"
@@ -108,15 +73,6 @@ class TestRegistry:
         with pytest.raises(ValueError):
             tuning.shape_bucket((0,))
 
-    def test_seeded_key_matches_registry_key(self):
-        # cache._bucketed_key (jax-free seeding) and registry.decision_key
-        # are duplicated-by-contract; they must produce the same string.
-        from chainermn_tpu.tuning.cache import _bucketed_key
-
-        assert _bucketed_key("TPU v5 lite", (16384, 16, 512), "bfloat16") \
-            == tuning.decision_key("TPU v5 lite", shape=(16384, 16, 512),
-                                   dtype=jnp.bfloat16)
-
     def test_forced_override_wins_and_validates(self, monkeypatch):
         monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE_FORCE",
                            "moe_dispatch=einsum")
@@ -128,232 +84,153 @@ class TestRegistry:
         with pytest.raises(ValueError, match="bogus"):
             tuning.choice("moe_dispatch", ("sort", "einsum"), key)
 
-    def test_spread_dominated_measurement_falls_back_to_table(self):
-        # candidates whose medians differ by less than their spread:
-        # the autotuner must refuse to adopt noise as a winner.
-        a = iter([10.0, 10.5, 12.0])
-        b = iter([10.2, 10.4, 11.8])
-        key = tuning.decision_key("cpu", shape=(64, 2, 8), dtype="bf16")
-        winner = tuning.choice(
-            "attention", ("flash", "xla"), key,
-            measure={"flash": lambda: next(a), "xla": lambda: next(b)},
-        )
-        assert winner == "xla"  # the CPU table entry, not the coin flip
-        rec = tuning.decisions_taken()[-1]
-        assert rec["source"] == "table:spread-dominated"
-        # nothing was persisted: a later lookup still has no cache entry
-        assert tuning.load_cache()["decisions"] == {}
-
-    def test_one_shot_measurement_persists(self):
-        calls = {"fast": 0, "slow": 0}
-
-        def mk(name, ms):
-            def f():
-                calls[name] += 1
-                return ms
-            return f
-
-        key = tuning.decision_key("cpu", shape=(256,), dtype="bf16")
-        w1 = tuning.choice(
-            "attention", ("fast", "slow"), key,
-            measure={"fast": mk("fast", 1.0), "slow": mk("slow", 9.0)},
-        )
-        assert w1 == "fast" and calls == {"fast": 3, "slow": 3}
-        # second resolution: cache hit, measurement NOT re-run
-        w2 = tuning.choice(
-            "attention", ("fast", "slow"), key,
-            measure={"fast": mk("fast", 1.0), "slow": mk("slow", 9.0)},
-        )
-        assert w2 == "fast" and calls == {"fast": 3, "slow": 3}
-
-
-# ---------------------------------------------------------------------------
-# Offline seeding: the acceptance demo (no hardware)
-# ---------------------------------------------------------------------------
-
-
-_FAKE_CPU_DETAILS = {
-    # a CPU-proxy run's rows (`bench.py --run cpu`)
-    "device_kind": "cpu", "n_devices": 8,
-    "moe_dispatch_shape": "T2048xE8xD64_cap320_top2",
-    "moe_dispatch_einsum_ms": 96.063, "moe_dispatch_sort_ms": 0.572,
-    "moe_dispatch_spread_pct": 12.4,
-    "attn_shape": "B1xT256xH2xD64_bf16_causal",
-    "flash_fwdbwd_ms": 4.893, "xla_fwdbwd_ms": 2.739,
-    "double_buffer_speedup": 0.752, "double_buffer_spread_pct": 19.4,
-    # ISSUE 3: the overlap phase's per-schedule medians + key material
-    "overlap_schedule_ms": {"flat": 11.3, "two_level": 11.8, "zero": 9.4},
-    "overlap_schedule_spread_pct": 8.5,
-    "overlap_world_shape": [8], "overlap_payload_mb": 1,
-}
-
-_FAKE_TPU_DETAILS = {
-    # a chip run's rows (`python bench.py`), 4-chip-shaped so the wire
-    # seeding (gated on a real multi-member axis) is exercised
-    "device_kind": "TPU v5 lite", "n_devices": 4,
-    "measured_at": "2026-08-01T08:46:00Z",
-    "moe_dispatch_shape": "T16384xE16xD512_cap1280_top2",
-    "moe_dispatch_einsum_ms": 11.362, "moe_dispatch_sort_ms": 6.981,
-    "attn_shape": "B4xT4096xH8xD128_bf16_causal",
-    "flash_fwdbwd_ms": 13.605, "xla_fwdbwd_ms": 41.08,
-    "double_buffer_speedup": 0.85,
-    "overlap_schedule_ms": {"flat": 5.0, "two_level": 3.9,
-                            "zero": 4.4},
-    "overlap_schedule_spread_pct": 2.0,
-    "overlap_world_shape": [4], "overlap_payload_mb": 128,
-    "allreduce_curve": [
-        {"mib": 128, "dtype": "bfloat16", "mode": "fused",
-         "busbw_gbps": 101.6},
-        {"mib": 512, "dtype": "bfloat16", "mode": "bucketed",
-         "busbw_gbps": 99.0},
-        {"mib": 256, "dtype": "float32", "mode": "int8",
-         "busbw_gbps": 55.0},
-    ],
+#: two candidates of each decision: whichever the table does not name
+#: for a key is the contrary winner the decoy file offers there. A
+#: decision added to the table needs its line here.
+_SIDES = {
+    "allreduce_wire": ("bf16", "int8"),
+    "allreduce_bucket_mb": ("64", "256"),
+    "double_buffering": ("off", "on"),
+    "reduction_schedule": ("flat", "two_level"),
+    "comp_slices": ("1", "4"),
+    "sched_search": ("topk", "exhaustive"),
+    "attention": ("flash", "xla"),
+    "attention_windowed": ("windowed", "xla"),
+    "moe_dispatch": ("sort", "einsum"),
+    "expert_parallel": ("off", "on"),
+    "seq_attn_impl": ("ring", "ulysses"),
+    "decode_impl": ("paged", "dense"),
+    "kv_block_size": ("64", "16"),
+    "decode_attend_impl": ("xla", "fused"),
+    "spec_tokens": ("0", "4"),
+    "prefix_cache": ("on", "off"),
+    "min_shared_blocks": ("1", "2"),
+    "prefill_chunk": ("0", "32"),
+    "cluster_disagg": ("colocated", "disaggregated"),
+    "prefill_seq_parallel": ("off", "on"),
+    "adapter_impl": ("gather", "merged"),
 }
 
 
-class TestSeeding:
-    def _seed(self, tmp_path):
-        """Seed one cache from a CPU-proxy artifact and a chip artifact:
-        each lands under its own device kind."""
-        seeded = []
-        for name, doc in (("cpu.json", _FAKE_CPU_DETAILS),
-                          ("tpu.json", _FAKE_TPU_DETAILS)):
-            p = tmp_path / name
-            p.write_text(json.dumps(doc))
-            seeded += tuning.seed_from_bench_details(str(p))
-        return seeded
+def _call_sites():
+    """``decision -> call(kind)``: the decision's own resolver in the
+    program, where it has one that is not a constructor."""
+    from chainermn_tpu.ops.attention import resolve_attention_impl
+    from chainermn_tpu.parallel import collectives, moe
+    from chainermn_tpu.parallel import reduction_schedule as rs
+    from chainermn_tpu.parallel.plan import ParallelPlan
+    from chainermn_tpu.serving import engine
 
-    def test_seeding_adopts_onchip_choice_cpu_measurement_picks_sort(
-        self, tmp_path
-    ):
-        """THE acceptance demo: one cache, both backends, no hardware.
+    def plan_seq(kind):
+        plan = ParallelPlan({"seq": 4}, devices=jax.devices("cpu")[:4])
+        plan.seq_attention(heads=4, t_local=16, impl="auto")
 
-        Seeded from the artifact, the TPU entry reproduces the on-chip
-        choice — sort, but einsum-COMPETITIVE (1.63x, vs 167.8x on the
-        proxy) — under the TPU device kind; a LIVE measurement of the
-        real dispatch impls on this CPU host picks sort by a margin no
-        spread can dominate."""
-        seeded = self._seed(tmp_path)
-        assert any("moe_dispatch|TPU v5 lite" in s for s in seeded)
+    def moe_dispatch(kind):
+        moe.resolve_dispatch_impl(2048, 8, 64, jnp.bfloat16)
+        plan = ParallelPlan({"expert": 4}, devices=jax.devices("cpu")[:4])
+        plan.moe_layer(tokens_local=16, d_model=D, impl="auto")
 
-        # 1) the seeded cache answers for the TPU device kind without
-        #    re-measuring, and carries the einsum-competitive evidence
-        tpu_key = tuning.decision_key(
-            "TPU v5 lite", shape=(16384, 16, 512), dtype="bfloat16"
-        )
-        assert tuning.choice("moe_dispatch", ("sort", "einsum"),
-                             tpu_key) == "sort"
-        rec = [r for r in tuning.decisions_taken()
-               if r["key"] == tpu_key][-1]
-        assert rec["source"].startswith("cache:seeded")
-        ms = rec["evidence"]["candidates_ms"]
-        ratio = ms["einsum"] / ms["sort"]
-        assert 1.0 < ratio < 2.0, f"on-chip einsum not competitive: {ratio}"
+    served = {
+        name: (lambda kind, f=getattr(engine, "resolve_" + name):
+               f(64, 4, 64))
+        for name in ("decode_impl", "kv_block_size", "decode_attend_impl",
+                     "spec_tokens", "prefix_cache", "min_shared_blocks",
+                     "prefill_chunk", "prefill_seq_parallel",
+                     "adapter_impl")
+    }
+    return {
+        "attention": lambda kind: resolve_attention_impl(
+            (1, 256, 2, 64), jnp.bfloat16),
+        "attention_windowed": lambda kind: resolve_attention_impl(
+            (1, 256, 2, 64), jnp.bfloat16, windowed=True),
+        "moe_dispatch": moe_dispatch,
+        "expert_parallel": lambda kind: moe.resolve_expert_parallel(
+            128, 8, 64, jnp.float32),
+        "allreduce_wire": lambda kind: collectives.resolve_allreduce_wire(
+            kind, 4),
+        "allreduce_bucket_mb": lambda kind: collectives.tuned_bucket_bytes(
+            kind, 4),
+        "reduction_schedule": lambda kind: rs.resolve_schedule(
+            kind, 1 << 20, (8,)),
+        "comp_slices": lambda kind: rs.resolve_comp_slices(
+            kind, 1 << 20, (2, 2, 2)),
+        "seq_attn_impl": plan_seq,
+        **served,
+    }
 
-        # 2) live CPU measurement of the REAL impls picks sort
-        T, E, d = 512, 8, 32
-        capacity = int(T / E * 1.25)
-        x = jax.random.normal(jax.random.PRNGKey(0), (T, d), jnp.float32)
-        logits = jax.random.normal(jax.random.PRNGKey(1), (T, E))
 
-        def timed(fn):
-            @jax.jit
-            def run(x, logits):
-                q, combine = fn(x, logits, capacity, 2)
-                return jnp.sum(combine(q).astype(jnp.float32))
+@pytest.mark.parametrize("name", sorted(tuning.DEFAULT_TABLE))
+def test_decision_resolves_from_its_table_entry(name, tmp_path, monkeypatch):
+    """The winner is the table's and the record says ``table``, on a TPU
+    key and a CPU key, whatever a file of contrary winners and a mode
+    variable in the environment say: nothing but the table and
+    ``CHAINERMN_TPU_AUTOTUNE_FORCE`` decides a path."""
+    from chainermn_tpu.tuning import registry
 
-            run(x, logits).block_until_ready()  # compile outside timing
+    table, sides = tuning.DEFAULT_TABLE[name], _SIDES[name]
+    site = _call_sites().get(name)
+    decoy = tmp_path / "decoy_cache.json"
+    monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE_CACHE", str(decoy))
+    monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE", "measure")
+    for kind in ("TPU v5 lite", "cpu"):
+        # sites that take no device kind key on the live backend's
+        monkeypatch.setattr(registry, "current_device_kind", lambda: kind)
+        expect = table.get(tuning.device_class(kind)) or table["*"]
 
-            def sample():
-                import time
+        def resolve():
+            tuning.reset_decisions()
+            if site is not None:
+                site(kind)
+            else:
+                tuning.choice(name, sides, tuning.decision_key(
+                    kind, shape=(4,), dtype="step"))
+            recs = [r for r in tuning.decisions_taken()
+                    if r["name"] == name]
+            assert recs and all(r["key"].startswith(kind) for r in recs)
+            return recs
 
-                t0 = time.perf_counter()
-                run(x, logits).block_until_ready()
-                return (time.perf_counter() - t0) * 1e3
+        recs = resolve()
+        # a contrary winner under exactly the keys the site asked for
+        contrary = next(w for w in sides if w != expect)
+        decoy.write_text(json.dumps({"version": 1, "decisions": {
+            f"{name}|{r['key']}": {"winner": contrary, "source": "decoy"}
+            for r in recs}}))
+        for rec in recs + resolve():
+            assert rec["winner"] == expect, (kind, rec)
+            assert rec["source"] == "table", (kind, rec)
 
-            return sample
 
-        cpu_key = tuning.decision_key(shape=(T, E, d), dtype=jnp.float32)
-        winner = tuning.choice(
-            "moe_dispatch", ("sort", "einsum"), cpu_key,
-            measure={"einsum": timed(dispatch_einsum),
-                     "sort": timed(dispatch_sort)},
-        )
-        assert winner == "sort"
-        rec = [r for r in tuning.decisions_taken()
-               if r["key"] == cpu_key][-1]
-        # measured decisively (the 100x+ side of the crossover), or —
-        # only if this box is pathologically noisy — the table, which
-        # ALSO says sort; either way the cpu choice is sort.
-        assert rec["source"] in ("measured", "table:spread-dominated")
-        # and both coexist in one cache file keyed by device kind
-        doc = tuning.load_cache()
-        assert f"moe_dispatch|{tpu_key}" in doc["decisions"]
+def test_choice_opens_no_file_and_reads_no_clock(monkeypatch):
+    """Resolving a decision is pure Python over the arguments, the
+    override and the table: with ``open`` and the clocks taken away the
+    call sites still resolve."""
+    import builtins
+    import time
 
-    def test_seeding_covers_attention_wire_and_double_buffering(
-        self, tmp_path
-    ):
-        self._seed(tmp_path)
-        doc = tuning.load_cache()["decisions"]
-        # attention: flash on chip (3.0x), xla on the cpu proxy (0.56x)
-        tpu_attn = tuning.decision_key("TPU v5 lite", shape=(4096, 8, 128),
-                                       dtype="bfloat16")
-        cpu_attn = tuning.decision_key("cpu", shape=(256, 2, 64),
-                                       dtype="bfloat16")
-        assert doc[f"attention|{tpu_attn}"]["winner"] == "flash"
-        assert doc[f"attention|{cpu_attn}"]["winner"] == "xla"
-        # wire: best busbw on the 4-chip curve is bf16 fused
-        wire_key = tuning.decision_key("TPU v5 lite", shape=(4,),
-                                       dtype="grad")
-        assert doc[f"allreduce_wire|{wire_key}"]["winner"] == "bf16"
-        # bucketed within 10% of fused -> keep the 64 MB discipline
-        assert doc[f"allreduce_bucket_mb|{wire_key}"]["winner"] == "64"
-        # ...but the CPU proxy's micro-bucket rows and n=1 curves must
-        # seed NEITHER a wire nor a bucket decision
-        assert not any(k.startswith("allreduce") and "|cpu|" in k
-                       for k in doc)
-        # double buffering measured a loss on both backends
-        for koff in (
-            tuning.decision_key("cpu", shape=(8,), dtype="step"),
-            tuning.decision_key("TPU v5 lite", shape=(4,), dtype="step"),
-        ):
-            assert doc[f"double_buffering|{koff}"]["winner"] == "off"
-        # reduction schedule (ISSUE 3): each backend's overlap rows seed
-        # ITS winner under its own (world-shape, payload-MB) key — the
-        # exact key MultiNodeOptimizer's 'auto' resolution asks for.
-        cpu_sched = tuning.decision_key("cpu", shape=(8, 1), dtype="sched")
-        assert doc[f"reduction_schedule|{cpu_sched}"]["winner"] == "zero"
-        assert doc[f"reduction_schedule|{cpu_sched}"]["candidates_ms"][
-            "two_level"] == 11.8
-        tpu_sched = tuning.decision_key(
-            "TPU v5 lite", shape=(4, 128), dtype="sched"
-        )
-        assert doc[f"reduction_schedule|{tpu_sched}"]["winner"] == (
-            "two_level"
-        )
-        # and the seeded entry answers resolve_schedule without
-        # re-measuring (the 'auto' front door)
-        from chainermn_tpu.parallel.reduction_schedule import (
-            resolve_schedule,
-        )
+    def refuse(*a, **k):
+        raise AssertionError("a decision touched a file or a clock")
 
-        winner, rec = resolve_schedule("cpu", 1 << 20, (8,))
-        assert winner == "zero"
-        assert rec["source"].startswith("cache:seeded")
+    sites = _call_sites()
+    monkeypatch.setattr(builtins, "open", refuse)
+    monkeypatch.setattr(time, "perf_counter", refuse)
+    monkeypatch.setattr(time, "time", refuse)
+    for name in ("attention", "allreduce_bucket_mb", "reduction_schedule",
+                 "decode_impl"):
+        sites[name]("cpu")
+    assert {r["source"] for r in tuning.decisions_taken()} == {"table"}
 
-    def test_seeding_from_recorded_details_is_self_consistent(self):
-        """A row bench.py really wrote (the 2026-08-01 one-chip capture,
-        tests/data/) seeds without error and its on-chip MoE row
-        reproduces the einsum-competitive choice."""
-        import os
 
-        details = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                               "data", "bench_details_moe_tpu.json")
-        seeded = tuning.seed_from_bench_details(details)
-        moe = [s for s in seeded if s.startswith("moe_dispatch|TPU")]
-        assert moe, seeded
-        assert moe[0].endswith("-> sort")
+def test_choice_takes_a_name_its_candidates_and_a_key():
+    """No call site can hand ``choice`` a measurement, a table of its
+    own or a cache path, and the package exports no way to store one."""
+    import inspect
+
+    assert list(inspect.signature(tuning.choice).parameters) == [
+        "name", "candidates", "key"]
+    assert sorted(tuning.__all__) == [
+        "DEFAULT_TABLE", "choice", "current_device_kind", "decision_key",
+        "decisions_taken", "device_class", "reset_decisions",
+        "shape_bucket"]
 
 
 # ---------------------------------------------------------------------------
@@ -490,7 +367,7 @@ class TestCallSites:
 
     def test_wire_both_sides_dist_equals_single(self, comm):
         """Both sides of the tuned wire (bf16 vs the f32 master wire,
-        plus the int8 wire the cache may adopt): the in-mesh mean of
+        plus the int8 wire): the in-mesh mean of
         per-shard grads equals the single-device numpy mean within each
         wire's tolerance."""
         from chainermn_tpu.optimizers import allreduce_gradients
@@ -518,7 +395,7 @@ class TestCallSites:
                 np.testing.assert_allclose(out[i], expect, rtol=tol,
                                            atol=tol)
 
-    def test_auto_wire_resolution_and_bucket(self, comm):
+    def test_auto_wire_resolution_and_bucket(self, comm, monkeypatch):
         from chainermn_tpu.communicators.xla_communicator import (
             NaiveCommunicator,
         )
@@ -527,55 +404,35 @@ class TestCallSites:
         c = NaiveCommunicator(allreduce_grad_dtype="auto")
         assert c.allreduce_grad_dtype == jnp.dtype(jnp.bfloat16)
         assert tuned_bucket_bytes(c.device_kind, c.size) == 64 << 20
-        # a cache entry flips the wire for this exact topology key
-        key = tuning.decision_key(c.device_kind, shape=(c.size,),
-                                  dtype="grad")
-        tuning.store_entry("allreduce_wire", key,
-                           {"winner": "int8", "source": "test"})
+        # the override flips the wire for the next communicator
+        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE_FORCE",
+                           "allreduce_wire=int8")
         c2 = NaiveCommunicator(allreduce_grad_dtype="auto")
         assert c2.allreduce_grad_dtype == jnp.dtype(jnp.int8)
 
-    def test_double_buffering_advisory_warns_not_overrides(self, comm):
-        """The advisory warns when the flag is enabled on a backend
-        where a cache/measured record says it loses — but NOT on the
-        blanket table fallback (an unmeasured topology has no evidence
-        to cite) — and semantics stay faithful staleness-1 (first
-        update applies the zero bank, banking this step's grads)."""
+    def test_double_buffering_is_the_callers_flag(self, comm):
+        """The flag is honoured with faithful staleness-1 semantics (the
+        first update applies the zero bank and banks this step's
+        gradients), warns of nothing and asks the registry nothing."""
+        import warnings
+
         import optax
 
         from chainermn_tpu import create_multi_node_optimizer
 
-        # empty cache -> table fallback: recorded, but NO warning
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            create_multi_node_optimizer(
-                optax.sgd(0.1), comm, double_buffering=True
-            )
-        assert not any("double_buffering" in str(x.message) for x in w)
-
-        # a measured record for THIS backend: the advisory fires
-        key = tuning.decision_key(comm.device_kind, shape=(comm.size,),
-                                  dtype="step")
-        tuning.store_entry(
-            "double_buffering", key,
-            {"winner": "off", "source": "measured:bench",
-             "double_buffer_speedup": 0.752},
-        )
-        tuning.reset_decisions()
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
             opt = create_multi_node_optimizer(
                 optax.sgd(0.1), comm, double_buffering=True
             )
-        assert any("double_buffering" in str(x.message) for x in w)
+        assert not [r for r in tuning.decisions_taken()
+                    if r["name"] == "double_buffering"]
         params = {"w": jnp.ones((4,))}
         state = opt.init(params)
         grads = {"w": jnp.full((4,), 2.0)}
         updates, state = opt.update(grads, state, params)
-        # staleness-1: the FIRST update applies the zero bank...
         np.testing.assert_allclose(np.asarray(updates["w"]),
                                    np.zeros(4), atol=0)
-        # ...and banks this step's (identity-reduced) grads
         np.testing.assert_allclose(
             np.asarray(state.communicated_grads["w"]),
             np.asarray(grads["w"]), atol=1e-6,

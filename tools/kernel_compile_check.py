@@ -346,11 +346,11 @@ def _rel_err(got, want) -> float:
 
 def _timings():
     """Forward + backward of causal flash attention, ms a call, at the
-    shape ``docs/benchmarks.md`` carries (B4 x T4096 x H8 x D128), at
+    shape ``PERF.md`` carries (B4 x T4096 x H8 x D128), at
     the LM cells' (B4 and B16 x T1024 x H16 x D64) and at
     ``chip_smoke.py``'s (B16 x T2048 x H16 x D64). Iterations are chained
-    through a scan inside one program, as ``bench.py`` times them, so the
-    host's dispatch stays out of the figure."""
+    through a scan inside one program, so the host's dispatch stays out
+    of the figure."""
     import jax
     import jax.numpy as jnp
 

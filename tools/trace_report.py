@@ -64,11 +64,10 @@ Sections:
   totals and the dispatch capacity, plus the layers observed. Omitted
   when the trace carries no MoE events.
 - **stragglers** — flagged divergence reports, if any.
-- **roofline** — where a device kind with a known HBM peak appears
-  (bench.py's per-kind tables, the same floors tools/byte_audit.py
-  uses), collective GB/s is floored against it: an eager-plane number
-  near the HBM peak is copy-bound, far below it is latency/dispatch
-  -bound. Skipped silently when bench.py is unimportable.
+- **roofline** — where a device kind with a published HBM peak appears
+  (``benchmark/peaks.py``), collective GB/s is floored against it: an
+  eager-plane number near the HBM peak is copy-bound, far below it is
+  latency/dispatch-bound.
 
 ``--json`` prints the machine-readable summary (the contract tested in
 tests/test_capture_tools.py); default output is a human table.
@@ -83,6 +82,7 @@ blocking durations either way. See docs/observability.md.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -131,14 +131,20 @@ def _read_events(paths) -> list[dict]:
     return events
 
 
+@functools.lru_cache(maxsize=None)
 def _hbm_peak(device_kind: str):
-    """Per-kind HBM peak via bench.py's table (the single place device
-    peaks live — byte_audit.py derives its floors the same way)."""
-    try:
-        import bench
+    """Per-kind HBM peak in bytes/s from ``benchmark/peaks.py`` (the one
+    place device peaks live), loaded by file path; None for a kind that
+    has no published row there (a CPU, say)."""
+    import importlib.util
 
-        return bench._peak_lookup(device_kind, bench._PEAK_HBM_BYTES)
-    except Exception:
+    path = os.path.join(_HERE, "benchmark", "peaks.py")
+    spec = importlib.util.spec_from_file_location("_bench_peaks", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    try:
+        return mod.lookup(device_kind)["hbm_bytes_per_s"]
+    except KeyError:
         return None
 
 
