@@ -42,8 +42,15 @@ visited sub-tile, and with either, or a window, dk/dv keeps the 512 x 1024
 tile. The gauge ``flash_tiles`` (labels ``kernel``, ``kind``) says what
 the last call of the op does.
 
-Layout: BTHD at the API (framework convention), BHTD inside the kernel grid;
-LSE/delta rows are ``[B, H, T]``.
+Layout: BTHD at the API (framework convention), BHTD inside the kernel grid.
+The row statistics (LSE, delta) cross HBM with ``T`` on the lane axis, from
+the kernel that makes them to the kernels that read them: ``[B, H, 1, T]``
+in blocks of ``(1, 1, 1, block_q)``, a value 4 bytes (with ``T`` second to
+last and a unit minor dimension a value would occupy a 128-lane tile row,
+128 times its bytes, and XLA would squeeze and pad round every kernel). The
+forward turns its ``[block_q, 1]`` column into the row once a q tile, the
+backward kernels turn the row back into the column their scores are
+corrected by; the ring's entry points speak ``[B, H, T]``.
 """
 
 from __future__ import annotations
@@ -235,6 +242,17 @@ def _pick_block(requested: int, T: int) -> int:
     return b if T % b == 0 else T
 
 
+def _pick_row_block(requested: int, Tq: int) -> int:
+    """:func:`_pick_block` for the q axis. The row statistics (log-sum-exp,
+    ``delta``) cross HBM with ``Tq`` on the lane axis, and Mosaic takes a
+    block of lanes only as a multiple of 128 or as the whole axis: where
+    halving ends below that (T=576 with a 512 request -> 64) the block is
+    the whole row. A ``requested`` block that divides ``Tq`` is the
+    caller's and stays (the interpreter takes any)."""
+    b = _pick_block(requested, Tq)
+    return Tq if b % _LANES and b != requested else b
+
+
 #: ``(block_q, block_k)`` where the caller names none, and for dk/dv where
 #: nothing but the causal mask acts on the scores and a row of an operand
 #: (``D`` elements) has at most ``_DKV_CAUSAL_ROW_BYTES``.
@@ -277,7 +295,7 @@ def _geometry(Tq, Tk, *, walks, causal, bare=True, row_bytes=0, window=None,
     whole = (walks == "q" and causal and bare and window is None
              and row_bytes <= _DKV_CAUSAL_ROW_BYTES)
     derived = _DKV_CAUSAL_TILES if whole else _TILES
-    block_q = _pick_block(block_q or derived[0], Tq)
+    block_q = _pick_row_block(block_q or derived[0], Tq)
     block_k = _pick_block(block_k or derived[1], Tk)
     nests = (walks == "k" and causal and window is None
              and block_k % block_q == 0 and q_offset % block_q == 0)
@@ -420,9 +438,11 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
             l > 0, acc / jnp.maximum(l, 1e-37), 0.0
         ).astype(o_ref.dtype)
         # LSE in the scaled-score domain; fully-masked rows stay NEG_INF.
+        # The column is turned into the row it crosses HBM as: once a
+        # q tile.
         lse_ref[0, 0] = jnp.where(
             l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), NEG_INF
-        )
+        ).T
 
     if not single:
         @pl.when(j == 0)
@@ -535,7 +555,7 @@ def _bias_spec(bias, block_q, block_k, swap=False, k_of=None, q_of=None):
 def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
                     scale, block_q, block_k, interpret, window=None,
                     q_offset=0):
-    """BHTD forward → (out [B,H,Tq,D], lse [B,H,Tq,1]).
+    """BHTD forward → (out [B,H,Tq,D], lse [B,H,1,Tq]).
 
     ``k``/``v`` may carry FEWER heads than ``q`` (GQA/MQA): kv head
     ``h // g`` serves q head ``h`` via the BlockSpec index map — no
@@ -611,12 +631,12 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
             out_specs=[
                 pl.BlockSpec((1, 1, block_q, D),
                              lambda b, h, iq, ik: (b, h, iq, 0)),
-                pl.BlockSpec((1, 1, block_q, 1),
-                             lambda b, h, iq, ik: (b, h, iq, 0)),
+                pl.BlockSpec((1, 1, 1, block_q),
+                             lambda b, h, iq, ik: (b, h, 0, iq)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
-                jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
+                jax.ShapeDtypeStruct((B, H, 1, Tq), jnp.float32),
             ],
             scratch_shapes=[] if grid_k == 1 else [
                 pltpu.VMEM((block_q, D), jnp.float32),      # acc
@@ -630,6 +650,12 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
 # ---------------------------------------------------------------------------
 # Backward: dq kernel (iterate K blocks per fixed Q block)
 # ---------------------------------------------------------------------------
+
+def _column(row_ref):
+    """A ``[1, 1, 1, block_q]`` block of row statistics as the
+    ``[block_q, 1]`` column the scores are corrected by."""
+    return jnp.expand_dims(row_ref[0, 0, 0], -1)
+
 
 def _bwd_dq_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
                  bias_ref, dq_ref, dq_acc, *, scale: float, causal: bool,
@@ -654,8 +680,8 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
     def _accumulate(pieces):
         q = q_ref[0, 0]
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]    # [block_q, 1]
-        delta = delta_ref[0, 0]
+        lse = _column(lse_ref)
+        delta = _column(delta_ref)
         dq = 0.0
         for a, b, masked in pieces:
             k = k_ref[0, 0, a:b]
@@ -735,7 +761,7 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
         do = do_ref[0, 0].astype(jnp.float32)
         s = _scores(q, k, bias_ref, seg_refs, masked, q0, k0, slice(None),
                     scale=scale, window=window)
-        p = jnp.exp(s - lse_ref[0, 0])  # [block_q, block_k]
+        p = jnp.exp(s - _column(lse_ref))  # [block_q, block_k]
         # dv += p^T @ do
         dv = jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -745,7 +771,7 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds_unscaled = p * (dp - delta_ref[0, 0])  # d loss / d s_total
+        ds_unscaled = p * (dp - _column(delta_ref))  # d loss / d s_total
         if dbias_ref is not None:
             # dbias tile == ds before the qk-scale factor (the bias adds
             # AFTER the scale multiplies q·k).
@@ -784,7 +810,8 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
                     causal, scale, block_q, block_k, interpret, window=None,
                     q_offset=0):
     """BHTD backward → ``(dq, dk, dv[, dbias])``, each f32, given saved
-    LSE and ``delta = rowsum(do * o)``. With GQA (kv heads Hkv < Hq),
+    LSE and ``delta = rowsum(do * o)``, both ``[B, H, 1, Tq]`` as the
+    forward writes its LSE. With GQA (kv heads Hkv < Hq),
     dk/dv come back at the KV head count: the per-q-head contributions
     are written per-head and group-summed outside the kernel.
     ``want_dbias`` materializes the full ``[B, H, Tq, Tk]`` f32 bias
@@ -823,8 +850,8 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
                      q_offset=q_offset, num_k_blocks=grid_k,
                      band_lo=band_lo, nk_total=nk)
     q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q, 1),
-                            lambda b, h, i, j: (b, h, i, 0))
+    row_spec = pl.BlockSpec((1, 1, 1, block_q),
+                            lambda b, h, i, j: (b, h, 0, i))
     kv_spec = pl.BlockSpec(
         (1, 1, block_k, D),
         lambda b, h, i, j: (b, h // g, k_block(i, j), 0),
@@ -890,8 +917,8 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
                               lambda b, h, i, j: (b, h, i, 0))
     q_spec_in = pl.BlockSpec((1, 1, block_q, D),
                              lambda b, h, i, j: (b, h, q_block(i, j), 0))
-    row_spec_in = pl.BlockSpec((1, 1, block_q, 1),
-                               lambda b, h, i, j: (b, h, q_block(i, j), 0))
+    row_spec_in = pl.BlockSpec((1, 1, 1, block_q),
+                               lambda b, h, i, j: (b, h, 0, q_block(i, j)))
     dkv_in_specs = [q_spec_in, k_spec_in, k_spec_in, q_spec_in,
                     row_spec_in, row_spec_in]
     if has_segments:
@@ -1025,23 +1052,23 @@ def _flash_core_fwd(q, k, v, seg, bias, has_seg, has_bias, bias_grad,
     )
     # What the backward takes from the kernel, by name: a remat policy
     # that saves these names (models/transformer.py, 'dots') keeps them,
-    # and the recomputation has no use for the kernel. The log-sum-exp
-    # goes without its unit minor dimension: as the kernel writes it a
-    # value occupies 128 lanes, and a saved one would hold 128 times its bytes.
+    # and the recomputation has no use for the kernel. Both are the
+    # kernel's results as it wrote them; the log-sum-exp is a row,
+    # [B, H, 1, Tq], which the backward kernels read as it is.
     out = checkpoint_name(out, train_path.FLASH_OUT)  # in BHTD
-    lse = checkpoint_name(lax.squeeze(lse, (3,)), train_path.FLASH_LSE)
+    lse = checkpoint_name(lse, train_path.FLASH_LSE)
     return _to_bhtd(out), (q, k, v, seg, bias, out, lse)
 
 
 def _flash_core_bwd(has_seg, has_bias, bias_grad, causal, scale, block_q,
                     block_k, interpret, window, res, g):
     q, k, v, seg, bias, out_bhtd, lse = res
-    lse = lax.expand_dims(lse, (3,))  # [B, H, Tq, 1] (kernel layout)
     do = _to_bhtd(g)
     # delta_i = sum_d dO_i . O_i — the rowwise correction term of the flash
     # backward (re-derives softmax jacobian contributions without P).
     delta = jnp.sum(do.astype(jnp.float32) * out_bhtd.astype(jnp.float32),
-                    axis=-1, keepdims=True)  # [B, H, Tq, 1] (kernel layout)
+                    axis=-1)
+    delta = delta.reshape(lse.shape)  # [B, H, 1, Tq] (kernel layout)
     res_bwd = _flash_bwd_bhtd(
         _to_bhtd(q), _to_bhtd(k), _to_bhtd(v), do, lse, delta,
         seg if has_seg else None, seg if has_seg else None,
@@ -1188,7 +1215,7 @@ def flash_block_fwd(q, k_blk, v_blk, *, causal, scale, block_q, block_k,
         causal=causal, window=window, q_offset=q_offset,
         scale=scale, block_q=block_q, block_k=block_k, interpret=interpret,
     )
-    return _to_bhtd(out), lse[..., 0]
+    return _to_bhtd(out), lse[:, :, 0]
 
 
 def flash_block_bwd(q, k_blk, v_blk, do, lse, delta, *, causal, scale,
@@ -1203,7 +1230,7 @@ def flash_block_bwd(q, k_blk, v_blk, do, lse, delta, *, causal, scale,
                    block_q=block_q, block_k=block_k)
     dq, dk, dv = _flash_bwd_bhtd(
         _to_bhtd(q), _to_bhtd(k_blk), _to_bhtd(v_blk), _to_bhtd(do),
-        lse[..., None], delta[..., None], seg_q, seg_kv,
+        lse[:, :, None], delta[:, :, None], seg_q, seg_kv,
         causal=causal, scale=scale, window=window, q_offset=q_offset,
         block_q=block_q, block_k=block_k, interpret=interpret,
     )

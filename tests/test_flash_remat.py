@@ -203,3 +203,83 @@ def test_names_are_the_policys():
     text = str(jax.make_jaxpr(jax.grad(lambda *a: attn(*a).sum()))(*args))
     for name in train_path.FLASH_RESIDUALS:
         assert f"name={name}" in text
+
+
+# -- the form in which the kernels pass their row statistics ---------------
+
+_FLASH_KERNELS = (train_path.FLASH_FWD, train_path.FLASH_BWD_DQ,
+                  train_path.FLASH_BWD_DKV)
+
+
+def _all_jaxprs(jaxpr):
+    """``jaxpr`` and every jaxpr nested in its equations, kernel bodies
+    left out."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "pallas_call":
+            for inner in _sub_jaxprs(eqn):
+                yield from _all_jaxprs(inner)
+
+
+def _source(jaxpr, var):
+    """``(var, eqn)`` behind ``var`` in ``jaxpr`` with the reshapes that
+    move no element walked back through; ``eqn`` None: an input of
+    ``jaxpr``."""
+    made_by = {out: eqn for eqn in jaxpr.eqns for out in eqn.outvars}
+    while var in made_by and made_by[var].primitive.name == "reshape":
+        var = made_by[var].invars[0]
+    return var, made_by.get(var)
+
+
+def _gradient_jaxpr(which):
+    if which == "remat_block":
+        return jax.make_jaxpr(jax.grad(_loss_of(
+            _lm(remat=True, remat_policy="dots"))))(_params(),
+                                                    _tokens()).jaxpr
+    args, attn = _op_cases()["plain"]
+    return jax.make_jaxpr(jax.grad(
+        lambda *a: (attn(*a) ** 2).sum(), argnums=(0, 1, 2)))(*args).jaxpr
+
+
+@pytest.mark.parametrize("which", ["remat_block", "plain"])
+def test_flash_kernels_pass_their_row_statistics_lane_dense(which):
+    """No operand or result of a flash kernel has a unit minor dimension
+    (such an array sits in 128-lane tiles, 128 times its bytes), and what
+    the backward kernels take as log-sum-exp and ``delta`` is padded and
+    broadcast by nobody: a residual as it came, or a row sum, through at
+    most a reshape that puts a unit dimension before ``T``."""
+    seen = {name: 0 for name in _FLASH_KERNELS}
+    for jaxpr in _all_jaxprs(_gradient_jaxpr(which)):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name != "pallas_call" \
+                    or eqn.params["name"] not in seen:
+                continue
+            seen[eqn.params["name"]] += 1
+            for var in (*eqn.invars, *eqn.outvars):
+                assert var.aval.shape[-1] != 1, (eqn.params["name"],
+                                                 var.aval)
+            if eqn.params["name"] == train_path.FLASH_FWD:
+                continue
+            B, H, T, _ = eqn.invars[0].aval.shape  # q in BHTD
+            for row in eqn.invars[4:6]:  # q, k, v, do, lse, delta
+                assert row.aval.shape == (B, H, 1, T)
+                src, made = _source(jaxpr, row)
+                assert src.aval.size == B * H * T
+                assert made is None or made.primitive.name in (
+                    "reduce_sum", "pallas_call"), made
+    assert all(seen.values()), seen
+
+
+def test_flash_lse_residual_is_the_forward_kernels_own_result():
+    """What the policy saves under ``flash_lse`` is the array the forward
+    kernel wrote: no squeeze between them that XLA has to run."""
+    named = 0
+    for jaxpr in _all_jaxprs(_gradient_jaxpr("remat_block")):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "name" \
+                    and eqn.params["name"] == train_path.FLASH_LSE:
+                named += 1
+                _, made = _source(jaxpr, eqn.invars[0])
+                assert made.primitive.name == "pallas_call"
+                assert made.params["name"] == train_path.FLASH_FWD
+    assert named >= LAYERS

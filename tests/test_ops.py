@@ -569,7 +569,7 @@ def test_flash_geometry_from_the_shapes():
 
 
 def _dense_block(q, k, v, *, causal, scale, seg_q=None, seg_kv=None,
-                 window=None, q_offset=0):
+                 window=None, q_offset=0, bias=None):
     """XLA reference of one flash block call: ``(out, lse)``, with the
     mask handed to ``ops.attention`` as a bias."""
     Tq, Tk = q.shape[1], k.shape[1]
@@ -583,7 +583,8 @@ def _dense_block(q, k, v, *, causal, scale, seg_q=None, seg_kv=None,
     ok = jnp.asarray(ok)[None, None]
     if seg_q is not None:
         ok = ok & (seg_q[:, None, :, None] == seg_kv[:, None, None, :])
-    bias = jnp.where(ok, 0.0, -1e30).astype(jnp.float32)
+    mask = jnp.where(ok, 0.0, -1e30).astype(jnp.float32)
+    bias = mask if bias is None else mask + bias
     out = dot_product_attention(q, k, v, scale=scale, bias=bias)
     g = q.shape[2] // k.shape[2]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, g, axis=2),
@@ -677,3 +678,119 @@ def test_flash_derived_geometry_bias_grad_matches_reference():
                                    err_msg=f"{what} against XLA")
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5,
                                    err_msg=f"{what} against 512x1024")
+
+
+# ---------------------------------------------------------------------------
+# The row statistics (log-sum-exp, delta) cross HBM as rows: [B, H, 1, Tq]
+# ---------------------------------------------------------------------------
+
+def _row_case(name):
+    """``(q, k, v, do), seg, bias, kw`` in BTHD for one variant of the
+    kernels' masks at a length that decides the row block: 1024 (two q
+    blocks of 512 lanes in the forward and dq), 200 (the block is the
+    whole row), 576 (halving 512 would end at 64, under a lane tile: the
+    whole row again)."""
+    T = {"whole_T_200": 200, "T_576": 576}.get(name, _T1K)
+    kv_heads = 2 if name == "window_gqa" else 4
+    ks = jax.random.split(jax.random.PRNGKey(29), 5)
+    q = jax.random.normal(ks[0], (1, T, 4, 64))
+    k = jax.random.normal(ks[1], (1, T, kv_heads, 64))
+    v = jax.random.normal(ks[2], (1, T, kv_heads, 64))
+    do = jax.random.normal(ks[3], (1, T, 4, 64))
+    seg = bias = None
+    kw = dict(causal=name != "noncausal")
+    if name == "segment_ids":
+        seg = jnp.asarray(np.repeat([0, 1, 2, 3], [300, 212, 412, 100])
+                          [None].astype(np.int32))
+    elif name == "trained_bias":
+        bias = 0.1 * jax.random.normal(ks[4], (1, 4, T, T))
+    elif name == "window_gqa":
+        kw.update(window=300)
+    return (q, k, v, do), seg, bias, kw
+
+
+@pytest.mark.parametrize("name", [
+    "causal", "noncausal", "segment_ids", "trained_bias", "window_gqa",
+    "whole_T_200", "T_576"])
+def test_flash_row_statistics_match_reference(name):
+    """The kernels hand the log-sum-exp over as ``[B, H, 1, Tq]`` and
+    take it and ``delta`` back in that form: out, log-sum-exp, dq, dk,
+    dv (and the bias gradient) against XLA's attention, at the geometry
+    the kernels derive."""
+    from chainermn_tpu.ops.flash_attention import (
+        _flash_bwd_bhtd,
+        _flash_fwd_bhtd,
+        _geometry,
+    )
+
+    (q, k, v, do), seg, bias, kw = _row_case(name)
+    T = q.shape[1]
+    # the q block is a multiple of 128 lanes or the whole row
+    for walks in ("k", "q"):
+        block_q = _geometry(T, T, walks=walks, **kw)[0]
+        assert block_q % 128 == 0 or block_q == T, (walks, block_q)
+    bhtd = lambda x: x.transpose(0, 2, 1, 3)
+    common = dict(scale=0.125, block_q=None, block_k=None, interpret=True,
+                  **kw)
+    out, lse = _flash_fwd_bhtd(bhtd(q), bhtd(k), bhtd(v), seg, seg, bias,
+                               **common)
+    assert lse.shape == (1, 4, 1, T) and lse.dtype == jnp.float32
+    delta = jnp.einsum("bqhd,bhqd->bhq", do, out)[:, :, None]
+    got = _flash_bwd_bhtd(bhtd(q), bhtd(k), bhtd(v), bhtd(do), lse, delta,
+                          seg, seg, bias, bias is not None, **common)
+
+    args = (q, k, v) if bias is None else (q, k, v, bias)
+    (ref_out, ref_lse), vjp = jax.vjp(
+        lambda q_, k_, v_, b_=None: _dense_block(
+            q_, k_, v_, scale=0.125, seg_q=seg, seg_kv=seg, bias=b_, **kw),
+        *args)
+    ref = vjp((do, jnp.zeros_like(ref_lse)))
+    want = (ref_out, ref_lse[:, :, None]) + tuple(
+        bhtd(x) for x in ref[:3]) + tuple(ref[3:])
+    names = ("out", "lse", "dq", "dk", "dv", "dbias")
+    for what, a, b in zip(names, (bhtd(out), lse) + tuple(got), want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
+                                   err_msg=f"{what} against XLA")
+
+
+def test_flash_fully_masked_rows_keep_neg_inf_and_zero_gradients():
+    """A query that sees no key (its segment id is on no key of the
+    arriving block, as in a ring step): the output is 0, the log-sum-exp
+    stays ``NEG_INF`` in its row and, under the ring's merged
+    log-sum-exp, the row gives dq = 0 and nothing to dk or dv; the other
+    rows are the reference's on the keys they see."""
+    from chainermn_tpu.ops.attention import NEG_INF
+    from chainermn_tpu.ops.flash_attention import (
+        flash_block_bwd,
+        flash_block_fwd,
+    )
+
+    T, dead = 256, slice(64, 192)
+    ks = jax.random.split(jax.random.PRNGKey(31), 4)
+    q, k, v, do = (jax.random.normal(kk, (1, T, 2, 32)) for kk in ks)
+    seg_q = jnp.zeros((1, T), jnp.int32).at[:, dead].set(7)
+    seg_kv = jnp.zeros((1, T), jnp.int32)
+    kw = dict(causal=False, scale=32 ** -0.5, seg_q=seg_q, seg_kv=seg_kv,
+              block_q=128, block_k=128, interpret=True)
+    out, lse = flash_block_fwd(q, k, v, **kw)
+    assert lse.shape == (1, 2, T)  # the ring's interface: [B, H, Tq]
+    delta = jnp.einsum("bqhd,bqhd->bhq", do, out)
+    # the backward takes the ring's merged log-sum-exp, which is finite
+    # in such a row (it saw another block's keys): exp(NEG_INF - lse) = 0
+    merged = jnp.where(lse > NEG_INF, lse, 0.0)
+    dq, dk, dv = flash_block_bwd(q, k, v, do, merged, delta, **kw)
+
+    np.testing.assert_array_equal(lse[:, :, dead], np.float32(NEG_INF))
+    np.testing.assert_array_equal(out[:, dead], 0.0)
+    np.testing.assert_array_equal(dq[:, dead], 0.0)
+    live = np.r_[0:dead.start, dead.stop:T]
+    (ref_out, ref_lse), vjp = jax.vjp(
+        lambda q_, k_, v_: _dense_block(q_, k_, v_, causal=False,
+                                        scale=kw["scale"]),
+        q[:, live], k, v)
+    ref_dq, ref_dk, ref_dv = vjp((do[:, live], jnp.zeros_like(ref_lse)))
+    for what, a, b in (("out", out[:, live], ref_out),
+                       ("lse", lse[:, :, live], ref_lse),
+                       ("dq", dq[:, live], ref_dq), ("dk", dk, ref_dk),
+                       ("dv", dv, ref_dv)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=what)

@@ -28,6 +28,10 @@ Checked kernels:
   shapes it rejected them
 - flash attention forward + backward at the OLMoE cell's shape (B 4,
   T 4096, 16 heads of 128)
+- flash attention forward + backward at T 200 and T 576: the kernels
+  pass log-sum-exp and ``delta`` as ``[B, H, 1, T]`` rows, whose block
+  Mosaic takes as a multiple of 128 lanes or as the whole row, and these
+  lengths have no such divisor (``_pick_row_block``)
 - the gradient of two remat'ed blocks (``remat_policy='dots'``) at the
   memory-full GPT-2 cell's shape (B 16, T 1024, 16 heads of 64): the
   policy keeps what the flash forward made, so the compiled gradient
@@ -162,6 +166,11 @@ def _cases():
     q_cell = jax.ShapeDtypeStruct((4, 1024, 16, 64), dt)
     # the OLMoE cell's: 4 sequences of 4096, 16 heads of 128
     q_olmoe = jax.ShapeDtypeStruct((4, 4096, 16, 128), dt)
+    # lengths no 128-lane block divides: the row statistics' block is the
+    # whole row, at 200 as the q block always was, at 576 where halving
+    # 512 would end at 64
+    q_200 = jax.ShapeDtypeStruct((4, 200, 8, 64), dt)
+    q_576 = jax.ShapeDtypeStruct((4, 576, 8, 64), dt)
 
     # The grouped matmul: group sizes are made from a float vector inside
     # the case, uneven and with empty groups; the reference multiplies
@@ -281,6 +290,10 @@ def _cases():
         ("flash_lm_fwdbwd", grads(flash), (q_lm,) * 3, grads(xla)),
         ("flash_cell_fwdbwd", grads(flash), (q_cell,) * 3, grads(xla)),
         ("flash_olmoe_fwdbwd", grads(flash), (q_olmoe,) * 3, grads(xla)),
+        ("flash_whole_row_t200_fwdbwd", grads(flash), (q_200,) * 3,
+         grads(xla)),
+        ("flash_whole_row_t576_fwdbwd", grads(flash), (q_576,) * 3,
+         grads(xla)),
         ("grouped_matmul_fwd", gmm, gmm_small, gmm_loop),
         ("grouped_matmul_grads", gmm_grads(gmm), gmm_small,
          gmm_grads(gmm_loop)),
