@@ -19,6 +19,7 @@ from chainermn_tpu.models.transformer import (
     TransformerLM,
     head_table,
     lm_from_config,
+    lm_loss_looped,
     lm_loss_moe,
     mlm_corrupt,
     mlm_loss,
@@ -58,6 +59,7 @@ __all__ = [
     "lm_from_config",
     "head_table",
     "lm_loss_moe",
+    "lm_loss_looped",
     "mlm_corrupt",
     "mlm_loss",
     "lm_loss",

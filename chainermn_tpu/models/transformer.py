@@ -60,6 +60,13 @@ class Architecture:
     experts_per_token: int = 0
     expert_width: int = 0
     renormalise_gates: bool = False
+    #: a norm of the block's kind on each sub-layer's output as well, before
+    #: the residual takes it: ``a = x + N(Attn(N(x)))``,
+    #: ``y = a + N(FFN(N(a)))`` (params ``attn_out_norm``, ``ffn_out_norm``)
+    post_norm: bool = False
+    #: a ``[d, 1]`` linear gate on the normed hidden state of each pass of
+    #: a looped model (param ``exit_gate``; :func:`lm_loss_looped`)
+    exit_gate: bool = False
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -101,6 +108,23 @@ class Architecture:
                 expert_width=int(config["intermediate_size"]),
                 renormalise_gates=bool(config["norm_topk_prob"]),
             )
+        if kind == "ouro":
+            if config.get("hidden_act", "silu") != "silu" or \
+                    config.get("use_sliding_window") or \
+                    config.get("rope_scaling") is not None or \
+                    config.get("head_dim") not in (None, config[
+                        "hidden_size"] // config["num_attention_heads"]):
+                raise ValueError(
+                    "an ouro config with another activation, a sliding "
+                    "window, scaled RoPE or heads that do not divide the "
+                    "width is not built here")
+            return cls(
+                norm="rmsnorm", norm_eps=float(config["rms_norm_eps"]),
+                ffn="gated_silu", positions="rope",
+                rope_base=float(config["rope_theta"]),
+                tied_head=bool(config["tie_word_embeddings"]),
+                post_norm=True, exit_gate=True,
+            )
         raise ValueError(f"no block is described for model_type {kind!r}")
 
 
@@ -124,6 +148,16 @@ MODEL_CONFIGS = {
         "rope_scaling": None, "attention_bias": False, "clip_qkv": None,
         "tie_word_embeddings": False, "vocab_size": 50304,
         "max_position_embeddings": 4096,
+    },
+    "ouro-2.6b": {
+        "model_type": "ouro", "num_hidden_layers": 48, "hidden_size": 2048,
+        "num_attention_heads": 16, "num_key_value_heads": 16,
+        "head_dim": 128, "intermediate_size": 5632, "hidden_act": "silu",
+        "rms_norm_eps": 1e-6, "rope_theta": 1000000, "rope_scaling": None,
+        "use_sliding_window": False, "sliding_window": None,
+        "tie_word_embeddings": False, "vocab_size": 49152,
+        "max_position_embeddings": 65536, "total_ut_steps": 4,
+        "early_exit_threshold": 1,
     },
 }
 
@@ -714,9 +748,17 @@ class TransformerBlock(nn.Module):
             # Row-parallel output projection: the ONE psum of the
             # attention column→row pair.
             o = reduce_from_tp(o, self.tp_axis)
-        if self.dropout_rate > 0.0:
-            o = nn.Dropout(self.dropout_rate, deterministic=not train)(o)
-        x = x + o
+
+        def branch(x, out, norm_name):
+            """A sub-layer's output into the residual stream."""
+            if arch.post_norm:
+                out = _norm_layer(arch, self.compute_dtype, norm_name)(out)
+            if self.dropout_rate > 0.0:
+                out = nn.Dropout(self.dropout_rate,
+                                 deterministic=not train)(out)
+            return x + out
+
+        x = branch(x, o, "attn_out_norm")
 
         h = _norm_layer(arch, self.compute_dtype)(x)
         if arch.n_experts > 0:
@@ -724,11 +766,7 @@ class TransformerBlock(nn.Module):
                 raise ValueError(
                     "the dropless mixture of experts is the training "
                     "path: no decode, adapters or tensor parallelism yet")
-            h = self._moe_dropless(h, arch)
-            if self.dropout_rate > 0.0:
-                h = nn.Dropout(self.dropout_rate,
-                               deterministic=not train)(h)
-            return x + h
+            return branch(x, self._moe_dropless(h, arch), "ffn_out_norm")
         if self.n_experts > 0:
             if adapters is not None and (
                 "ff_up" in adapters or "ff_down" in adapters
@@ -737,11 +775,7 @@ class TransformerBlock(nn.Module):
                     "MoE blocks have no ff_up/ff_down projections to "
                     "hook — adapters may target qkv/proj only"
                 )
-            h = self._moe_ffn(h)
-            if self.dropout_rate > 0.0:
-                h = nn.Dropout(self.dropout_rate,
-                               deterministic=not train)(h)
-            return x + h
+            return branch(x, self._moe_ffn(h), "ffn_out_norm")
         if self.tp_axis is not None:
             h = copy_to_tp(h, self.tp_axis)
         gated = arch.ffn == "gated_silu"
@@ -772,9 +806,7 @@ class TransformerBlock(nn.Module):
             # ff_down's bias rides INSIDE the reduce: the sharder stores
             # bias / axis_size so the psum reassembles it exactly.
             h = reduce_from_tp(h, self.tp_axis)
-        if self.dropout_rate > 0.0:
-            h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
-        return x + h
+        return branch(x, h, "ffn_out_norm")
 
 
 #: what ``jax.checkpoint`` keeps of a block for the backward, by the name
@@ -807,6 +839,28 @@ def _remat_block(remat_policy: str):
         policy=_REMAT_POLICIES[remat_policy],
         static_argnums=(4, 5),  # (self, x, seg, rope_pos, train, dec)
     )
+
+
+def refuse_looped_decode(model, what: str):
+    """Decoding a looped model needs a KV cache a pass; until there is
+    one, every decode entry point refuses it (never a silent single
+    pass)."""
+    raise NotImplementedError(
+        f"{what} of a looped model (total_ut_steps="
+        f"{model.total_ut_steps}) is not built: it needs a KV cache a "
+        "pass; the training path (lm_loss_looped) is")
+
+
+def _publish_loop_passes(passes: int):
+    """Gauge :data:`train_path.LOOP_PASSES`, set while the caller's
+    program is traced."""
+    from chainermn_tpu.observability.metrics import registry
+
+    registry().gauge(
+        train_path.LOOP_PASSES,
+        "passes of a looped model's layer stack over one set of weights "
+        "(total_ut_steps), at the last model traced",
+    ).set(float(passes))
 
 
 class TransformerLM(nn.Module):
@@ -913,6 +967,20 @@ class TransformerLM(nn.Module):
     #: ``None`` is the GPT-2 instance with ``pos_encoding``'s positions;
     #: a description given here is read alone and ``pos_encoding`` is not.
     arch: Optional[Architecture] = None
+    #: passes of a looped model (Ouro's ``total_ut_steps``): the same
+    #: ``num_layers`` blocks, one set of parameters, applied this many
+    #: times, each pass closed by the final norm, whose output the next
+    #: pass reads. 1 is the plain stack. With more than one pass, or with
+    #: the description's exit gate, ``return_hidden`` hands back ``(hidden
+    #: [passes, B, T, D], gate_logits [passes, B, T] float32 or None)``
+    #: for :func:`lm_loss_looped`, and the logits are the last pass's.
+    #: Training only: decoding needs a cache a pass and is refused.
+    total_ut_steps: int = 1
+
+    @property
+    def looped(self) -> bool:
+        return self.total_ut_steps > 1 or bool(
+            self.arch and self.arch.exit_gate)
 
     @nn.compact
     def __call__(self, tokens, *, segment_ids=None, positions=None,
@@ -958,6 +1026,15 @@ class TransformerLM(nn.Module):
                 "n_experts selects the top-1 serving form, the "
                 "description's experts the dropless one: give one"
             )
+        passes, looped = self.total_ut_steps, self.looped
+        if passes < 1:
+            raise ValueError(f"total_ut_steps must be >= 1, got {passes}")
+        if looped and decode:
+            refuse_looped_decode(self, "decode=True")
+        if looped and arch.n_experts > 0:
+            raise ValueError(
+                "a looped model with experts is not built: the router's "
+                "auxiliary losses are read one entry a block")
         if decode and not self.causal:
             raise ValueError(
                 "decode=True is autoregressive and requires causal=True"
@@ -1014,8 +1091,8 @@ class TransformerLM(nn.Module):
             _remat_block(self.remat_policy) if self.remat
             else TransformerBlock
         )
-        for i in range(self.num_layers):
-            x = block_cls(
+        blocks = [
+            block_cls(
                 num_heads=self.num_heads,
                 d_ff=self.d_ff,
                 compute_dtype=self.compute_dtype,
@@ -1038,10 +1115,29 @@ class TransformerLM(nn.Module):
                 moe_experts_local=self.moe_experts_local,
                 arch=arch,
                 name=f"block_{i}",
-            )(x, segment_ids, rope_positions, train, decode,
-              decode_positions, block_tables, decode_slots,
-              adapters[i] if adapters is not None else None)
-        x = _norm_layer(arch, self.compute_dtype)(x)
+            ) for i in range(self.num_layers)
+        ]
+        final_norm = _norm_layer(arch, self.compute_dtype)
+
+        def stack(x):
+            """The blocks once, then the final norm. A module called again
+            reads the parameters of its first call."""
+            for i, block in enumerate(blocks):
+                x = block(x, segment_ids, rope_positions, train, decode,
+                          decode_positions, block_tables, decode_slots,
+                          adapters[i] if adapters is not None else None)
+            return final_norm(x)
+
+        if not looped:
+            x = stack(x)
+        else:
+            _publish_loop_passes(passes)
+            exits = []
+            with jax.named_scope(train_path.LOOP_STACK):
+                for t in range(passes):
+                    with jax.named_scope(train_path.pass_scope(t)):
+                        x = stack(x)
+                    exits.append(x)
         if arch.tied_head:
             head = emb
         else:
@@ -1051,10 +1147,22 @@ class TransformerLM(nn.Module):
                 self.vocab_size, self.d_model, param_dtype=jnp.float32,
                 dtype=self.compute_dtype, name="lm_head",
             )
+        if looped:
+            hidden = jnp.stack(exits)
+            gate_logits = None
+            if arch.exit_gate:
+                with jax.named_scope(train_path.EXIT_GATE):
+                    # [d, 1] and a bias, one gate for all passes; float32
+                    # at full precision: 2 d flops a token and pass
+                    gate_logits = nn.Dense(
+                        1, dtype=jnp.float32, param_dtype=jnp.float32,
+                        precision=jax.lax.Precision.HIGHEST,
+                        name="exit_gate",
+                    )(hidden.astype(jnp.float32))[..., 0]
         if self.return_hidden:
             if not arch.tied_head and self.is_initializing():
                 head.attend(x[:, :1])  # creates the table
-            return x
+            return (hidden, gate_logits) if looped else x
         with jax.named_scope(train_path.LM_HEAD):
             logits = head.attend(x.astype(jnp.float32))
         return logits
@@ -1070,8 +1178,8 @@ def head_table(params, arch: Optional[Architecture] = None):
 def lm_from_config(config: dict, *, num_layers: Optional[int] = None,
                    **kwargs) -> "TransformerLM":
     """A :class:`TransformerLM` from a ``config.json``-style dict (GPT-2's
-    keys or OLMoE's; :data:`MODEL_CONFIGS` holds two), at the published
-    sizes but for ``num_layers`` where given. ``kwargs`` are the model's
+    keys, OLMoE's or Ouro's; :data:`MODEL_CONFIGS` holds one of each), at
+    the published sizes but for ``num_layers`` where given. ``kwargs`` are the model's
     other fields (``compute_dtype``, ``attention_fn``, ``remat``, ...)."""
     arch = Architecture.from_config(config)
     if config.get("model_type", "gpt2") == "gpt2":
@@ -1090,6 +1198,7 @@ def lm_from_config(config: dict, *, num_layers: Optional[int] = None,
             d_model=config["hidden_size"],
             d_ff=config["intermediate_size"],
             max_len=config["max_position_embeddings"],
+            total_ut_steps=config.get("total_ut_steps", 1),
         )
     if num_layers is not None:
         sizes["num_layers"] = num_layers
@@ -1144,7 +1253,7 @@ def mlm_corrupt(rng, tokens, *, mask_id, vocab_size, rate=0.15):
 
 
 def lm_loss_fused(hidden, emb_table, tokens, *, n_chunks=8,
-                  compute_dtype=jnp.bfloat16):
+                  compute_dtype=jnp.bfloat16, weights=None):
     """Fused chunked LM-head + next-token cross-entropy.
 
     The naive head materializes ``[B, T, vocab]`` f32 logits (≈ 4·B·T·V
@@ -1162,13 +1271,20 @@ def lm_loss_fused(hidden, emb_table, tokens, *, n_chunks=8,
       tokens: integer tokens ``[B, T]``.
       n_chunks: token-dimension split; ``B*(T-1)`` need not divide evenly —
         the tail partial chunk is padded and masked out.
+      weights: optional float32 ``[B, T-1]``, one for each predicted
+        position (``weights[b, t]`` for ``tokens[b, t+1]``): the result is
+        ``sum(weights * cross_entropy) / (B*(T-1))`` and is differentiable
+        in the weights, each row's cross-entropy being its gradient
+        (:func:`lm_loss_looped`'s exit probabilities). ``None`` is all
+        ones: the mean, and the program it always was.
     """
     with jax.named_scope(train_path.LM_HEAD):
         return _lm_loss_fused(hidden, emb_table, tokens, n_chunks,
-                              compute_dtype)
+                              compute_dtype, weights)
 
 
-def _lm_loss_fused(hidden, emb_table, tokens, n_chunks, compute_dtype):
+def _lm_loss_fused(hidden, emb_table, tokens, n_chunks, compute_dtype,
+                   weights):
     B, T, D = hidden.shape
     h = hidden[:, :-1].reshape(-1, D)
     t = tokens[:, 1:].reshape(-1)
@@ -1177,7 +1293,9 @@ def _lm_loss_fused(hidden, emb_table, tokens, n_chunks, compute_dtype):
     pad = chunk * n_chunks - n
     h = jnp.pad(h, ((0, pad), (0, 0)))
     t = jnp.pad(t, (0, pad))
-    valid = jnp.pad(jnp.ones((n,), jnp.float32), (0, pad))
+    valid = jnp.pad(
+        jnp.ones((n,), jnp.float32) if weights is None
+        else weights.astype(jnp.float32).reshape(n), (0, pad))
     w = emb_table.astype(compute_dtype).T  # [D, vocab]
 
     @jax.checkpoint
@@ -1243,6 +1361,51 @@ def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
     }
     loss = ce + load_balance_coef * load_balance + z_loss_coef * z_loss
     return loss, metrics
+
+
+def lm_loss_looped(model: "TransformerLM", params, tokens, *, n_chunks=8,
+                   beta=0.1):
+    """Loss of a looped model with an exit gate (build it with
+    ``return_hidden=True``): the expected next-token cross-entropy over
+    the exits less ``beta`` x the entropy of the exit distribution, a
+    token at a time, then the mean over tokens (Ouro's first-stage
+    objective, arXiv:2510.25741). With ``lambda^t = sigmoid(gate(h^t))``
+    the exit distribution is ``p^t = lambda^t prod_{j<t}(1 - lambda^j)``
+    for ``t < R`` and ``p^R = prod_{j<R}(1 - lambda^j)``; it and the
+    entropy are built in float32 from log-sigmoids. The ``R`` exits go
+    through ONE fused-head call as ``R*B*(T-1)`` rows weighted by ``p``,
+    so no logits tensor exists and the gate's gradient comes out of the
+    head's per-row cross-entropies.
+
+    Returns ``(loss, metrics)`` as :func:`~chainermn_tpu.training.
+    make_train_step` takes it: ``loop/exit_mass_<t>`` (the mean of
+    ``p^t``, ``t`` from 1), ``loop/exit_entropy`` and
+    ``loop/expected_pass`` (the mean of ``sum_t t p^t``)."""
+    hidden, gate_logits = model.apply({"params": params}, tokens)
+    if gate_logits is None:
+        raise ValueError("lm_loss_looped needs the description's exit gate")
+    R, B, T, D = hidden.shape
+    with jax.named_scope(train_path.EXIT_GATE):
+        g = gate_logits[:-1, :, :-1]  # the last pass takes what is left
+        zero = jnp.zeros_like(gate_logits[:1, :, :-1])
+        # log of what no earlier pass took, plus log of this pass's share
+        left = jnp.concatenate(
+            [zero, jnp.cumsum(jax.nn.log_sigmoid(-g), axis=0)])
+        log_p = left + jnp.concatenate([jax.nn.log_sigmoid(g), zero])
+        p = jnp.exp(log_p)  # [R, B, T-1]
+        entropy = -(p * log_p).sum(0).mean()
+        mass = p.mean((1, 2))
+    # R * the mean over the R*B*(T-1) weighted rows: the mean over tokens
+    # of the expected cross-entropy
+    ce = R * lm_loss_fused(
+        hidden.reshape(R * B, T, D), head_table(params, model.arch),
+        jnp.tile(tokens, (R, 1)), n_chunks=n_chunks,
+        compute_dtype=model.compute_dtype, weights=p.reshape(R * B, T - 1))
+    metrics = {f"loop/exit_mass_{t + 1}": mass[t] for t in range(R)}
+    metrics["loop/exit_entropy"] = entropy
+    metrics["loop/expected_pass"] = jnp.sum(
+        mass * jnp.arange(1, R + 1, dtype=mass.dtype))
+    return ce - beta * entropy, metrics
 
 
 def init_cache(model: TransformerLM, params, batch_size: int):

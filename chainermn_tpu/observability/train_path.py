@@ -44,6 +44,12 @@ MOE_ROUTE = "moe_route"
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
 MOE_COMBINE = "moe_combine"
+#: a looped model: round the passes of the layer stack over one set of
+#: weights (sub-scope :func:`pass_scope` round each, final norm included);
+#: round the exit gate's product, the exit distribution, its entropy and
+#: the weighting of the exits
+LOOP_STACK = "loop_stack"
+EXIT_GATE = "exit_gate"
 
 #: how JAX marks the transposed (backward) and the recomputed code of a
 #: scope in ``op_name``
@@ -61,6 +67,11 @@ REMAT_MARKER = "rematted_computation"
 FLASH_OUT = "flash_out"
 FLASH_LSE = "flash_lse"
 FLASH_RESIDUALS = (FLASH_OUT, FLASH_LSE)
+
+
+def pass_scope(index: int) -> str:
+    """Sub-scope of :data:`LOOP_STACK` for one pass (they are unrolled)."""
+    return f"pass{index}"
 
 
 def bucket_scope(index: int) -> str:
@@ -98,4 +109,6 @@ FLASH_TILES = "flash_tiles"
 #: (tokens x experts per token), and the experts it chooses among
 MOE_ROWS_PER_STEP = "moe_rows_per_step"
 MOE_EXPERTS_TOTAL = "moe_experts_total"
-
+#: gauge set while a looped model is traced: passes of its layer stack
+#: over one set of weights
+LOOP_PASSES = "loop_passes"

@@ -498,6 +498,10 @@ class ServingEngine:
         if not isinstance(model, TransformerLM):
             raise TypeError(f"ServingEngine serves TransformerLM, got "
                             f"{type(model).__name__}")
+        if model.looped:
+            from chainermn_tpu.models.transformer import refuse_looped_decode
+
+            refuse_looped_decode(model, "serving")
         if model.return_hidden or not model.causal:
             raise ValueError("serving needs a causal LM with logits "
                              "(return_hidden=False, causal=True)")

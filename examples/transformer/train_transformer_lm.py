@@ -15,6 +15,9 @@ parallelism for long context (``--sequence-parallel``).
     python examples/transformer/train_transformer_lm.py \
         --communicator xla --model olmoe-1b-7b --layers 1 \
         --batchsize 4 --seq-len 4096      # one v5e chip, published widths
+    python examples/transformer/train_transformer_lm.py \
+        --communicator xla --model ouro-2.6b --layers 2 \
+        --batchsize 1 --seq-len 4096      # looped: 2 blocks, 4 passes
 """
 
 from __future__ import annotations
@@ -40,6 +43,7 @@ from chainermn_tpu.models import (
     lm_from_config,
     lm_loss,
     lm_loss_fused,
+    lm_loss_looped,
     lm_loss_moe,
 )
 from chainermn_tpu.training import make_train_step
@@ -308,6 +312,10 @@ def run_named_model(args, comm, compute_dtype, rng):
 
         def loss_fn(params, tokens):
             return lm_loss_moe(model, params, tokens)
+    elif model.looped:
+
+        def loss_fn(params, tokens):
+            return lm_loss_looped(model, params, tokens)
     else:
 
         def loss_fn(params, tokens):
@@ -331,7 +339,7 @@ def run_named_model(args, comm, compute_dtype, rng):
             extra = "".join(
                 f" {k.split('/')[1]}={float(v):.3f}"
                 for k, v in sorted(metrics.items())
-                if k.startswith("moe/") and jnp.ndim(v) == 0)
+                if k.startswith(("moe/", "loop/")) and jnp.ndim(v) == 0)
             print(
                 f"iter {it + 1}/{args.iterations} "
                 f"loss={float(metrics['loss']):.4f}{extra} "

@@ -34,46 +34,22 @@ compute) for a run of the tool itself on a CPU; its numbers mean nothing.
 
 from __future__ import annotations
 
-import argparse
-import json
 import os
 import sys
 import time
 
-_HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(_HERE, "benchmark"))
-sys.path.insert(0, _HERE)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import _controls  # noqa: E402  (puts the checkout and benchmark/ on the path)
 
 CELL = "olmoe-hostfill-1chip"
 
 
-class _Patched:
-    """``obj.name = value`` for a ``with`` block. A ``jax.jit`` traces at
-    its first call: that call has to sit inside the block."""
-
-    def __init__(self, obj, name, value):
-        self.obj, self.name, self.value = obj, name, value
-
-    def __enter__(self):
-        self.old = getattr(self.obj, self.name)
-        setattr(self.obj, self.name, self.value)
-
-    def __exit__(self, *exc):
-        setattr(self.obj, self.name, self.old)
-
-
 def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--seeds", required=True,
-                    help="comma-separated; a seed is one check row")
-    ap.add_argument("--out", required=True, help="JSON lines, appended")
-    ap.add_argument("--tiny", action="store_true")
-    args = ap.parse_args(argv)
+    args = _controls.parser(__doc__).parse_args(argv)
 
     import jax
     import jax.numpy as jnp
 
-    import correct
     import spec
     from chainermn_tpu.models import lm_loss_moe, transformer
     from chainermn_tpu.parallel import moe
@@ -85,14 +61,7 @@ def main(argv=None) -> int:
     config, job = cell["config_spec"], cell["job"]
     samples = cell["mix"]["samples"]["tokens"]
     if args.tiny:
-        import importlib.util
-
-        where = importlib.util.spec_from_file_location(
-            "benchmark_tests_test_moe",
-            os.path.join(_HERE, "benchmark", "tests", "test_moe.py"))
-        tests = importlib.util.module_from_spec(where)
-        where.loader.exec_module(tests)
-        TINY_MOE = tests.TINY_MOE
+        TINY_MOE = _controls.benchmark_test("test_moe").TINY_MOE
         config = {**TINY_MOE, "training": {**TINY_MOE["training"],
                                            "compute_dtype": "bfloat16"}}
         job = {"per_chip_batch": 2, "head_chunks": 2, "seq_len": 128}
@@ -100,14 +69,7 @@ def main(argv=None) -> int:
     ref = roots.module("reference", "moe_lm")
     gen = roots.module("traffic", "gen_tokens")
     tol = ref.TOLERANCES
-    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
-    out = open(args.out, "a")
-
-    def say(**row):
-        line = json.dumps(row, default=str)
-        print(line, flush=True)
-        out.write(line + "\n")
-        out.flush()
+    say, highest = _controls.writer(args.out), _controls.highest
 
     def build(cfg=config):
         return fam_mod.build(cfg, job)
@@ -191,12 +153,6 @@ def main(argv=None) -> int:
             fam, coefs={"z_loss_coef": 0.0}), None, False),
     }
 
-    def highest(fn):
-        def call(*a):
-            with jax.default_matmul_precision("highest"):
-                return fn(*a)
-        return call
-
     ref_vg = highest(jax.jit(jax.value_and_grad(
         lambda p, b: ref.loss(p, (), b, config))))
     ref_bf16_vg = jax.jit(jax.value_and_grad(
@@ -206,21 +162,12 @@ def main(argv=None) -> int:
         tiny=args.tiny, seeds=args.seeds)
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
         params = jax.block_until_ready(fam.init(seed)[0])
-        # check (a)'s batch of this seed, as loops/train.py draws it
-        batch = jnp.asarray(gen.pool(
-            seed + 1_000_003, {**samples, "pool_batches": 1},
-            **fam.pool_args(fam.check_rows))[0])
-        ref_l, ref_g = ref_vg(params, batch)
+        batch = _controls.check_batch(gen, fam, samples, seed)
+        want = ref_vg(params, batch)
 
         def compare(what, loss, grads, t0, **extra):
-            a = correct.compare_loss("loss", float(loss), float(ref_l), tol)
-            b = correct.compare_grads("grads", grads, ref_g, tol)
-            say(what=what, seed=seed, loss_rel_err=a["rel_err"],
-                tree_rel_err=b["tree_rel_err"], worst_leaf=b["worst_leaf"],
-                worst_leaf_rel_err=b["worst_leaf_rel_err"],
-                loss_ok=a["ok"], grads_ok=b["ok"],
-                refused=not (a["ok"] and b["ok"]),
-                seconds=time.perf_counter() - t0, **extra)
+            say(**_controls.reading(tol, what, seed, (loss, grads), want,
+                                    t0, **extra))
 
         variants = {**every_seed, **(first_seed if i == 0 else {})}
         for what, (vg, patch, on_bf16) in variants.items():
@@ -230,7 +177,7 @@ def main(argv=None) -> int:
             if patch is None:
                 (loss, metrics), grads = vg(p, batch)
             else:
-                with _Patched(*patch):
+                with _controls.Patched(*patch):
                     (loss, metrics), grads = vg(p, batch)
             compare(what, loss, grads, t0,
                     dropped=float(metrics["moe/dropped"]))
@@ -238,7 +185,7 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         loss, grads = ref_bf16_vg(params, batch)
         compare("reference computed in bf16", loss, grads, t0)
-        del grads, ref_g, params
+        del grads, want, params
     return 0
 
 
