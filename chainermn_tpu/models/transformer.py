@@ -894,8 +894,9 @@ class TransformerLM(nn.Module):
     #: FLOPs: the knob the MFU sweep explores for HBM-bound configs).
     remat_policy: str = "dots"
     #: skip the weight-tied LM head and return the final (post-LN) hidden
-    #: states; pair with :func:`lm_loss_fused` to avoid materializing the
-    #:  ``[B, T, vocab]`` logits tensor.
+    #: states; pair with :func:`lm_loss_fused`, which makes loss and
+    #: gradient a chunk of tokens at a time and never materializes the
+    #: ``[B, T, vocab]`` logits tensor.
     return_hidden: bool = False
     #: kv heads for GQA/MQA (None → num_heads).
     num_kv_heads: Optional[int] = None
@@ -1259,11 +1260,17 @@ def lm_loss_fused(hidden, emb_table, tokens, *, n_chunks=8,
     The naive head materializes ``[B, T, vocab]`` f32 logits (≈ 4·B·T·V
     bytes of HBM traffic both ways, plus an f32 matmul off the MXU's fast
     path). This computes the head matmul per token-chunk in ``compute_dtype``
-    with f32 MXU accumulation, reduces each chunk to its scalar loss
-    immediately, and rematerializes the chunk in the backward pass
-    (``jax.checkpoint``) — so the full logits tensor never exists in HBM in
-    either pass. Equivalent to ``lm_loss(emb.attend(hidden), tokens)`` up to
-    compute-dtype rounding; pair with ``TransformerLM(return_hidden=True)``.
+    with f32 MXU accumulation and reduces each chunk to its scalar loss
+    immediately. Under differentiation the same loop also makes the
+    gradient while a chunk's logits are there (a ``jax.custom_vjp`` whose
+    forward rule forms ``softmax - onehot``, scaled by the row's weight
+    over the row count and cast once to ``compute_dtype``, and multiplies
+    it out: three matmuls a chunk, the table's gradient summed over the
+    chunks in float32), so the backward pass only scales what the forward
+    kept by the loss's cotangent. The full logits tensor never exists in
+    HBM, and no chunk is computed twice. Equivalent to
+    ``lm_loss(emb.attend(hidden), tokens)`` up to compute-dtype rounding;
+    pair with ``TransformerLM(return_hidden=True)``.
 
     Args:
       hidden: final post-LN hidden states ``[B, T, D]``.
@@ -1276,11 +1283,35 @@ def lm_loss_fused(hidden, emb_table, tokens, *, n_chunks=8,
         ``sum(weights * cross_entropy) / (B*(T-1))`` and is differentiable
         in the weights, each row's cross-entropy being its gradient
         (:func:`lm_loss_looped`'s exit probabilities). ``None`` is all
-        ones: the mean, and the program it always was.
+        ones: the mean.
     """
     with jax.named_scope(train_path.LM_HEAD):
         return _lm_loss_fused(hidden, emb_table, tokens, n_chunks,
                               compute_dtype, weights)
+
+
+def _publish_head_grad_in_forward(engaged: bool):
+    """Gauge :data:`train_path.LM_HEAD_GRAD_IN_FORWARD`, set while the
+    caller's program is traced."""
+    from chainermn_tpu.observability.metrics import registry
+
+    registry().gauge(
+        train_path.LM_HEAD_GRAD_IN_FORWARD,
+        "1 where the fused head traced last made its gradient in its "
+        "forward loop (it was differentiated), 0 where it made the loss "
+        "alone",
+    ).set(float(engaged))
+
+
+def _head_chunk(hc, table, tc):
+    """One chunk of the fused head: float32 logits ``[rows, vocab]`` of
+    ``hc @ table.T``, each row's log-sum-exp and its cross-entropy."""
+    logits = jax.lax.dot_general(
+        hc, table, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+    return logits, lse, lse - gold
 
 
 def _lm_loss_fused(hidden, emb_table, tokens, n_chunks, compute_dtype,
@@ -1296,29 +1327,58 @@ def _lm_loss_fused(hidden, emb_table, tokens, n_chunks, compute_dtype,
     valid = jnp.pad(
         jnp.ones((n,), jnp.float32) if weights is None
         else weights.astype(jnp.float32).reshape(n), (0, pad))
-    w = emb_table.astype(compute_dtype).T  # [D, vocab]
+    h_dtype, table_dtype = hidden.dtype, emb_table.dtype
 
-    @jax.checkpoint
-    def chunk_loss(hc, tc, mc):
-        logits = jnp.dot(
-            hc.astype(compute_dtype), w,
-            preferred_element_type=jnp.float32,
-        )
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-        return jnp.sum((lse - gold) * mc)
+    # hs [chunks, rows, D], table [vocab, D], ts and ms [chunks, rows]
+    @jax.custom_vjp
+    def head(hs, table, ts, ms):
+        _publish_head_grad_in_forward(False)
+        w = table.astype(compute_dtype)
 
-    def body(acc, xs):
-        hc, tc, mc = xs
-        return acc + chunk_loss(hc, tc, mc), ()
+        def body(total, xs):
+            hc, tc, mc = xs
+            _, _, ce = _head_chunk(hc.astype(compute_dtype), w, tc)
+            return total + jnp.sum(ce * mc), ()
 
-    total, _ = jax.lax.scan(
-        body, jnp.float32(0.0),
-        (h.reshape(n_chunks, chunk, D),
-         t.reshape(n_chunks, chunk),
-         valid.reshape(n_chunks, chunk)),
-    )
-    return total / n
+        total, _ = jax.lax.scan(body, jnp.float32(0.0), (hs, ts, ms))
+        return total / n
+
+    def head_fwd(hs, table, ts, ms):
+        _publish_head_grad_in_forward(True)
+        w = table.astype(compute_dtype)
+
+        def body(carry, xs):
+            total, dw = carry
+            hc, tc, mc = xs
+            hc = hc.astype(compute_dtype)
+            logits, lse, ce = _head_chunk(hc, w, tc)
+            hit = jax.nn.one_hot(tc, logits.shape[-1], dtype=jnp.float32)
+            # the loss's gradient in this chunk's logits at cotangent 1,
+            # formed in float32 and rounded once for the two matmuls
+            dl = ((jnp.exp(logits - lse[:, None]) - hit)
+                  * (mc / n)[:, None]).astype(compute_dtype)
+            dh = jnp.dot(dl, w, preferred_element_type=jnp.float32)
+            dw = dw + jax.lax.dot_general(
+                dl, hc, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            # d hidden leaves the chunk in the dtype it is handed back in
+            # (bf16 states: half the bytes kept until the backward)
+            return ((total + jnp.sum(ce * mc), dw),
+                    (dh.astype(h_dtype), ce))
+
+        (total, dw), (dh, ce) = jax.lax.scan(
+            body, (jnp.float32(0.0), jnp.zeros(table.shape, jnp.float32)),
+            (hs, ts, ms))
+        return total / n, (dh, dw, ce)
+
+    def head_bwd(residuals, g):
+        dh, dw, ce = residuals
+        return ((g * dh).astype(h_dtype), (g * dw).astype(table_dtype),
+                None, g * ce / n)
+
+    head.defvjp(head_fwd, head_bwd)
+    return head(h.reshape(n_chunks, chunk, D), emb_table,
+                t.reshape(n_chunks, chunk), valid.reshape(n_chunks, chunk))
 
 
 def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,

@@ -112,3 +112,7 @@ MOE_EXPERTS_TOTAL = "moe_experts_total"
 #: gauge set while a looped model is traced: passes of its layer stack
 #: over one set of weights
 LOOP_PASSES = "loop_passes"
+#: gauge set while the fused LM head is traced: 1 where the trace made the
+#: head's gradient inside its forward loop (it was differentiated), 0
+#: where it made the loss alone (evaluation)
+LM_HEAD_GRAD_IN_FORWARD = "lm_head_grad_in_forward"

@@ -240,7 +240,7 @@ class TestTransformerLM:
         """``lm_loss_fused`` on hidden states == ``lm_loss`` on the full
         logits (f32 compute so rounding cannot hide a real defect), for an
         uneven B*(T-1) that exercises the padded tail chunk — value AND
-        gradients (the head is rematerialized in the backward)."""
+        gradients (the head makes them in its forward loop)."""
         from chainermn_tpu.models import lm_loss_fused
 
         model = tiny_lm()

@@ -223,7 +223,10 @@ def test_remat_of_shared_blocks_changes_nothing(policy, tiny):
 # -- one pass and no gate is today's model ----------------------------------
 
 #: losses and squared gradient norms of the parent commit (0de82aa) on a
-#: seed, float32 on the CPU, from a checkout of it
+#: seed, float32 on the CPU, from a checkout of it. The loss holds to the
+#: bit; the gradient to float32 rounding since ISSUE 31, whose fused head
+#: forms ``softmax - onehot`` itself, the row count inside it: the same
+#: float32 operations in another order than autodiff's
 PARENT = {
     "gpt2": ({"model_type": "gpt2", "n_layer": 2, "n_embd": 32, "n_head": 2,
               "n_inner": 64, "n_positions": 16, "vocab_size": 96},
@@ -259,8 +262,8 @@ def test_one_pass_and_no_gate_is_the_parents_loss_to_the_bit(name):
 
     loss, grads = jax.value_and_grad(loss_fn)(params)
     assert float(loss).hex() == parent_loss
-    assert float(sum(float(jnp.sum(g ** 2))
-                     for g in jax.tree.leaves(grads))).hex() == parent_grad_sq
+    assert float(sum(float(jnp.sum(g ** 2)) for g in jax.tree.leaves(
+        grads))) == pytest.approx(float.fromhex(parent_grad_sq), rel=1e-6)
     if not model.arch.n_experts:
         # the mask is the case of constant weights
         ones = jnp.ones((2, t - 1), jnp.float32)
