@@ -59,9 +59,10 @@ REMAT_MARKER = "rematted_computation"
 # -- checkpoint names -----------------------------------------------------
 #: ``jax.ad_checkpoint.checkpoint_name`` tags on what the flash forward
 #: kernel made and its backward takes: the attention output as the kernel
-#: leaves it (``[B, H, T, D]``) and the log-sum-exp of a row's scores
-#: (``[B, H, T]``: without the kernel's unit minor dimension, which a TPU
-#: pads to 128 lanes). A name is an identity outside ``jax.checkpoint``;
+#: leaves it (the projections' own ``[B, T, H*D]`` rows; ``[B*H, T, D]``
+#: where the op's wrapper transposed) and the log-sum-exp of a row's
+#: scores (``[B, H, 1, T]``: ``T`` on the lane axis, 4 bytes a value).
+#: A name is an identity outside ``jax.checkpoint``;
 #: a remat policy that saves these names keeps the kernel's results, and
 #: the recomputation no longer calls the kernel.
 FLASH_OUT = "flash_out"
@@ -104,6 +105,11 @@ FEED_NOT_READY = "feed_not_ready_total"
 #: (``total`` / ``visited`` / ``masked``): the tile geometry of the last
 #: call of the op (for a jitted step: the last one traced)
 FLASH_TILES = "flash_tiles"
+#: gauge set beside it, one series per flash kernel (label ``kernel``): the
+#: heads a grid step reads from the projections' own ``[B, T, H*D]`` rows
+#: (2 for heads of 64, 1 for heads of 128); 0 where the op's wrapper
+#: transposed its operands to ``[B*H, T, D]``
+FLASH_HEADS_PER_BLOCK = "flash_heads_per_block"
 #: gauges set while a dropless MoE layer is traced (the last layer traced
 #: is what a scrape sees): (token, slot) rows the layer routes in one call
 #: (tokens x experts per token), and the experts it chooses among

@@ -18,13 +18,13 @@ kernels power the sequence-parallel ring attention
 
 Iteration geometry, the same for the three kernels (:func:`_geometry`
 derives it from the lengths and the mask kind unless the caller passes
-``block_q`` / ``block_k``): a grid ``(B, H, space, reduce)`` of tiles that
-are long along the axis a kernel walks (1024 keys for the forward and dq,
-1024 queries for dk/dv) and 512 across it. Under a causal mask a tile is
-one of three kinds (:func:`_tile_class`): wholly above the diagonal — no
-matmul, no vector work and, because the index maps repeat the last live
-block there, no DMA; wholly below — computed with no mask at all; crossed
-by the diagonal. In the forward and dq a crossed tile is walked in
+``block_q`` / ``block_k``): a grid ``(B, head blocks, space, reduce)`` of
+tiles that are long along the axis a kernel walks (1024 keys for the
+forward and dq, 1024 queries for dk/dv) and 512 across it. Under a causal
+mask a tile is one of three kinds (:func:`_tile_class`): wholly above the
+diagonal — no matmul, no vector work and, because the index maps repeat
+the last live block there, no DMA; wholly below — computed with no mask
+at all; crossed by the diagonal. In the forward and dq a crossed tile is walked in
 sub-tiles of 512 keys inside the grid step, of which those above the
 diagonal are skipped, the one it crosses builds the iota/compare/select
 mask and the rest are computed without one (:func:`_pieces`); dk/dv masks
@@ -42,21 +42,40 @@ visited sub-tile, and with either, or a window, dk/dv keeps the 512 x 1024
 tile. The gauge ``flash_tiles`` (labels ``kernel``, ``kind``) says what
 the last call of the op does.
 
-Layout: BTHD at the API (framework convention), BHTD inside the kernel grid.
-The row statistics (LSE, delta) cross HBM with ``T`` on the lane axis, from
-the kernel that makes them to the kernels that read them: ``[B, H, 1, T]``
-in blocks of ``(1, 1, 1, block_q)``, a value 4 bytes (with ``T`` second to
-last and a unit minor dimension a value would occupy a 128-lane tile row,
-128 times its bytes, and XLA would squeeze and pad round every kernel). The
-forward turns its ``[block_q, 1]`` column into the row once a q tile, the
-backward kernels turn the row back into the column their scores are
-corrected by; the ring's entry points speak ``[B, H, T]``.
+Layout: ``[B, T, H, D]`` at the API, and the kernels read and write the
+layout the projections round them produce and consume (:class:`_Layout`):
+``[B, T, H * D]`` rows, a head being a block of columns, in the operands'
+dtype, so the reshape at the op's door transposes nothing and no XLA op
+stands between the qkv matmul's slices, the kernels and the output
+projection, forward or backward. Heads of a multiple of 128 lanes take
+one head a grid step; heads that divide a lane tile (64: two) share one
+128-lane block, each read and written through its own lanes of the block
+(:func:`_head_lanes`) and walked one after the other
+(:func:`_each_head`). Anything else (odd widths, GQA at heads under 128)
+goes through the same kernels as ``[B * H, T, D]``, one head a "batch"
+row, and for those the wrapper
+transposes as it always did. The gauge ``flash_heads_per_block`` (label
+``kernel``) says which: 2, 1, or 0 for the transposed form. dq, dk, dv
+leave the kernels rounded once, from the float32 scratch they are summed
+in, to their operand's dtype (the ring's partial gradients to float32;
+with GQA dk/dv are float32 a q head until the group sum).
+
+The log-sum-exp crosses HBM with ``T`` on the lane axis, from the forward
+to the backward kernels: ``[B, H, 1, T]`` in blocks of ``(1, heads, 1,
+block_q)``, a value 4 bytes (with ``T`` second to last and a unit minor
+dimension a value would occupy a 128-lane tile row, 128 times its bytes,
+and XLA would squeeze and pad round every kernel). The forward turns its
+``[block_q, 1]`` column into the row once a q tile, the backward kernels
+turn the row back into the column their scores are corrected by; the
+ring's entry points speak ``[B, H, T]``. ``delta = rowsum(dO * O)`` is
+no array: both backward kernels take the forward's output beside ``dO``
+and sum the rows themselves (:func:`_delta`).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -71,8 +90,8 @@ from chainermn_tpu.ops.attention import NEG_INF
 
 _LANES = 128
 
-# All three kernels share the (B, H, space, reduce) grid shape: the first
-# three dims produce disjoint output/scratch slices (any iteration order
+# All three kernels share the (B, head blocks, space, reduce) grid shape:
+# the first three dims produce disjoint output/scratch slices (any order
 # is valid — lets Mosaic parallelise/pipeline them), while the LAST dim
 # carries the online-softmax / gradient accumulators and must stay
 # sequential. Consumed only by the Mosaic lowering; interpret mode
@@ -262,7 +281,7 @@ _DKV_CAUSAL_ROW_BYTES = 512
 
 
 def _geometry(Tq, Tk, *, walks, causal, bare=True, row_bytes=0, window=None,
-              q_offset=0, block_q=None, block_k=None):
+              q_offset=0, bias_heads=1, block_q=None, block_k=None):
     """``(block_q, block_k, sub)`` of a kernel, from what a call shows:
     the lengths, the mask kind (``bare``: no segment ids and no bias),
     the bytes of an operand's row (head width times item size; only
@@ -290,13 +309,18 @@ def _geometry(Tq, Tk, *, walks, causal, bare=True, row_bytes=0, window=None,
     rows add to what a tile holds in VMEM: Mosaic refuses 1024 x 1024
     with a bias gradient or with f32 heads of 256 (PERF.md, PR 24).
     There, and with no mask to skip by, the tile stays 512 x 1024. A
-    caller's ``block_q`` / ``block_k`` are taken as given
-    (``_pick_block`` still makes them divide the lengths)."""
+    step that computes several heads (``bias_heads``: those whose bias
+    squares, and bias gradient's, a step holds at once; 1 without a
+    bias) takes as many times fewer keys, down to a lane tile, so the
+    bias tiles fill what one head's did. A caller's ``block_q`` /
+    ``block_k`` are taken as given (``_pick_block`` still makes them
+    divide the lengths)."""
     whole = (walks == "q" and causal and bare and window is None
              and row_bytes <= _DKV_CAUSAL_ROW_BYTES)
     derived = _DKV_CAUSAL_TILES if whole else _TILES
     block_q = _pick_row_block(block_q or derived[0], Tq)
-    block_k = _pick_block(block_k or derived[1], Tk)
+    block_k = _pick_block(
+        block_k or max(derived[1] // bias_heads, _LANES), Tk)
     nests = (walks == "k" and causal and window is None
              and block_k % block_q == 0 and q_offset % block_q == 0)
     return block_q, block_k, block_q if nests else block_k
@@ -307,17 +331,26 @@ _WALKS = {train_path.FLASH_FWD: "k", train_path.FLASH_BWD_DQ: "k",
           train_path.FLASH_BWD_DKV: "q"}
 
 
-def _publish_tiles(kernels, Tq, Tk, *, causal, window=None, q_offset=0,
-                   **geometry):
+def _publish_tiles(kernels, lay, Tq, Tk, *, causal, window=None, q_offset=0,
+                   has_bias=False, **geometry):
     """Set the ``flash_tiles`` gauge for each of ``kernels`` from a call's
     static arguments, in the sub-tiles the kernel skips and masks by:
     those of one head's square (``total``), those computed (``visited``)
-    and those of them that build the causal mask (``masked``). The public
-    entry points call it outside any jit of this module, so every call
-    of theirs sets it, which for a jitted step is while the step is
-    traced; the last call is what a scrape sees."""
+    and those of them that build the causal mask (``masked``); and beside
+    it ``flash_heads_per_block``: the heads a grid step takes from the
+    projections' own layout, 0 where the wrapper transposed
+    (``lay.heads``). The public entry points call it outside any jit of
+    this module, so every call of theirs sets it, which for a jitted
+    step is while the step is traced; the last call is what a scrape
+    sees."""
     from chainermn_tpu.observability.metrics import registry
 
+    heads = registry().gauge(
+        train_path.FLASH_HEADS_PER_BLOCK,
+        "heads a grid step of each flash kernel reads from the "
+        "projections' own [B, T, H*D] rows in its last call; 0: the "
+        "wrapper transposed to [B*H, T, D]",
+    )
     gauge = registry().gauge(
         train_path.FLASH_TILES,
         "sub-tiles of one head's score square in the last call of each "
@@ -325,9 +358,11 @@ def _publish_tiles(kernels, Tq, Tk, *, causal, window=None, q_offset=0,
         "crossed by the diagonal or a window edge)",
     )
     for kernel in kernels:
+        heads.set(float(lay.heads), kernel=kernel)
         unit_q, _, unit_k = _geometry(
             Tq, Tk, walks=_WALKS[kernel], causal=causal, window=window,
-            q_offset=q_offset, **geometry)
+            q_offset=q_offset,
+            bias_heads=lay.step_heads if has_bias else 1, **geometry)
         nq, nk = Tq // unit_q, Tk // unit_k
         live, full = _tile_class(
             np.arange(nk)[None, :], np.arange(nq)[:, None], unit_q, unit_k,
@@ -365,16 +400,162 @@ def _visit(live, index, branches, causal, tile):
 
 
 # ---------------------------------------------------------------------------
+# The layout the kernels read and write
+# ---------------------------------------------------------------------------
+
+def _group(Hq: int, Hkv: int) -> int:
+    """GQA group size: q heads per kv head (MQA when Hkv == 1)."""
+    if Hq % Hkv:
+        raise ValueError(
+            f"q heads ({Hq}) must be a multiple of kv heads ({Hkv})"
+        )
+    return Hq // Hkv
+
+
+class _Layout(NamedTuple):
+    """How the three kernels see the op's ``[B, T, H, D]`` operands,
+    from head width, head count and group size alone.
+
+    ``heads >= 1``: the projections' own rows, ``[B, T, H * D]`` (the
+    reshape is no transposition: XLA hands a projection's slice over as
+    it is, and re-tiles an array it was given four-dimensional, such as
+    RoPE's result, in one pass), a head being a block of columns; a grid
+    step takes ``heads`` of them in one block of ``heads * D`` lanes.
+    Heads as wide as a lane tile or a multiple of it take one a step
+    (``D % 128 == 0``; fewer KV heads are column ``h // g``). Narrower
+    heads that divide a lane tile, in a row that is whole lane tiles,
+    share one: ``128 // D`` heads a step (16 heads of 64: two), without
+    GQA, whose group would have to share K/V lanes it does not own.
+    ``heads == 0``: everything else (odd widths, GQA under 128 lanes);
+    the wrapper transposes to ``[B * H, T, D]`` as it always did, and a
+    head is a "batch" row whose block spans the array's last dimension.
+    One set of kernels serves the three: they differ in the index maps
+    (:meth:`spec`, :meth:`row_spec`) and in how many heads a step walks.
+    """
+
+    B: int
+    H: int
+    Hkv: int
+    D: int
+    heads: int
+
+    @classmethod
+    def of(cls, q_shape, k_shape):
+        """The layout of a call with ``q`` and ``k`` of these BTHD
+        shapes."""
+        B, _, H, D = q_shape
+        Hkv = k_shape[2]
+        if D % _LANES == 0:
+            heads = 1
+        elif _group(H, Hkv) == 1 and _LANES % D == 0 \
+                and (H * D) % _LANES == 0:
+            heads = _LANES // D
+        else:
+            heads = 0
+        return cls(B, H, Hkv, D, heads)
+
+    @property
+    def step_heads(self) -> int:
+        """Heads one grid step computes."""
+        return max(self.heads, 1)
+
+    @property
+    def width(self) -> int:
+        """Lanes of a step's block of q, k, v, dO or a result."""
+        return self.step_heads * self.D
+
+    @property
+    def group(self) -> int:
+        return _group(self.H, self.Hkv)
+
+    def enter(self, x):
+        """``[B, T, h, D]`` as the kernels take it."""
+        B, T, h, D = x.shape
+        if self.heads:
+            return x.reshape(B, T, h * D)
+        return x.transpose(0, 2, 1, 3).reshape(B * h, T, D)
+
+    def leave(self, x):
+        """A kernel's ``q``-, ``k``- or ``v``-shaped array as
+        ``[B, T, h, D]``."""
+        if self.heads:
+            return x.reshape(*x.shape[:2], x.shape[2] // self.D, self.D)
+        return x.reshape(self.B, x.shape[0] // self.B, *x.shape[1:]) \
+            .transpose(0, 2, 1, 3)
+
+    def spec(self, rows, row_of, shared=False):
+        """BlockSpec of ``rows`` rows (block ``row_of(i, j)`` of the
+        grid's last two ids) of a step's heads; ``shared``: of K or V,
+        whose head ``h // g`` serves q head ``h``."""
+        g = self.group if shared else 1
+        if self.heads:
+            return pl.BlockSpec(
+                (1, rows, self.width),
+                lambda b, h, i, j: (b, row_of(i, j), h // g))
+        n = self.H // g
+        return pl.BlockSpec(
+            (1, rows, self.D),
+            lambda b, h, i, j: (b * n + h // g, row_of(i, j), 0))
+
+    def row_spec(self, block_q, row_of):
+        """BlockSpec of a step's rows of statistics in ``[B, H, 1, Tq]``:
+        one row of ``block_q`` lanes a head."""
+        return pl.BlockSpec((1, self.step_heads, 1, block_q),
+                            lambda b, h, i, j: (b, h, 0, row_of(i, j)))
+
+    def group_sum(self, x):
+        """Per-q-head dk or dv summed over each KV head's group."""
+        g, T = self.group, x.shape[1]
+        if self.heads:
+            return x.reshape(self.B, T, self.Hkv, g, self.D).sum(axis=3) \
+                .reshape(self.B, T, self.Hkv * self.D)
+        return x.reshape(self.B, self.Hkv, g, T, self.D).sum(axis=2) \
+            .reshape(self.B * self.Hkv, T, self.D)
+
+
+def _head_lanes(head: int, D: int):
+    """The lanes of head ``head`` in a step's block ``[rows, heads * D]``
+    (all of them where one head fills the block). A kernel reads and
+    writes a head through this slice of its refs, so a head's matmuls
+    and its rounding are those of a head alone in a block, whichever
+    form the layout has; where two heads share a lane tile the second
+    one's loads and stores are shifted by half a tile, which Mosaic does
+    beside the vector work (measured against masking the other head's
+    lanes off whole blocks, which cost the vector unit 7% of the
+    kernels' time: PERF.md, PR 37)."""
+    return slice(head * D, (head + 1) * D)
+
+
+def _each_head(heads: int, head, looped: bool = False):
+    """Run ``head(p)`` for every head of a step, ``p`` a Python int. By
+    default the heads stand one after the other in the step's code, so
+    one's vector work can run under the other's matmuls (the forward and
+    dq, whose score temporaries are sub-tiles of 512 keys). ``looped``:
+    they are a loop's iterations, each picking its own branch, and the
+    step holds one head's temporaries (dk/dv: the two heads of its whole
+    1024 x 1024 tile do not fit VMEM side by side)."""
+    if heads == 1 or not looped:
+        for p in range(heads):
+            head(p)
+        return
+    branches = [functools.partial(head, p) for p in range(heads)]
+    lax.fori_loop(0, heads,
+                  lambda p, carry: (lax.switch(p, branches), carry)[1], 0)
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
-def _scores(q, k, bias_ref, seg_refs, masked, q0, k0, cols, *, scale, window):
+def _scores(q, k, bias_ref, seg_refs, masked, q0, k0, cols, *, scale, window,
+            head=0):
     """One head's masked f32 scores of a piece of a tile: ``q k^T``
     scaled, plus the bias, ``NEG_INF`` where the segment ids differ and,
     on a ``masked`` piece, where the causal mask forbids. ``cols`` is the
     piece's slice of the tile's keys (for the bias and the segment ids,
     which arrive tile-sized), ``q0`` / ``k0`` the tile's first global
-    positions.
+    positions, ``head`` the head's place among the block's (its bias is
+    row ``head`` of the bias tile, or the one row all heads share).
 
     The segment-id refs are ``[1, block_q, 1]`` and ``[1, 1, block_k]``
     — the host side stores ids as ``[B, T, 1]`` / ``[B, 1, T]`` so every
@@ -389,7 +570,8 @@ def _scores(q, k, bias_ref, seg_refs, masked, q0, k0, cols, *, scale, window):
         preferred_element_type=jnp.float32,
     ) * scale
     if bias_ref is not None:
-        s = s + bias_ref[0, 0, :, cols].astype(jnp.float32)
+        row = head if bias_ref.shape[1] > 1 else 0
+        s = s + bias_ref[0, row, :, cols].astype(jnp.float32)
     if seg_refs is not None:
         sq_ref, sk_ref = seg_refs
         s = jnp.where(sq_ref[0] == sk_ref[0, :, cols], s, NEG_INF)
@@ -421,9 +603,10 @@ def _walk(iq, ik, block_q, block_k, sub, causal, window, q_offset):
 
 
 def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
-              acc_ref, m_ref, l_ref, *, scale: float, causal: bool,
-              block_q: int, block_k: int, sub: int, num_k_blocks: int,
-              window=None, band_lo=None, nk_total=None, q_offset: int = 0):
+              acc_ref, m_ref, l_ref, *, heads: int, D: int, scale: float,
+              causal: bool, block_q: int, block_k: int, sub: int,
+              num_k_blocks: int, window=None, band_lo=None, nk_total=None,
+              q_offset: int = 0):
     iq = pl.program_id(2)
     j = pl.program_id(3)
     # Banded grid: slot j covers TRUE k block band_lo(iq) + j; slots
@@ -433,14 +616,14 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
     # kept (the same sums, with the factor exp(-inf) = 0 on nothing).
     single = num_k_blocks == 1
 
-    def _emit(acc, m, l):
-        o_ref[0, 0] = jnp.where(
+    def _emit(p, acc, m, l):
+        o_ref[0, :, _head_lanes(p, D)] = jnp.where(
             l > 0, acc / jnp.maximum(l, 1e-37), 0.0
         ).astype(o_ref.dtype)
         # LSE in the scaled-score domain; fully-masked rows stay NEG_INF.
         # The column is turned into the row it crosses HBM as: once a
         # q tile.
-        lse_ref[0, 0] = jnp.where(
+        lse_ref[0, p] = jnp.where(
             l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), NEG_INF
         ).T
 
@@ -457,39 +640,44 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
         live &= (ik >= 0) & (ik < nk_total)
 
     def _accumulate(pieces):
-        q = q_ref[0, 0]
-        scores = [
-            _scores(q, k_ref[0, 0, a:b], bias_ref, seg_refs, masked, q0, k0,
-                    slice(a, b), scale=scale, window=window)
-            for a, b, masked in pieces
-        ]
-        m_new = functools.reduce(jnp.maximum, [
-            jnp.max(s, axis=1, keepdims=True) for s in scores
-        ])  # [block_q, 1]
-        if not single:
-            m_prev = m_ref[:, 0:1]
-            m_new = jnp.maximum(m_prev, m_new)
-        # A masked score is NEG_INF and exp(NEG_INF - m) is exactly 0,
-        # except in a row that has seen nothing yet: there m is NEG_INF
-        # too and the difference 0. Subtract 0 in such a row.
-        m_sub = jnp.where(m_new > NEG_INF, m_new, 0.0)
-        l_new = acc = 0.0
-        for s, (a, b, _) in zip(scores, pieces):
-            p = jnp.exp(s - m_sub)
-            l_new += jnp.sum(p, axis=1, keepdims=True)
-            v = v_ref[0, 0, a:b]
-            acc += jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        if single:
-            _emit(acc, m_new, l_new)
-            return
-        corr = jnp.exp(m_prev - m_new)  # [block_q, 1]
-        acc_ref[...] = acc_ref[...] * corr + acc
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_ref[:, 0:1] * corr + l_new,
-                                      l_ref.shape)
+        def head(p):
+            lanes = _head_lanes(p, D)
+            q = q_ref[0, :, lanes]
+            scores = [
+                _scores(q, k_ref[0, a:b, lanes], bias_ref, seg_refs, masked,
+                        q0, k0, slice(a, b), scale=scale, window=window,
+                        head=p)
+                for a, b, masked in pieces
+            ]
+            m_new = functools.reduce(jnp.maximum, [
+                jnp.max(s, axis=1, keepdims=True) for s in scores
+            ])  # [block_q, 1]
+            if not single:
+                m_prev = m_ref[p, :, 0:1]
+                m_new = jnp.maximum(m_prev, m_new)
+            # A masked score is NEG_INF and exp(NEG_INF - m) is exactly
+            # 0, except in a row that has seen nothing yet: there m is
+            # NEG_INF too and the difference 0. Subtract 0 in such a row.
+            m_sub = jnp.where(m_new > NEG_INF, m_new, 0.0)
+            l_new = acc = 0.0
+            for s, (a, b, _) in zip(scores, pieces):
+                pr = jnp.exp(s - m_sub)
+                l_new += jnp.sum(pr, axis=1, keepdims=True)
+                v = v_ref[0, a:b, lanes]
+                acc += jax.lax.dot_general(
+                    pr.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            if single:
+                _emit(p, acc, m_new, l_new)
+                return
+            corr = jnp.exp(m_prev - m_new)  # [block_q, 1]
+            acc_ref[:, lanes] = acc_ref[:, lanes] * corr + acc
+            m_ref[p] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[p] = jnp.broadcast_to(l_ref[p, :, 0:1] * corr + l_new,
+                                        l_ref.shape[1:])
+
+        _each_head(heads, head)
 
     _visit(live, index, branches, causal, _accumulate)
 
@@ -503,16 +691,9 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
 
     @pl.when(j == num_k_blocks - 1)
     def _finalize():
-        _emit(acc_ref[...], m_ref[:, 0:1], l_ref[:, 0:1])
-
-
-def _group(Hq: int, Hkv: int) -> int:
-    """GQA group size: q heads per kv head (MQA when Hkv == 1)."""
-    if Hq % Hkv:
-        raise ValueError(
-            f"q heads ({Hq}) must be a multiple of kv heads ({Hkv})"
-        )
-    return Hq // Hkv
+        _each_head(heads, lambda p: _emit(
+            p, acc_ref[:, _head_lanes(p, D)], m_ref[p, :, 0:1],
+            l_ref[p, :, 0:1]))
 
 
 def _split_refs(refs, n_fixed, has_segments, has_bias):
@@ -530,9 +711,11 @@ def _split_refs(refs, n_fixed, has_segments, has_bias):
     return seg_refs, bias_ref, refs[i:]
 
 
-def _bias_spec(bias, block_q, block_k, swap=False, k_of=None, q_of=None):
+def _bias_spec(bias, heads, block_q, block_k, swap=False, k_of=None,
+               q_of=None):
     """BlockSpec for an additive bias ``[B|1, H|1, Tq, Tk]`` — size-1
-    leading dims broadcast via the index map. ``swap=True`` for grids
+    leading dims broadcast via the index map; a bias with a square a
+    head brings those of a step's ``heads``. ``swap=True`` for grids
     whose 3rd/4th program ids are (ik, iq) instead of (iq, ik).
     ``k_of(iq, j)`` / ``q_of(ik, j)`` translate a grid slot to the true
     (clipped) block index."""
@@ -549,13 +732,13 @@ def _bias_spec(bias, block_q, block_k, swap=False, k_of=None, q_of=None):
         return (bb if bb is not None else b,
                 bh if bh is not None else h, iq, ik)
 
-    return pl.BlockSpec((1, 1, block_q, block_k), idx)
+    return pl.BlockSpec((1, 1 if bh == 0 else heads, block_q, block_k), idx)
 
 
-def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
-                    scale, block_q, block_k, interpret, window=None,
-                    q_offset=0):
-    """BHTD forward → (out [B,H,Tq,D], lse [B,H,1,Tq]).
+def _flash_fwd(lay, q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
+               scale, block_q, block_k, interpret, window=None, q_offset=0):
+    """Forward on operands in the layout ``lay`` → ``(out, lse)``: the
+    output as ``q`` came, the log-sum-exp ``[B, H, 1, Tq]`` float32.
 
     ``k``/``v`` may carry FEWER heads than ``q`` (GQA/MQA): kv head
     ``h // g`` serves q head ``h`` via the BlockSpec index map — no
@@ -563,11 +746,13 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
     int32 packed-segment ids; ``bias`` an optional additive
     ``[B|1, H|1, Tq, Tk]`` score bias (ALiBi etc.), tiled per block.
     ``block_q``/``block_k`` of None are derived (:func:`_geometry`)."""
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    g = _group(H, k.shape[1])
+    Tq, Tk = q.shape[1], k.shape[1]
+    heads = lay.step_heads
+    has_segments = seg_q is not None
+    has_bias = bias is not None
     block_q, block_k, sub = _geometry(
         Tq, Tk, walks="k", causal=causal, window=window, q_offset=q_offset,
+        bias_heads=heads if has_bias else 1,
         block_q=block_q, block_k=block_k,
     )
     nq, nk = Tq // block_q, Tk // block_k
@@ -584,21 +769,13 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
 
     k_block = _k_slot(band_lo, nk, block_q, block_k, causal, q_offset)
 
-    params = dict(scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, sub=sub, num_k_blocks=grid_k,
-                  window=window, band_lo=band_lo, nk_total=nk,
-                  q_offset=q_offset)
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, D),
-        lambda b, h, iq, j: (b, h // g, k_block(iq, j), 0),
-    )
-    in_specs = [
-        pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-        kv_spec,
-        kv_spec,
-    ]
-    has_segments = seg_q is not None
-    has_bias = bias is not None
+    params = dict(heads=heads, D=lay.D, scale=scale, causal=causal,
+                  block_q=block_q, block_k=block_k, sub=sub,
+                  num_k_blocks=grid_k, window=window, band_lo=band_lo,
+                  nk_total=nk, q_offset=q_offset)
+    q_spec = lay.spec(block_q, lambda iq, j: iq)
+    kv_spec = lay.spec(block_k, k_block, shared=True)
+    in_specs = [q_spec, kv_spec, kv_spec]
     args = (q, k, v)
     if has_segments:
         in_specs += [
@@ -609,7 +786,7 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
         args += (seg_q[:, :, None], seg_k[:, None, :])
     if has_bias:
         in_specs.append(
-            _bias_spec(bias, block_q, block_k, k_of=k_block)
+            _bias_spec(bias, heads, block_q, block_k, k_of=k_block)
         )
         args += (bias,)
 
@@ -618,30 +795,25 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
             refs, 3, has_segments, has_bias
         )
         o_ref, lse_ref, *scratch = rest
-        _fwd_body(refs[0], refs[1], refs[2], seg_refs, bias_ref,
-                  o_ref, lse_ref, *(scratch or (None,) * 3), **params)
+        _fwd_body(*refs[:3], seg_refs, bias_ref, o_ref, lse_ref,
+                  *(scratch or (None,) * 3), **params)
 
     with jax.named_scope(train_path.FLASH_FWD):
         return pl.pallas_call(
             kernel,
             name=train_path.FLASH_FWD,
-            grid=(B, H, nq, grid_k),
+            grid=(lay.B, lay.H // heads, nq, grid_k),
             compiler_params=_GRID_SEMANTICS,
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, block_q, D),
-                             lambda b, h, iq, ik: (b, h, iq, 0)),
-                pl.BlockSpec((1, 1, 1, block_q),
-                             lambda b, h, iq, ik: (b, h, 0, iq)),
-            ],
+            out_specs=[q_spec, lay.row_spec(block_q, lambda iq, j: iq)],
             out_shape=[
-                jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
-                jax.ShapeDtypeStruct((B, H, 1, Tq), jnp.float32),
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct((lay.B, lay.H, 1, Tq), jnp.float32),
             ],
             scratch_shapes=[] if grid_k == 1 else [
-                pltpu.VMEM((block_q, D), jnp.float32),      # acc
-                pltpu.VMEM((block_q, _LANES), jnp.float32),  # m
-                pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
+                pltpu.VMEM((block_q, lay.width), jnp.float32),        # acc
+                pltpu.VMEM((heads, block_q, _LANES), jnp.float32),    # m
+                pltpu.VMEM((heads, block_q, _LANES), jnp.float32),    # l
             ],
             interpret=interpret,
         )(*args)
@@ -651,17 +823,31 @@ def _flash_fwd_bhtd(q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
 # Backward: dq kernel (iterate K blocks per fixed Q block)
 # ---------------------------------------------------------------------------
 
-def _column(row_ref):
-    """A ``[1, 1, 1, block_q]`` block of row statistics as the
-    ``[block_q, 1]`` column the scores are corrected by."""
-    return jnp.expand_dims(row_ref[0, 0, 0], -1)
+def _column(row_ref, head=0):
+    """Head ``head``'s ``block_q`` lanes of a ``[1, heads, 1, block_q]``
+    block of row statistics as the ``[block_q, 1]`` column the scores
+    are corrected by."""
+    return jnp.expand_dims(row_ref[0, head, 0], -1)
 
 
-def _bwd_dq_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
-                 bias_ref, dq_ref, dq_acc, *, scale: float, causal: bool,
-                 block_q: int, block_k: int, sub: int, num_k_blocks: int,
-                 window=None, band_lo=None, nk_total=None,
-                 q_offset: int = 0):
+def _delta(do, o):
+    """``rowsum(dO * O)`` of one head as a ``[block_q, 1]`` column: the
+    rowwise correction of the flash backward (it re-derives the softmax
+    Jacobian's contribution without P), from the head's float32 ``dO``
+    and its lanes of the forward's output as that kernel wrote it. Both
+    backward kernels make it where they use it. In the projections'
+    layout XLA's row sum would first transpose the float32 product to
+    put ``T`` on the lanes a kernel reads statistics from, and a kernel
+    that wrote it as a row for the other paid more for turning the
+    column than the other pays for the sum (PERF.md, PR 37)."""
+    return jnp.sum(do * o.astype(jnp.float32), axis=1, keepdims=True)
+
+
+def _bwd_dq_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
+                 bias_ref, dq_ref, dq_acc, *, heads: int, D: int,
+                 scale: float, causal: bool, block_q: int, block_k: int,
+                 sub: int, num_k_blocks: int, window=None, band_lo=None,
+                 nk_total=None, q_offset: int = 0):
     iq = pl.program_id(2)
     j = pl.program_id(3)
     ik = j if band_lo is None else band_lo(iq) + j
@@ -678,32 +864,36 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
         live &= (ik >= 0) & (ik < nk_total)
 
     def _accumulate(pieces):
-        q = q_ref[0, 0]
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = _column(lse_ref)
-        delta = _column(delta_ref)
-        dq = 0.0
-        for a, b, masked in pieces:
-            k = k_ref[0, 0, a:b]
-            s = _scores(q, k, bias_ref, seg_refs, masked, q0, k0,
-                        slice(a, b), scale=scale, window=window)
-            # p from the saved LSE: exp(NEG_INF - lse) underflows to
-            # exactly 0, so masked/never-attended entries contribute
-            # nothing.
-            p = jnp.exp(s - lse)
-            dp = jax.lax.dot_general(
-                do, v_ref[0, 0, a:b], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [block_q, b - a]
-            ds = p * (dp - delta) * scale
-            dq += jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        if single:
-            dq_ref[0, 0] = dq.astype(dq_ref.dtype)
-        else:
-            dq_acc[...] += dq
+        def head(p):
+            lanes = _head_lanes(p, D)
+            q = q_ref[0, :, lanes]
+            do = do_ref[0, :, lanes].astype(jnp.float32)
+            lse = _column(lse_ref, p)
+            delta = _delta(do, o_ref[0, :, lanes])
+            dq = 0.0
+            for a, b, masked in pieces:
+                k = k_ref[0, a:b, lanes]
+                s = _scores(q, k, bias_ref, seg_refs, masked, q0, k0,
+                            slice(a, b), scale=scale, window=window, head=p)
+                # p from the saved LSE: exp(NEG_INF - lse) underflows to
+                # exactly 0, so masked/never-attended entries contribute
+                # nothing.
+                pr = jnp.exp(s - lse)
+                dp = jax.lax.dot_general(
+                    do, v_ref[0, a:b, lanes], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [block_q, b - a]
+                ds = pr * (dp - delta) * scale
+                dq += jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            if single:
+                dq_ref[0, :, lanes] = dq.astype(dq_ref.dtype)
+            else:
+                dq_acc[:, lanes] += dq
+
+        _each_head(heads, head)
 
     _visit(live, index, branches, causal, _accumulate)
 
@@ -716,18 +906,18 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
 
     @pl.when(j == num_k_blocks - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
 # Backward: dk/dv kernel (iterate Q blocks per fixed K block)
 # ---------------------------------------------------------------------------
 
-def _bwd_dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
+def _bwd_dkv_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
                   bias_ref, dk_ref, dv_ref, dbias_ref, dk_acc, dv_acc, *,
-                  scale: float, causal: bool, block_q: int, block_k: int,
-                  num_q_blocks: int, window=None, band_lo=None,
-                  nq_total=None, q_offset: int = 0):
+                  heads: int, D: int, scale: float, causal: bool,
+                  block_q: int, block_k: int, num_q_blocks: int, window=None,
+                  band_lo=None, nq_total=None, q_offset: int = 0):
     ik = pl.program_id(2)
     j = pl.program_id(3)
     iq = j if band_lo is None else band_lo(ik) + j
@@ -755,39 +945,45 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
 
     def _accumulate(pieces):
         (_, _, masked), = pieces  # the whole tile, masked or not
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0].astype(jnp.float32)
-        s = _scores(q, k, bias_ref, seg_refs, masked, q0, k0, slice(None),
-                    scale=scale, window=window)
-        p = jnp.exp(s - _column(lse_ref))  # [block_q, block_k]
-        # dv += p^T @ do
-        dv = jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds_unscaled = p * (dp - _column(delta_ref))  # d loss / d s_total
-        if dbias_ref is not None:
-            # dbias tile == ds before the qk-scale factor (the bias adds
-            # AFTER the scale multiplies q·k).
-            dbias_ref[0, 0] = ds_unscaled.astype(dbias_ref.dtype)
-        ds = ds_unscaled * scale  # [block_q, block_k]
-        # dk += ds^T @ q
-        dk = jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if single:
-            dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-            dv_ref[0, 0] = dv.astype(dv_ref.dtype)
-        else:
-            dk_acc[...] += dk
-            dv_acc[...] += dv
+
+        def head(p):
+            lanes = _head_lanes(p, D)
+            q = q_ref[0, :, lanes]
+            k = k_ref[0, :, lanes]
+            v = v_ref[0, :, lanes]
+            do = do_ref[0, :, lanes].astype(jnp.float32)
+            s = _scores(q, k, bias_ref, seg_refs, masked, q0, k0,
+                        slice(None), scale=scale, window=window, head=p)
+            pr = jnp.exp(s - _column(lse_ref, p))  # [block_q, block_k]
+            # dv += p^T @ do
+            dv = jax.lax.dot_general(
+                pr.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            # d loss / d s_total
+            ds_unscaled = pr * (dp - _delta(do, o_ref[0, :, lanes]))
+            if dbias_ref is not None:
+                # dbias tile == ds before the qk-scale factor (the bias
+                # adds AFTER the scale multiplies q·k).
+                dbias_ref[0, p] = ds_unscaled.astype(dbias_ref.dtype)
+            ds = ds_unscaled * scale  # [block_q, block_k]
+            # dk += ds^T @ q
+            dk = jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            if single:
+                dk_ref[0, :, lanes] = dk.astype(dk_ref.dtype)
+                dv_ref[0, :, lanes] = dv.astype(dv_ref.dtype)
+            else:
+                dk_acc[:, lanes] += dk
+                dv_acc[:, lanes] += dv
+
+        _each_head(heads, head, looped=True)
 
     _visit(live, index, branches, causal, _accumulate)
 
@@ -801,34 +997,41 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_refs,
 
     @pl.when(j == num_q_blocks - 1)
     def _finalize():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
-                    bias=None, want_dbias=False, *,
-                    causal, scale, block_q, block_k, interpret, window=None,
-                    q_offset=0):
-    """BHTD backward → ``(dq, dk, dv[, dbias])``, each f32, given saved
-    LSE and ``delta = rowsum(do * o)``, both ``[B, H, 1, Tq]`` as the
-    forward writes its LSE. With GQA (kv heads Hkv < Hq),
-    dk/dv come back at the KV head count: the per-q-head contributions
-    are written per-head and group-summed outside the kernel.
-    ``want_dbias`` materializes the full ``[B, H, Tq, Tk]`` f32 bias
-    gradient (then reduced to ``bias``'s broadcast shape) — O(B·H·T²)
-    regardless of the bias's own broadcast shape; see the public
-    docstring's sizing caution."""
-    B, H, Tq, D = q.shape
-    Hkv, Tk = k.shape[1], k.shape[2]
-    g = _group(H, Hkv)
+def _flash_bwd(lay, q, k, v, out, do, lse, seg_q=None, seg_k=None,
+               bias=None, want_dbias=False, *, causal, scale, block_q,
+               block_k, interpret, window=None, q_offset=0, grad_dtype=None):
+    """Backward on operands in the layout ``lay`` → ``(dq, dk, dv[,
+    dbias])`` in that layout, given the forward's output (the ring: the
+    merged one) and log-sum-exp as the forward wrote them, the latter
+    ``[B, H, 1, Tq]``; the kernels make ``delta = rowsum(do * out)``
+    themselves (:func:`_delta`).
+
+    Both kernels add up in a float32 scratch and round once, in their
+    last line, to ``grad_dtype`` (None: the operand's own dtype, which
+    is what the op hands back; the ring sums partial gradients over its
+    steps and asks for float32). With GQA (kv heads Hkv < Hq) dk/dv
+    leave the kernel a q head each, float32, and are rounded after the
+    group sum. ``want_dbias`` materializes the full ``[B, H, Tq, Tk]``
+    f32 bias gradient (then reduced to ``bias``'s broadcast shape) —
+    O(B·H·T²) regardless of the bias's own broadcast shape; see the
+    public docstring's sizing caution."""
+    Tq, Tk = q.shape[1], k.shape[1]
+    heads = lay.step_heads
     has_segments = seg_q is not None
     has_bias = bias is not None
     assert not (want_dbias and not has_bias)
     geometry = functools.partial(
         _geometry, Tq, Tk, causal=causal, window=window, q_offset=q_offset,
         bare=not (has_segments or has_bias),
-        row_bytes=D * q.dtype.itemsize, block_q=block_q, block_k=block_k,
+        row_bytes=lay.D * q.dtype.itemsize,
+        bias_heads=heads if has_bias else 1,
+        block_q=block_q, block_k=block_k,
     )
+    grid_bh = (lay.B, lay.H // heads)
     seg_args = ((seg_q[:, :, None], seg_k[:, None, :]) if has_segments
                 else ())
     bias_args = (bias,) if has_bias else ()
@@ -836,7 +1039,7 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
     # -- dq: K blocks per Q block -------------------------------------
     block_q, block_k, sub = geometry(walks="k")
     nq, nk = Tq // block_q, Tk // block_k
-    # Banded grids (see _flash_fwd_bhtd): dq iterates only the k blocks in
+    # Banded grids (see _flash_fwd): dq iterates only the k blocks in
     # the window band; dk/dv only the q blocks that can see this k block.
     band_lo = None
     grid_k = nk
@@ -845,18 +1048,14 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
         if span_k < nk:
             band_lo, grid_k = lo_k, span_k
     k_block = _k_slot(band_lo, nk, block_q, block_k, causal, q_offset)
-    dq_params = dict(scale=scale, causal=causal, block_q=block_q,
-                     block_k=block_k, sub=sub, window=window,
-                     q_offset=q_offset, num_k_blocks=grid_k,
+    dq_params = dict(heads=heads, D=lay.D, scale=scale, causal=causal,
+                     block_q=block_q, block_k=block_k, sub=sub,
+                     window=window, q_offset=q_offset, num_k_blocks=grid_k,
                      band_lo=band_lo, nk_total=nk)
-    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
-    row_spec = pl.BlockSpec((1, 1, 1, block_q),
-                            lambda b, h, i, j: (b, h, 0, i))
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, D),
-        lambda b, h, i, j: (b, h // g, k_block(i, j), 0),
-    )
-    dq_in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    q_spec = lay.spec(block_q, lambda i, j: i)
+    row_spec = lay.row_spec(block_q, lambda i, j: i)
+    kv_spec = lay.spec(block_k, k_block, shared=True)
+    dq_in_specs = [q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec]
     if has_segments:
         dq_in_specs += [
             pl.BlockSpec((1, block_q, 1), lambda b, h, i, j: (b, i, 0)),
@@ -865,7 +1064,7 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
         ]
     if has_bias:
         dq_in_specs.append(
-            _bias_spec(bias, block_q, block_k, k_of=k_block)
+            _bias_spec(bias, heads, block_q, block_k, k_of=k_block)
         )
 
     def dq_kernel(*refs):
@@ -873,30 +1072,29 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
             refs, 6, has_segments, has_bias
         )
         dq_ref, *dq_acc = rest
-        _bwd_dq_body(refs[0], refs[1], refs[2], refs[3], refs[4], refs[5],
-                     seg_refs, bias_ref, dq_ref, *(dq_acc or (None,)),
-                     **dq_params)
+        _bwd_dq_body(*refs[:6], seg_refs, bias_ref, dq_ref,
+                     *(dq_acc or (None,)), **dq_params)
 
     with jax.named_scope(train_path.FLASH_BWD_DQ):
         dq = pl.pallas_call(
             dq_kernel,
             name=train_path.FLASH_BWD_DQ,
-            grid=(B, H, nq, grid_k),
+            grid=(*grid_bh, nq, grid_k),
             compiler_params=_GRID_SEMANTICS,
             in_specs=dq_in_specs,
             out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct(q.shape, grad_dtype or q.dtype),
             scratch_shapes=[] if grid_k == 1 else [
-                pltpu.VMEM((block_q, D), jnp.float32)],
+                pltpu.VMEM((block_q, lay.width), jnp.float32)],
             interpret=interpret,
-        )(q, k, v, do, lse, delta, *seg_args, *bias_args)
+        )(q, k, v, out, do, lse, *seg_args, *bias_args)
 
     # -- dk/dv: Q blocks per K block ----------------------------------
     # The grid iterates Q heads; with GQA each q head writes its own
-    # [B, H, Tk, D] slot (no cross-head accumulation inside the grid) and
-    # the group sum happens below. Grid program ids here are (ik, iq).
-    # want_dbias forces the full grid — its output tiles every (iq, ik),
-    # written at its grid slot.
+    # slot of a q-shaped array (no cross-head accumulation inside the
+    # grid) and the group sum happens below. Grid program ids here are
+    # (ik, iq). want_dbias forces the full grid — its output tiles every
+    # (iq, ik), written at its grid slot.
     block_q, block_k, _ = geometry(walks="q")
     nq, nk = Tq // block_q, Tk // block_k
     band_lo = None
@@ -907,20 +1105,16 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
             band_lo, grid_q = lo_q, span_q
     q_block = _q_slot(band_lo, nq, block_q, block_k,
                       causal and not want_dbias, q_offset)
-    dkv_params = dict(scale=scale, causal=causal, block_q=block_q,
-                      block_k=block_k, window=window,
+    dkv_params = dict(heads=heads, D=lay.D, scale=scale, causal=causal,
+                      block_q=block_q, block_k=block_k, window=window,
                       q_offset=q_offset, num_q_blocks=grid_q,
                       band_lo=band_lo, nq_total=nq)
-    k_spec_in = pl.BlockSpec((1, 1, block_k, D),
-                             lambda b, h, i, j: (b, h // g, i, 0))
-    k_spec_out = pl.BlockSpec((1, 1, block_k, D),
-                              lambda b, h, i, j: (b, h, i, 0))
-    q_spec_in = pl.BlockSpec((1, 1, block_q, D),
-                             lambda b, h, i, j: (b, h, q_block(i, j), 0))
-    row_spec_in = pl.BlockSpec((1, 1, 1, block_q),
-                               lambda b, h, i, j: (b, h, 0, q_block(i, j)))
-    dkv_in_specs = [q_spec_in, k_spec_in, k_spec_in, q_spec_in,
-                    row_spec_in, row_spec_in]
+    k_spec_in = lay.spec(block_k, lambda i, j: i, shared=True)
+    k_spec_out = lay.spec(block_k, lambda i, j: i)
+    q_spec_in = lay.spec(block_q, q_block)
+    row_spec_in = lay.row_spec(block_q, q_block)
+    dkv_in_specs = [q_spec_in, k_spec_in, k_spec_in, q_spec_in, q_spec_in,
+                    row_spec_in]
     if has_segments:
         dkv_in_specs += [
             pl.BlockSpec((1, block_q, 1),
@@ -929,22 +1123,22 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
         ]
     if has_bias:
         dkv_in_specs.append(
-            _bias_spec(bias, block_q, block_k, swap=True,
+            _bias_spec(bias, heads, block_q, block_k, swap=True,
                        q_of=q_block)
         )
 
+    # a q head each: k's rows at q's width (the same array without GQA)
+    kv_dtype = jnp.float32 if lay.group > 1 else grad_dtype or k.dtype
+    dkv_shape = jax.ShapeDtypeStruct((q.shape[0], Tk, q.shape[2]), kv_dtype)
     out_specs = [k_spec_out, k_spec_out]
-    out_shape = [
-        jax.ShapeDtypeStruct((B, H, Tk, D), jnp.float32),
-        jax.ShapeDtypeStruct((B, H, Tk, D), jnp.float32),
-    ]
+    out_shape = [dkv_shape, dkv_shape]
     if want_dbias:
         out_specs.append(
-            pl.BlockSpec((1, 1, block_q, block_k),
+            pl.BlockSpec((1, heads, block_q, block_k),
                          lambda b, h, i, j: (b, h, j, i))
         )
         out_shape.append(
-            jax.ShapeDtypeStruct((B, H, Tq, Tk), jnp.float32)
+            jax.ShapeDtypeStruct((lay.B, lay.H, Tq, Tk), jnp.float32)
         )
 
     def dkv_kernel(*refs):
@@ -953,33 +1147,32 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, seg_q=None, seg_k=None,
         )
         dk_ref, dv_ref, *rest = rest
         dbias_ref = rest.pop(0) if want_dbias else None
-        _bwd_dkv_body(refs[0], refs[1], refs[2], refs[3], refs[4], refs[5],
-                      seg_refs, bias_ref, dk_ref, dv_ref, dbias_ref,
-                      *(rest or (None, None)), **dkv_params)
+        _bwd_dkv_body(*refs[:6], seg_refs, bias_ref, dk_ref, dv_ref,
+                      dbias_ref, *(rest or (None, None)), **dkv_params)
 
     with jax.named_scope(train_path.FLASH_BWD_DKV):
         res = pl.pallas_call(
             dkv_kernel,
             name=train_path.FLASH_BWD_DKV,
-            grid=(B, H, nk, grid_q),
+            grid=(*grid_bh, nk, grid_q),
             compiler_params=_GRID_SEMANTICS,
             in_specs=dkv_in_specs,
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[] if grid_q == 1 else [
-                pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, lay.width), jnp.float32),
+                pltpu.VMEM((block_k, lay.width), jnp.float32),
             ],
             interpret=interpret,
-        )(q, k, v, do, lse, delta, *seg_args, *bias_args)
+        )(q, k, v, out, do, lse, *seg_args, *bias_args)
     if want_dbias:
         dk, dv, dbias = res
     else:
         dk, dv = res
         dbias = None
-    if g > 1:
-        dk = dk.reshape(B, Hkv, g, Tk, D).sum(axis=2)
-        dv = dv.reshape(B, Hkv, g, Tk, D).sum(axis=2)
+    if lay.group > 1:
+        dk = lay.group_sum(dk).astype(grad_dtype or k.dtype)
+        dv = lay.group_sum(dv).astype(grad_dtype or v.dtype)
     if want_dbias:
         # Reduce to the bias's broadcast shape.
         if bias.shape[1] == 1:
@@ -1020,10 +1213,6 @@ def _use_interpret() -> bool:
     return interpret_on(jax.default_backend())
 
 
-def _to_bhtd(x):
-    return x.transpose(0, 2, 1, 3)
-
-
 # One custom_vjp covers every operand combination: seg/bias are always
 # passed (zero-size dummies when unused, selected by the static has_*
 # flags), which avoids a per-combination class explosion.
@@ -1042,8 +1231,9 @@ def _flash_core(q, k, v, seg, bias, has_seg, has_bias, bias_grad, causal,
 
 def _flash_core_fwd(q, k, v, seg, bias, has_seg, has_bias, bias_grad,
                     causal, scale, block_q, block_k, interpret, window):
-    out, lse = _flash_fwd_bhtd(
-        _to_bhtd(q), _to_bhtd(k), _to_bhtd(v),
+    lay = _Layout.of(q.shape, k.shape)
+    out, lse = _flash_fwd(
+        lay, lay.enter(q), lay.enter(k), lay.enter(v),
         seg if has_seg else None, seg if has_seg else None,
         bias if has_bias else None,  # bias is already scores-layout BHQK
         causal=causal, scale=scale,
@@ -1053,40 +1243,34 @@ def _flash_core_fwd(q, k, v, seg, bias, has_seg, has_bias, bias_grad,
     # What the backward takes from the kernel, by name: a remat policy
     # that saves these names (models/transformer.py, 'dots') keeps them,
     # and the recomputation has no use for the kernel. Both are the
-    # kernel's results as it wrote them; the log-sum-exp is a row,
-    # [B, H, 1, Tq], which the backward kernels read as it is.
-    out = checkpoint_name(out, train_path.FLASH_OUT)  # in BHTD
+    # kernel's results as it wrote them: the output in the kernels'
+    # layout, the log-sum-exp a row, [B, H, 1, Tq]; the backward kernels
+    # read both as they are.
+    out = checkpoint_name(out, train_path.FLASH_OUT)
     lse = checkpoint_name(lse, train_path.FLASH_LSE)
-    return _to_bhtd(out), (q, k, v, seg, bias, out, lse)
+    return lay.leave(out), (q, k, v, seg, bias, out, lse)
 
 
 def _flash_core_bwd(has_seg, has_bias, bias_grad, causal, scale, block_q,
                     block_k, interpret, window, res, g):
-    q, k, v, seg, bias, out_bhtd, lse = res
-    do = _to_bhtd(g)
-    # delta_i = sum_d dO_i . O_i — the rowwise correction term of the flash
-    # backward (re-derives softmax jacobian contributions without P).
-    delta = jnp.sum(do.astype(jnp.float32) * out_bhtd.astype(jnp.float32),
-                    axis=-1)
-    delta = delta.reshape(lse.shape)  # [B, H, 1, Tq] (kernel layout)
-    res_bwd = _flash_bwd_bhtd(
-        _to_bhtd(q), _to_bhtd(k), _to_bhtd(v), do, lse, delta,
-        seg if has_seg else None, seg if has_seg else None,
+    q, k, v, seg, bias, out, lse = res
+    lay = _Layout.of(q.shape, k.shape)
+    res_bwd = _flash_bwd(
+        lay, lay.enter(q), lay.enter(k), lay.enter(v), out, lay.enter(g),
+        lse, seg if has_seg else None, seg if has_seg else None,
         bias if has_bias else None, bias_grad,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret, window=window,
     )
-    dq, dk, dv = res_bwd[:3]
     if bias_grad:
         dbias = res_bwd[3].astype(bias.dtype)  # already BHQK
     else:
         # No-grad bias (the common ALiBi/static case): a zero cotangent —
         # callers training a bias must pass bias_grad=True.
         dbias = jnp.zeros_like(bias)
+    # dq, dk, dv: rounded to their operand's dtype by the kernels
     return (
-        _to_bhtd(dq).astype(q.dtype),
-        _to_bhtd(dk).astype(k.dtype),
-        _to_bhtd(dv).astype(v.dtype),
+        *(lay.leave(x) for x in res_bwd[:3]),
         None,  # integer segment ids carry no gradient
         dbias,
     )
@@ -1154,6 +1338,15 @@ def flash_attention(
     lengths and the mask kind (:func:`_geometry`); numbers are taken as
     given, halved until they divide the lengths.
 
+    The kernels see ``q``, ``k``, ``v`` and the output's cotangent as
+    ``[B, T, H * D]``, the layout a projection hands them in, and write
+    the output and dq, dk, dv so, in the operands' dtype: where ``D`` is
+    a multiple of 128, or divides 128 with ``H * D`` a multiple of 128
+    and no GQA, the reshapes here are all that stands between the caller
+    and the kernels. For other head widths, and GQA at heads under 128,
+    this wrapper transposes to ``[B * H, T, D]`` and back
+    (:class:`_Layout`; the gauge ``flash_heads_per_block`` reads 0).
+
     On TPU the kernels compile via Mosaic; elsewhere (CPU tests) they run in
     Pallas interpreter mode unless ``interpret=False``.
     """
@@ -1186,8 +1379,9 @@ def flash_attention(
     # Here and not in the kernels' builders, which `_flash_call` traces
     # once a shape: the backward's entries are what this call's gradient
     # runs, whether or not one is taken.
-    _publish_tiles(_WALKS, q.shape[1], k.shape[1], causal=causal,
-                   window=window, bare=not (has_seg or has_bias),
+    _publish_tiles(_WALKS, _Layout.of(q.shape, k.shape), q.shape[1],
+                   k.shape[1], causal=causal, window=window,
+                   bare=not (has_seg or has_bias), has_bias=has_bias,
                    row_bytes=q.shape[3] * q.dtype.itemsize,
                    block_q=block_q, block_k=block_k)
     return _flash_call(q, k, v, seg, b, has_seg, has_bias, bias_grad,
@@ -1207,31 +1401,36 @@ def flash_block_fwd(q, k_blk, v_blk, *, causal, scale, block_q, block_k,
     (:func:`chainermn_tpu.parallel.ring_attention.merge_partials`).
     ``seg_q``/``seg_kv`` are the per-shard segment-id slices (the kv ids
     travel with their block around the ring)."""
-    _publish_tiles((train_path.FLASH_FWD,), q.shape[1], k_blk.shape[1],
+    lay = _Layout.of(q.shape, k_blk.shape)
+    _publish_tiles((train_path.FLASH_FWD,), lay, q.shape[1], k_blk.shape[1],
                    causal=causal, window=window, q_offset=q_offset,
                    block_q=block_q, block_k=block_k)
-    out, lse = _flash_fwd_bhtd(
-        _to_bhtd(q), _to_bhtd(k_blk), _to_bhtd(v_blk), seg_q, seg_kv,
+    out, lse = _flash_fwd(
+        lay, lay.enter(q), lay.enter(k_blk), lay.enter(v_blk), seg_q, seg_kv,
         causal=causal, window=window, q_offset=q_offset,
         scale=scale, block_q=block_q, block_k=block_k, interpret=interpret,
     )
-    return _to_bhtd(out), lse[:, :, 0]
+    return lay.leave(out), lse[:, :, 0]
 
 
-def flash_block_bwd(q, k_blk, v_blk, do, lse, delta, *, causal, scale,
+def flash_block_bwd(q, k_blk, v_blk, do, lse, out, *, causal, scale,
                     block_q, block_k, interpret, seg_q=None, seg_kv=None,
                     window=None, q_offset=0):
     """One ring step's backward: (dq, dk_blk, dv_blk) contributions for one
-    K/V block, f32, BTHD (lse/delta are ``[B, H, Tq]``)."""
-    _publish_tiles((train_path.FLASH_BWD_DQ, train_path.FLASH_BWD_DKV),
+    K/V block, BTHD, in float32 whatever the operands': the ring adds
+    them up over its steps. ``lse`` (``[B, H, Tq]``) and ``out`` (BTHD)
+    are the ring's merged log-sum-exp and output."""
+    lay = _Layout.of(q.shape, k_blk.shape)
+    _publish_tiles((train_path.FLASH_BWD_DQ, train_path.FLASH_BWD_DKV), lay,
                    q.shape[1], k_blk.shape[1], causal=causal, window=window,
                    q_offset=q_offset, bare=seg_q is None,
                    row_bytes=q.shape[3] * q.dtype.itemsize,
                    block_q=block_q, block_k=block_k)
-    dq, dk, dv = _flash_bwd_bhtd(
-        _to_bhtd(q), _to_bhtd(k_blk), _to_bhtd(v_blk), _to_bhtd(do),
-        lse[:, :, None], delta[:, :, None], seg_q, seg_kv,
+    grads = _flash_bwd(
+        lay, lay.enter(q), lay.enter(k_blk), lay.enter(v_blk),
+        lay.enter(out), lay.enter(do), lse[:, :, None], seg_q, seg_kv,
         causal=causal, scale=scale, window=window, q_offset=q_offset,
         block_q=block_q, block_k=block_k, interpret=interpret,
+        grad_dtype=jnp.float32,
     )
-    return _to_bhtd(dq), _to_bhtd(dk), _to_bhtd(dv)
+    return tuple(lay.leave(x) for x in grads)

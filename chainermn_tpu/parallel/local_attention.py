@@ -178,12 +178,8 @@ def _local_window_bwd(axis_name, window, scale, block_q, block_k, interpret,
     k_ext, v_ext, seg_k_ids = _pad_ext_to_block(
         k_ext, v_ext, seg_k_ids, block_k
     )
-    do = g.astype(jnp.float32)
-    delta = jnp.sum(
-        do * out.astype(jnp.float32), axis=-1
-    ).transpose(0, 2, 1)  # [B, H, L]
     dq, dk_ext, dv_ext = flash_block_bwd(
-        q, k_ext, v_ext, g, lse, delta, causal=True, scale=scale,
+        q, k_ext, v_ext, g, lse, out, causal=True, scale=scale,
         window=window, q_offset=prefix, seg_q=seg_q_ids, seg_kv=seg_k_ids,
         block_q=block_q, block_k=block_k, interpret=interpret,
     )

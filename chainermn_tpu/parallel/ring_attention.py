@@ -189,9 +189,6 @@ def _ring_flash_bwd_impl(q, k, v, seg_q, seg_kv, out, lse, g, axis_name,
               interpret=interpret)
     has_seg = seg_q is not None
     do = g
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    ).transpose(0, 2, 1)  # [B, H, Tq]
 
     dq0 = jnp.zeros(q.shape, jnp.float32)
     dk0 = jnp.zeros(k.shape, jnp.float32)
@@ -199,11 +196,11 @@ def _ring_flash_bwd_impl(q, k, v, seg_q, seg_kv, out, lse, g, axis_name,
     perm = _ring_perm(n)
 
     def _full(k_blk, v_blk, sk):
-        return flash_block_bwd(q, k_blk, v_blk, do, lse, delta,
+        return flash_block_bwd(q, k_blk, v_blk, do, lse, out,
                                causal=False, seg_q=seg_q, seg_kv=sk, **kw)
 
     def _diag(k_blk, v_blk, sk):
-        return flash_block_bwd(q, k_blk, v_blk, do, lse, delta,
+        return flash_block_bwd(q, k_blk, v_blk, do, lse, out,
                                causal=True, seg_q=seg_q, seg_kv=sk, **kw)
 
     def _skip(k_blk, v_blk, sk):
@@ -448,12 +445,9 @@ def _zigzag_ring_flash_bwd_impl(q, k, v, seg, out, lse, g, axis_name, scale,
     sq_f = seg[:, :C] if has_seg else None
     sq_b = seg[:, C:] if has_seg else None
     do = g
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    ).transpose(0, 2, 1)  # [B, H, Tq]
     do_f, do_b = do[:, :C], do[:, C:]
     lse_f, lse_b = lse[..., :C], lse[..., C:]
-    dlt_f, dlt_b = delta[..., :C], delta[..., C:]
+    out_f, out_b = out[:, :C], out[:, C:]
 
     # dq pads at the Q head count; dk/dv pads at the KV head count (GQA:
     # flash_block_bwd group-sums dk/dv down to the kv heads).
@@ -469,7 +463,7 @@ def _zigzag_ring_flash_bwd_impl(q, k, v, seg, out, lse, g, axis_name, scale,
     def _past(k_blk, v_blk, sk):
         sk_f, _ = _halves(sk)
         dq_c, dkf, dvf = flash_block_bwd(
-            q, k_blk[:, :C], v_blk[:, :C], do, lse, delta,
+            q, k_blk[:, :C], v_blk[:, :C], do, lse, out,
             causal=False, seg_q=seg, seg_kv=sk_f, **kw,
         )
         return (dq_c,
@@ -479,15 +473,15 @@ def _zigzag_ring_flash_bwd_impl(q, k, v, seg, out, lse, g, axis_name, scale,
     def _diag(k_blk, v_blk, sk):
         sk_f, sk_b = _halves(sk)
         dqf, dkf1, dvf1 = flash_block_bwd(
-            qf, k_blk[:, :C], v_blk[:, :C], do_f, lse_f, dlt_f,
+            qf, k_blk[:, :C], v_blk[:, :C], do_f, lse_f, out_f,
             causal=True, seg_q=sq_f, seg_kv=sk_f, **kw,
         )
         dqb1, dkf2, dvf2 = flash_block_bwd(
-            qb, k_blk[:, :C], v_blk[:, :C], do_b, lse_b, dlt_b,
+            qb, k_blk[:, :C], v_blk[:, :C], do_b, lse_b, out_b,
             causal=False, seg_q=sq_b, seg_kv=sk_f, **kw,
         )
         dqb2, dkb, dvb = flash_block_bwd(
-            qb, k_blk[:, C:], v_blk[:, C:], do_b, lse_b, dlt_b,
+            qb, k_blk[:, C:], v_blk[:, C:], do_b, lse_b, out_b,
             causal=True, seg_q=sq_b, seg_kv=sk_b, **kw,
         )
         dq_c = jnp.concatenate([dqf, dqb1 + dqb2], axis=1)
@@ -497,7 +491,7 @@ def _zigzag_ring_flash_bwd_impl(q, k, v, seg, out, lse, g, axis_name, scale,
 
     def _future(k_blk, v_blk, sk):
         dqb, dk_c, dv_c = flash_block_bwd(
-            qb, k_blk, v_blk, do_b, lse_b, dlt_b, causal=False,
+            qb, k_blk, v_blk, do_b, lse_b, out_b, causal=False,
             seg_q=sq_b, seg_kv=sk, **kw,
         )
         return jnp.concatenate([zQ, dqb], axis=1), dk_c, dv_c
@@ -757,17 +751,14 @@ def _seq_ring_bwd_impl(q, k, v, out, lse, g, axis_name, causal, scale,
     kw = dict(scale=scale, block_q=block_q, block_k=block_k,
               interpret=interpret)
     do = g
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    ).transpose(0, 2, 1)  # [B, H, Tq]
     perm = _ring_perm(n)
 
     def _full(k_blk, v_blk):
-        return flash_block_bwd(q, k_blk, v_blk, do, lse, delta,
+        return flash_block_bwd(q, k_blk, v_blk, do, lse, out,
                                causal=False, **kw)
 
     def _diag(k_blk, v_blk):
-        return flash_block_bwd(q, k_blk, v_blk, do, lse, delta,
+        return flash_block_bwd(q, k_blk, v_blk, do, lse, out,
                                causal=True, **kw)
 
     def _skip(k_blk, v_blk):

@@ -245,9 +245,10 @@ def _gradient_jaxpr(which):
 def test_flash_kernels_pass_their_row_statistics_lane_dense(which):
     """No operand or result of a flash kernel has a unit minor dimension
     (such an array sits in 128-lane tiles, 128 times its bytes), and what
-    the backward kernels take as log-sum-exp and ``delta`` is padded and
-    broadcast by nobody: a residual as it came, or a row sum, through at
-    most a reshape that puts a unit dimension before ``T``."""
+    the backward kernels take as log-sum-exp is padded and broadcast by
+    nobody: the residual as it came. ``delta`` is no array at all: the
+    backward kernels take the forward's output, the other residual, and
+    sum its rows against ``dO`` themselves."""
     seen = {name: 0 for name in _FLASH_KERNELS}
     for jaxpr in _all_jaxprs(_gradient_jaxpr(which)):
         for eqn in jaxpr.eqns:
@@ -260,13 +261,16 @@ def test_flash_kernels_pass_their_row_statistics_lane_dense(which):
                                                  var.aval)
             if eqn.params["name"] == train_path.FLASH_FWD:
                 continue
-            B, H, T, _ = eqn.invars[0].aval.shape  # q in BHTD
-            for row in eqn.invars[4:6]:  # q, k, v, do, lse, delta
-                assert row.aval.shape == (B, H, 1, T)
-                src, made = _source(jaxpr, row)
-                assert src.aval.size == B * H * T
-                assert made is None or made.primitive.name in (
-                    "reduce_sum", "pallas_call"), made
+            q, _, _, out, do, lse = eqn.invars[:6]
+            assert out.aval == do.aval == q.aval
+            # [B * H, T, D] here: heads of 16 take the transposed form
+            N, T, _ = q.aval.shape
+            assert lse.aval.shape[2:] == (1, T)
+            assert lse.aval.size == N * T
+            for residual in (out, lse):
+                _, made = _source(jaxpr, residual)
+                assert made is None or made.primitive.name == "pallas_call", \
+                    made
     assert all(seen.values()), seen
 
 
@@ -283,3 +287,37 @@ def test_flash_lse_residual_is_the_forward_kernels_own_result():
                 assert made.primitive.name == "pallas_call"
                 assert made.params["name"] == train_path.FLASH_FWD
     assert named >= LAYERS
+
+
+# -- the layout the kernels read and write -----------------------------------
+
+@pytest.mark.parametrize("heads, width, moved", [
+    (4, 64, False),   # two heads a 128-lane block of [B, T, H*D]
+    (2, 128, False),  # one head a block of columns
+    (2, 96, True),    # no lane-tile fit: [B*H, T, D], transposed outside
+])
+def test_no_op_moves_or_casts_an_operand_round_the_kernels(heads, width,
+                                                           moved):
+    """In the projections' layout the op and its gradient hold, outside
+    the kernels, no ``transpose`` and no ``convert_element_type`` of an
+    array as large as an operand: ``[B, T, H, D]`` reaches the kernels
+    through reshapes that move nothing, and dq, dk, dv leave them in the
+    operands' dtype. A head width that fits no lane tile is transposed
+    by the wrapper as before (and this test sees it)."""
+    B, T = 2, 256
+    x = jax.ShapeDtypeStruct((B, T, heads, width), jnp.bfloat16)
+
+    def op_and_gradient(q, k, v, g):
+        out, vjp = jax.vjp(
+            lambda *a: flash_attention(*a, causal=True, interpret=True),
+            q, k, v)
+        return out, vjp(g)
+
+    found = set()
+    for jaxpr in _all_jaxprs(jax.make_jaxpr(op_and_gradient)(x, x, x,
+                                                              x).jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in ("transpose", "convert_element_type") \
+                    and eqn.invars[0].aval.size == B * T * heads * width:
+                found.add(eqn.primitive.name)
+    assert found == ({"transpose"} if moved else set())

@@ -493,17 +493,21 @@ def test_flash_tiles_follow_the_last_call_not_the_last_trace():
     # so dk/dv keeps the 512 x 1024 tile; K is walked in sub-tiles still
     ("segments", (16, 10, 4), (8, 6, 4)),
     ("bias", (16, 10, 4), (8, 6, 4)),
+    # two heads of 64 a step hold two bias squares: half the keys a tile
+    ("bias_two_heads", (16, 10, 4), (16, 10, 4)),
     # a window: every kernel masks its crossed 512 x 1024 tiles whole
     ("window", (8, 5, 5), (8, 5, 5)),
 ])
 def test_flash_dkv_tile_is_whole_only_under_a_bare_causal_mask(
         name, walks_k, walks_q):
     T = 2048
+    bias = dict(bias=jnp.zeros((1, 1, T, T), jnp.bfloat16))
     kw = {"segments": dict(segment_ids=jnp.zeros((1, T), jnp.int32)),
-          "bias": dict(bias=jnp.zeros((1, 1, T, T), jnp.bfloat16)),
+          "bias": bias, "bias_two_heads": bias,
           "window": dict(window=300)}[name]
+    D = 128 if name == "bias" else 64
     got = {k: (v["total"], v["visited"], v["masked"])
-           for k, v in _traced_tiles(T, 64, H=2, **kw).items()}
+           for k, v in _traced_tiles(T, D, H=2, **kw).items()}
     assert got == {"flash_fwd": walks_k, "flash_bwd_dq": walks_k,
                    "flash_bwd_dkv": walks_q}
 
@@ -637,8 +641,7 @@ def test_flash_derived_geometry_matches_reference_and_former_tiles(name):
     def flash(block_q, block_k):
         blocks = dict(block_q=block_q, block_k=block_k, interpret=True)
         out, lse = flash_block_fwd(q, k, v, **kw, **blocks)
-        delta = jnp.einsum("bqhd,bqhd->bhq", do, out)
-        return (out, lse) + flash_block_bwd(q, k, v, do, lse, delta, **kw,
+        return (out, lse) + flash_block_bwd(q, k, v, do, lse, out, **kw,
                                             **blocks)
 
     (ref_out, ref_lse), vjp = jax.vjp(
@@ -714,13 +717,15 @@ def _row_case(name):
     "whole_T_200", "T_576"])
 def test_flash_row_statistics_match_reference(name):
     """The kernels hand the log-sum-exp over as ``[B, H, 1, Tq]`` and
-    take it and ``delta`` back in that form: out, log-sum-exp, dq, dk,
-    dv (and the bias gradient) against XLA's attention, at the geometry
-    the kernels derive."""
+    take it back in that form (``delta`` they make themselves, from the
+    forward's output): out, log-sum-exp, dq, dk, dv (and the bias
+    gradient) against XLA's attention, at the geometry the kernels
+    derive."""
     from chainermn_tpu.ops.flash_attention import (
-        _flash_bwd_bhtd,
-        _flash_fwd_bhtd,
+        _flash_bwd,
+        _flash_fwd,
         _geometry,
+        _Layout,
     )
 
     (q, k, v, do), seg, bias, kw = _row_case(name)
@@ -729,15 +734,16 @@ def test_flash_row_statistics_match_reference(name):
     for walks in ("k", "q"):
         block_q = _geometry(T, T, walks=walks, **kw)[0]
         assert block_q % 128 == 0 or block_q == T, (walks, block_q)
-    bhtd = lambda x: x.transpose(0, 2, 1, 3)
+    lay = _Layout.of(q.shape, k.shape)
     common = dict(scale=0.125, block_q=None, block_k=None, interpret=True,
                   **kw)
-    out, lse = _flash_fwd_bhtd(bhtd(q), bhtd(k), bhtd(v), seg, seg, bias,
-                               **common)
+    out, lse = _flash_fwd(lay, lay.enter(q), lay.enter(k), lay.enter(v),
+                          seg, seg, bias, **common)
     assert lse.shape == (1, 4, 1, T) and lse.dtype == jnp.float32
-    delta = jnp.einsum("bqhd,bhqd->bhq", do, out)[:, :, None]
-    got = _flash_bwd_bhtd(bhtd(q), bhtd(k), bhtd(v), bhtd(do), lse, delta,
-                          seg, seg, bias, bias is not None, **common)
+    got = _flash_bwd(lay, lay.enter(q), lay.enter(k), lay.enter(v), out,
+                     lay.enter(do), lse, seg, seg, bias, bias is not None,
+                     **common)
+    got = tuple(lay.leave(x) for x in got[:3]) + tuple(got[3:])
 
     args = (q, k, v) if bias is None else (q, k, v, bias)
     (ref_out, ref_lse), vjp = jax.vjp(
@@ -745,10 +751,9 @@ def test_flash_row_statistics_match_reference(name):
             q_, k_, v_, scale=0.125, seg_q=seg, seg_kv=seg, bias=b_, **kw),
         *args)
     ref = vjp((do, jnp.zeros_like(ref_lse)))
-    want = (ref_out, ref_lse[:, :, None]) + tuple(
-        bhtd(x) for x in ref[:3]) + tuple(ref[3:])
+    want = (ref_out, ref_lse[:, :, None]) + tuple(ref)
     names = ("out", "lse", "dq", "dk", "dv", "dbias")
-    for what, a, b in zip(names, (bhtd(out), lse) + tuple(got), want):
+    for what, a, b in zip(names, (lay.leave(out), lse) + got, want):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
                                    err_msg=f"{what} against XLA")
 
@@ -774,11 +779,10 @@ def test_flash_fully_masked_rows_keep_neg_inf_and_zero_gradients():
               block_q=128, block_k=128, interpret=True)
     out, lse = flash_block_fwd(q, k, v, **kw)
     assert lse.shape == (1, 2, T)  # the ring's interface: [B, H, Tq]
-    delta = jnp.einsum("bqhd,bqhd->bhq", do, out)
     # the backward takes the ring's merged log-sum-exp, which is finite
     # in such a row (it saw another block's keys): exp(NEG_INF - lse) = 0
     merged = jnp.where(lse > NEG_INF, lse, 0.0)
-    dq, dk, dv = flash_block_bwd(q, k, v, do, merged, delta, **kw)
+    dq, dk, dv = flash_block_bwd(q, k, v, do, merged, out, **kw)
 
     np.testing.assert_array_equal(lse[:, :, dead], np.float32(NEG_INF))
     np.testing.assert_array_equal(out[:, dead], 0.0)
@@ -794,3 +798,159 @@ def test_flash_fully_masked_rows_keep_neg_inf_and_zero_gradients():
                        ("dq", dq[:, live], ref_dq), ("dk", dk, ref_dk),
                        ("dv", dv, ref_dv)):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=what)
+
+
+# ---------------------------------------------------------------------------
+# The layout the kernels see: the projections' own rows, or [B*H, T, D]
+# ---------------------------------------------------------------------------
+
+#: name -> (q heads, kv heads, head width, heads a grid step takes from
+#: ``[B, T, H*D]``; 0: the wrapper transposes)
+_FORMS = {
+    "heads_of_128": (2, 2, 128, 1),
+    "heads_of_64_in_pairs": (4, 4, 64, 2),
+    "width_96_transposed": (2, 2, 96, 0),
+    "gqa_at_128": (4, 2, 128, 1),
+    "gqa_at_64_transposed": (4, 2, 64, 0),
+}
+_MASKS = ("causal", "segment_ids", "bias_grad", "window")
+_T_FORM = 256
+
+
+def _form_case(form, mask, dtype=jnp.float32):
+    """``(q, k, v, w), bias, flash keywords, reference keywords`` of one
+    form under one mask, at B 2 and T 256."""
+    H, Hkv, D, _ = _FORMS[form]
+    ks = jax.random.split(jax.random.PRNGKey(37), 5)
+    q = jax.random.normal(ks[0], (2, _T_FORM, H, D), dtype)
+    k = jax.random.normal(ks[1], (2, _T_FORM, Hkv, D), dtype)
+    v = jax.random.normal(ks[2], (2, _T_FORM, Hkv, D), dtype)
+    w = jax.random.normal(ks[3], (2, _T_FORM, H, D), dtype)
+    bias, kw, ref_kw = None, {}, {}
+    if mask == "segment_ids":
+        seg = jnp.asarray(np.repeat([[0, 1, 2], [0, 3, 4]],
+                                    [100, 56, 100], axis=1).astype(np.int32))
+        kw, ref_kw = dict(segment_ids=seg), dict(seg_q=seg, seg_kv=seg)
+    elif mask == "bias_grad":
+        bias = 0.1 * jax.random.normal(ks[4], (1, H, _T_FORM, _T_FORM))
+        kw = dict(bias_grad=True)
+    elif mask == "window":
+        kw = ref_kw = dict(window=72)
+    return (q, k, v, w), bias, kw, ref_kw
+
+
+def _out_and_grads(attn, q, k, v, w, bias):
+    """``attn``'s output and the gradients of ``sum(out * w)`` by q, k,
+    v (and the bias)."""
+    args = (q, k, v) if bias is None else (q, k, v, bias)
+    out, vjp = jax.vjp(attn, *args)
+    return (out,) + vjp(w.astype(out.dtype))
+
+
+@pytest.mark.parametrize("blocks", [None, 128], ids=["derived", "128x128"])
+@pytest.mark.parametrize("mask", _MASKS)
+@pytest.mark.parametrize("form", _FORMS)
+def test_flash_layout_forms_match_reference(form, mask, blocks):
+    """Every form of the kernels' layout under every mask: output and
+    all gradients against XLA's attention (the bare causal case against
+    ``blockwise_attention`` too), at the geometry the kernels derive
+    (one step a row) and on tiles of 128 x 128, where the rows' running
+    statistics and the accumulators are kept across steps; the gradients
+    come back in their operands' dtype; the gauge says which form
+    engaged."""
+    from chainermn_tpu.observability import train_path
+    from chainermn_tpu.observability.metrics import registry
+
+    (q, k, v, w), bias, kw, ref_kw = _form_case(form, mask)
+    scale = q.shape[-1] ** -0.5
+
+    def flash(q_, k_, v_, b_=None):
+        return flash_attention(q_, k_, v_, causal=True, bias=b_,
+                               block_q=blocks, block_k=blocks,
+                               interpret=True, **kw)
+
+    def dense(q_, k_, v_, b_=None):
+        return _dense_block(q_, k_, v_, causal=True, scale=scale, bias=b_,
+                            **ref_kw)[0]
+
+    got = _out_and_grads(flash, q, k, v, w, bias)
+    want = _out_and_grads(dense, q, k, v, w, bias)
+    for what, a, b in zip(("out", "dq", "dk", "dv", "dbias"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, what
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
+                                   err_msg=f"{what} against XLA")
+    if mask == "causal" and _FORMS[form][0] == _FORMS[form][1]:
+        blockwise = _out_and_grads(
+            lambda q_, k_, v_: blockwise_attention(
+                q_, k_, v_, block_k=64, causal=True), q, k, v, w, None)
+        for what, a, b in zip(("out", "dq", "dk", "dv"), got, blockwise):
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
+                                       err_msg=f"{what} against blockwise")
+    gauge = registry().gauge(train_path.FLASH_HEADS_PER_BLOCK)
+    assert [gauge.value(kernel=kernel) for kernel in _FLASH_KERNELS] \
+        == [_FORMS[form][3]] * 3
+
+
+@pytest.mark.parametrize("blocks", [None, 128], ids=["derived", "128x128"])
+@pytest.mark.parametrize("mask", _MASKS)
+def test_flash_pairs_equal_the_transposed_form_to_the_bit(
+        monkeypatch, mask, blocks):
+    """Two heads of 64 in one 128-lane block give the bits one head a
+    ``[B*H, T, D]`` row gives on the same bf16 operands: a head is read
+    and written through its own lanes of the block, so its matmuls and
+    its rounding are those of a head alone."""
+    import importlib
+
+    flash_mod = importlib.import_module("chainermn_tpu.ops.flash_attention")
+    (q, k, v, w), bias, kw, _ = _form_case("heads_of_64_in_pairs", mask,
+                                           jnp.bfloat16)
+
+    def run():
+        # under no jit of the op's own: each call is traced as patched
+        seg = kw.get("segment_ids", jnp.zeros((0,), jnp.int32))
+        b = jnp.zeros((0,), q.dtype) if bias is None else bias
+        return _out_and_grads(
+            lambda q_, k_, v_, b_=b: flash_mod._flash_core(
+                q_, k_, v_, seg, b_, "segment_ids" in kw, bias is not None,
+                bias is not None, True, 0.125, blocks, blocks, True,
+                kw.get("window")),
+            q, k, v, w, bias)
+
+    lay = flash_mod._Layout.of(q.shape, k.shape)
+    assert lay.heads == 2
+    pairs = run()
+    monkeypatch.setattr(flash_mod._Layout, "of",
+                        classmethod(lambda cls, *_: lay._replace(heads=0)))
+    transposed = run()
+    for what, a, b in zip(("out", "dq", "dk", "dv", "dbias"), pairs,
+                          transposed):
+        assert a.dtype == b.dtype, what
+        np.testing.assert_array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), what)
+
+
+@pytest.mark.parametrize("form", _FORMS)
+def test_flash_gradient_dtypes(form):
+    """The op's gradients leave the kernels in their operands' dtype
+    (with GQA dk/dv after the group sum); a ring step's partial
+    gradients are float32 whatever the operands'."""
+    from chainermn_tpu.ops.flash_attention import (
+        flash_block_bwd,
+        flash_block_fwd,
+    )
+
+    (q, k, v, w), _, _, _ = _form_case(form, "causal", jnp.bfloat16)
+    grads = _out_and_grads(
+        lambda *a: flash_attention(*a, causal=True, interpret=True),
+        q, k, v, w, None)[1:]
+    assert [g.dtype for g in grads] == [jnp.bfloat16] * 3
+    kw = dict(causal=True, scale=q.shape[-1] ** -0.5, block_q=None,
+              block_k=None, interpret=True)
+    out, lse = flash_block_fwd(q, k, v, **kw)
+    partial = flash_block_bwd(q, k, v, w, lse, out, **kw)
+    assert [g.dtype for g in partial] == [jnp.float32] * 3
+    assert [g.shape for g in partial] == [q.shape, k.shape, v.shape]
+    for what, a, b in zip(("dq", "dk", "dv"), grads, partial):
+        np.testing.assert_array_equal(
+            np.asarray(a, np.float32),
+            np.asarray(b.astype(jnp.bfloat16), np.float32), what)

@@ -29,9 +29,16 @@ Checked kernels:
 - flash attention forward + backward at the OLMoE cell's shape (B 4,
   T 4096, 16 heads of 128)
 - flash attention forward + backward at T 200 and T 576: the kernels
-  pass log-sum-exp and ``delta`` as ``[B, H, 1, T]`` rows, whose block
-  Mosaic takes as a multiple of 128 lanes or as the whole row, and these
+  pass the log-sum-exp as ``[B, H, 1, T]`` rows, whose block Mosaic
+  takes as a multiple of 128 lanes or as the whole row, and these
   lengths have no such divisor (``_pick_row_block``)
+- flash attention forward + backward in the projections' own layout
+  (``[B, T, H * D]``, no transposition round the kernels): two heads of
+  64 a 128-lane block at the GPT-2 cells' shapes (B 16 and B 4, T 1024,
+  16 heads) and one head of 128 a block of columns at OLMoE's and Ouro's
+  (B 4 and B 1, T 4096, 16 heads), each also with packed ``segment_ids``
+  and with a window of 64; the GQA and odd-width cases above take the
+  transposed form, ``[B * H, T, D]``, through the same kernels
 - the gradient of two remat'ed blocks (``remat_policy='dots'``) at the
   memory-full GPT-2 cell's shape (B 16, T 1024, 16 heads of 64): the
   policy keeps what the flash forward made, so the compiled gradient
@@ -144,6 +151,10 @@ def _cases():
             lambda q_, k_, v_, b_: attn(q_, k_, v_, b_)
             .astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))
 
+    def segments_grads(attn):
+        return lambda q_, k_, v_, s_: grads(
+            lambda *a: attn(*a, s_))(q_, k_, v_)
+
     def band_bias(Tq, Tk, window, q_offset=0):
         i = q_offset + jnp.arange(Tq)[:, None]
         j = jnp.arange(Tk)[None, :]
@@ -171,6 +182,16 @@ def _cases():
     # 512 would end at 64
     q_200 = jax.ShapeDtypeStruct((4, 200, 8, 64), dt)
     q_576 = jax.ShapeDtypeStruct((4, 576, 8, 64), dt)
+    # the projections' layout at the other cells' batches: the memory-full
+    # GPT-2 cell's (two heads of 64 a block) and Ouro's (one head of 128)
+    q_cell16 = jax.ShapeDtypeStruct((16, 1024, 16, 64), dt)
+    q_ouro = jax.ShapeDtypeStruct((1, 4096, 16, 128), dt)
+    seg_cell = jax.ShapeDtypeStruct((4, 1024), jnp.int32)
+    seg_olmoe = jax.ShapeDtypeStruct((4, 4096), jnp.int32)
+
+    def xla_window64(T):
+        return per_example(lambda q_, k_, v_: dot_product_attention(
+            q_, k_, v_, causal=True, bias=band_bias(T, T, 64)))
 
     # The grouped matmul: group sizes are made from a float vector inside
     # the case, uneven and with empty groups; the reference multiplies
@@ -290,6 +311,9 @@ def _cases():
         ("flash_lm_fwdbwd", grads(flash), (q_lm,) * 3, grads(xla)),
         ("flash_cell_fwdbwd", grads(flash), (q_cell,) * 3, grads(xla)),
         ("flash_olmoe_fwdbwd", grads(flash), (q_olmoe,) * 3, grads(xla)),
+        ("flash_pairs_b16_fwdbwd", grads(flash), (q_cell16,) * 3,
+         grads(xla)),
+        ("flash_columns_b1_fwdbwd", grads(flash), (q_ouro,) * 3, grads(xla)),
         ("flash_whole_row_t200_fwdbwd", grads(flash), (q_200,) * 3,
          grads(xla)),
         ("flash_whole_row_t576_fwdbwd", grads(flash), (q_576,) * 3,
@@ -309,13 +333,27 @@ def _cases():
          (q, kv, kv, bias),
          grads_with_bias(lambda q_, k_, v_, b_: dot_product_attention(
              q_, k_, v_, causal=True, bias=b_))),
+        # two heads a step hold two bias squares and two of its gradient:
+        # the tile takes half the keys (``_geometry``'s ``bias_heads``)
+        ("flash_pairs_bias_grad",
+         grads_with_bias(lambda q_, k_, v_, b_: flash(
+             q_, k_, v_, bias=b_, bias_grad=True)),
+         (q, q, q, bias),
+         grads_with_bias(lambda q_, k_, v_, b_: dot_product_attention(
+             q_, k_, v_, causal=True, bias=b_))),
         ("segments_fwd", segments, (qs, qs, qs, seg), xla_segments),
-        ("segments_bwd",
-         lambda q_, k_, v_, s_: grads(
-             lambda *a: segments(*a, s_))(q_, k_, v_),
-         (qs, qs, qs, seg),
-         lambda q_, k_, v_, s_: grads(
-             lambda *a: xla_segments(*a, s_))(q_, k_, v_)),
+        ("segments_bwd", segments_grads(segments), (qs, qs, qs, seg),
+         segments_grads(xla_segments)),
+        ("flash_pairs_segments_fwdbwd", segments_grads(segments),
+         (q_cell,) * 3 + (seg_cell,), segments_grads(xla_segments)),
+        ("flash_columns_segments_fwdbwd", segments_grads(segments),
+         (q_olmoe,) * 3 + (seg_olmoe,), segments_grads(xla_segments)),
+        ("flash_pairs_window64_fwdbwd",
+         grads(functools.partial(flash, window=64)), (q_cell,) * 3,
+         grads(xla_window64(1024))),
+        ("flash_columns_window64_fwdbwd",
+         grads(functools.partial(flash, window=64)), (q_olmoe,) * 3,
+         grads(xla_window64(4096))),
         ("sp_window_ext_fwd", sp_window_ext_fwd, (qs, seg),
          per_example(sp_window_ext_ref)),
         paged("paged_decode_t1", 1),
