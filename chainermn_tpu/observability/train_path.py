@@ -90,12 +90,29 @@ TRAINER_H2D = "chainermn.trainer.h2d"
 TRAINER_LOG = "chainermn.trainer.log"
 
 # -- counters -----------------------------------------------------------
+#: the compile counters, each a series by ``program``: the name JAX gives
+#: the jitted function, the same through tracing, lowering and the
+#: backend. A function traced inside another's trace counts under the
+#: outermost; past ``utils.compile_cache.MAX_PROGRAMS`` names a process
+#: files what comes later, and what JAX gave no name, under
+#: :data:`OTHER_PROGRAM`
 JAX_TRACE_SECONDS = "jax_trace_seconds_total"
 JAX_LOWER_SECONDS = "jax_lower_seconds_total"
 JAX_BACKEND_COMPILE_SECONDS = "jax_backend_compile_seconds_total"
 PROGRAMS_COMPILED = "programs_compiled_total"
 COMPILE_CACHE_HITS = "compile_cache_hits_total"
 COMPILE_CACHE_MISSES = "compile_cache_misses_total"
+COMPILE_CACHE_RETRIEVAL_SECONDS = "compile_cache_retrieval_seconds_total"
+OTHER_PROGRAM = "other"
+#: the ``program`` of ``training.make_train_step``'s step: the name of
+#: the function it shards and jits (``shard_map`` keeps it), which is
+#: also in the compiled module's name and so in the cache's key
+TRAIN_STEP_PROGRAM = "local_step"
+#: what the host made the process wait and what it gave it, read from
+#: the kernel when the registry is: seconds its threads stood runnable
+#: with no core to run on, and its CPU seconds (user and system)
+PROCESS_RUNQUEUE_WAIT_SECONDS = "process_runqueue_wait_seconds_total"
+PROCESS_CPU_SECONDS = "process_cpu_seconds_total"
 GRAD_WIRE_BYTES = "grad_wire_bytes_per_step"
 GRAD_REDUCE_BUCKETS = "grad_reduce_buckets"
 FEED_BATCHES = "feed_batches_total"

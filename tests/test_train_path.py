@@ -321,6 +321,403 @@ def test_a_second_process_on_a_warm_cache_counts_hits(tmp_path):
         cold[train_path.PROGRAMS_COMPILED]
 
 
+
+# -- compile counters by program -----------------------------------------
+
+_STAGES = (train_path.JAX_TRACE_SECONDS, train_path.JAX_LOWER_SECONDS,
+           train_path.JAX_BACKEND_COMPILE_SECONDS,
+           train_path.PROGRAMS_COMPILED)
+_CACHE = (train_path.COMPILE_CACHE_HITS, train_path.COMPILE_CACHE_MISSES,
+          train_path.COMPILE_CACHE_RETRIEVAL_SECONDS)
+
+
+def _by_program(name):
+    fam = metrics.registry().snapshot().get(name)
+    return {r["labels"]["program"]: r["value"]
+            for r in fam["values"]} if fam else {}
+
+
+@pytest.fixture
+def counts():
+    """Listeners of their own on an empty registry: a test replays events
+    into them as JAX would call them, and the process's own label set
+    stays as it was."""
+    from chainermn_tpu.utils import compile_cache
+
+    metrics.reset()
+    fresh = compile_cache._CompileCounts()
+    metrics.registry().register_collect(fresh.collect)
+    yield fresh
+    metrics.reset()
+
+
+def _replay(counts, events):
+    """``events`` as JAX's three kinds of listener receive them."""
+    for kind, event, *args, kw in events:
+        {"span": counts.on_span, "duration": counts.on_duration,
+         "event": counts.on_event}[kind](event, *args, **kw)
+
+
+def _a_compile(name, t, *, trace=1.0, lower=0.25, backend=0.5, nested=(),
+               cache=()):
+    """What JAX reports, in its order, when a jitted function called
+    ``name`` is traced (holding the traces of ``nested`` functions),
+    lowered and compiled at time ``t``."""
+    from chainermn_tpu.utils import compile_cache as cc
+
+    inner = trace / (2 * len(nested) + 1)
+    events = [("span", cc._TRACE_EVENT, t + (2 * i + 1) * inner,
+               t + (2 * i + 2) * inner, {"fun_name": n})
+              for i, n in enumerate(nested)]
+    events.append(("span", cc._TRACE_EVENT, t, t + trace,
+                   {"fun_name": name}))
+    events.append(("duration", cc._LOWER_EVENT, lower,
+                   {"fun_name": f"jit({name})"}))
+    for kind in cache:
+        if kind == "hit":
+            events.append(("event", cc._HIT_EVENT, {}))
+            events.append(("duration", cc._RETRIEVAL_EVENT, backend / 2, {}))
+        else:
+            events.append(("event", cc._MISS_EVENT, {}))
+    events.append(("duration", cc._BACKEND_EVENT, backend,
+                   {"fun_name": f"jit({name})"}))
+    return events
+
+
+def _parents_totals(events):
+    """The unlabelled counters as the listeners before the ``program``
+    label counted them: a traced span takes the place of those it holds,
+    every other event adds to its one number."""
+    from chainermn_tpu.utils import compile_cache as cc
+
+    totals = dict.fromkeys(_STAGES + _CACHE[:2], 0.0)
+    counted = []
+    for kind, event, *args, _ in events:
+        if kind == "span" and event == cc._TRACE_EVENT:
+            start, end = args
+            held = 0.0
+            while counted and counted[-1][0] >= start:
+                s, e = counted.pop()
+                held += e - s
+            counted.append((start, end))
+            totals[train_path.JAX_TRACE_SECONDS] += end - start - held
+        elif kind == "duration" and event == cc._LOWER_EVENT:
+            totals[train_path.JAX_LOWER_SECONDS] += args[0]
+        elif kind == "duration" and event == cc._BACKEND_EVENT:
+            totals[train_path.JAX_BACKEND_COMPILE_SECONDS] += args[0]
+            totals[train_path.PROGRAMS_COMPILED] += 1
+        elif kind == "event" and event == cc._HIT_EVENT:
+            totals[train_path.COMPILE_CACHE_HITS] += 1
+        elif kind == "event" and event == cc._MISS_EVENT:
+            totals[train_path.COMPILE_CACHE_MISSES] += 1
+    return totals
+
+
+def test_program_labels_sum_to_the_unlabelled_totals(counts):
+    """On one recorded sequence (a real compile with a jitted function
+    inside another, then made-up programs that hit, wrote and did
+    neither), each counter summed over ``program`` is the number the
+    unlabelled counter held."""
+    from jax import monitoring
+
+    from chainermn_tpu.utils import compile_cache as cc
+
+    heard = (cc._TRACE_EVENT, cc._LOWER_EVENT, cc._BACKEND_EVENT,
+             cc._HIT_EVENT, cc._MISS_EVENT, cc._RETRIEVAL_EVENT)
+    recorded = []
+
+    def record(kind):
+        def listener(event, *args, **kw):
+            if event in heard:
+                recorded.append((kind, event, *args, kw))
+        return listener
+
+    span, duration, plain = record("span"), record("duration"), \
+        record("event")
+    monitoring.register_event_time_span_listener(span)
+    monitoring.register_event_duration_secs_listener(duration)
+    monitoring.register_event_listener(plain)
+    inner = jax.jit(lambda x: jnp.tanh(x) * 1.75)
+    try:
+        jax.jit(lambda x: inner(x) + jnp.cos(x) * 0.375)(jnp.ones((5, 3)))
+    finally:
+        monitoring.unregister_event_time_span_listener(span)
+        monitoring.unregister_event_duration_listener(duration)
+        monitoring.unregister_event_listener(plain)
+    assert {e for _, e, *_ in recorded} >= set(heard[:3])
+    # the process's own listeners heard that compile too
+    metrics.reset()
+    metrics.registry().register_collect(counts.collect)
+    t = time.time() + 1000.0
+    events = recorded \
+        + _a_compile("loaded", t, nested=("a", "b"), cache=("hit",)) \
+        + _a_compile("written", t + 2, cache=("miss",)) \
+        + _a_compile("neither", t + 4, nested=("a",))
+    _replay(counts, events)
+    for name, total in _parents_totals(events).items():
+        assert sum(_by_program(name).values()) == pytest.approx(total), name
+
+
+def test_a_nested_trace_files_under_the_outermost_program_once(counts):
+    _replay(counts, _a_compile("outermost", 1000.0, trace=3.0,
+                               nested=("held", "held_too")))
+    assert _by_program(train_path.JAX_TRACE_SECONDS) == \
+        {"outermost": pytest.approx(3.0)}
+    # and as JAX itself reports a jitted function called inside another
+    from chainermn_tpu.utils import compile_cache
+
+    compile_cache._count_compiles()
+
+    @jax.jit
+    def held_inside_a_probe(x):
+        return jnp.tanh(x) * 2.625
+
+    def outermost_probe(x):
+        return held_inside_a_probe(x) + 0.875
+
+    jax.jit(outermost_probe)(jnp.ones((3, 7)))
+    traced = _by_program(train_path.JAX_TRACE_SECONDS)
+    assert traced["outermost_probe"] > 0
+    assert "held_inside_a_probe" not in traced and "tanh" not in traced
+
+
+@pytest.mark.parametrize("wrapper", ["jit", "pmap"])
+def test_the_three_stages_of_a_program_share_a_label(counts, wrapper):
+    from chainermn_tpu.utils import compile_cache as cc
+
+    _replay(counts, [
+        ("span", cc._TRACE_EVENT, 10.0, 11.0, {"fun_name": "probe"}),
+        ("duration", cc._LOWER_EVENT, 0.5,
+         {"fun_name": f"{wrapper}(probe)"}),
+        ("duration", cc._BACKEND_EVENT, 2.0,
+         {"fun_name": f"{wrapper}(probe)"}),
+    ])
+    assert [_by_program(name) for name in _STAGES] == \
+        [{"probe": 1.0}, {"probe": 0.5}, {"probe": 2.0}, {"probe": 1.0}]
+
+
+def test_jax_names_the_three_stages_of_a_jitted_function_alike():
+    from chainermn_tpu.utils import compile_cache
+
+    compile_cache._count_compiles()
+
+    def three_stage_probe(x):
+        return jnp.sinh(x) * 0.5625
+
+    jax.jit(three_stage_probe)(jnp.ones((2, 9)))
+    for name in _STAGES:
+        assert _by_program(name).get("three_stage_probe", 0) > 0, name
+
+
+def test_the_65th_program_lands_in_other(counts):
+    from chainermn_tpu.utils import compile_cache as cc
+
+    n = cc.MAX_PROGRAMS + 6
+    for i in range(n):
+        _replay(counts, _a_compile(f"program_{i}", 1000.0 + 2 * i))
+    # a name the process has met keeps its label past the cap
+    _replay(counts, _a_compile("program_0", 5000.0))
+    for name in _STAGES:
+        series = _by_program(name)
+        assert len(series) == cc.MAX_PROGRAMS + 1, name
+        assert f"program_{cc.MAX_PROGRAMS}" not in series
+    compiled = _by_program(train_path.PROGRAMS_COMPILED)
+    assert compiled[train_path.OTHER_PROGRAM] == 6
+    assert compiled["program_0"] == 2 and sum(compiled.values()) == n + 1
+
+
+def test_a_retrieval_is_filed_under_the_backend_event_of_its_own_thread(
+        counts):
+    """Two threads load a program each, their events interleaved: each
+    retrieval goes to the backend event that closes round it on the
+    thread it arrived on, whichever closes first."""
+    import threading
+
+    from chainermn_tpu.utils import compile_cache as cc
+
+    turn = threading.Barrier(2, timeout=30)
+
+    def load(name, seconds, closes_first):
+        counts.on_event(cc._HIT_EVENT)
+        counts.on_duration(cc._RETRIEVAL_EVENT, seconds)
+        turn.wait()  # both retrievals are in, no backend event yet
+        if not closes_first:
+            turn.wait()
+        counts.on_duration(cc._BACKEND_EVENT, seconds + 0.125,
+                           fun_name=f"jit({name})")
+        if closes_first:
+            turn.wait()
+
+    threads = [threading.Thread(target=load, args=args) for args in
+               (("slow_load", 0.5, False), ("quick_load", 0.25, True))]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=60)
+    assert not any(th.is_alive() for th in threads)
+    assert _by_program(train_path.COMPILE_CACHE_RETRIEVAL_SECONDS) == \
+        {"slow_load": 0.5, "quick_load": 0.25}
+    assert _by_program(train_path.COMPILE_CACHE_HITS) == \
+        {"slow_load": 1.0, "quick_load": 1.0}
+    assert counts._held == {}  # nothing of a closed compile is kept
+
+
+@pytest.mark.parametrize("cache,loaded,written,neither", [
+    (("hit",), 1, 0, 0), (("miss",), 0, 1, 0), ((), 0, 0, 1)])
+def test_loaded_written_and_neither_are_told_apart(counts, cache, loaded,
+                                                   written, neither):
+    """Per program: loaded from the cache, compiled and written to it, or
+    compiled and never written (under JAX's thresholds)."""
+    _replay(counts, _a_compile("bystander", 900.0, cache=("miss",))
+            + _a_compile("probe", 1000.0, cache=cache))
+    hits = _by_program(train_path.COMPILE_CACHE_HITS).get("probe", 0)
+    misses = _by_program(train_path.COMPILE_CACHE_MISSES).get("probe", 0)
+    through = _by_program(train_path.PROGRAMS_COMPILED)["probe"]
+    assert (hits, misses, through - hits - misses) == \
+        (loaded, written, neither)
+    retrieved = _by_program(train_path.COMPILE_CACHE_RETRIEVAL_SECONDS)
+    assert retrieved == ({"probe": 0.25} if loaded else {})
+
+
+def test_closed_roots_are_folded_and_a_read_in_between_counts_once(counts):
+    """The traced spans a thread holds back go when it lowers; a read of
+    the registry in the middle of an outer trace counts what has closed,
+    and the outer span then adds only the rest."""
+    from chainermn_tpu.utils import compile_cache as cc
+
+    for i in range(50):
+        counts.on_span(cc._TRACE_EVENT, 100.0 + i, 100.5 + i,
+                       fun_name="traced_only")
+    assert len(counts._held[next(iter(counts._held))].roots) == 50
+    assert _by_program(train_path.JAX_TRACE_SECONDS) == {"traced_only": 25.0}
+    counts.on_span(cc._TRACE_EVENT, 99.0, 151.0, fun_name="round_them")
+    counts.on_duration(cc._LOWER_EVENT, 1.0, fun_name="jit(round_them)")
+    assert counts._held[next(iter(counts._held))].roots == []
+    assert _by_program(train_path.JAX_TRACE_SECONDS) == \
+        {"traced_only": 25.0, "round_them": 27.0}
+
+
+def test_listeners_keep_their_sums_under_many_threads(counts):
+    import threading
+
+    per_thread, n_threads = 200, 16
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def compile_many(k):
+            for i in range(per_thread):
+                _replay(counts, _a_compile(
+                    f"program_{i % 8}", 1000.0 * k + 2 * i, nested=("n",),
+                    cache=("hit",) if i % 2 else ("miss",)))
+
+        threads = [threading.Thread(target=compile_many, args=(k,))
+                   for k in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+        assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(was)
+    n = per_thread * n_threads
+    want = {train_path.JAX_TRACE_SECONDS: n * 1.0,
+            train_path.JAX_LOWER_SECONDS: n * 0.25,
+            train_path.JAX_BACKEND_COMPILE_SECONDS: n * 0.5,
+            train_path.PROGRAMS_COMPILED: n,
+            train_path.COMPILE_CACHE_HITS: n / 2,
+            train_path.COMPILE_CACHE_MISSES: n / 2,
+            train_path.COMPILE_CACHE_RETRIEVAL_SECONDS: n / 2 * 0.25}
+    for name, total in want.items():
+        assert sum(_by_program(name).values()) == pytest.approx(total), name
+    assert counts._held == {}
+
+
+def test_the_train_step_names_itself():
+    """``make_train_step``'s program carries ``TRAIN_STEP_PROGRAM`` through
+    every stage of a compile, and its module's name (part of the cache's
+    key) is the same name."""
+    from chainermn_tpu.utils import compile_cache
+
+    compile_cache._count_compiles()
+    before = {n: _by_program(n).get(train_path.TRAIN_STEP_PROGRAM, 0.0)
+              for n in _STAGES}
+    comm = chainermn_tpu.create_communicator(
+        "naive", devices=jax.devices("cpu")[:1])
+    opt = mno(optax.sgd(0.0625), comm)
+    state = create_train_state(_params(), opt, comm)
+    step = make_train_step(_loss, opt, comm)
+    compiled = step.lower(state, jnp.ones((6, 16), jnp.float32)).compile()
+    assert compiled.as_text().startswith(
+        f"HloModule jit_{train_path.TRAIN_STEP_PROGRAM},")
+    for name, was in before.items():
+        assert _by_program(name)[train_path.TRAIN_STEP_PROGRAM] > was, name
+    assert _by_program(train_path.PROGRAMS_COMPILED)[
+        train_path.TRAIN_STEP_PROGRAM] == \
+        before[train_path.PROGRAMS_COMPILED] + 1
+
+
+# -- what the host made the process wait ----------------------------------
+
+def _schedstat(proc, thread, text):
+    os.makedirs(os.path.join(proc, "task", thread))
+    with open(os.path.join(proc, "task", thread, "schedstat"), "w") as f:
+        f.write(text)
+
+
+def test_host_hook_sums_the_threads_runqueue_wait(tmp_path):
+    from chainermn_tpu.utils import compile_cache
+
+    metrics.reset()
+    proc = str(tmp_path)
+    with open(os.path.join(proc, "schedstat"), "w") as f:
+        f.write("39744403 2500000000 7\n")  # the main thread's again
+    _schedstat(proc, "101", "39744403 2500000000 7\n")
+    _schedstat(proc, "102", "1200 500000000 3\n")
+    _schedstat(proc, "103", "")  # a thread on its way out
+    os.makedirs(os.path.join(proc, "task", "104"))  # one that has gone
+    reg = metrics.registry()
+    compile_cache._collect_host(reg, proc)
+    wait = reg.counter(train_path.PROCESS_RUNQUEUE_WAIT_SECONDS)
+    assert wait.value() == pytest.approx(3.0)
+    _schedstat(proc, "105", "5 250000000 1\n")
+    compile_cache._collect_host(reg, proc)
+    assert wait.value() == pytest.approx(3.25)
+    # a thread that exits takes its share along: the counter stays
+    os.remove(os.path.join(proc, "task", "101", "schedstat"))
+    compile_cache._collect_host(reg, proc)
+    assert wait.value() == pytest.approx(3.25)
+    assert reg.counter(train_path.PROCESS_CPU_SECONDS).value() > 0
+
+
+@pytest.mark.parametrize("there", ["no_proc", "no_schedstat"])
+def test_host_hook_publishes_nothing_where_the_kernel_gives_none(
+        tmp_path, there):
+    """A kernel without scheduler statistics (the chip tool's sandbox is
+    one) has the threads' directories and no ``schedstat`` in them."""
+    from chainermn_tpu.utils import compile_cache
+
+    metrics.reset()
+    proc = str(tmp_path / "self")
+    if there == "no_schedstat":
+        os.makedirs(os.path.join(proc, "task", "101"))
+    compile_cache._collect_host(metrics.registry(), proc)
+    snap = metrics.registry().snapshot()
+    assert train_path.PROCESS_RUNQUEUE_WAIT_SECONDS not in snap
+    assert snap[train_path.PROCESS_CPU_SECONDS]["values"][0]["value"] > 0
+
+
+def test_use_compile_cache_hangs_the_hooks_on_the_registry(monkeypatch,
+                                                           tmp_path):
+    from chainermn_tpu.utils import compile_cache
+
+    metrics.reset()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.use_compile_cache() == str(tmp_path)
+    snap = metrics.registry().snapshot()
+    assert snap[train_path.PROCESS_CPU_SECONDS]["values"][0]["value"] > 0
+    assert (train_path.PROCESS_RUNQUEUE_WAIT_SECONDS in snap) == \
+        os.path.exists("/proc/self/schedstat")
+
 # -- the feed ------------------------------------------------------------
 
 def test_feed_counters_on_a_host_iterator():
