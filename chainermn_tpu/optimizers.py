@@ -9,11 +9,15 @@ CUDA stream at the cost of one step of gradient staleness.
 
 TPU-native design: the wrapped object is an :class:`optax.GradientTransformation`
 meant to be used *inside the jitted train step*. ``allreduce_grad`` is a
-``lax.pmean`` over the communicator's mesh axes — XLA fuses the reference's
+mean over the communicator's mesh axes — XLA fuses the reference's
 pack / fp16-cast / ncclAllReduce / scale / unpack pipeline
-(``pure_nccl_communicator.py`` (dagger)) into its collective schedule, and its
-latency-hiding scheduler overlaps the collective with remaining backward
-computation, which is what double buffering bought on GPU. The
+(``pure_nccl_communicator.py`` (dagger)) into its collective schedule. On a
+TPU an all-reduce is a synchronous op that the core waits on wherever the
+scheduler puts it, so the mean of every large leaf is written as two
+``all_to_all``s (:func:`allreduce_gradients`), which XLA compiles to
+asynchronous pairs and flies under the rest of the backward and the
+optimizer's sweep: what double buffering bought on GPU, at staleness 0
+(PERF.md, PR 39). The
 ``double_buffering=True`` flag is still honoured with *faithful semantics*
 (updates apply the previous step's reduced gradients, staleness 1) so
 convergence behaviour matches the reference feature; on TPU it additionally
@@ -74,6 +78,15 @@ def allreduce_gradients(
     ~2 bytes/element on the wire vs bf16's 4, at ~1/127-relative
     rounding noise per stage. Outside a named-axis context int8 is an
     identity (no pointless quantization round-trip).
+
+    Over more than one device a floating leaf of two or more dimensions
+    and at least ``collectives.ALL_TO_ALL_MIN_BYTES`` on the wire is
+    averaged where it lies by
+    :func:`~chainermn_tpu.parallel.collectives.all_to_all_mean` (the n
+    pieces summed in float32, the mean rounded once to the wire dtype,
+    the same bits on every member): asynchronous on a TPU where the step
+    is jitted with ``collectives.async_collective_options``, as
+    ``make_train_step`` does. Every other leaf takes ``pmean``.
     """
     if axis_names is None:
         if comm is None:
@@ -83,27 +96,33 @@ def allreduce_gradients(
         # reduce-scatter -> inter-allreduce -> all-gather).
         return comm.reduce_gradients_in_jit(grads, compress_dtype=compress_dtype)
 
-    from chainermn_tpu.parallel.collectives import publish_grad_wire
+    from chainermn_tpu.parallel import collectives
 
     int8_wire = (compress_dtype is not None
                  and jnp.dtype(compress_dtype) == jnp.dtype(jnp.int8))
-    # one collective a leaf as the program writes it (XLA combines them)
+    # one collective a leaf as the program writes it (XLA combines the
+    # all-reduces; an all_to_all pair stays its leaf's own)
     leaves = jax.tree.leaves(grads)
-    publish_grad_wire(leaves, compress_dtype, axis_names, len(leaves))
+    collectives.publish_grad_wire(leaves, compress_dtype, axis_names,
+                                  len(leaves))
+
+    n = collectives.axes_size(axis_names) \
+        if collectives.axes_bound(axis_names) else 1
 
     def reduce_leaf(g):
-        if int8_wire and jnp.issubdtype(g.dtype, jnp.floating):
-            from chainermn_tpu.parallel.collectives import (
-                axes_bound,
-                int8_allreduce_mean,
-            )
-
-            if not axes_bound(axis_names):
+        floating = jnp.issubdtype(g.dtype, jnp.floating)
+        if int8_wire and floating:
+            if not collectives.axes_bound(axis_names):
                 return g
-            return int8_allreduce_mean(g, axis_names)
-        if compress_dtype is not None and not int8_wire and jnp.issubdtype(
-            g.dtype, jnp.floating
-        ):
+            return collectives.int8_allreduce_mean(g, axis_names)
+        wire = jnp.dtype(compress_dtype if floating
+                         and compress_dtype is not None else g.dtype)
+        axis = collectives.all_to_all_split_axis(g.shape, n)
+        if floating and n > 1 and axis is not None and \
+                g.size * wire.itemsize >= collectives.ALL_TO_ALL_MIN_BYTES:
+            return collectives.all_to_all_mean(
+                g.astype(wire), axis_names, axis).astype(g.dtype)
+        if compress_dtype is not None and not int8_wire and floating:
             return _pmean_if_in_axis(g.astype(compress_dtype), axis_names).astype(
                 g.dtype
             )

@@ -4,10 +4,15 @@ TPU mapping of the reference's hot loop (SURVEY.md section 3.2): where
 ChainerMN ran eager backward, then packed gradients into a flat buffer,
 ``ncclAllReduce``-d it, scaled and unpacked (``pure_nccl_communicator.py``
 (dagger)), here the *entire iteration* — forward, backward, gradient pmean
-over the mesh, optimizer update — is one ``jax.jit`` program: XLA fuses the
-packing/scaling away and overlaps the collective with remaining backward
-compute (its latency-hiding scheduler provides what double buffering bought
-on GPU).
+over the mesh, optimizer update — is one ``jax.jit`` program, and XLA fuses
+the packing/scaling away. What it does not do by itself is overlap the
+collective: on a TPU an all-reduce is one synchronous op wherever the
+scheduler puts it (PERF.md, PR 39). So the default reduction averages
+every large leaf with two ``all_to_all``s (``allreduce_gradients``), and
+the step's ``jax.jit`` carries the option under which XLA compiles those
+to asynchronous pairs and flies them under the rest of the backward and
+the optimizer's sweep: what double buffering bought on GPU, without its
+step of staleness.
 """
 
 from __future__ import annotations
@@ -29,6 +34,7 @@ from chainermn_tpu.optimizers import (
     _ErrorFeedbackState,
     allreduce_gradients,
 )
+from chainermn_tpu.parallel.collectives import async_collective_options
 
 PyTree = Any
 
@@ -398,7 +404,11 @@ def make_train_step(
         out_specs=(state_spec, P()),
         check_vma=False,
     )
-    jitted = jax.jit(sharded, donate_argnums=(0,) if donate else ())
+    # The default reduction's all_to_alls are asynchronous on a TPU only
+    # under an option of XLA's own: the step's policy, attached to its
+    # jit (whoever lowers and compiles the step ahead of time gets it too).
+    jitted = jax.jit(sharded, donate_argnums=(0,) if donate else (),
+                     compiler_options=async_collective_options(mesh))
     # Overlap metadata for the observability layer: the Trainer emits
     # this once as an ``overlap_config`` trace event, so a trace's
     # comm-hidden numbers carry the mode that produced them (schedule,

@@ -107,3 +107,56 @@ def test_two_level_allreduce_sum_op():
     want = np.asarray(x).sum(axis=0)
     for row in np.asarray(out):
         np.testing.assert_allclose(row, want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("mesh_shape,names", [
+    ((4,), ("x",)), ((8,), ("x",)), ((3,), ("x",)), ((2, 2), ("a", "b")),
+])
+@pytest.mark.parametrize("shape,axis", [
+    ((24, 5), 0), ((2, 48, 7), 1), ((24, 1), 0), ((25, 5), 0), ((2, 7, 3), 1),
+])
+def test_all_to_all_mean_is_the_mean_on_every_member(mesh_shape, names,
+                                                     shape, axis):
+    """Two all_to_alls along a dimension give every member the mean, in
+    the array's own shape, and the same bits everywhere (a slice's mean
+    is made on one member); a dimension the group's size does not divide
+    is padded for the flight and cut back."""
+    n = int(np.prod(mesh_shape))
+    mesh = Mesh(np.array(jax.devices("cpu")[:n]).reshape(mesh_shape), names)
+    x = jnp.asarray(np.random.RandomState(3).randn(n, *shape), jnp.float32)
+    fn = jax.jit(shard_map(
+        lambda v: C.all_to_all_mean(v[0], names, axis)[None],
+        mesh=mesh, in_specs=P(names), out_specs=P(names), check_vma=False))
+    got = np.asarray(fn(x))
+    assert got.shape == (n,) + shape
+    np.testing.assert_allclose(got[0], np.asarray(x).mean(0), rtol=1e-6,
+                               atol=1e-6)
+    for r in range(1, n):
+        np.testing.assert_array_equal(got[r], got[0])
+
+
+def test_all_to_all_mean_rounds_the_float32_sum_once():
+    """On a bfloat16 wire the n pieces are summed in float32 and the mean
+    rounded once: no coarser than an all-reduce that rounds at every hop."""
+    n = 4
+    mesh = Mesh(np.array(jax.devices("cpu")[:n]), ("x",))
+    x = jnp.asarray(np.random.RandomState(4).randn(n, 8, 128),
+                    jnp.bfloat16)
+    fn = jax.jit(shard_map(
+        lambda v: C.all_to_all_mean(v[0], "x")[None],
+        mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_vma=False))
+    want = (np.asarray(x, np.float32).sum(0) / n).astype(jnp.bfloat16)
+    np.testing.assert_array_equal(np.asarray(fn(x)[0]), want)
+
+
+@pytest.mark.parametrize("shape,n,want", [
+    ((50304, 1024), 4, 0),      # the first dimension n divides
+    ((3, 3, 64, 64), 4, 2),     # never the minor one
+    ((50257, 1024), 4, 0),      # none divides: the least padding
+    ((3, 1000, 7), 4, 1),
+    ((5, 6), 4, 0),
+    ((1024,), 4, None),         # a vector is left to the all-reduce
+    ((), 4, None),
+])
+def test_all_to_all_split_axis(shape, n, want):
+    assert C.all_to_all_split_axis(shape, n) == want

@@ -1359,3 +1359,155 @@ def test_local_sgd_rejects_bad_cadence(comm):
 
     with pytest.raises(ValueError, match="sync_every"):
         create_local_sgd(optax.sgd(0.1), comm, sync_every=0)
+
+
+# ----------------------------------------------------------------------
+# The default reduction as two all_to_alls a matrix (ISSUE 39), on the
+# traced program: what each leaf's reduction is made of and what it
+# waits for.
+# ----------------------------------------------------------------------
+
+
+def _flat_eqns(jaxpr):
+    """Equations of a jaxpr with the calls that only wrap one (pjit,
+    custom-AD) opened up, their variables renamed to the caller's."""
+    from jax.extend import core as jex_core
+
+    from chainermn_tpu.testing import _subjaxprs
+
+    out = []
+
+    def walk(j, rename):
+        def name(v):
+            return v if isinstance(v, jex_core.Literal) \
+                else rename.get(v, v)
+
+        for eqn in j.eqns:
+            subs = _subjaxprs(eqn.params)
+            if len(subs) == 1 and \
+                    len(subs[0][1].invars) == len(eqn.invars) and \
+                    len(subs[0][1].outvars) == len(eqn.outvars):
+                sub = subs[0][1]
+                inner = dict(zip(sub.invars, map(name, eqn.invars)))
+                walk(sub, inner)
+                for o, so in zip(eqn.outvars, sub.outvars):
+                    rename[o] = so if isinstance(so, jex_core.Literal) \
+                        else inner.get(so, so)
+            else:
+                out.append((eqn.primitive.name,
+                            [name(v) for v in eqn.invars
+                             if not isinstance(v, jex_core.Literal)],
+                            list(eqn.outvars)))
+        return rename
+
+    rename = walk(jaxpr, {})
+    return out, [rename.get(v, v) for v in jaxpr.outvars]
+
+
+def _slice_of(eqns, var):
+    """The equations ``var`` depends on, as indices into ``eqns``."""
+    made_by = {o: i for i, (_, _, outs) in enumerate(eqns) for o in outs}
+    seen, todo = set(), [var]
+    while todo:
+        i = made_by.get(todo.pop())
+        if i is not None and i not in seen:
+            seen.add(i)
+            todo.extend(eqns[i][1])
+    return seen
+
+
+def test_each_matrix_is_two_all_to_alls_that_wait_for_its_gradient_alone(
+        monkeypatch):
+    """Three dense blocks of distinct shapes on four devices. Each
+    matrix crosses as two all_to_alls and each vector as a psum, at the
+    wire dtype. The last block's reduced gradient, the first the
+    backward makes, has no data path from any other leaf's collectives
+    nor from the matmul that makes the first block's gradient, the last
+    the backward makes: nothing in the program makes it wait for the end
+    of the backward, so a scheduler is free to fly it under the rest."""
+    from chainermn_tpu.parallel import collectives
+
+    monkeypatch.setattr(collectives, "ALL_TO_ALL_MIN_BYTES", 0)
+    comm = create_communicator("xla", devices=jax.devices("cpu")[:4],
+                               allreduce_grad_dtype=jnp.bfloat16)
+    widths = [8, 16, 24, 12]
+    rs = np.random.RandomState(11)
+    params = {f"block_{i}": {
+        "kernel": jnp.asarray(rs.randn(a, b), jnp.float32),
+        "bias": jnp.zeros((b,), jnp.float32)}
+        for i, (a, b) in enumerate(zip(widths, widths[1:]))}
+    x = jnp.asarray(rs.randn(4, widths[0]), jnp.float32)
+
+    def loss(p, x):
+        for i in range(3):
+            x = jnp.tanh(x @ p[f"block_{i}"]["kernel"]
+                         + p[f"block_{i}"]["bias"])
+        return jnp.mean(x ** 2)
+
+    closed = jax.make_jaxpr(
+        lambda p, x: allreduce_gradients(jax.grad(loss)(p, x), comm),
+        axis_env=[("data", 4)])(params, x)
+    eqns, outs = _flat_eqns(closed.jaxpr)
+    grads = jax.tree.unflatten(jax.tree.structure(params), outs)
+
+    def collectives_of(indices):
+        return sorted(eqns[i][0] for i in indices
+                      if eqns[i][0] in ("all_to_all", "psum"))
+
+    everything = collectives_of(range(len(eqns)))
+    assert everything == ["all_to_all"] * 6 + ["psum"] * 3
+    for i in range(3):
+        block = grads[f"block_{i}"]
+        assert collectives_of(_slice_of(eqns, block["kernel"])) == \
+            ["all_to_all"] * 2
+        assert collectives_of(_slice_of(eqns, block["bias"])) == ["psum"]
+
+    # the matmul that makes block 0's kernel gradient: the only
+    # dot_general whose result has that kernel's shape (or its transpose)
+    first_block = [i for i, (name, _, o) in enumerate(eqns)
+                   if name == "dot_general"
+                   and sorted(o[0].aval.shape) == sorted(widths[:2])]
+    assert len(first_block) == 1
+    last = _slice_of(eqns, grads["block_2"]["kernel"]) \
+        | _slice_of(eqns, grads["block_2"]["bias"])
+    assert first_block[0] in _slice_of(eqns, grads["block_0"]["kernel"])
+    assert first_block[0] not in last
+    # and the wire carries bf16
+    wire = {str(ins[0].aval.dtype) for name, ins, _ in eqns
+            if name in ("all_to_all", "psum")}
+    assert wire == {"bfloat16"}
+
+
+@pytest.mark.parametrize("shape,dtype,wire,moved", [
+    ((1024, 512), jnp.float32, jnp.bfloat16, True),    # 1 MiB on the wire
+    ((1024, 511), jnp.float32, jnp.bfloat16, False),   # just under it
+    ((1023, 513), jnp.float32, None, True),   # no dimension 4 divides
+    ((1 << 20,), jnp.float32, None, False),   # a vector, however long
+    ((1024, 512), jnp.int32, None, False),    # not a floating leaf
+])
+def test_which_leaves_the_default_reduction_sends_as_all_to_alls(
+        shape, dtype, wire, moved):
+    """By bytes on the wire, not by what the device count divides: a
+    floating leaf of two or more dimensions and 1 MiB or more."""
+    comm = create_communicator("xla", devices=jax.devices("cpu")[:4],
+                               allreduce_grad_dtype=wire)
+    text = str(jax.make_jaxpr(
+        lambda g: allreduce_gradients(g, comm),
+        axis_env=[("data", 4)])(jnp.zeros(shape, dtype)))
+    assert ("all_to_all" in text) == moved
+    assert ("psum" in text) != moved
+
+
+def test_the_default_reduction_outside_any_axis_is_the_identity():
+    """Outside shard_map there is nothing to reduce over, whatever the
+    leaf's size: the gradient passes (through the wire dtype's rounding)
+    unchanged, and no collective is traced."""
+    comm = create_communicator("xla", devices=jax.devices("cpu")[:4],
+                               allreduce_grad_dtype=jnp.bfloat16)
+    grads = {"w": jnp.full((1024, 512), 2.0), "b": jnp.ones((3,))}
+    text = str(jax.make_jaxpr(lambda g: allreduce_gradients(g, comm))(grads))
+    assert "all_to_all" not in text and "psum" not in text
+    out = allreduce_gradients(grads, comm)
+    np.testing.assert_array_equal(np.asarray(out["w"]),
+                                  np.full((1024, 512), 2.0))
+    np.testing.assert_array_equal(np.asarray(out["b"]), np.ones((3,)))

@@ -1,0 +1,107 @@
+"""What only the TPU's compiler can say, compiled here for a v5e 2x2 that
+is described and not attached (no chip, no time): the option
+``make_train_step`` gives its jit on several TPU devices is one this
+libtpu knows, and under it the default gradient reduction's all_to_alls
+come out as asynchronous pairs. A libtpu that renames or drops the
+option fails here, and not in a user's step.
+
+One file, one fixture: only one process may load the TPU's library, so
+the topology is described inside the fixture and nowhere at import."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from chainermn_tpu import create_communicator, create_multi_node_optimizer
+from chainermn_tpu.parallel import collectives
+from chainermn_tpu.training import make_train_step
+from chainermn_tpu.training.train_step import TrainState
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def _loss(params, batch):
+    return jnp.mean((jnp.tanh(batch @ params["w"]) + params["b"]) ** 2)
+
+
+def _compiled_step(devices):
+    """The default optimizer's step over ``devices``, compiled from
+    shapes: a 1024 x 512 matrix (1 MiB on the bf16 wire) and a vector."""
+    comm = create_communicator("xla", devices=devices,
+                               allreduce_grad_dtype=jnp.bfloat16)
+    opt = create_multi_node_optimizer(optax.sgd(0.1), comm)
+    step = make_train_step(_loss, opt, comm)
+    params = {"w": jnp.zeros((1024, 512)), "b": jnp.zeros((512,))}
+    state = jax.eval_shape(lambda: TrainState(
+        params, opt.init(params), jnp.zeros((), jnp.int32)))
+    replicated = NamedSharding(comm.mesh, P())
+    state = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=replicated), state)
+    batch = jax.ShapeDtypeStruct(
+        (8 * len(devices), 1024), jnp.float32,
+        sharding=NamedSharding(comm.mesh, P(comm.grad_axes)))
+    return step.lower(state, batch).compile().as_text()
+
+
+def test_several_tpu_devices_get_the_option_and_one_device_none(topo):
+    devices = list(topo.devices)
+    assert len(devices) == 4 and devices[0].platform == "tpu"
+    assert collectives.async_collective_options(
+        Mesh(np.array(devices), ("data",))) == collectives.ASYNC_ALL_TO_ALL
+    assert collectives.async_collective_options(
+        Mesh(np.array(devices[:1]), ("data",))) is None
+    assert collectives.async_collective_options(
+        Mesh(np.array(jax.devices("cpu")[:4]), ("data",))) is None
+
+
+def _all_to_alls(text):
+    """``(pairs, made synchronous again, never asynchronous)``: XLA's
+    scheduler turns a pair with nothing to fly under back into one op
+    and leaves ``async_collective_name`` on it."""
+    ops = [line for line in text.splitlines() if " all-to-all(" in line]
+    again = sum("async_collective_name" in line for line in ops)
+    return text.count(" all-to-all-start("), again, len(ops) - again
+
+
+def test_four_device_step_compiles_to_asynchronous_all_to_alls(topo):
+    text = _compiled_step(list(topo.devices))
+    pairs, again, never = _all_to_alls(text)
+    # this toy step has nothing to fly the first one under
+    assert pairs >= 1 and pairs + again == 2 and never == 0
+    assert text.count(" all-to-all-done(") == pairs
+    # the vector and the loss still cross as all-reduces
+    assert " all-reduce(" in text
+
+
+def test_one_device_step_holds_no_collective_of_the_reduction(topo):
+    text = _compiled_step(list(topo.devices)[:1])
+    assert "all-to-all" not in text
+
+
+def test_without_the_option_the_all_to_alls_are_synchronous(topo):
+    """What the option buys, and that this compiler still needs it: the
+    same reduction jitted without it holds two synchronous all-to-alls."""
+    from jax import shard_map
+
+    mesh = Mesh(np.array(list(topo.devices)), ("data",))
+    fn = shard_map(
+        lambda g: collectives.all_to_all_mean(g, "data"), mesh=mesh,
+        in_specs=P(), out_specs=P(), check_vma=False)
+    g = jax.ShapeDtypeStruct((1024, 512), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P()))
+    assert _all_to_alls(jax.jit(fn).lower(g).compile().as_text()) == (0, 0, 2)
+    pairs, again, never = _all_to_alls(
+        jax.jit(fn, compiler_options=collectives.ASYNC_ALL_TO_ALL)
+        .lower(g).compile().as_text())
+    assert pairs + again == 2 and never == 0

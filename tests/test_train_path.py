@@ -56,9 +56,20 @@ def _loss(params, batch):
 
 
 @functools.lru_cache(maxsize=None)
-def _lowered(kind, n):
+def _lowered(kind, n, all_to_all_min_bytes=None):
     """``(op_names of the lowered step, registry snapshot after tracing
-    it)`` for one optimizer on ``n`` virtual devices."""
+    it)`` for one optimizer on ``n`` virtual devices;
+    ``all_to_all_min_bytes`` 0 sends these toy leaves where the default
+    reduction sends a real model's matrices."""
+    if all_to_all_min_bytes is not None:
+        from chainermn_tpu.parallel import collectives
+
+        keep = collectives.ALL_TO_ALL_MIN_BYTES
+        collectives.ALL_TO_ALL_MIN_BYTES = all_to_all_min_bytes
+        try:
+            return _lowered.__wrapped__(kind, n)
+        finally:
+            collectives.ALL_TO_ALL_MIN_BYTES = keep
     metrics.reset()
     comm = chainermn_tpu.create_communicator(
         "naive", devices=jax.devices("cpu")[:n],
@@ -113,6 +124,25 @@ def test_grad_wire_bytes_are_the_leaves_at_the_wire_dtype(kind, n):
         assert got == expect
         assert snap[train_path.GRAD_REDUCE_BUCKETS]["values"][0]["value"] \
             >= 1.0
+
+
+@pytest.mark.parametrize("n", [1, 4])
+@pytest.mark.parametrize("kind", list(OPTIMIZERS))
+def test_the_default_reductions_all_to_alls_carry_its_scope(kind, n):
+    """The default optimizer on several devices averages a large leaf
+    with two all_to_alls: they sit in the reduction's scope, after the
+    backward and before the sweep, so ``allreduce_ms`` and
+    ``grad_pack_ms`` read them and ``backward_ms`` does not. The packed
+    schedules and one device hold none."""
+    names, _ = _lowered(kind, n, 0)
+    moved = [x for x in names if "all_to_all" in x]
+    if kind == "default" and n > 1:
+        assert moved and all(
+            train_path.GRAD_REDUCE in x
+            and train_path.LOSS_AND_GRAD not in x
+            and train_path.OPTIMIZER_UPDATE not in x for x in moved)
+    elif kind != "error_feedback":  # whose int8 wire is all_to_alls too
+        assert not moved
 
 
 def test_grad_wire_bytes_zero_outside_any_axis():

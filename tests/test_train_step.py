@@ -317,3 +317,234 @@ def test_train_step_local_sgd_true_local_evolution(comm):
     got = jax.tree.map(np.asarray, state.params)
     for k in ("w", "b"):
         np.testing.assert_allclose(got[k], expect[k], rtol=1e-5, atol=1e-6)
+
+
+# ----------------------------------------------------------------------
+# The default reduction as two all_to_alls a large leaf (ISSUE 39). The
+# all-reduce it took the place of, kept here as the oracle, is the same
+# function with the size from which a leaf goes that way out of reach.
+# ----------------------------------------------------------------------
+
+
+@pytest.fixture
+def min_bytes(monkeypatch):
+    """Set ``collectives.ALL_TO_ALL_MIN_BYTES``: 0 sends every matrix of
+    these toy models where a real model's matrices go, ``None`` leaves
+    none to go there (the all-reduce a leaf, as before ISSUE 39)."""
+    from chainermn_tpu.parallel import collectives
+
+    def set_to(n):
+        monkeypatch.setattr(collectives, "ALL_TO_ALL_MIN_BYTES",
+                            float("inf") if n is None else n)
+    return set_to
+
+
+def _replicas(tree):
+    """Each leaf's per-device copies as numpy arrays."""
+    return [[np.asarray(s.data) for s in leaf.addressable_shards]
+            for leaf in jax.tree.leaves(tree)]
+
+
+def _lm_job(n_dev, wire="bfloat16"):
+    from chainermn_tpu.models import TransformerLM
+
+    comm = create_communicator(
+        "xla", devices=jax.devices("cpu")[:n_dev], allreduce_grad_dtype=wire)
+    model = TransformerLM(vocab_size=64, num_layers=2, num_heads=2,
+                          d_model=32, d_ff=64, max_len=16)
+    tokens = jax.random.randint(jax.random.key(0), (8, 16), 0, 64)
+    params = model.init(jax.random.key(1), tokens[:1])["params"]
+
+    def loss_fn(p, t):
+        logits = model.apply({"params": p}, t)
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits[:, :-1], t[:, 1:]).mean()
+
+    return comm, params, tokens, loss_fn
+
+
+def _two_steps(comm, params, batch, loss_fn, lr=0.5, **kwargs):
+    opt = create_multi_node_optimizer(optax.sgd(lr), comm, **kwargs)
+    state = create_train_state(params, opt, comm)
+    step = make_train_step(loss_fn, opt, comm, donate=False)
+    for _ in range(2):
+        state, metrics = step(state, batch)
+    return state, metrics
+
+
+def test_all_to_all_reduction_equals_the_all_reduce_on_four_devices(
+        min_bytes):
+    """Two steps of a small TransformerLM on 4 devices at the bf16 wire:
+    the parameters equal the all-reduce's within the wire's rounding and
+    every replica holds the same bits."""
+    comm, params, tokens, loss_fn = _lm_job(4)
+    min_bytes(None)
+    want, _ = _two_steps(comm, params, tokens, loss_fn)
+    min_bytes(0)
+    got, metrics = _two_steps(comm, params, tokens, loss_fn)
+    assert np.isfinite(float(metrics["loss"]))
+    for g, w, p0 in zip(jax.tree.leaves(got.params),
+                        jax.tree.leaves(want.params),
+                        jax.tree.leaves(params)):
+        moved = float(jnp.max(jnp.abs(w - p0)))
+        # a bf16 wire keeps 8 bits: two steps' updates agree to 2^-7
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=moved * 2 ** -7 + 1e-7, rtol=0)
+    for copies in _replicas(got.params):
+        for c in copies[1:]:
+            np.testing.assert_array_equal(c, copies[0])
+
+
+@pytest.mark.parametrize("rows", [8, 7])
+def test_all_to_all_reduction_is_the_mean_over_the_devices(min_bytes, rows):
+    """Per-device gradients that differ by a known factor: device r's
+    rows are r + 1, so the matrix's gradient there is r + 1 and the
+    vector's 1; a float32 wire and a unit step leave -mean exactly,
+    through both paths (the all_to_alls for the matrix, with 8 rows and
+    with 7, which four devices do not divide; the all-reduce for the
+    vector)."""
+    min_bytes(0)
+    n = 4
+    comm = create_communicator("xla", devices=jax.devices("cpu")[:n])
+    params = {"w": jnp.zeros((rows, 3)), "b": jnp.zeros((3,))}
+    x = jnp.repeat(jnp.arange(1.0, n + 1), 2)[:, None, None] \
+        * jnp.ones((2 * n, rows, 3))
+
+    def loss_fn(p, batch):
+        return jnp.mean(jnp.sum(batch * p["w"], axis=(1, 2))) \
+            + jnp.sum(p["b"])
+
+    opt = create_multi_node_optimizer(optax.sgd(1.0), comm)
+    state = create_train_state(params, opt, comm)
+    step = make_train_step(loss_fn, opt, comm, donate=False)
+    assert "all_to_all" in str(jax.make_jaxpr(step)(state, x))
+    state, _ = step(state, x)
+    np.testing.assert_array_equal(
+        np.asarray(state.params["w"]),
+        np.full((rows, 3), -(1 + 2 + 3 + 4) / n))
+    np.testing.assert_array_equal(np.asarray(state.params["b"]),
+                                  np.full((3,), -1.0))
+
+
+def test_one_device_step_is_the_program_it_was(min_bytes):
+    """On one device there is nothing to reduce over: the traced step
+    is, equation for equation, the step with the all-reduce a leaf, and
+    holds no all_to_all."""
+    comm, params, tokens, loss_fn = _lm_job(1)
+
+    def traced():
+        opt = create_multi_node_optimizer(optax.adamw(1e-3), comm)
+        state = create_train_state(params, opt, comm)
+        step = make_train_step(loss_fn, opt, comm, donate=False)
+        return str(jax.make_jaxpr(step)(state, tokens))
+
+    min_bytes(0)
+    text = traced()
+    min_bytes(None)
+    assert text == traced()
+    assert "all_to_all" not in text
+
+
+@pytest.mark.parametrize("how", [
+    "accum_steps", "plain_optax", "double_buffering", "error_feedback",
+    "int8_wire", "zero", "flat_schedule", "two_dimensional",
+])
+def test_every_path_gives_what_it_gave(how, min_bytes):
+    """Accumulation and a plain optax optimizer reduce through the same
+    function (the sum crosses once, after the last microbatch) and agree
+    with the all-reduce to float32's rounding; double buffering, error
+    feedback, the int8 wire, an explicit schedule and a communicator
+    with a pipeline of its own never reach it and give the same bits."""
+    name = "two_dimensional" if how == "two_dimensional" else "xla"
+    wire = jnp.int8 if how in ("error_feedback", "int8_wire") else None
+    comm = create_communicator(name, devices=jax.devices("cpu")[:4],
+                               allreduce_grad_dtype=wire)
+    accum = 2 if how == "accum_steps" else 1
+    x, y = _data(n=16)
+    params = {"w": jnp.zeros((4, 1)), "b": jnp.zeros(())}
+
+    def loss_fn(p, batch):
+        return _linreg_loss({"w": p["w"][:, 0], "b": p["b"]}, batch)
+
+    def run():
+        opt = optax.sgd(0.1) if how == "plain_optax" else \
+            create_multi_node_optimizer(
+                optax.sgd(0.1), comm,
+                double_buffering=how == "double_buffering",
+                error_feedback=how == "error_feedback",
+                reduction_schedule={"zero": "zero",
+                                    "flat_schedule": "flat"}.get(how))
+        state = create_train_state(params, opt, comm)
+        step = make_train_step(loss_fn, opt, comm, donate=False,
+                               accum_steps=accum)
+        for _ in range(2):
+            state, metrics = step(state, (x, y))
+        assert np.isfinite(float(metrics["loss"]))
+        return jax.tree.leaves(state.params)
+
+    min_bytes(None)
+    want = run()
+    min_bytes(0)
+    got = run()
+    for g, w in zip(got, want):
+        if how in ("accum_steps", "plain_optax"):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-6, atol=1e-7)
+        else:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_accumulated_step_reduces_once_and_equals_the_full_batch(min_bytes):
+    """accum_steps=2 on 4 devices: the sum crosses the wire once, after
+    the last microbatch (two all_to_alls for the one matrix, as without
+    accumulation), and the step equals the unaccumulated one."""
+    min_bytes(0)
+    comm = create_communicator("xla", devices=jax.devices("cpu")[:4])
+    x, y = _data(n=32)
+    params = {"w": jnp.zeros((4, 1)), "b": jnp.zeros(())}
+
+    def loss_fn(p, batch):
+        return _linreg_loss({"w": p["w"][:, 0], "b": p["b"]}, batch)
+
+    out = []
+    for accum in (1, 2):
+        opt = create_multi_node_optimizer(optax.sgd(0.1), comm)
+        state = create_train_state(params, opt, comm)
+        step = make_train_step(loss_fn, opt, comm, donate=False,
+                               accum_steps=accum)
+        text = str(jax.make_jaxpr(step)(state, (x, y)))
+        assert text.count(" all_to_all[") == 2
+        out.append(step(state, (x, y))[0].params)
+    for a, b in zip(jax.tree.leaves(out[0]), jax.tree.leaves(out[1])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
+
+
+def test_a_leaf_used_twice_arrives_summed(min_bytes):
+    """A tied leaf (the loss reads it in two places) is reduced once, as
+    the sum of both uses' cotangents."""
+    comm = create_communicator("xla", devices=jax.devices("cpu")[:4])
+    rs = np.random.RandomState(5)
+    params = {"tied": jnp.asarray(rs.randn(8, 4), jnp.float32),
+              "own": jnp.asarray(rs.randn(4, 4), jnp.float32)}
+    rows = jnp.asarray(rs.randn(16, 8), jnp.float32)
+
+    def loss_fn(p, x):
+        h = jnp.tanh(x @ p["tied"]) @ p["own"]
+        return jnp.mean((h @ p["tied"].T - x) ** 2)
+
+    results = []
+    for n in (0, None):
+        min_bytes(n)
+        state, _ = _two_steps(comm, params, rows, loss_fn, lr=0.05)
+        results.append(state.params)
+    single = params
+    for _ in range(2):
+        g = jax.grad(loss_fn)(single, rows)
+        single = jax.tree.map(lambda p, d: p - 0.05 * d, single, g)
+    for got, want, ref in zip(*(jax.tree.leaves(t)
+                                for t in (*results, single))):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-7)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-5)
