@@ -15,6 +15,7 @@ from chainermn_tpu.models.seq2seq import (
 )
 from chainermn_tpu.models.transformer import (
     MODEL_CONFIGS,
+    ROUTER_STATE,
     Architecture,
     TransformerLM,
     head_table,
@@ -56,6 +57,7 @@ __all__ = [
     "TransformerLM",
     "Architecture",
     "MODEL_CONFIGS",
+    "ROUTER_STATE",
     "lm_from_config",
     "head_table",
     "lm_loss_moe",

@@ -25,6 +25,12 @@ from chainermn_tpu.observability import train_path
 from chainermn_tpu.ops.attention import blockwise_attention
 
 
+#: the token mixers and feed-forwards a layer of the stack may have
+#: (:attr:`Architecture.layers`)
+MIXERS = ("attention", "short_conv")
+FFNS = ("dense", "experts")
+
+
 @dataclasses.dataclass(frozen=True)
 class Architecture:
     """What kind of block a model is built from: everything about a
@@ -44,9 +50,11 @@ class Architecture:
     #: ``'gelu'``: up, tanh GELU, down, both with a bias; ``'gated_silu'``:
     #: ``down(silu(gate(x)) * up(x))`` without biases
     ffn: str = "gelu"
-    #: RMSNorm over the whole query and key projections, before the split
-    #: into heads (OLMoE's QK-norm)
-    qk_norm: bool = False
+    #: a norm of the block's kind on queries and keys before RoPE:
+    #: ``'projection'`` over the whole projection, before the split into
+    #: heads (OLMoE's; ``True`` reads as it), ``'head'`` over each head's
+    #: values with one scale shared by the heads (LFM2's), ``False`` none
+    qk_norm: Any = False
     #: ``'learned'`` absolute table or ``'rope'`` at ``rope_base``
     positions: str = "learned"
     rope_base: float = 10000.0
@@ -67,8 +75,39 @@ class Architecture:
     #: a ``[d, 1]`` linear gate on the normed hidden state of each pass of
     #: a looped model (param ``exit_gate``; :func:`lm_loss_looped`)
     exit_gate: bool = False
+    #: the router's score of an expert: the ``'softmax'`` over the experts
+    #: or each logit's ``'sigmoid'``
+    router_score: str = "softmax"
+    #: a per-expert bias added to the scores for the choice alone (the
+    #: gates are the scores without it). It is no parameter: it lives in
+    #: the :data:`ROUTER_STATE` collection (``moe_router_bias``, ``[E]``),
+    #: takes no gradient, and this program never updates it
+    router_bias: bool = False
+    #: added to the sum the chosen gates are renormalised by
+    gate_eps: float = 0.0
+    #: the gates' last factor (``routed_scaling_factor``)
+    routed_scaling: float = 1.0
+    #: ``(lo, hi)``: the experts whose weights this program holds, a chip's
+    #: share of ``n_experts`` under expert parallelism (``None``: all). The
+    #: router keeps ``n_experts`` outputs and chooses among all of them,
+    #: the expert leaves are ``[hi - lo, ...]``, and a layer's output is
+    #: the held experts' part of the sum: what the absent ones would add
+    #: is left out, and nothing stands in for the exchange. Training only
+    experts_held: Optional[tuple] = None
+    #: the stack layer by layer: one ``(mixer, ffn)`` pair a layer, the
+    #: mixer ``'attention'`` or ``'short_conv'`` (LFM2's gated short
+    #: convolution, ``conv_width`` taps), the feed-forward ``'dense'`` (at
+    #: the model's ``d_ff``) or ``'experts'``. ``None``: every layer has
+    #: attention and, where there are experts, experts (:meth:`layer`)
+    layers: Optional[tuple] = None
+    conv_width: int = 0
 
     def __post_init__(self):
+        if self.qk_norm is True:
+            object.__setattr__(self, "qk_norm", "projection")
+        if self.qk_norm not in (False, "projection", "head"):
+            raise ValueError(f"qk_norm must be False, 'projection' or "
+                             f"'head', got {self.qk_norm!r}")
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got "
                              f"{self.norm!r}")
@@ -84,6 +123,68 @@ class Architecture:
             raise ValueError(
                 "a mixture of experts needs 0 < experts_per_token <= "
                 "n_experts, an expert_width and gated SiLU experts")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score must be 'softmax' or "
+                             f"'sigmoid', got {self.router_score!r}")
+        if self.experts_held is not None:
+            lo, hi = self.experts_held
+            if not 0 <= lo < hi <= self.n_experts:
+                raise ValueError(
+                    f"experts_held={self.experts_held} is no range of "
+                    f"{self.n_experts} experts")
+            object.__setattr__(self, "experts_held", (int(lo), int(hi)))
+        if self.layers is not None:
+            layers = tuple(tuple(pair) for pair in self.layers)
+            object.__setattr__(self, "layers", layers)
+            for pair in layers:
+                if len(pair) != 2 or pair[0] not in MIXERS \
+                        or pair[1] not in FFNS:
+                    raise ValueError(
+                        f"a layer is a (mixer, ffn) pair of {MIXERS} and "
+                        f"{FFNS}, got {pair!r}")
+            if self.has_short_conv and self.conv_width < 1:
+                raise ValueError("a short_conv layer needs conv_width >= 1")
+            if any(f == "experts" for _, f in layers) \
+                    and not self.n_experts:
+                raise ValueError("a layer of experts needs n_experts")
+            if self.exit_gate or self.post_norm:
+                raise ValueError(
+                    "a stack described layer by layer with post-norms or "
+                    "an exit gate (a looped model's) is not built")
+
+    def layer(self, index: int) -> tuple:
+        """``(mixer, ffn)`` of layer ``index``."""
+        if self.layers is not None:
+            return self.layers[index]
+        return ("attention", "experts" if self.n_experts else "dense")
+
+    @property
+    def has_short_conv(self) -> bool:
+        return any(m == "short_conv" for m, _ in self.layers or ())
+
+    @property
+    def n_experts_held(self) -> int:
+        lo, hi = self.experts_held or (0, self.n_experts)
+        return hi - lo
+
+    def router_kwargs(self) -> dict:
+        """What :func:`~chainermn_tpu.parallel.moe.dropless_topk` takes
+        beyond ``k`` and ``renormalise``, where it differs from a softmax
+        router over experts that are all held. For that router it is
+        empty, so that what wraps ``dropless_topk`` under its first
+        signature still fits (``benchmark/tests/test_moe.py`` and
+        ``tools/moe_controls.py`` do, to lose a row or round the router:
+        a benchmark file is not edited with the program)."""
+        kw = {}
+        if self.router_score != "softmax":
+            kw["score"] = self.router_score
+        if self.gate_eps:
+            kw["gate_eps"] = self.gate_eps
+        if self.routed_scaling != 1.0:
+            kw["scale"] = self.routed_scaling
+        if self.n_experts_held != self.n_experts:
+            kw["held"] = self.experts_held
+        return kw
 
     @classmethod
     def from_config(cls, config: dict) -> "Architecture":
@@ -100,7 +201,7 @@ class Architecture:
                     "biases, clipped qkv or scaled RoPE is not built here")
             return cls(
                 norm="rmsnorm", norm_eps=float(config["rms_norm_eps"]),
-                ffn="gated_silu", qk_norm=True, positions="rope",
+                ffn="gated_silu", qk_norm="projection", positions="rope",
                 rope_base=float(config["rope_theta"]),
                 tied_head=bool(config["tie_word_embeddings"]),
                 n_experts=int(config["num_experts"]),
@@ -125,12 +226,68 @@ class Architecture:
                 tied_head=bool(config["tie_word_embeddings"]),
                 post_norm=True, exit_gate=True,
             )
+        if kind == "lfm2_moe":
+            return cls._from_lfm2_moe(config)
         raise ValueError(f"no block is described for model_type {kind!r}")
+
+    @classmethod
+    def _from_lfm2_moe(cls, config: dict) -> "Architecture":
+        """LFM2-MoE: ``layer_types`` names each layer's mixer, the first
+        ``num_dense_layers`` have a dense feed-forward and the rest
+        experts behind a sigmoid router with a selection bias. A file that
+        holds a chip's share gives the experts it holds as ``num_experts``
+        and ``experts_held_range``, and the router's width as
+        ``experts_published``."""
+        mixers = {"conv": "short_conv", "full_attention": "attention"}
+        kinds = config["layer_types"]
+        unknown = sorted(set(kinds) - set(mixers))
+        if unknown or config.get("conv_bias") or \
+                len(kinds) != config["num_hidden_layers"] or \
+                config.get("rope_scaling") is not None:
+            raise ValueError(
+                "an lfm2_moe config with a convolution bias, scaled RoPE, "
+                "layer_types that do not count num_hidden_layers or a "
+                f"layer type other than {sorted(mixers)} is not built "
+                f"here (layer types not known: {unknown})")
+        n_experts = int(config.get("experts_published",
+                                   config["num_experts"]))
+        held = config.get("experts_held_range")
+        if (held is None) != (n_experts == config["num_experts"]) or (
+                held is not None
+                and held[1] - held[0] != config["num_experts"]):
+            raise ValueError(
+                "a share of the experts is spelled num_experts (held), "
+                "experts_published (the router's width) and "
+                "experts_held_range [lo, hi) of num_experts entries")
+        dense = int(config["num_dense_layers"])
+        renorm = bool(config["norm_topk_prob"])
+        return cls(
+            norm="rmsnorm", norm_eps=float(config["norm_eps"]),
+            ffn="gated_silu", qk_norm="head", positions="rope",
+            rope_base=float(config["rope_theta"]),
+            tied_head=bool(config.get("tie_embedding", True)),
+            n_experts=n_experts,
+            experts_per_token=int(config["num_experts_per_tok"]),
+            expert_width=int(config["moe_intermediate_size"]),
+            renormalise_gates=renorm, router_score="sigmoid",
+            router_bias=bool(config["use_expert_bias"]),
+            gate_eps=1e-6 if renorm else 0.0,
+            routed_scaling=float(config["routed_scaling_factor"]),
+            experts_held=tuple(held) if held is not None else None,
+            layers=tuple((mixers[kind], "dense" if i < dense else "experts")
+                         for i, kind in enumerate(kinds)),
+            conv_width=int(config["conv_L_cache"]),
+        )
 
 
 #: collection a dropless MoE block sows its router's auxiliary losses and
 #: load into (``apply(..., mutable=[MOE_AUX])``; :func:`lm_loss_moe`)
 MOE_AUX = "moe_aux"
+#: collection of a router's selection bias (``Architecture.router_bias``):
+#: ``init`` makes it zero; ``apply`` reads it beside the parameters
+#: (``{"params": ..., ROUTER_STATE: ...}``), a train step carries it as
+#: ``model_state``
+ROUTER_STATE = "router_state"
 
 #: ``config.json``-style descriptions of the models the examples name
 #: (:func:`lm_from_config` builds them); sources in the README
@@ -158,6 +315,24 @@ MODEL_CONFIGS = {
         "tie_word_embeddings": False, "vocab_size": 49152,
         "max_position_embeddings": 65536, "total_ut_steps": 4,
         "early_exit_threshold": 1,
+    },
+    "lfm2-8b-a1b": {
+        "model_type": "lfm2_moe", "num_hidden_layers": 24,
+        "num_dense_layers": 2,
+        "layer_types": [
+            "conv", "conv", "full_attention", "conv", "conv", "conv",
+            "full_attention", "conv", "conv", "conv", "full_attention",
+            "conv", "conv", "conv", "full_attention", "conv", "conv",
+            "conv", "full_attention", "conv", "conv", "full_attention",
+            "conv", "conv"],
+        "hidden_size": 2048, "num_attention_heads": 32,
+        "num_key_value_heads": 8, "intermediate_size": 7168,
+        "moe_intermediate_size": 1792, "num_experts": 32,
+        "num_experts_per_tok": 4, "norm_topk_prob": True,
+        "use_expert_bias": True, "routed_scaling_factor": 1,
+        "conv_L_cache": 3, "conv_bias": False, "norm_eps": 1e-5,
+        "rope_theta": 1000000, "vocab_size": 65536,
+        "max_position_embeddings": 128000,
     },
 }
 
@@ -292,6 +467,9 @@ class TransformerBlock(nn.Module):
     #: the kind of block (:class:`Architecture`); the default instance is
     #: the GPT-2 block
     arch: Architecture = Architecture()
+    #: which layer of the stack this block is: it reads its own entry of
+    #: a description that goes layer by layer (``arch.layer``)
+    layer_index: int = 0
 
     @staticmethod
     def _lora_delta(name, adapters, inp, out):
@@ -621,11 +799,15 @@ class TransformerBlock(nn.Module):
         ``tokens * k`` rows by expert, two grouped matmuls round the SiLU
         gate, weighted sum back. No capacity, so no token is dropped and
         none is padded; the router's auxiliary losses and load are sown
-        into :data:`MOE_AUX`."""
+        into :data:`MOE_AUX`. With a share of the experts held
+        (``arch.experts_held``) the leaves and the groups are the held
+        experts', and the rows of the absent ones, which lie behind the
+        last group, come out of the grouped matmuls as zeros."""
         from chainermn_tpu.ops.grouped_matmul import grouped_matmul
         from chainermn_tpu.parallel import moe as _moe
 
         E, F = arch.n_experts, arch.expert_width
+        held = arch.n_experts_held
         B, T, D = h.shape
         kern = nn.initializers.variance_scaling(
             1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
@@ -635,14 +817,26 @@ class TransformerBlock(nn.Module):
                             (D, E), jnp.float32)
         # gate and up of an expert are one matrix, gate's columns first:
         # one grouped matmul makes both
-        w_gate_up = self.param("moe_w_gate_up", kern, (E, D, 2 * F),
+        w_gate_up = self.param("moe_w_gate_up", kern, (held, D, 2 * F),
                                jnp.float32)
-        w_down = self.param("moe_w_down", kern, (E, F, D), jnp.float32)
+        w_down = self.param("moe_w_down", kern, (held, F, D), jnp.float32)
+        kw = arch.router_kwargs()
+        if arch.router_bias:
+            if not (self.is_initializing()
+                    or self.has_variable(ROUTER_STATE, "moe_router_bias")):
+                raise ValueError(
+                    "this router chooses by score + bias: apply the model "
+                    f"with the {ROUTER_STATE!r} collection that init made "
+                    "beside the parameters")
+            kw["select_bias"] = self.variable(
+                ROUTER_STATE, "moe_router_bias", jnp.zeros, (E,),
+                jnp.float32).value
 
         tokens = h.reshape(B * T, D)
         routing = _moe.dropless_topk(tokens, router, arch.experts_per_token,
-                                     arch.renormalise_gates)
-        for name, value in _moe.dropless_aux(routing).items():
+                                     arch.renormalise_gates, **kw)
+        for name, value in _moe.dropless_aux(
+                routing, arch.router_score == "softmax").items():
             self.sow(MOE_AUX, name, value)
         rows = _moe.dispatch(tokens, routing)
         gate_up = grouped_matmul(rows, w_gate_up, routing.group_sizes)
@@ -650,6 +844,42 @@ class TransformerBlock(nn.Module):
             act = nn.silu(gate_up[:, :F]) * gate_up[:, F:]
         out = grouped_matmul(act, w_down, routing.group_sizes)
         return _moe.combine(out, routing).reshape(B, T, D)
+
+    def _short_conv(self, h, arch):
+        """LFM2's gated short convolution: ``(B, C, x) = split3(h W_in)``,
+        ``u = B * x``, a depthwise causal convolution of ``conv_width``
+        taps over ``u`` (zero before the sequence starts, no bias),
+        ``(C * conv) W_out``. Plain ``jax.numpy`` in the compute dtype:
+        one shifted multiply-add a tap, under :data:`train_path.
+        SHORT_CONV` (the two projections are matmuls outside it)."""
+        D, L = h.shape[-1], arch.conv_width
+        T = h.shape[1]
+        bcx = nn.Dense(
+            3 * D, use_bias=False, dtype=self.compute_dtype,
+            param_dtype=jnp.float32, name="conv_in",
+        )(h)
+        # torch's Conv1d default for a depthwise kernel of L taps; tap j
+        # multiplies the input L - 1 - j steps back
+        bound = L ** -0.5
+        taps = self.param(
+            "conv_w",
+            lambda key, shape, dtype: jax.random.uniform(
+                key, shape, dtype, -bound, bound),
+            (L, D), jnp.float32)
+        with jax.named_scope(train_path.SHORT_CONV):
+            b, c, x = jnp.split(bcx, 3, axis=-1)
+            u = b * x
+            w = taps.astype(self.compute_dtype)
+            conv = w[L - 1] * u
+            for j in range(L - 1):
+                back = L - 1 - j
+                conv = conv + w[j] * jnp.pad(
+                    u, ((0, 0), (back, 0), (0, 0)))[:, :T]
+            y = c * conv
+        return nn.Dense(
+            D, use_bias=False, dtype=self.compute_dtype,
+            param_dtype=jnp.float32, name="conv_out",
+        )(y)
 
     @nn.compact
     def __call__(self, x, segment_ids=None, rope_positions=None,
@@ -675,79 +905,105 @@ class TransformerBlock(nn.Module):
                 reduce_from_tp,
             )
 
-        h = _norm_layer(arch, self.compute_dtype)(x)
-        if self.tp_axis is not None:
-            h = copy_to_tp(h, self.tp_axis)
-        qkv = nn.Dense(
-            (self.num_heads + 2 * kv_heads) * head_dim, use_bias=False,
-            dtype=self.compute_dtype, param_dtype=jnp.float32, name="qkv",
-        )(h)
-        # Column-parallel delta (ISSUE 14): h is replicated under TP
-        # (post copy_to_tp), the adapter's B is column-sharded like the
-        # qkv kernel — the delta lands on the shard's own columns, no
-        # new collective.
-        qkv = self._lora_delta("qkv", adapters, h, qkv)
-        q, k, v = jnp.split(
-            qkv,
-            [self.num_heads * head_dim, (self.num_heads + kv_heads) * head_dim],
-            axis=-1,
-        )
-        B, T = q.shape[:2]
-        if arch.qk_norm:
-            q = _norm_layer(arch, self.compute_dtype, "q_norm")(q)
-            k = _norm_layer(arch, self.compute_dtype, "k_norm")(k)
+        mixer, ffn = arch.layer(self.layer_index)
 
-        def heads(t, n):
-            return t.reshape(B, T, n, head_dim)
+        def attention(h):
+            """Queries, keys and values, the attention of the call's mode
+            and the output projection."""
+            if self.tp_axis is not None:
+                h = copy_to_tp(h, self.tp_axis)
+            qkv = nn.Dense(
+                (self.num_heads + 2 * kv_heads) * head_dim, use_bias=False,
+                dtype=self.compute_dtype, param_dtype=jnp.float32, name="qkv",
+            )(h)
+            # Column-parallel delta (ISSUE 14): h is replicated under TP
+            # (post copy_to_tp), the adapter's B is column-sharded like the
+            # qkv kernel — the delta lands on the shard's own columns, no
+            # new collective.
+            qkv = self._lora_delta("qkv", adapters, h, qkv)
+            q, k, v = jnp.split(
+                qkv,
+                [self.num_heads * head_dim,
+                 (self.num_heads + kv_heads) * head_dim],
+                axis=-1,
+            )
+            B, T = q.shape[:2]
+            if arch.qk_norm == "projection":
+                q = _norm_layer(arch, self.compute_dtype, "q_norm")(q)
+                k = _norm_layer(arch, self.compute_dtype, "k_norm")(k)
 
-        qh, kh = heads(q, self.num_heads), heads(k, kv_heads)
-        if rope_positions is not None:
-            qh = apply_rope(qh, rope_positions, arch.rope_base)
-            kh = apply_rope(kh, rope_positions, arch.rope_base)
-        if decode:
-            if not self.causal:
-                raise ValueError("decode=True requires a causal block")
-            if decode_positions is not None:
-                o = self._slot_decode_attend(
-                    qh, kh, heads(v, kv_heads), head_dim,
-                    decode_positions, block_tables, decode_slots,
-                )
-            else:
-                if T != 1:
-                    raise ValueError(
-                        f"decode=True expects one token per step, got T={T}"
+            def heads(t, n):
+                return t.reshape(B, T, n, head_dim)
+
+            qh, kh = heads(q, self.num_heads), heads(k, kv_heads)
+            if arch.qk_norm == "head":
+                # over each head's values, one scale for all heads
+                qh = _norm_layer(arch, self.compute_dtype, "q_norm")(qh)
+                kh = _norm_layer(arch, self.compute_dtype, "k_norm")(kh)
+            if rope_positions is not None:
+                qh = apply_rope(qh, rope_positions, arch.rope_base)
+                kh = apply_rope(kh, rope_positions, arch.rope_base)
+            if decode:
+                if not self.causal:
+                    raise ValueError("decode=True requires a causal block")
+                if decode_positions is not None:
+                    o = self._slot_decode_attend(
+                        qh, kh, heads(v, kv_heads), head_dim,
+                        decode_positions, block_tables, decode_slots,
                     )
-                o = self._decode_attend(qh, kh, heads(v, kv_heads), head_dim)
-        else:
-            if self.window is not None and self.attention_fn is None:
+                else:
+                    if T != 1:
+                        raise ValueError(
+                            "decode=True expects one token per step, "
+                            f"got T={T}"
+                        )
+                    o = self._decode_attend(qh, kh, heads(v, kv_heads),
+                                            head_dim)
+            else:
+                if self.window is not None and self.attention_fn is None:
+                    raise ValueError(
+                        "window needs a window-honouring attention_fn (e.g. "
+                        "flash_attention(..., window=W)) — the default "
+                        "blockwise reference has no window support"
+                    )
+                if self.window is not None and not self.causal:
+                    raise ValueError("window requires a causal block")
+                vh = heads(v, kv_heads)
+                if self.sow_kv:
+                    self.sow("kv_out", "k", kh.astype(self.compute_dtype))
+                    self.sow("kv_out", "v", vh.astype(self.compute_dtype))
+                kw = {} if segment_ids is None \
+                    else {"segment_ids": segment_ids}
+                o = attn(qh, kh, vh, causal=self.causal,
+                         scale=head_dim**-0.5, **kw)
+            o_flat = o.reshape(B, T, self.num_heads * head_dim)
+            o = nn.Dense(
+                D, use_bias=False,
+                dtype=self.compute_dtype, param_dtype=jnp.float32, name="proj",
+            )(o_flat)
+            # Row-parallel delta (ISSUE 14): the adapter's A is sharded
+            # along the same local-head rows as the proj kernel, so the
+            # per-shard partial delta rides the existing psum below —
+            # exactly the pre-adapter collective set.
+            o = self._lora_delta("proj", adapters, o_flat, o)
+            if self.tp_axis is not None:
+                # Row-parallel output projection: the ONE psum of the
+                # attention column→row pair.
+                o = reduce_from_tp(o, self.tp_axis)
+            return o
+
+        h = _norm_layer(arch, self.compute_dtype)(x)
+        if mixer == "short_conv":
+            if decode or adapters is not None or self.sow_kv \
+                    or self.tp_axis is not None or segment_ids is not None:
                 raise ValueError(
-                    "window needs a window-honouring attention_fn (e.g. "
-                    "flash_attention(..., window=W)) — the default "
-                    "blockwise reference has no window support"
-                )
-            if self.window is not None and not self.causal:
-                raise ValueError("window requires a causal block")
-            vh = heads(v, kv_heads)
-            if self.sow_kv:
-                self.sow("kv_out", "k", kh.astype(self.compute_dtype))
-                self.sow("kv_out", "v", vh.astype(self.compute_dtype))
-            kw = {} if segment_ids is None else {"segment_ids": segment_ids}
-            o = attn(qh, kh, vh, causal=self.causal,
-                     scale=head_dim**-0.5, **kw)
-        o_flat = o.reshape(B, T, self.num_heads * head_dim)
-        o = nn.Dense(
-            D, use_bias=False,
-            dtype=self.compute_dtype, param_dtype=jnp.float32, name="proj",
-        )(o_flat)
-        # Row-parallel delta (ISSUE 14): the adapter's A is sharded
-        # along the same local-head rows as the proj kernel, so the
-        # per-shard partial delta rides the existing psum below —
-        # exactly the pre-adapter collective set.
-        o = self._lora_delta("proj", adapters, o_flat, o)
-        if self.tp_axis is not None:
-            # Row-parallel output projection: the ONE psum of the
-            # attention column→row pair.
-            o = reduce_from_tp(o, self.tp_axis)
+                    "the gated short convolution is the training path: no "
+                    "decode (it needs a state of conv_width - 1 rows a "
+                    "slot), adapters, tensor parallelism, captured keys "
+                    "or segment ids yet")
+            o = self._short_conv(h, arch)
+        else:
+            o = attention(h)
 
         def branch(x, out, norm_name):
             """A sub-layer's output into the residual stream."""
@@ -761,7 +1017,7 @@ class TransformerBlock(nn.Module):
         x = branch(x, o, "attn_out_norm")
 
         h = _norm_layer(arch, self.compute_dtype)(x)
-        if arch.n_experts > 0:
+        if ffn == "experts":
             if decode or adapters is not None or self.tp_axis is not None:
                 raise ValueError(
                     "the dropless mixture of experts is the training "
@@ -849,6 +1105,47 @@ def refuse_looped_decode(model, what: str):
         f"{what} of a looped model (total_ut_steps="
         f"{model.total_ut_steps}) is not built: it needs a KV cache a "
         "pass; the training path (lm_loss_looped) is")
+
+
+def refuse_unbuilt_decode(model, what: str):
+    """Decoding, serving and their caches are refused for a model with a
+    layer kind or a share the decode path does not build (never a silent
+    substitute): a gated short convolution needs a state of ``conv_width
+    - 1`` rows a slot beside the KV blocks, and a chip's share of the
+    experts is a part of each layer's sum that means nothing without the
+    other chips' parts. No-op for every other model."""
+    arch = model.arch
+    if arch is None:
+        return
+    if arch.has_short_conv:
+        raise NotImplementedError(
+            f"{what} of a model with short_conv layers is not built: it "
+            f"needs a convolution state of {arch.conv_width - 1} rows a "
+            "slot beside the KV cache; the training path is")
+    if arch.n_experts_held != arch.n_experts:
+        raise NotImplementedError(
+            f"{what} of a model that holds experts "
+            f"{list(arch.experts_held)} of {arch.n_experts} is not built: "
+            "a share's output is one chip's part of each layer's sum; "
+            "the training path is")
+
+
+def _publish_stack_kinds(arch: Architecture, num_layers: int):
+    """Gauge :data:`train_path.STACK_LAYERS_BY_KIND`, set while the
+    caller's program is traced."""
+    from chainermn_tpu.observability.metrics import registry
+
+    gauge = registry().gauge(
+        train_path.STACK_LAYERS_BY_KIND,
+        "layers of the stack with a mixer or a feed-forward of each "
+        "kind, at the last model traced",
+    )
+    kinds = [arch.layer(i) for i in range(num_layers)]
+    for label, where, name in (("attention", 0, "attention"),
+                               ("short_conv", 0, "short_conv"),
+                               ("dense_ffn", 1, "dense"),
+                               ("expert_ffn", 1, "experts")):
+        gauge.set(float(sum(k[where] == name for k in kinds)), kind=label)
 
 
 def _publish_loop_passes(passes: int):
@@ -983,6 +1280,14 @@ class TransformerLM(nn.Module):
         return self.total_ut_steps > 1 or bool(
             self.arch and self.arch.exit_gate)
 
+    @property
+    def expert_layers(self) -> tuple:
+        """The layers whose feed-forward is the description's experts."""
+        if self.arch is None:
+            return ()
+        return tuple(i for i in range(self.num_layers)
+                     if self.arch.layer(i)[1] == "experts")
+
     @nn.compact
     def __call__(self, tokens, *, segment_ids=None, positions=None,
                  train: bool = True, decode: bool = False,
@@ -1032,6 +1337,18 @@ class TransformerLM(nn.Module):
             raise ValueError(f"total_ut_steps must be >= 1, got {passes}")
         if looped and decode:
             refuse_looped_decode(self, "decode=True")
+        if decode:
+            refuse_unbuilt_decode(self, "decode=True")
+        if arch.layers is not None:
+            if len(arch.layers) != self.num_layers:
+                raise ValueError(
+                    f"the description has {len(arch.layers)} layers, the "
+                    f"model {self.num_layers}")
+            if looped:
+                raise ValueError(
+                    "a looped model with a stack described layer by layer "
+                    "is not built")
+        _publish_stack_kinds(arch, self.num_layers)
         if looped and arch.n_experts > 0:
             raise ValueError(
                 "a looped model with experts is not built: the router's "
@@ -1115,6 +1432,7 @@ class TransformerLM(nn.Module):
                 moe_dispatch_impl=self.moe_dispatch_impl,
                 moe_experts_local=self.moe_experts_local,
                 arch=arch,
+                layer_index=i,
                 name=f"block_{i}",
             ) for i in range(self.num_layers)
         ]
@@ -1179,10 +1497,18 @@ def head_table(params, arch: Optional[Architecture] = None):
 def lm_from_config(config: dict, *, num_layers: Optional[int] = None,
                    **kwargs) -> "TransformerLM":
     """A :class:`TransformerLM` from a ``config.json``-style dict (GPT-2's
-    keys, OLMoE's or Ouro's; :data:`MODEL_CONFIGS` holds one of each), at
-    the published sizes but for ``num_layers`` where given. ``kwargs`` are the model's
-    other fields (``compute_dtype``, ``attention_fn``, ``remat``, ...)."""
+    keys, OLMoE's, Ouro's or LFM2-MoE's; :data:`MODEL_CONFIGS` holds one
+    of each), at the published sizes but for ``num_layers`` where given
+    (of a stack described layer by layer: its first ``num_layers``).
+    ``kwargs`` are the model's other fields (``compute_dtype``,
+    ``attention_fn``, ``remat``, ...)."""
     arch = Architecture.from_config(config)
+    if num_layers is not None and arch.layers is not None:
+        if num_layers > len(arch.layers):
+            raise ValueError(
+                f"num_layers={num_layers} exceeds the {len(arch.layers)} "
+                "layers the config describes")
+        arch = dataclasses.replace(arch, layers=arch.layers[:num_layers])
     if config.get("model_type", "gpt2") == "gpt2":
         sizes = dict(
             vocab_size=config["vocab_size"], num_layers=config["n_layer"],
@@ -1382,44 +1708,59 @@ def _lm_loss_fused(hidden, emb_table, tokens, n_chunks, compute_dtype,
 
 
 def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
-                load_balance_coef=0.01, z_loss_coef=0.001):
+                load_balance_coef=0.01, z_loss_coef=0.001,
+                router_state=None):
     """Loss of a model whose description has experts (build it with
     ``return_hidden=True``): next-token cross-entropy through the fused
     head plus the router's two auxiliary losses, each the mean over the
-    layers: ``load_balance_coef`` x the load-balancing loss and
-    ``z_loss_coef`` x the router z-loss
-    (:func:`chainermn_tpu.parallel.moe.dropless_aux`).
+    layers that have experts: ``load_balance_coef`` x the load-balancing
+    loss and ``z_loss_coef`` x the router z-loss
+    (:func:`chainermn_tpu.parallel.moe.dropless_aux`). Both are a softmax
+    router's: a sigmoid router has neither, and its coefficients must be
+    0. ``router_state`` is the :data:`ROUTER_STATE` collection of a router
+    with a selection bias.
 
     Returns ``(loss, metrics)`` as :func:`~chainermn_tpu.training.
-    make_train_step` takes it: ``moe/load_balance``, ``moe/z_loss``,
-    ``moe/expert_load_max_over_mean`` (the busiest expert's rows over the
-    mean, all layers together), ``moe/dropped`` (rows that lie in no
-    expert's group, counted from each layer's group sizes: 0 while the
-    dropless path keeps its word) and the vector ``moe/expert_load`` (rows
-    an expert received, summed over the layers), which ``Trainer`` hands
-    to ``record_moe_dispatch``."""
-    hidden, sown = model.apply({"params": params}, tokens,
-                               mutable=[MOE_AUX])
+    make_train_step` takes it: ``moe/load_balance``, ``moe/z_loss`` (a
+    softmax router's), ``moe/expert_load_max_over_mean`` (the busiest held
+    expert's rows over the mean, all layers together), ``moe/rows_held``
+    (the rows whose expert this program holds, summed over the layers:
+    all ``tokens * k`` a layer unless it holds a share), ``moe/dropped``
+    (rows routed to a held expert that lie in no expert's group, counted
+    from each layer's group sizes: 0 while the dropless path keeps its
+    word) and the vector ``moe/expert_load`` (rows a held expert received,
+    summed over the layers), which ``Trainer`` hands to
+    ``record_moe_dispatch``."""
+    variables = {"params": params}
+    if router_state is not None:
+        variables[ROUTER_STATE] = router_state
+    hidden, sown = model.apply(variables, tokens, mutable=[MOE_AUX])
     ce = lm_loss_fused(hidden, head_table(params, model.arch), tokens,
                        n_chunks=n_chunks, compute_dtype=model.compute_dtype)
-    # one entry a block, each a 1-tuple (sow appends)
+    # one entry a block that has experts, each a 1-tuple (sow appends)
     layers = [{k: v[0] for k, v in sown[MOE_AUX][f"block_{i}"].items()}
-              for i in range(model.num_layers)]
+              for i in model.expert_layers]
 
     def over_layers(name, reduce):
         return reduce(jnp.stack([layer[name] for layer in layers]), axis=0)
 
-    load_balance = over_layers("load_balance", jnp.mean)
-    z_loss = over_layers("z_loss", jnp.mean)
     load = over_layers("expert_load", jnp.sum)
     metrics = {
-        "moe/load_balance": load_balance,
-        "moe/z_loss": z_loss,
         "moe/expert_load_max_over_mean": load.max() / load.mean(),
+        "moe/rows_held": over_layers("rows_held", jnp.sum),
         "moe/dropped": over_layers("dropped", jnp.sum),
         "moe/expert_load": load,
     }
-    loss = ce + load_balance_coef * load_balance + z_loss_coef * z_loss
+    loss = ce
+    for name, coef in (("load_balance", load_balance_coef),
+                       ("z_loss", z_loss_coef)):
+        if name in layers[0]:
+            metrics["moe/" + name] = over_layers(name, jnp.mean)
+            loss = loss + coef * metrics["moe/" + name]
+        elif coef:
+            raise ValueError(
+                f"{name} is a softmax router's auxiliary loss; this "
+                f"model's router has none (coefficient {coef})")
     return loss, metrics
 
 

@@ -44,6 +44,10 @@ MOE_ROUTE = "moe_route"
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
 MOE_COMBINE = "moe_combine"
+#: a gated short convolution's mixer between its two projections: the
+#: split of the input projection, the two gates and the depthwise causal
+#: convolution (the projections themselves are matmuls outside it)
+SHORT_CONV = "short_conv"
 #: a looped model: round the passes of the layer stack over one set of
 #: weights (sub-scope :func:`pass_scope` round each, final norm included);
 #: round the exit gate's product, the exit distribution, its entropy and
@@ -132,6 +136,13 @@ FLASH_HEADS_PER_BLOCK = "flash_heads_per_block"
 #: (tokens x experts per token), and the experts it chooses among
 MOE_ROWS_PER_STEP = "moe_rows_per_step"
 MOE_EXPERTS_TOTAL = "moe_experts_total"
+#: gauge set beside them: the experts whose weights the layer holds (all
+#: of them, or the share ``Architecture.experts_held`` names)
+MOE_EXPERTS_HELD = "moe_experts_held"
+#: gauge set while a ``TransformerLM`` is traced, label ``kind``
+#: (``attention`` / ``short_conv`` / ``dense_ffn`` / ``expert_ffn``): the
+#: layers of the stack that have a mixer or a feed-forward of that kind
+STACK_LAYERS_BY_KIND = "stack_layers_by_kind"
 #: gauge set while a looped model is traced: passes of its layer stack
 #: over one set of weights
 LOOP_PASSES = "loop_passes"

@@ -25,7 +25,10 @@ are summed back with their gates. **This path drops nothing** and pads
 nothing: exactly ``tokens * k`` rows pass through the experts whatever the
 routing. On one chip there is no collective; an expert axis puts its two
 ``all_to_all``s between :func:`dispatch` and the experts and between the
-experts and :func:`combine`.
+experts and :func:`combine`. A caller that holds a share of the experts
+(``held``) gets the part of the result its own experts give: the choice
+and the gates are over all experts, the rows of the absent ones lie in no
+group and add nothing, and nothing stands in for the exchange.
 
 Differentiable end to end: routing uses straight-through softmax gating
 (gradient flows through the gate probability, not the indices), and
@@ -528,29 +531,51 @@ class Routing(NamedTuple):
     at position ``j`` of the expert-sorted rows, ``inverse`` its inverse
     permutation; rows of one expert are contiguous, experts ascending, and
     within an expert in token order. ``logits`` are the router's float32
-    scores, kept for the auxiliary losses."""
+    scores, kept for the auxiliary losses. Where only a share of the
+    experts is held, ``group_sizes`` has one entry a *held* expert, the
+    rows routed to an absent one sort behind the last group and lie in
+    none, and ``rows_held`` counts the rows whose expert is held, from
+    the choice and not from the groups."""
 
     gates: jax.Array        # [T, k] float32
     experts: jax.Array      # [T, k] int32
     order: jax.Array        # [T * k] int32
     inverse: jax.Array      # [T * k] int32
-    group_sizes: jax.Array  # [E] int32, sums to T * k
+    group_sizes: jax.Array  # [held] int32, sums to rows_held
     logits: jax.Array       # [T, E] float32
+    rows_held: jax.Array    # [] int32; T * k where every expert is held
 
 
-def dropless_topk(u, router_w, k: int, renormalise: bool = False) -> Routing:
+def dropless_topk(u, router_w, k: int, renormalise: bool = False, *,
+                  score: str = "softmax", select_bias=None,
+                  gate_eps: float = 0.0, scale: float = 1.0,
+                  held: tuple[int, int] | None = None) -> Routing:
     """Route every row of ``u [T, D]`` to its ``k`` best of ``E`` experts.
 
-    The scores are ``softmax(u @ router_w)`` in float32 at full matmul
-    precision (a TPU's default would round the operands to bf16 and flip
-    near-tied experts), the gates the chosen experts' probabilities, not
-    renormalised over the ``k`` unless asked; ties go to the lower expert
-    index. Differentiable in the gates, not in the choice."""
+    The scores are ``softmax(u @ router_w)`` (or, with ``score='sigmoid'``,
+    each logit's sigmoid) in float32 at full matmul precision (a TPU's
+    default would round the operands to bf16 and flip near-tied experts).
+    The choice is by ``score + select_bias`` (``[E]``, not differentiated)
+    where a bias is given, ties to the lower expert index; the gates are
+    the chosen experts' scores without the bias, over ``sum + gate_eps``
+    where ``renormalise``, times ``scale``. Differentiable in the gates,
+    not in the choice.
+
+    ``held = (lo, hi)``: the caller holds experts ``lo <= e < hi`` alone
+    (a chip's share under expert parallelism). The choice and the gates
+    are over all ``E``; the groups are the held experts' (see
+    :class:`Routing`)."""
     from chainermn_tpu.observability.metrics import registry
 
     n_experts = router_w.shape[-1]
     if k > n_experts:
         raise ValueError(f"k={k} exceeds n_experts={n_experts}")
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"score must be 'softmax' or 'sigmoid', got "
+                         f"{score!r}")
+    lo, hi = held if held is not None else (0, n_experts)
+    if not 0 <= lo < hi <= n_experts:
+        raise ValueError(f"held={held} is no range of {n_experts} experts")
     # set while the caller's program is traced; the last layer traced is
     # what a scrape sees
     registry().gauge(
@@ -563,26 +588,55 @@ def dropless_topk(u, router_w, k: int, renormalise: bool = False) -> Routing:
         "experts a dropless MoE layer routes among, at the last call "
         "traced",
     ).set(float(n_experts))
+    registry().gauge(
+        train_path.MOE_EXPERTS_HELD,
+        "experts whose weights a dropless MoE layer holds (its share of "
+        "moe_experts_total), at the last call traced",
+    ).set(float(hi - lo))
     with jax.named_scope(train_path.MOE_ROUTE):
         logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, experts = lax.top_k(probs, k)
+        probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+            else jax.nn.sigmoid(logits)
+        if select_bias is None:
+            gates, experts = lax.top_k(probs, k)
+        else:
+            _, experts = lax.top_k(
+                probs + lax.stop_gradient(select_bias.astype(jnp.float32)),
+                k)
+            # the chosen scores without the bias, by a one-hot product: one
+            # term a sum, so exact, and no gather of T * k rows either way
+            gates = jnp.einsum(
+                "te,tke->tk", probs,
+                jax.nn.one_hot(experts, n_experts, dtype=probs.dtype))
         if renormalise:
-            gates = gates / gates.sum(-1, keepdims=True)
+            gates = gates / (gates.sum(-1, keepdims=True) + gate_eps)
+        if scale != 1.0:
+            gates = gates * scale
     with jax.named_scope(train_path.MOE_DISPATCH):
         flat = experts.reshape(-1).astype(jnp.int32)
         rows = jnp.arange(flat.shape[0], dtype=jnp.int32)
+        if (lo, hi) == (0, n_experts):
+            # every expert held: the one key path below gives the same
+            # values and costs the OLMoE cell 0.08% of its step (PERF.md
+            # section 6, PR 40)
+            keys = flat
+            rows_held = jnp.int32(flat.shape[0])
+        else:
+            # an absent expert's rows take the key past the last group
+            here = (flat >= lo) & (flat < hi)
+            keys = jnp.where(here, flat - lo, hi - lo)
+            rows_held = here.sum(dtype=jnp.int32)
         # a stable sort by expert keeps token order within an expert
-        by_expert, order = lax.sort((flat, rows), num_keys=1, is_stable=True)
+        by_expert, order = lax.sort((keys, rows), num_keys=1, is_stable=True)
         _, inverse = lax.sort((order, rows), num_keys=1)
         # where each expert's rows end in the sorted keys (cheaper on a
         # TPU than a scatter-add of one a row into the experts' counters)
         ends = jnp.searchsorted(
-            by_expert, jnp.arange(1, n_experts + 1, dtype=jnp.int32))
+            by_expert, jnp.arange(1, hi - lo + 1, dtype=jnp.int32))
         group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
     return Routing(gates, experts.astype(jnp.int32), order, inverse,
-                   group_sizes, logits)
+                   group_sizes, logits, rows_held)
 
 
 @jax.custom_vjp
@@ -644,21 +698,28 @@ def combine(y, routing: Routing):
         return out.astype(y.dtype)
 
 
-def dropless_aux(routing: Routing) -> dict:
+def dropless_aux(routing: Routing, losses: bool = True) -> dict:
     """The router's auxiliary losses and statistics of one layer:
-    ``load_balance`` (:func:`load_balancing_loss` over the top ``k``),
-    ``z_loss`` (``mean(logsumexp(logits)^2)``), ``expert_load`` (rows an
-    expert received, float32 ``[E]``) and ``dropped`` (the (token, slot)
-    rows that lie in no expert's group, ``tokens * k -
-    sum(group_sizes)``, counted from the routing the experts are given:
-    0 while this path keeps its word, since it has no capacity)."""
-    tokens, k = routing.gates.shape
+    ``load_balance`` (:func:`load_balancing_loss` over the top ``k``) and
+    ``z_loss`` (``mean(logsumexp(logits)^2)``), both of a softmax router
+    and left out with ``losses=False``; ``expert_load`` (rows a held
+    expert received, float32 ``[held]``), ``rows_held`` (the (token,
+    slot) rows whose expert is held: all ``tokens * k`` unless the layer
+    holds a share) and ``dropped`` (the rows routed to a held expert that
+    lie in no expert's group, ``rows_held - sum(group_sizes)``, counted
+    from the routing the experts are given: 0 while this path keeps its
+    word, since it has no capacity)."""
+    _, k = routing.gates.shape
     with jax.named_scope(train_path.MOE_ROUTE):
-        return {
+        aux = {
             "load_balance": load_balancing_loss(routing.logits, k=k),
             "z_loss": jnp.mean(
                 jax.nn.logsumexp(routing.logits, axis=-1) ** 2),
+        } if losses else {}
+        return {
+            **aux,
             "expert_load": routing.group_sizes.astype(jnp.float32),
-            "dropped": (tokens * k - routing.group_sizes.sum()).astype(
-                jnp.float32),
+            "rows_held": routing.rows_held.astype(jnp.float32),
+            "dropped": (routing.rows_held - routing.group_sizes.sum()
+                        ).astype(jnp.float32),
         }

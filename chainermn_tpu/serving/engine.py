@@ -502,6 +502,9 @@ class ServingEngine:
             from chainermn_tpu.models.transformer import refuse_looped_decode
 
             refuse_looped_decode(model, "serving")
+        from chainermn_tpu.models.transformer import refuse_unbuilt_decode
+
+        refuse_unbuilt_decode(model, "serving")
         if model.return_hidden or not model.causal:
             raise ValueError("serving needs a causal LM with logits "
                              "(return_hidden=False, causal=True)")

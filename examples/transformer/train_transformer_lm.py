@@ -18,6 +18,9 @@ parallelism for long context (``--sequence-parallel``).
     python examples/transformer/train_transformer_lm.py \
         --communicator xla --model ouro-2.6b --layers 2 \
         --batchsize 1 --seq-len 4096      # looped: 2 blocks, 4 passes
+    python examples/transformer/train_transformer_lm.py \
+        --communicator xla --model lfm2-8b-a1b --layers 3 \
+        --batchsize 1 --seq-len 4096      # conv, conv, attention; 32 experts
 """
 
 from __future__ import annotations
@@ -38,6 +41,7 @@ import chainermn_tpu
 from chainermn_tpu import global_except_hook
 from chainermn_tpu.models import (
     MODEL_CONFIGS,
+    ROUTER_STATE,
     TransformerLM,
     head_table,
     lm_from_config,
@@ -304,14 +308,23 @@ def run_named_model(args, comm, compute_dtype, rng):
         raise SystemExit(f"{args.model} has {model.max_len} positions")
     global_batch = args.batchsize * comm.size
     tokens0 = synthetic_tokens(rng, global_batch, args.seq_len)
-    params = jax.jit(model.init)(
-        jax.random.key(0), jnp.asarray(tokens0[:1])
-    )["params"]
+    variables = jax.jit(model.init)(
+        jax.random.key(0), jnp.asarray(tokens0[:1]))
+    params = variables["params"]
+    # a router's selection bias (LFM2's) is state, not a parameter: it
+    # rides the train state as model_state, here at its initial zero
+    router_state = variables.get(ROUTER_STATE, ())
 
-    if model.arch.n_experts:
+    if model.expert_layers:
+        # a sigmoid router has no auxiliary losses
+        coefs = {} if model.arch.router_score == "softmax" else dict(
+            load_balance_coef=0.0, z_loss_coef=0.0)
 
-        def loss_fn(params, tokens):
-            return lm_loss_moe(model, params, tokens)
+        def loss_fn(params, tokens, router_state=()):
+            loss, metrics = lm_loss_moe(
+                model, params, tokens, router_state=router_state or None,
+                **coefs)
+            return loss, (metrics, router_state)
     elif model.looped:
 
         def loss_fn(params, tokens):
@@ -324,7 +337,8 @@ def run_named_model(args, comm, compute_dtype, rng):
                                  tokens, compute_dtype=compute_dtype)
 
     optimizer = _make_optimizer(args, comm)
-    state = create_train_state(params, optimizer, comm)
+    state = create_train_state(params, optimizer, comm,
+                               model_state=router_state)
     step = make_train_step(loss_fn, optimizer, comm)
     t0 = time.perf_counter()
     for it in range(args.iterations):

@@ -106,11 +106,13 @@ def test_mnist_example_local_sgd_smoke():
     ])
 
 
-@pytest.mark.parametrize("model", ["olmoe-tiny", "gpt2-tiny"])
+@pytest.mark.parametrize("model", ["olmoe-tiny", "gpt2-tiny", "lfm2-tiny"])
 def test_transformer_example_named_model_smoke(model, monkeypatch, capsys):
     """``--model <name> --layers N`` builds a published architecture
-    through the model description (ISSUE 25); here two tiny stand-ins
-    under the published ones' ``model_type``s."""
+    through the model description (ISSUE 25); here three tiny stand-ins
+    under the published ones' ``model_type``s (LFM2's first three layers:
+    two short convolutions and an attention layer, the third with
+    experts)."""
     ex = _load_example("transformer", "train_transformer_lm.py")
     monkeypatch.setitem(ex.MODEL_CONFIGS, "olmoe-tiny", dict(
         ex.MODEL_CONFIGS["olmoe-1b-7b"], num_hidden_layers=4,
@@ -120,8 +122,15 @@ def test_transformer_example_named_model_smoke(model, monkeypatch, capsys):
     monkeypatch.setitem(ex.MODEL_CONFIGS, "gpt2-tiny", dict(
         ex.MODEL_CONFIGS["gpt2-medium"], n_layer=4, n_embd=32, n_head=2,
         n_inner=64, n_positions=64, vocab_size=1024))
+    monkeypatch.setitem(ex.MODEL_CONFIGS, "lfm2-tiny", dict(
+        ex.MODEL_CONFIGS["lfm2-8b-a1b"], hidden_size=32,
+        num_attention_heads=2, num_key_value_heads=1, intermediate_size=48,
+        moe_intermediate_size=16, num_experts=4, num_experts_per_tok=2,
+        vocab_size=1024, max_position_embeddings=64))
+    layers = 3 if model == "lfm2-tiny" else 1
     ex.main(["--iterations", "10", "--batchsize", "8", "--seq-len", "32",
-             "--model", model, "--layers", "1"])
+             "--model", model, "--layers", str(layers)])
     out = capsys.readouterr().out
-    assert f"done ({model}, 1 layers)" in out
+    assert f"done ({model}, {layers} layers)" in out
     assert ("load_balance=" in out) == (model == "olmoe-tiny")
+    assert ("rows_held=" in out) == (model != "gpt2-tiny")
