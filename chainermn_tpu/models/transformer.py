@@ -849,11 +849,13 @@ class TransformerBlock(nn.Module):
         """LFM2's gated short convolution: ``(B, C, x) = split3(h W_in)``,
         ``u = B * x``, a depthwise causal convolution of ``conv_width``
         taps over ``u`` (zero before the sequence starts, no bias),
-        ``(C * conv) W_out``. Plain ``jax.numpy`` in the compute dtype:
-        one shifted multiply-add a tap, under :data:`train_path.
-        SHORT_CONV` (the two projections are matmuls outside it)."""
+        ``(C * conv) W_out``. What lies between the two projections is
+        :func:`~chainermn_tpu.ops.short_conv.gated_short_conv`, under
+        :data:`train_path.SHORT_CONV` (the projections are matmuls
+        outside it)."""
+        from chainermn_tpu.ops.short_conv import gated_short_conv
+
         D, L = h.shape[-1], arch.conv_width
-        T = h.shape[1]
         bcx = nn.Dense(
             3 * D, use_bias=False, dtype=self.compute_dtype,
             param_dtype=jnp.float32, name="conv_in",
@@ -866,16 +868,7 @@ class TransformerBlock(nn.Module):
             lambda key, shape, dtype: jax.random.uniform(
                 key, shape, dtype, -bound, bound),
             (L, D), jnp.float32)
-        with jax.named_scope(train_path.SHORT_CONV):
-            b, c, x = jnp.split(bcx, 3, axis=-1)
-            u = b * x
-            w = taps.astype(self.compute_dtype)
-            conv = w[L - 1] * u
-            for j in range(L - 1):
-                back = L - 1 - j
-                conv = conv + w[j] * jnp.pad(
-                    u, ((0, 0), (back, 0), (0, 0)))[:, :T]
-            y = c * conv
+        y = gated_short_conv(bcx, taps)
         return nn.Dense(
             D, use_bias=False, dtype=self.compute_dtype,
             param_dtype=jnp.float32, name="conv_out",

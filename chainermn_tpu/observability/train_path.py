@@ -46,7 +46,9 @@ MOE_EXPERTS = "moe_experts"
 MOE_COMBINE = "moe_combine"
 #: a gated short convolution's mixer between its two projections: the
 #: split of the input projection, the two gates and the depthwise causal
-#: convolution (the projections themselves are matmuls outside it)
+#: convolution (the projections themselves are matmuls outside it): the
+#: two kernels of ``ops/short_conv.py`` (their ``name``s hold the scope's),
+#: or the plain spelling where the shape does not tile
 SHORT_CONV = "short_conv"
 #: a looped model: round the passes of the layer stack over one set of
 #: weights (sub-scope :func:`pass_scope` round each, final norm included);
@@ -150,3 +152,7 @@ LOOP_PASSES = "loop_passes"
 #: head's gradient inside its forward loop (it was differentiated), 0
 #: where it made the loss alone (evaluation)
 LM_HEAD_GRAD_IN_FORWARD = "lm_head_grad_in_forward"
+#: gauge set while a gated short convolution is traced: 1 where its
+#: gate-and-tap chain went through the two Pallas kernels, 0 where its
+#: shape does not tile and it took the plain ``jax.numpy`` spelling
+SHORT_CONV_FUSED = "short_conv_fused"

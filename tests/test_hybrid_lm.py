@@ -9,6 +9,7 @@ parameter trees and the softmax router's bits."""
 import dataclasses
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +30,7 @@ from chainermn_tpu.models import (
 from chainermn_tpu.models.transformer import TransformerBlock
 from chainermn_tpu.observability import train_path
 from chainermn_tpu.observability.metrics import registry
+from chainermn_tpu.ops import short_conv
 from chainermn_tpu.ops.flash_attention import flash_attention
 from chainermn_tpu.parallel import moe
 
@@ -476,24 +478,65 @@ def _gauge(name):
     return {tuple(sorted(r["labels"].items())): r["value"] for r in rows}
 
 
-def test_the_short_conv_scope_and_the_new_gauges_appear(tiny):
-    params, state, tokens = tiny
-    lowered = jax.jit(jax.grad(
-        lambda p: _system_loss(p, state, tokens)[0])).lower(params)
+#: the tiny preset at a width the short convolution's kernels tile (128
+#: lanes; 64 positions are four sublane tiles of float32), under remat
+TILING = {**TINY, "hidden_size": 128}
+
+
+def _call_sites(text, callee):
+    """The name stacks of the lowered module's calls of the functions named
+    ``callee`` (a jit of its own is a function of the module, and its ops
+    carry their names from the call's on)."""
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+    return [locs[m.group(1)] for m in re.finditer(
+        rf"call @{callee}\w*\(.*loc\((#loc\d+)\)", text)]
+
+
+@pytest.mark.parametrize("which", ["tiny", "tiling"])
+def test_the_short_conv_scope_and_the_new_gauges_appear(which, tiny):
+    if which == "tiny":
+        config, model_kw = TINY, {}
+        params, state, tokens = tiny
+    else:
+        config, model_kw = TILING, {"remat": True}
+        params, state, tokens = _init(TILING)
+
+    def loss(p):
+        return lm_loss_moe(_model(config, **model_kw), p, tokens,
+                           n_chunks=2, load_balance_coef=0.0,
+                           z_loss_coef=0.0, router_state=state)[0]
+
+    lowered = jax.jit(jax.grad(loss)).lower(params)
     text = lowered.as_text(debug_info=True)
     assert train_path.SHORT_CONV == "short_conv"
-    assert "/short_conv/" in text
-    # the backward of the scope is under it too
-    assert any("transpose(" in line and "/short_conv/" in line
-               for line in text.splitlines())
-    # the projections are matmuls outside the scope
-    assert not any("dot_general" in line and "/short_conv/" in line
-                   and "conv_in" in line for line in text.splitlines())
     assert _gauge(train_path.MOE_EXPERTS_HELD) == {(): 4.0}
     assert _gauge(train_path.MOE_EXPERTS_TOTAL) == {(): 8.0}
     assert _gauge(train_path.STACK_LAYERS_BY_KIND) == {
         (("kind", "attention"),): 1.0, (("kind", "short_conv"),): 4.0,
         (("kind", "dense_ffn"),): 1.0, (("kind", "expert_ffn"),): 4.0}
+    # the projections are matmuls outside the scope
+    assert not any("dot_general" in line and "/short_conv/" in line
+                   and "conv_in" in line for line in text.splitlines())
+    if which == "tiny":
+        # a width of 64 does not tile: the plain spelling, under the scope
+        assert _gauge(train_path.SHORT_CONV_FUSED) == {(): 0.0}
+        assert "/short_conv/" in text
+        # the backward of the scope is under it too
+        assert any("transpose(" in line and "/short_conv/" in line
+                   for line in text.splitlines())
+        return
+    assert _gauge(train_path.SHORT_CONV_FUSED) == {(): 1.0}
+    # both kernels are named and lie under the scope ...
+    for kernel in (short_conv.FWD, short_conv.BWD):
+        assert f'"short_conv/{kernel}/pallas_call"' in text
+    # ... in functions of their own, called forward, recomputed and
+    # transposed: four layers of each
+    sites = _call_sites(text, "_fused")
+    recomputed = [s for s in sites if train_path.REMAT_MARKER in s]
+    transposed = [s for s in sites if train_path.BACKWARD_MARKER in s
+                  and train_path.REMAT_MARKER not in s]
+    assert (len(sites), len(recomputed), len(transposed)) == (12, 4, 4)
+    assert all("_short_conv/jit(_fused)" in s for s in sites)
 
 
 def test_a_model_without_a_description_by_layer_counts_its_kinds():

@@ -3,7 +3,9 @@ is described and not attached (no chip, no time): the option
 ``make_train_step`` gives its jit on several TPU devices is one this
 libtpu knows, and under it the default gradient reduction's all_to_alls
 come out as asynchronous pairs. A libtpu that renames or drops the
-option fails here, and not in a user's step.
+option fails here, and not in a user's step. And Mosaic takes the gated
+short convolution's two kernels at the LFM2 cell's shape, which the
+interpreter's tests cannot say (it accepts layouts Mosaic refuses).
 
 One file, one fixture: only one process may load the TPU's library, so
 the topology is described inside the fixture and nowhere at import."""
@@ -16,6 +18,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from chainermn_tpu import create_communicator, create_multi_node_optimizer
+from chainermn_tpu.ops import short_conv
 from chainermn_tpu.parallel import collectives
 from chainermn_tpu.training import make_train_step
 from chainermn_tpu.training.train_step import TrainState
@@ -105,3 +108,28 @@ def test_without_the_option_the_all_to_alls_are_synchronous(topo):
         jax.jit(fn, compiler_options=collectives.ASYNC_ALL_TO_ALL)
         .lower(g).compile().as_text())
     assert pairs + again == 2 and never == 0
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((2, 8192, 2048, 3), jnp.bfloat16),  # the LFM2 cell's
+    ((1, 64, 128, 4), jnp.float32),
+], ids=["cell_bf16", "small_f32"])
+@pytest.mark.parametrize("kernel", [short_conv.FWD, short_conv.BWD])
+def test_mosaic_compiles_the_short_conv_kernels(kernel, shape, dtype, topo):
+    from jax.sharding import SingleDeviceSharding
+
+    B, T, D, L = shape
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    geometry = short_conv._geometry(T, D, L, dtype)
+    bcx = jax.ShapeDtypeStruct((B, T, 3 * D), dtype, sharding=one_chip)
+    taps = jax.ShapeDtypeStruct((L, D), jnp.float32, sharding=one_chip)
+    dy = jax.ShapeDtypeStruct((B, T, D), dtype, sharding=one_chip)
+    if kernel == short_conv.FWD:
+        lowered = jax.jit(lambda a, w: short_conv._forward(
+            a, w, geometry, False)).lower(bcx, taps)
+    else:
+        lowered = jax.jit(lambda a, w, g: short_conv._backward(
+            a, w, g, geometry, False)).lower(bcx, taps, dy)
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"short_conv/{kernel}" in text

@@ -49,6 +49,12 @@ Checked kernels:
   under ``_TOL``), and compiled at the OLMoE cell's shapes (131,072 rows,
   64 experts, 2048 x 2048 and 1024 x 2048); ``timings`` carries its
   forward and forward + backward there
+- the gated short convolution's gate-and-tap chain
+  (``ops/short_conv.py``): forward and both gradients at the LFM2 cell's
+  shape (``[2, 8192, 6144]`` bf16, 3 taps) and at a small float32 one of
+  4 taps, run against the plain ``jax.numpy`` spelling in float32
+  (``rel_err`` under ``_TOL``); ``timings`` carries the kernels' and the
+  plain spelling's forward and forward + backward at the cell's shape
 - fused paged decode (ISSUE 19): plain tick T=1, verify span T>1,
   window, and the dense-cache wrapper — the ``(1, bs, 1, D)`` KV block
   (second-to-last dim 1 over the kv-head axis) is exactly the kind of
@@ -99,6 +105,22 @@ def _group_sizes(noise, rows: int):
     return sizes.at[0].add(rows - sizes.sum())
 
 
+def _short_conv_plain32(bcx, taps):
+    """The short convolution's plain spelling in float32 on what the
+    operands' dtype holds, rounded once. The taps are rounded by
+    ``reduce_precision``: XLA takes a cast there and back again for excess
+    precision it may keep."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu.ops.short_conv import plain
+
+    held = jnp.finfo(bcx.dtype)
+    return plain(bcx.astype(jnp.float32),
+                 jax.lax.reduce_precision(taps, held.nexp, held.nmant)
+                 ).astype(bcx.dtype)
+
+
 def _note(msg: str) -> None:
     print(f"[kernel-check] {msg}", file=sys.stderr, flush=True)
 
@@ -123,6 +145,7 @@ def _cases():
         dense_flash_decode,
         paged_flash_decode,
     )
+    from chainermn_tpu.ops.short_conv import gated_short_conv
     from chainermn_tpu.parallel.local_attention import (
         _WRAP_SENTINEL,
         _pad_ext_to_block,
@@ -229,6 +252,25 @@ def _cases():
 
     gmm_small = gmm_specs(8192, 16, 1024, 512)
 
+    # The gated short convolution: the reference is the plain spelling in
+    # float32 on what the operands' dtype holds; the gradients' cotangent
+    # varies along all three axes.
+    def conv_grads(fn):
+        def weighed(bcx, taps):
+            y = fn(bcx, taps).astype(jnp.float32)
+            ramp = sum(jnp.cos(jax.lax.broadcasted_iota(
+                jnp.float32, y.shape, axis) * (0.37 + axis))
+                for axis in range(3))
+            return (y * ramp).sum()
+        return jax.grad(weighed, argnums=(0, 1))
+
+    def conv_specs(B, T, D, L, dtype):
+        return (jax.ShapeDtypeStruct((B, T, 3 * D), dtype),
+                jax.ShapeDtypeStruct((L, D), jnp.float32))
+
+    conv_cell = conv_specs(2, 8192, 2048, 3, dt)
+    conv_small = conv_specs(2, 64, 128, 4, jnp.float32)
+
     # The two variants Mosaic rejected, at bench's kernel-sweep shape.
     Bs, Ts, Hs, Ds = 2, 2048, 8, 128
     qs = jax.ShapeDtypeStruct((Bs, Ts, Hs, Ds), dt)
@@ -325,6 +367,13 @@ def _cases():
          gmm_specs(131072, 64, 2048, 2048), None),
         ("grouped_matmul_olmoe_down", gmm_grads(gmm),
          gmm_specs(131072, 64, 1024, 2048), None),
+        ("short_conv_fwd", gated_short_conv, conv_cell, _short_conv_plain32),
+        ("short_conv_grads", conv_grads(gated_short_conv), conv_cell,
+         conv_grads(_short_conv_plain32)),
+        ("short_conv_small_f32_fwd", gated_short_conv, conv_small,
+         _short_conv_plain32),
+        ("short_conv_small_f32_grads", conv_grads(gated_short_conv),
+         conv_small, conv_grads(_short_conv_plain32)),
         ("flash_bwd_window", grads(functools.partial(flash, window=1024)),
          (q, kv, kv), grads(xla_window)),
         ("flash_bwd_bias_grad",
@@ -431,7 +480,58 @@ def _timings():
             samples.append((time.perf_counter() - t0) / iters * 1e3)
         rows.append({"shape": f"B{B}xT{T}xH{H}xD{D}_bf16_causal",
                      "flash_fwdbwd_ms": [round(x, 4) for x in samples]})
-    return rows + _grouped_matmul_timings()
+    return rows + _grouped_matmul_timings() + _short_conv_timings()
+
+
+def _chained_ms(fn, iters: int, *args):
+    """ms a call of ``fn``, which runs ``iters`` chained calls in one
+    program: three samples after a compiling one."""
+    float(fn(*args))  # compile + warm
+    samples = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        float(fn(*args))
+        samples.append((time.perf_counter() - t0) / iters * 1e3)
+    return [round(x, 4) for x in samples]
+
+
+def _short_conv_timings(iters: int = 20):
+    """The gated short convolution's chain at the LFM2 cell's shape
+    (``[2, 8192, 6144]`` bf16, 3 taps): the two kernels and the plain
+    spelling, forward, and forward + both gradients, ms a call. The chain
+    runs through the taps (a few KB), so nothing but the op itself moves
+    the operands."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu.ops import short_conv
+
+    keys = jax.random.split(jax.random.PRNGKey(41), 2)
+    bcx = jax.random.normal(keys[0], (2, 8192, 3 * 2048), jnp.bfloat16)
+    taps = jax.random.uniform(keys[1], (3, 2048), jnp.float32, -0.5, 0.5)
+
+    def chained(step):
+        return jax.jit(lambda a, w: jax.lax.scan(
+            lambda w, _: (step(a, w), ()), w, None, length=iters)[0].sum())
+
+    def fwd(op):
+        return chained(lambda a, w: w + 1e-9 * op(a, w)[0, :3].astype(
+            jnp.float32))
+
+    def fwdbwd(op):
+        def step(a, w):
+            y, vjp = jax.vjp(op, a, w)
+            da, dw = vjp(y)
+            return w + 1e-9 * dw + 1e-9 * da[0, :3, :2048].astype(
+                jnp.float32)
+        return chained(step)
+
+    row = {"shape": "B2xT8192xD2048xL3_bf16"}
+    for name, op in (("short_conv", short_conv.gated_short_conv),
+                     ("short_conv_plain", short_conv.plain)):
+        row[f"{name}_fwd_ms"] = _chained_ms(fwd(op), iters, bcx, taps)
+        row[f"{name}_fwdbwd_ms"] = _chained_ms(fwdbwd(op), iters, bcx, taps)
+    return [row]
 
 
 def _grouped_matmul_timings(iters: int = 5):
@@ -443,15 +543,6 @@ def _grouped_matmul_timings(iters: int = 5):
     import jax.numpy as jnp
 
     from chainermn_tpu.ops.grouped_matmul import grouped_matmul
-
-    def timed(fn, *args):
-        float(fn(*args))  # compile + warm
-        samples = []
-        for _ in range(3):
-            t0 = time.perf_counter()
-            float(fn(*args))
-            samples.append((time.perf_counter() - t0) / iters * 1e3)
-        return [round(x, 4) for x in samples]
 
     rows = []
     for k_dim, n_dim in ((2048, 2048), (1024, 2048)):
@@ -481,9 +572,10 @@ def _grouped_matmul_timings(iters: int = 5):
                 .astype(jnp.float32).sum()
 
         rows.append({"shape": f"M131072xE64xK{k_dim}xN{n_dim}_bf16",
-                     "gmm_fwd_ms": timed(jax.jit(fwd), lhs, rhs, sizes),
-                     "gmm_fwdbwd_ms": timed(jax.jit(fwdbwd), lhs, rhs,
-                                            sizes)})
+                     "gmm_fwd_ms": _chained_ms(jax.jit(fwd), iters, lhs,
+                                               rhs, sizes),
+                     "gmm_fwdbwd_ms": _chained_ms(jax.jit(fwdbwd), iters,
+                                                  lhs, rhs, sizes)})
     return rows
 
 
@@ -510,7 +602,7 @@ def main() -> int:
                     'custom_call_target="tpu_custom_call"')
                 row["ok"] = row["mosaic_calls"] == _MOSAIC_CALLS[name]
             if ref is not None:
-                _note(f"running {name} against XLA's attention")
+                _note(f"running {name} against its reference")
                 inputs = _seeded(specs)
                 row["rel_err"] = _rel_err(compiled(*inputs),
                                           jax.jit(ref)(*inputs))
