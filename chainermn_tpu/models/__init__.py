@@ -19,7 +19,12 @@ from chainermn_tpu.models.transformer import (
     Architecture,
     TransformerLM,
     head_table,
+    block_diffusion_noise,
+    block_diffusion_rows,
+    diffusion_noise_key,
+    diffusion_noise_state,
     lm_from_config,
+    lm_loss_block_diffusion,
     lm_loss_looped,
     lm_loss_moe,
     mlm_corrupt,
@@ -62,6 +67,11 @@ __all__ = [
     "head_table",
     "lm_loss_moe",
     "lm_loss_looped",
+    "lm_loss_block_diffusion",
+    "block_diffusion_noise",
+    "block_diffusion_rows",
+    "diffusion_noise_state",
+    "diffusion_noise_key",
     "mlm_corrupt",
     "mlm_loss",
     "lm_loss",

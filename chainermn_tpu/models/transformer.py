@@ -101,6 +101,14 @@ class Architecture:
     #: attention and, where there are experts, experts (:meth:`layer`)
     layers: Optional[tuple] = None
     conv_width: int = 0
+    #: positions a block of a block-diffusion model holds (SDAR's; 0: the
+    #: model is trained left to right). Its training pass is one forward
+    #: over a clean and a noised copy of every sequence, ``[x ; x~]``,
+    #: ``2L`` rows at positions ``[0..L-1 ; 0..L-1]``, under
+    #: :func:`~chainermn_tpu.ops.block_diffusion.block_diffusion_attention`'s
+    #: mask by blocks, and its loss :func:`lm_loss_block_diffusion`.
+    #: Training only
+    diffusion_block: int = 0
 
     def __post_init__(self):
         if self.qk_norm is True:
@@ -126,6 +134,12 @@ class Architecture:
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score must be 'softmax' or "
                              f"'sigmoid', got {self.router_score!r}")
+        if self.diffusion_block < 0 or (self.diffusion_block and (
+                self.exit_gate or self.has_short_conv)):
+            raise ValueError(
+                "diffusion_block is a block's length (0: none); a block-"
+                "diffusion model with an exit gate or short_conv layers "
+                "is not built")
         if self.experts_held is not None:
             lo, hi = self.experts_held
             if not 0 <= lo < hi <= self.n_experts:
@@ -228,7 +242,59 @@ class Architecture:
             )
         if kind == "lfm2_moe":
             return cls._from_lfm2_moe(config)
+        if kind == "sdar_moe":
+            return cls._from_sdar_moe(config)
         raise ValueError(f"no block is described for model_type {kind!r}")
+
+    @staticmethod
+    def _experts_share(config: dict) -> tuple:
+        """``(router width, held range or None)`` of a file that may hold
+        a chip's share of the experts: the experts it holds as
+        ``num_experts`` and ``experts_held_range``, the router's width as
+        ``experts_published``."""
+        n_experts = int(config.get("experts_published",
+                                   config["num_experts"]))
+        held = config.get("experts_held_range")
+        if (held is None) != (n_experts == config["num_experts"]) or (
+                held is not None
+                and held[1] - held[0] != config["num_experts"]):
+            raise ValueError(
+                "a share of the experts is spelled num_experts (held), "
+                "experts_published (the router's width) and "
+                "experts_held_range [lo, hi) of num_experts entries")
+        return n_experts, tuple(held) if held is not None else None
+
+    @classmethod
+    def _from_sdar_moe(cls, config: dict) -> "Architecture":
+        """SDAR-MoE: Qwen3-MoE's layers (RMSNorm, grouped-query attention
+        with a norm over each head's values of ``q`` and of ``k`` before
+        RoPE, top-``k`` of gated-SiLU experts behind a softmax router in
+        every layer, an untied head) trained by block diffusion at
+        ``block_length`` (no key of the published ``config.json``: the
+        released checkpoints' default is 4)."""
+        if config.get("hidden_act", "silu") != "silu" or \
+                config.get("attention_bias") or \
+                config.get("use_sliding_window") or \
+                config.get("rope_scaling") is not None or \
+                config.get("mlp_only_layers") or \
+                config.get("decoder_sparse_step", 1) != 1:
+            raise ValueError(
+                "an sdar_moe config with another activation, attention "
+                "biases, a sliding window, scaled RoPE or layers without "
+                "experts is not built here")
+        n_experts, held = cls._experts_share(config)
+        return cls(
+            norm="rmsnorm", norm_eps=float(config["rms_norm_eps"]),
+            ffn="gated_silu", qk_norm="head", positions="rope",
+            rope_base=float(config["rope_theta"]),
+            tied_head=bool(config["tie_word_embeddings"]),
+            n_experts=n_experts,
+            experts_per_token=int(config["num_experts_per_tok"]),
+            expert_width=int(config["moe_intermediate_size"]),
+            renormalise_gates=bool(config["norm_topk_prob"]),
+            experts_held=held,
+            diffusion_block=int(config.get("block_length", 4)),
+        )
 
     @classmethod
     def _from_lfm2_moe(cls, config: dict) -> "Architecture":
@@ -249,16 +315,7 @@ class Architecture:
                 "layer_types that do not count num_hidden_layers or a "
                 f"layer type other than {sorted(mixers)} is not built "
                 f"here (layer types not known: {unknown})")
-        n_experts = int(config.get("experts_published",
-                                   config["num_experts"]))
-        held = config.get("experts_held_range")
-        if (held is None) != (n_experts == config["num_experts"]) or (
-                held is not None
-                and held[1] - held[0] != config["num_experts"]):
-            raise ValueError(
-                "a share of the experts is spelled num_experts (held), "
-                "experts_published (the router's width) and "
-                "experts_held_range [lo, hi) of num_experts entries")
+        n_experts, held = cls._experts_share(config)
         dense = int(config["num_dense_layers"])
         renorm = bool(config["norm_topk_prob"])
         return cls(
@@ -273,7 +330,7 @@ class Architecture:
             router_bias=bool(config["use_expert_bias"]),
             gate_eps=1e-6 if renorm else 0.0,
             routed_scaling=float(config["routed_scaling_factor"]),
-            experts_held=tuple(held) if held is not None else None,
+            experts_held=held,
             layers=tuple((mixers[kind], "dense" if i < dense else "experts")
                          for i, kind in enumerate(kinds)),
             conv_width=int(config["conv_L_cache"]),
@@ -333,6 +390,22 @@ MODEL_CONFIGS = {
         "conv_L_cache": 3, "conv_bias": False, "norm_eps": 1e-5,
         "rope_theta": 1000000, "vocab_size": 65536,
         "max_position_embeddings": 128000,
+    },
+    "sdar-30b-a3b": {
+        "model_type": "sdar_moe", "num_hidden_layers": 48,
+        "hidden_size": 2048, "num_attention_heads": 32,
+        "num_key_value_heads": 4, "head_dim": 128,
+        "intermediate_size": 6144, "moe_intermediate_size": 768,
+        "num_experts": 128, "num_experts_per_tok": 8,
+        "norm_topk_prob": True, "decoder_sparse_step": 1,
+        "mlp_only_layers": [], "hidden_act": "silu",
+        "rms_norm_eps": 1e-6, "rope_theta": 1000000, "rope_scaling": None,
+        "attention_bias": False, "use_sliding_window": False,
+        "sliding_window": None, "tie_word_embeddings": False,
+        "vocab_size": 151936, "max_position_embeddings": 32768,
+        # no key of the published config.json: the released checkpoints'
+        # default block length
+        "block_length": 4,
     },
 }
 
@@ -967,8 +1040,25 @@ class TransformerBlock(nn.Module):
                     self.sow("kv_out", "v", vh.astype(self.compute_dtype))
                 kw = {} if segment_ids is None \
                     else {"segment_ids": segment_ids}
-                o = attn(qh, kh, vh, causal=self.causal,
-                         scale=head_dim**-0.5, **kw)
+                if arch.diffusion_block:
+                    # the rows are [x ; x~]: the mask is the model's own
+                    # and no pluggable attention_fn knows it
+                    if kw or self.window is not None or not self.causal \
+                            or self.sow_kv or self.tp_axis is not None:
+                        raise ValueError(
+                            "a block-diffusion model's attention is its "
+                            "mask by blocks over [x ; x~]: no segment "
+                            "ids, window, bidirectional blocks, captured "
+                            "keys or tensor parallelism yet")
+                    from chainermn_tpu.ops.block_diffusion import (
+                        block_diffusion_attention,
+                    )
+                    o = block_diffusion_attention(
+                        qh, kh, vh, block_length=arch.diffusion_block,
+                        scale=head_dim**-0.5)
+                else:
+                    o = attn(qh, kh, vh, causal=self.causal,
+                             scale=head_dim**-0.5, **kw)
             o_flat = o.reshape(B, T, self.num_heads * head_dim)
             o = nn.Dense(
                 D, use_bias=False,
@@ -1110,6 +1200,13 @@ def refuse_unbuilt_decode(model, what: str):
     arch = model.arch
     if arch is None:
         return
+    if arch.diffusion_block:
+        raise NotImplementedError(
+            f"{what} of a block-diffusion model (blocks of "
+            f"{arch.diffusion_block}) is not built: a step yields a block "
+            "over several denoising passes and the cache is written when "
+            "a block is final; the training path "
+            "(lm_loss_block_diffusion) is")
     if arch.has_short_conv:
         raise NotImplementedError(
             f"{what} of a model with short_conv layers is not built: it "
@@ -1378,6 +1475,11 @@ class TransformerLM(nn.Module):
             x = jnp.take(emb.embedding, tokens, axis=0).astype(
                 self.compute_dtype)
         rope_positions = None
+        if arch.diffusion_block and positions is None and not decode:
+            # the rows are [x ; x~]: the noised copy sits at the clean
+            # copy's positions
+            positions = self.pos_offset + jnp.tile(
+                jnp.arange(T // 2, dtype=jnp.int32), 2)
         if arch.positions == "rope":
             if positions is None:
                 positions = self.pos_offset + jnp.arange(T, dtype=jnp.int32)
@@ -1520,6 +1622,11 @@ def lm_from_config(config: dict, *, num_layers: Optional[int] = None,
             max_len=config["max_position_embeddings"],
             total_ut_steps=config.get("total_ut_steps", 1),
         )
+        if config.get("head_dim") not in (None, sizes["d_model"]
+                                          // sizes["num_heads"]):
+            # heads wider than width / heads (SDAR's 32 heads of 128 on a
+            # width of 2048)
+            sizes["head_dim"] = int(config["head_dim"])
     if num_layers is not None:
         sizes["num_layers"] = num_layers
     sizes.update(kwargs)
@@ -1573,7 +1680,7 @@ def mlm_corrupt(rng, tokens, *, mask_id, vocab_size, rate=0.15):
 
 
 def lm_loss_fused(hidden, emb_table, tokens, *, n_chunks=8,
-                  compute_dtype=jnp.bfloat16, weights=None):
+                  compute_dtype=jnp.bfloat16, weights=None, shift=True):
     """Fused chunked LM-head + next-token cross-entropy.
 
     The naive head materializes ``[B, T, vocab]`` f32 logits (≈ 4·B·T·V
@@ -1603,10 +1710,14 @@ def lm_loss_fused(hidden, emb_table, tokens, *, n_chunks=8,
         in the weights, each row's cross-entropy being its gradient
         (:func:`lm_loss_looped`'s exit probabilities). ``None`` is all
         ones: the mean.
+      shift: ``False`` takes the row at position ``t`` as the prediction
+        of ``tokens[b, t]`` itself (a denoiser's: :func:`lm_loss_block_
+        diffusion`): all ``B*T`` rows count, and ``weights`` is
+        ``[B, T]``.
     """
     with jax.named_scope(train_path.LM_HEAD):
         return _lm_loss_fused(hidden, emb_table, tokens, n_chunks,
-                              compute_dtype, weights)
+                              compute_dtype, weights, shift)
 
 
 def _publish_head_grad_in_forward(engaged: bool):
@@ -1634,10 +1745,10 @@ def _head_chunk(hc, table, tc):
 
 
 def _lm_loss_fused(hidden, emb_table, tokens, n_chunks, compute_dtype,
-                   weights):
+                   weights, shift=True):
     B, T, D = hidden.shape
-    h = hidden[:, :-1].reshape(-1, D)
-    t = tokens[:, 1:].reshape(-1)
+    h = (hidden[:, :-1] if shift else hidden).reshape(-1, D)
+    t = (tokens[:, 1:] if shift else tokens).reshape(-1)
     n = h.shape[0]
     chunk = -(-n // n_chunks)  # ceil
     pad = chunk * n_chunks - n
@@ -1730,6 +1841,14 @@ def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
     hidden, sown = model.apply(variables, tokens, mutable=[MOE_AUX])
     ce = lm_loss_fused(hidden, head_table(params, model.arch), tokens,
                        n_chunks=n_chunks, compute_dtype=model.compute_dtype)
+    return _with_router_aux(ce, model, sown, load_balance_coef, z_loss_coef)
+
+
+def _with_router_aux(loss, model: "TransformerLM", sown, load_balance_coef,
+                     z_loss_coef):
+    """``(loss + the router's auxiliary losses, metrics)`` from what the
+    expert layers of one ``apply`` sowed into :data:`MOE_AUX`
+    (:func:`lm_loss_moe` says which metrics)."""
     # one entry a block that has experts, each a 1-tuple (sow appends)
     layers = [{k: v[0] for k, v in sown[MOE_AUX][f"block_{i}"].items()}
               for i in model.expert_layers]
@@ -1744,7 +1863,6 @@ def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
         "moe/dropped": over_layers("dropped", jnp.sum),
         "moe/expert_load": load,
     }
-    loss = ce
     for name, coef in (("load_balance", load_balance_coef),
                        ("z_loss", z_loss_coef)):
         if name in layers[0]:
@@ -1754,6 +1872,112 @@ def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
             raise ValueError(
                 f"{name} is a softmax router's auxiliary loss; this "
                 f"model's router has none (coefficient {coef})")
+    return loss, metrics
+
+
+def diffusion_noise_state(seed: int) -> dict:
+    """The ``model_state`` a block-diffusion train step carries so that
+    every step draws its own noise inside the compiled step: the seed as
+    two 16-bit halves and the count of draws made, all float32, because a
+    train step averages ``model_state`` over its shards (``lax.pmean``)
+    and an average of equal small integers is exact in float32 alone."""
+    seed = int(seed) & 0xFFFFFFFF
+    return {"seed": jnp.array([seed >> 16, seed & 0xFFFF], jnp.float32),
+            "draw": jnp.float32(0.0)}
+
+
+def diffusion_noise_key(state: dict):
+    """``(key, next state)``: the key of this step's draw, and the state
+    with one more draw counted."""
+    hi, lo = (state["seed"][i].astype(jnp.uint32) for i in (0, 1))
+    key = jax.random.fold_in(
+        jax.random.fold_in(jax.random.key(0), (hi << 16) | lo),
+        state["draw"].astype(jnp.uint32))
+    return key, {**state, "draw": state["draw"] + 1.0}
+
+
+def block_diffusion_noise(key, shape, *, block_length: int,
+                          t_min: float = 0.05):
+    """The noise of one training pass over ``shape = (B, L)`` tokens
+    (BD3-LMs' linear schedule): a level ``t ~ U[t_min, 1]`` a block of
+    ``block_length`` positions and a mask ``m_i ~ Bernoulli(t_b(i))`` a
+    token. Returns ``(masked [B, L] bool, t [B, L // block_length]
+    float32)``."""
+    B, L = shape
+    k_t, k_m = jax.random.split(key)
+    t = jax.random.uniform(k_t, (B, L // block_length), jnp.float32,
+                           t_min, 1.0)
+    masked = jax.random.uniform(k_m, (B, L), jnp.float32) \
+        < jnp.repeat(t, block_length, axis=1)
+    return masked, t
+
+
+def block_diffusion_rows(tokens, masked, t, *, mask_id: int):
+    """What one training pass puts through the stack and weighs its loss
+    by: ``(rows [B, 2L], positions [2L], weights [B, L])``, the rows ``[x
+    ; x~]`` with ``x~_i = mask_id`` where ``masked`` else ``x_i``, the
+    noised copy at the clean copy's positions, and the loss's weights
+    ``m_i / t_b(i)``."""
+    L = tokens.shape[1]
+    noised = jnp.where(masked, jnp.asarray(mask_id, tokens.dtype), tokens)
+    weights = masked.astype(jnp.float32) \
+        / jnp.repeat(t, L // t.shape[1], axis=1)
+    return (jnp.concatenate([tokens, noised], axis=1),
+            jnp.tile(jnp.arange(L, dtype=jnp.int32), 2), weights)
+
+
+def lm_loss_block_diffusion(model: "TransformerLM", params, tokens, key=None,
+                            *, mask_id: int, t_min: float = 0.05,
+                            n_chunks=8, load_balance_coef=0.001,
+                            noise=None):
+    """Loss of a block-diffusion model (``arch.diffusion_block``; build it
+    with ``return_hidden=True``) on clean ``tokens [B, L]`` (BD3-LMs,
+    arXiv:2503.09573, which SDAR adopts): with ``bl`` the block length and
+    ``b(i) = i // bl``, a level ``t_b ~ U[t_min, 1]`` a block and a mask
+    ``m_i ~ Bernoulli(t_b(i))`` a token (:func:`block_diffusion_noise`
+    from ``key``, drawn here, inside the caller's program; or ``noise =
+    (masked, t)`` as data), ``x~_i = mask_id`` where ``m_i`` else ``x_i``,
+    one forward over ``[x ; x~]`` under the mask by blocks, and
+
+        loss = 1 / (B L) sum_i m_i / t_b(i) CE(logits(noised row i), x_i)
+
+    the noised row at position ``i`` predicting token ``i`` itself (no
+    shift), through the fused head over the ``L`` noised rows alone, plus
+    ``load_balance_coef`` x the router's load-balancing loss, the mean
+    over the layers, each layer's over the ``2L`` rows it routes.
+
+    Returns ``(loss, metrics)``: :func:`lm_loss_moe`'s ``moe/`` metrics,
+    ``bd/masked_share`` (the mean of ``m``) and ``bd/mean_weight`` (the
+    mean of ``m / t``, 1 in expectation)."""
+    from chainermn_tpu.observability.metrics import registry
+
+    bl = model.arch.diffusion_block if model.arch else 0
+    B, L = tokens.shape
+    if not bl or L % bl:
+        raise ValueError(
+            f"lm_loss_block_diffusion needs a block-diffusion model and "
+            f"whole blocks: block length {bl}, {L} tokens a row")
+    if (key is None) == (noise is None):
+        raise ValueError("give the key the noise is drawn from, or the "
+                         "noise itself (masked, t): one of the two")
+    registry().gauge(
+        train_path.BD_ROWS_PER_STEP,
+        "rows a block-diffusion step puts through the stack (the clean "
+        "and the noised copy of every token), at the last loss traced",
+    ).set(float(2 * B * L))
+    with jax.named_scope(train_path.BD_NOISE):
+        masked, t = noise if noise is not None else block_diffusion_noise(
+            key, (B, L), block_length=bl, t_min=t_min)
+        rows, positions, weights = block_diffusion_rows(
+            tokens, masked, t, mask_id=mask_id)
+    hidden, sown = model.apply({"params": params}, rows,
+                               positions=positions, mutable=[MOE_AUX])
+    ce = lm_loss_fused(hidden[:, L:], head_table(params, model.arch), tokens,
+                       n_chunks=n_chunks, compute_dtype=model.compute_dtype,
+                       weights=weights, shift=False)
+    loss, metrics = _with_router_aux(ce, model, sown, load_balance_coef, 0.0)
+    metrics["bd/masked_share"] = masked.mean(dtype=jnp.float32)
+    metrics["bd/mean_weight"] = weights.mean()
     return loss, metrics
 
 

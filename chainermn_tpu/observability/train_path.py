@@ -56,6 +56,13 @@ SHORT_CONV = "short_conv"
 #: the weighting of the exits
 LOOP_STACK = "loop_stack"
 EXIT_GATE = "exit_gate"
+#: a block-diffusion model's training pass: round the draw of a noise
+#: level a block and a mask a token, the noised copy, the concatenation
+#: ``[x ; x~]`` and its positions; round the masked attention over the
+#: two copies (the flash kernels' clean-on-clean and noised-on-clean
+#: calls, the in-block part and the merge by log-sum-exps)
+BD_NOISE = "bd_noise"
+BD_ATTENTION = "bd_attention"
 
 #: how JAX marks the transposed (backward) and the recomputed code of a
 #: scope in ``op_name``
@@ -148,6 +155,12 @@ STACK_LAYERS_BY_KIND = "stack_layers_by_kind"
 #: gauge set while a looped model is traced: passes of its layer stack
 #: over one set of weights
 LOOP_PASSES = "loop_passes"
+#: gauges set while a block-diffusion model's loss is traced: the
+#: positions a block of its mask holds (set by the attention), and the
+#: rows a step puts through the stack (``B * 2L``: the clean and the
+#: noised copy; the head sees half of them)
+BD_BLOCK_LENGTH = "bd_block_length"
+BD_ROWS_PER_STEP = "bd_rows_per_step"
 #: gauge set while the fused LM head is traced: 1 where the trace made the
 #: head's gradient inside its forward loop (it was differentiated), 0
 #: where it made the loss alone (evaluation)

@@ -39,8 +39,15 @@ former 512 x 1024 default 2 of 2, 6 of 8 and 20 of 32, every one masked.
 A window, or a ``q_offset`` off the sub-tile grid, masks a crossed tile
 whole in every kernel; segment ids and a bias keep their work on every
 visited sub-tile, and with either, or a window, dk/dv keeps the 512 x 1024
-tile. The gauge ``flash_tiles`` (labels ``kernel``, ``kind``) says what
-the last call of the op does.
+tile. A causal mask by blocks (``causal_block``, ``causal_strict``: block
+diffusion's, a query sees the keys of earlier blocks of ``bl`` positions
+and, unless strict, of its own) is the same stair with steps of ``bl``:
+the diagonal is the queries' :func:`_horizon`, tiles are sorted, skipped
+and walked by it, and as long as ``bl`` divides the sub-tile the stair
+stays inside the one sub-tile the diagonal crosses (at ``bl`` 4 and T
+8192 the tiles visited are the causal mask's). With blocks of one,
+inclusive, nothing of a kernel changes. The gauge ``flash_tiles`` (labels
+``kernel``, ``kind``) says what the last call of the op does.
 
 Layout: ``[B, T, H, D]`` at the API, and the kernels read and write the
 layout the projections round them produce and consume (:class:`_Layout`):
@@ -102,13 +109,61 @@ _GRID_SEMANTICS = pltpu.CompilerParams(
 )
 
 
-def _causal_mask(q0, k0, shape, window=None):
+def _blocks(causal_block: int, causal_strict: bool):
+    """The kernels' static description of a causal mask by blocks:
+    ``None`` for today's ``q_pos >= k_pos`` (blocks of one position,
+    inclusive: nothing of a kernel changes), else ``(bl, strict)``: a
+    query sees the keys of earlier blocks of ``bl`` positions and, unless
+    ``strict``, of its own: ``q_pos // bl >= k_pos // bl`` (block
+    diffusion's clean rows) or ``q_pos // bl > k_pos // bl`` (its noised
+    rows on the clean keys)."""
+    if causal_block < 1:
+        raise ValueError(f"causal_block must be >= 1, got {causal_block}")
+    if causal_block == 1 and not causal_strict:
+        return None
+    return int(causal_block), bool(causal_strict)
+
+
+def _horizon(q_pos, blocks):
+    """The last key position a query at ``q_pos`` sees under the causal
+    mask (``blocks`` as :func:`_blocks` makes it); -1 where it sees none.
+    Monotone in ``q_pos``, for Python ints, traced ints and numpy grids
+    of positions that are not negative."""
+    if blocks is None:
+        return q_pos
+    bl, strict = blocks
+    start = q_pos if bl == 1 else (q_pos // bl) * bl
+    return start - 1 if strict else start + (bl - 1)
+
+
+def _first_query(k_pos, blocks):
+    """The first query position that sees the key at ``k_pos``:
+    :func:`_horizon`'s inverse."""
+    if blocks is None:
+        return k_pos
+    bl, strict = blocks
+    start = k_pos if bl == 1 else (k_pos // bl) * bl
+    return start + bl if strict else start
+
+
+def _causal_mask(q0, k0, shape, window=None, blocks=None):
     """Causal mask of a score tile whose first query and first key sit at
     GLOBAL positions ``q0`` and ``k0``, optionally banded to a sliding
     window: a query at ``i`` sees keys ``j`` with ``i - window < j <= i``
-    (``window=None`` → full causal). A caller folds ``q_offset`` (Q
-    aligned against a K axis that starts earlier — the sequence-parallel
-    neighbour-tail layout) into ``q0``."""
+    (``window=None`` → full causal), or by blocks (``blocks``,
+    :func:`_blocks`): a query sees the keys up to its :func:`_horizon`,
+    which is made on the column of queries and compared along the keys.
+    A caller folds ``q_offset`` (Q aligned against a K axis that starts
+    earlier — the sequence-parallel neighbour-tail layout) into ``q0``."""
+    if blocks is not None:
+        bl, strict = blocks
+        q_col = q0 + lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0)
+        if bl & (bl - 1) == 0:  # a power of two: the block's start by a mask
+            start = q_col & jnp.int32(-bl)
+        else:
+            start = lax.div(q_col, jnp.int32(bl)) * bl
+        k_pos = k0 + lax.broadcasted_iota(jnp.int32, shape, 1)
+        return (start - 1 if strict else start + (bl - 1)) >= k_pos
     q_pos = q0 + lax.broadcasted_iota(jnp.int32, shape, 0)
     k_pos = k0 + lax.broadcasted_iota(jnp.int32, shape, 1)
     mask = q_pos >= k_pos
@@ -117,7 +172,8 @@ def _causal_mask(q0, k0, shape, window=None):
     return mask
 
 
-def _tile_class(ik, iq, block_q, block_k, causal, window=None, q_offset=0):
+def _tile_class(ik, iq, block_q, block_k, causal, window=None, q_offset=0,
+                blocks=None):
     """``(live, full)`` of tile ``(iq, ik)`` under the mask, for traced
     ints in a kernel and for numpy index grids alike.
 
@@ -133,15 +189,18 @@ def _tile_class(ik, iq, block_q, block_k, causal, window=None, q_offset=0):
     the only kind that pays for iota, compare and select. Without
     ``causal`` every tile is live and full. With the band-narrowed grids
     (``_band_k``/``_band_q``) the predicate only sorts the slots the
-    grid still visits."""
+    grid still visits. Under a mask by blocks (``blocks``,
+    :func:`_blocks`) the diagonal is the queries' :func:`_horizon`: a
+    tile is live where its last query sees its first key, and full where
+    its first query sees its last."""
     if not causal:
         return True, True
     q0 = q_offset + iq * block_q  # first and last global q position
     q1 = q0 + block_q - 1
     k0 = ik * block_k
     k1 = k0 + block_k - 1
-    live = k0 <= q1
-    full = k1 <= q0
+    live = k0 <= _horizon(q1, blocks)
+    full = k1 <= _horizon(q0, blocks)
     if window is not None:
         live &= q0 - k1 < window
         full &= q1 - k0 < window
@@ -212,7 +271,7 @@ def _band_q(block_q: int, block_k: int, window: int, nq: int,
     return span, lo
 
 
-def _k_slot(band_lo, nk, block_q, block_k, causal, q_offset):
+def _k_slot(band_lo, nk, block_q, block_k, causal, q_offset, blocks=None):
     """Slot→k-block mapper for the index maps of the kernels that walk K
     per Q block (forward, dq): slot ``j`` of q block ``iq`` is true block
     ``band_lo(iq) + j`` (``j`` itself un-banded), held inside ``[0, nk)``
@@ -227,14 +286,15 @@ def _k_slot(band_lo, nk, block_q, block_k, causal, q_offset):
         hi = nk - 1
         if causal:
             hi = jnp.minimum(
-                hi, (q_offset + (iq + 1) * block_q - 1) // block_k
+                hi, _horizon(q_offset + (iq + 1) * block_q - 1, blocks)
+                // block_k
             )
         return jnp.maximum(jnp.minimum(ik, hi), 0)
 
     return k_block
 
 
-def _q_slot(band_lo, nq, block_q, block_k, causal, q_offset):
+def _q_slot(band_lo, nq, block_q, block_k, causal, q_offset, blocks=None):
     """:func:`_k_slot`'s mirror for the dk/dv kernel, which walks Q per K
     block: its dead slots come first (queries before the k block), so a
     slot is held at or after the first q block that sees ``ik``."""
@@ -245,7 +305,9 @@ def _q_slot(band_lo, nq, block_q, block_k, causal, q_offset):
         iq = j if band_lo is None else band_lo(ik) + j
         lo = 0
         if causal:
-            lo = jnp.maximum(lo, (ik * block_k - q_offset) // block_q)
+            lo = jnp.maximum(
+                lo, (_first_query(ik * block_k, blocks) - q_offset)
+                // block_q)
         return jnp.minimum(jnp.maximum(iq, lo), nq - 1)
 
     return q_block
@@ -281,7 +343,8 @@ _DKV_CAUSAL_ROW_BYTES = 512
 
 
 def _geometry(Tq, Tk, *, walks, causal, bare=True, row_bytes=0, window=None,
-              q_offset=0, bias_heads=1, block_q=None, block_k=None):
+              q_offset=0, bias_heads=1, block_q=None, block_k=None,
+              blocks=None):
     """``(block_q, block_k, sub)`` of a kernel, from what a call shows:
     the lengths, the mask kind (``bare``: no segment ids and no bias),
     the bytes of an operand's row (head width times item size; only
@@ -314,7 +377,10 @@ def _geometry(Tq, Tk, *, walks, causal, bare=True, row_bytes=0, window=None,
     bias) takes as many times fewer keys, down to a lane tile, so the
     bias tiles fill what one head's did. A caller's ``block_q`` /
     ``block_k`` are taken as given (``_pick_block`` still makes them
-    divide the lengths)."""
+    divide the lengths). Under a mask by blocks (``blocks``,
+    :func:`_blocks`) the stair's steps stay inside the diagonal's
+    sub-tile as long as the block length divides ``sub``; where it does
+    not, the tile is masked whole."""
     whole = (walks == "q" and causal and bare and window is None
              and row_bytes <= _DKV_CAUSAL_ROW_BYTES)
     derived = _DKV_CAUSAL_TILES if whole else _TILES
@@ -322,7 +388,8 @@ def _geometry(Tq, Tk, *, walks, causal, bare=True, row_bytes=0, window=None,
     block_k = _pick_block(
         block_k or max(derived[1] // bias_heads, _LANES), Tk)
     nests = (walks == "k" and causal and window is None
-             and block_k % block_q == 0 and q_offset % block_q == 0)
+             and block_k % block_q == 0 and q_offset % block_q == 0
+             and (blocks is None or block_q % blocks[0] == 0))
     return block_q, block_k, block_q if nests else block_k
 
 
@@ -332,7 +399,7 @@ _WALKS = {train_path.FLASH_FWD: "k", train_path.FLASH_BWD_DQ: "k",
 
 
 def _publish_tiles(kernels, lay, Tq, Tk, *, causal, window=None, q_offset=0,
-                   has_bias=False, **geometry):
+                   has_bias=False, blocks=None, **geometry):
     """Set the ``flash_tiles`` gauge for each of ``kernels`` from a call's
     static arguments, in the sub-tiles the kernel skips and masks by:
     those of one head's square (``total``), those computed (``visited``)
@@ -361,12 +428,12 @@ def _publish_tiles(kernels, lay, Tq, Tk, *, causal, window=None, q_offset=0,
         heads.set(float(lay.heads), kernel=kernel)
         unit_q, _, unit_k = _geometry(
             Tq, Tk, walks=_WALKS[kernel], causal=causal, window=window,
-            q_offset=q_offset,
+            q_offset=q_offset, blocks=blocks,
             bias_heads=lay.step_heads if has_bias else 1, **geometry)
         nq, nk = Tq // unit_q, Tk // unit_k
         live, full = _tile_class(
             np.arange(nk)[None, :], np.arange(nq)[:, None], unit_q, unit_k,
-            causal, window, q_offset,
+            causal, window, q_offset, blocks,
         )
         live = np.broadcast_to(live, (nq, nk))
         masked = live & ~np.broadcast_to(full, (nq, nk))
@@ -548,7 +615,7 @@ def _each_head(heads: int, head, looped: bool = False):
 # ---------------------------------------------------------------------------
 
 def _scores(q, k, bias_ref, seg_refs, masked, q0, k0, cols, *, scale, window,
-            head=0):
+            head=0, blocks=None):
     """One head's masked f32 scores of a piece of a tile: ``q k^T``
     scaled, plus the bias, ``NEG_INF`` where the segment ids differ and,
     on a ``masked`` piece, where the causal mask forbids. ``cols`` is the
@@ -577,12 +644,14 @@ def _scores(q, k, bias_ref, seg_refs, masked, q0, k0, cols, *, scale, window,
         s = jnp.where(sq_ref[0] == sk_ref[0, :, cols], s, NEG_INF)
     if masked:
         s = jnp.where(
-            _causal_mask(q0, k0 + (cols.start or 0), s.shape, window),
+            _causal_mask(q0, k0 + (cols.start or 0), s.shape, window,
+                         blocks),
             s, NEG_INF)
     return s
 
 
-def _walk(iq, ik, block_q, block_k, sub, causal, window, q_offset):
+def _walk(iq, ik, block_q, block_k, sub, causal, window, q_offset,
+          blocks=None):
     """``(q0, k0, live, index, branches)`` of tile ``(iq, ik)``: its first
     global positions, whether anything of it is computed, and the branch
     (a list of :func:`_pieces`) ``index`` picks: 0 where every pair is
@@ -593,7 +662,7 @@ def _walk(iq, ik, block_q, block_k, sub, causal, window, q_offset):
     k0 = ik * block_k
     n_sub = block_k // sub
     live, full = _tile_class(ik, iq, block_q, block_k, causal, window,
-                             q_offset)
+                             q_offset, blocks)
     index = 0
     if causal:
         crossed = jnp.clip((q0 + block_q - 1 - k0) // sub + 1, 1, n_sub)
@@ -606,7 +675,7 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
               acc_ref, m_ref, l_ref, *, heads: int, D: int, scale: float,
               causal: bool, block_q: int, block_k: int, sub: int,
               num_k_blocks: int, window=None, band_lo=None, nk_total=None,
-              q_offset: int = 0):
+              q_offset: int = 0, blocks=None):
     iq = pl.program_id(2)
     j = pl.program_id(3)
     # Banded grid: slot j covers TRUE k block band_lo(iq) + j; slots
@@ -635,7 +704,7 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
             l_ref[...] = jnp.zeros_like(l_ref)
 
     q0, k0, live, index, branches = _walk(
-        iq, ik, block_q, block_k, sub, causal, window, q_offset)
+        iq, ik, block_q, block_k, sub, causal, window, q_offset, blocks)
     if band_lo is not None:
         live &= (ik >= 0) & (ik < nk_total)
 
@@ -646,7 +715,7 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
             scores = [
                 _scores(q, k_ref[0, a:b, lanes], bias_ref, seg_refs, masked,
                         q0, k0, slice(a, b), scale=scale, window=window,
-                        head=p)
+                        head=p, blocks=blocks)
                 for a, b, masked in pieces
             ]
             m_new = functools.reduce(jnp.maximum, [
@@ -736,7 +805,8 @@ def _bias_spec(bias, heads, block_q, block_k, swap=False, k_of=None,
 
 
 def _flash_fwd(lay, q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
-               scale, block_q, block_k, interpret, window=None, q_offset=0):
+               scale, block_q, block_k, interpret, window=None, q_offset=0,
+               blocks=None):
     """Forward on operands in the layout ``lay`` → ``(out, lse)``: the
     output as ``q`` came, the log-sum-exp ``[B, H, 1, Tq]`` float32.
 
@@ -753,7 +823,7 @@ def _flash_fwd(lay, q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
     block_q, block_k, sub = _geometry(
         Tq, Tk, walks="k", causal=causal, window=window, q_offset=q_offset,
         bias_heads=heads if has_bias else 1,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, blocks=blocks,
     )
     nq, nk = Tq // block_q, Tk // block_k
 
@@ -767,12 +837,13 @@ def _flash_fwd(lay, q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
         if span < nk:
             band_lo, grid_k = lo, span
 
-    k_block = _k_slot(band_lo, nk, block_q, block_k, causal, q_offset)
+    k_block = _k_slot(band_lo, nk, block_q, block_k, causal, q_offset,
+                      blocks)
 
     params = dict(heads=heads, D=lay.D, scale=scale, causal=causal,
                   block_q=block_q, block_k=block_k, sub=sub,
                   num_k_blocks=grid_k, window=window, band_lo=band_lo,
-                  nk_total=nk, q_offset=q_offset)
+                  nk_total=nk, q_offset=q_offset, blocks=blocks)
     q_spec = lay.spec(block_q, lambda iq, j: iq)
     kv_spec = lay.spec(block_k, k_block, shared=True)
     in_specs = [q_spec, kv_spec, kv_spec]
@@ -823,11 +894,17 @@ def _flash_fwd(lay, q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
 # Backward: dq kernel (iterate K blocks per fixed Q block)
 # ---------------------------------------------------------------------------
 
-def _column(row_ref, head=0):
+def _column(row_ref, head=0, blocks=None):
     """Head ``head``'s ``block_q`` lanes of a ``[1, heads, 1, block_q]``
     block of row statistics as the ``[block_q, 1]`` column the scores
-    are corrected by."""
-    return jnp.expand_dims(row_ref[0, head, 0], -1)
+    are corrected by. Under a strict mask by blocks the first block's
+    queries see no key, and the forward hands their log-sum-exp back as
+    ``NEG_INF``: a masked score less that is 0 and its ``exp`` 1, so
+    such a row's column is 0 here and the ``exp`` of its scores 0."""
+    col = jnp.expand_dims(row_ref[0, head, 0], -1)
+    if blocks is not None and blocks[1]:
+        col = jnp.where(col > NEG_INF / 2, col, 0.0)
+    return col
 
 
 def _delta(do, o):
@@ -847,7 +924,7 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
                  bias_ref, dq_ref, dq_acc, *, heads: int, D: int,
                  scale: float, causal: bool, block_q: int, block_k: int,
                  sub: int, num_k_blocks: int, window=None, band_lo=None,
-                 nk_total=None, q_offset: int = 0):
+                 nk_total=None, q_offset: int = 0, blocks=None):
     iq = pl.program_id(2)
     j = pl.program_id(3)
     ik = j if band_lo is None else band_lo(iq) + j
@@ -859,7 +936,7 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
     q0, k0, live, index, branches = _walk(
-        iq, ik, block_q, block_k, sub, causal, window, q_offset)
+        iq, ik, block_q, block_k, sub, causal, window, q_offset, blocks)
     if band_lo is not None:
         live &= (ik >= 0) & (ik < nk_total)
 
@@ -868,13 +945,14 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
             lanes = _head_lanes(p, D)
             q = q_ref[0, :, lanes]
             do = do_ref[0, :, lanes].astype(jnp.float32)
-            lse = _column(lse_ref, p)
+            lse = _column(lse_ref, p, blocks)
             delta = _delta(do, o_ref[0, :, lanes])
             dq = 0.0
             for a, b, masked in pieces:
                 k = k_ref[0, a:b, lanes]
                 s = _scores(q, k, bias_ref, seg_refs, masked, q0, k0,
-                            slice(a, b), scale=scale, window=window, head=p)
+                            slice(a, b), scale=scale, window=window, head=p,
+                            blocks=blocks)
                 # p from the saved LSE: exp(NEG_INF - lse) underflows to
                 # exactly 0, so masked/never-attended entries contribute
                 # nothing.
@@ -917,7 +995,8 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
                   bias_ref, dk_ref, dv_ref, dbias_ref, dk_acc, dv_acc, *,
                   heads: int, D: int, scale: float, causal: bool,
                   block_q: int, block_k: int, num_q_blocks: int, window=None,
-                  band_lo=None, nq_total=None, q_offset: int = 0):
+                  band_lo=None, nq_total=None, q_offset: int = 0,
+                  blocks=None):
     ik = pl.program_id(2)
     j = pl.program_id(3)
     iq = j if band_lo is None else band_lo(ik) + j
@@ -930,7 +1009,7 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
             dv_acc[...] = jnp.zeros_like(dv_acc)
 
     q0, k0, live, index, branches = _walk(
-        iq, ik, block_q, block_k, block_k, causal, window, q_offset)
+        iq, ik, block_q, block_k, block_k, causal, window, q_offset, blocks)
     if band_lo is not None:
         # With q_offset > 0 the low end can undershoot too.
         live &= (iq >= 0) & (iq < nq_total)
@@ -953,8 +1032,9 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
             v = v_ref[0, :, lanes]
             do = do_ref[0, :, lanes].astype(jnp.float32)
             s = _scores(q, k, bias_ref, seg_refs, masked, q0, k0,
-                        slice(None), scale=scale, window=window, head=p)
-            pr = jnp.exp(s - _column(lse_ref, p))  # [block_q, block_k]
+                        slice(None), scale=scale, window=window, head=p,
+                        blocks=blocks)
+            pr = jnp.exp(s - _column(lse_ref, p, blocks))  # [block_q, block_k]
             # dv += p^T @ do
             dv = jax.lax.dot_general(
                 pr.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -1003,7 +1083,8 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
 
 def _flash_bwd(lay, q, k, v, out, do, lse, seg_q=None, seg_k=None,
                bias=None, want_dbias=False, *, causal, scale, block_q,
-               block_k, interpret, window=None, q_offset=0, grad_dtype=None):
+               block_k, interpret, window=None, q_offset=0, grad_dtype=None,
+               blocks=None):
     """Backward on operands in the layout ``lay`` → ``(dq, dk, dv[,
     dbias])`` in that layout, given the forward's output (the ring: the
     merged one) and log-sum-exp as the forward wrote them, the latter
@@ -1029,7 +1110,7 @@ def _flash_bwd(lay, q, k, v, out, do, lse, seg_q=None, seg_k=None,
         bare=not (has_segments or has_bias),
         row_bytes=lay.D * q.dtype.itemsize,
         bias_heads=heads if has_bias else 1,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, blocks=blocks,
     )
     grid_bh = (lay.B, lay.H // heads)
     seg_args = ((seg_q[:, :, None], seg_k[:, None, :]) if has_segments
@@ -1047,11 +1128,12 @@ def _flash_bwd(lay, q, k, v, out, do, lse, seg_q=None, seg_k=None,
         span_k, lo_k = _band_k(block_q, block_k, window, nk, q_offset)
         if span_k < nk:
             band_lo, grid_k = lo_k, span_k
-    k_block = _k_slot(band_lo, nk, block_q, block_k, causal, q_offset)
+    k_block = _k_slot(band_lo, nk, block_q, block_k, causal, q_offset,
+                      blocks)
     dq_params = dict(heads=heads, D=lay.D, scale=scale, causal=causal,
                      block_q=block_q, block_k=block_k, sub=sub,
                      window=window, q_offset=q_offset, num_k_blocks=grid_k,
-                     band_lo=band_lo, nk_total=nk)
+                     band_lo=band_lo, nk_total=nk, blocks=blocks)
     q_spec = lay.spec(block_q, lambda i, j: i)
     row_spec = lay.row_spec(block_q, lambda i, j: i)
     kv_spec = lay.spec(block_k, k_block, shared=True)
@@ -1104,11 +1186,11 @@ def _flash_bwd(lay, q, k, v, out, do, lse, seg_q=None, seg_k=None,
         if span_q < nq:
             band_lo, grid_q = lo_q, span_q
     q_block = _q_slot(band_lo, nq, block_q, block_k,
-                      causal and not want_dbias, q_offset)
+                      causal and not want_dbias, q_offset, blocks)
     dkv_params = dict(heads=heads, D=lay.D, scale=scale, causal=causal,
                       block_q=block_q, block_k=block_k, window=window,
                       q_offset=q_offset, num_q_blocks=grid_q,
-                      band_lo=band_lo, nq_total=nq)
+                      band_lo=band_lo, nq_total=nq, blocks=blocks)
     k_spec_in = lay.spec(block_k, lambda i, j: i, shared=True)
     k_spec_out = lay.spec(block_k, lambda i, j: i)
     q_spec_in = lay.spec(block_q, q_block)
@@ -1217,20 +1299,21 @@ def _use_interpret() -> bool:
 # passed (zero-size dummies when unused, selected by the static has_*
 # flags), which avoids a per-combination class explosion.
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13))
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13, 14))
 def _flash_core(q, k, v, seg, bias, has_seg, has_bias, bias_grad, causal,
-                scale, block_q, block_k, interpret, window):
+                scale, block_q, block_k, interpret, window, blocks=None):
     # Primal == fwd minus the residuals: ONE body owns the operand
     # plumbing so primal and vjp forwards can never diverge.
     out, _res = _flash_core_fwd(
         q, k, v, seg, bias, has_seg, has_bias, bias_grad, causal, scale,
-        block_q, block_k, interpret, window,
+        block_q, block_k, interpret, window, blocks,
     )
     return out
 
 
 def _flash_core_fwd(q, k, v, seg, bias, has_seg, has_bias, bias_grad,
-                    causal, scale, block_q, block_k, interpret, window):
+                    causal, scale, block_q, block_k, interpret, window,
+                    blocks=None):
     lay = _Layout.of(q.shape, k.shape)
     out, lse = _flash_fwd(
         lay, lay.enter(q), lay.enter(k), lay.enter(v),
@@ -1238,7 +1321,7 @@ def _flash_core_fwd(q, k, v, seg, bias, has_seg, has_bias, bias_grad,
         bias if has_bias else None,  # bias is already scores-layout BHQK
         causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
-        window=window,
+        window=window, blocks=blocks,
     )
     # What the backward takes from the kernel, by name: a remat policy
     # that saves these names (models/transformer.py, 'dots') keeps them,
@@ -1252,7 +1335,7 @@ def _flash_core_fwd(q, k, v, seg, bias, has_seg, has_bias, bias_grad,
 
 
 def _flash_core_bwd(has_seg, has_bias, bias_grad, causal, scale, block_q,
-                    block_k, interpret, window, res, g):
+                    block_k, interpret, window, blocks, res, g):
     q, k, v, seg, bias, out, lse = res
     lay = _Layout.of(q.shape, k.shape)
     res_bwd = _flash_bwd(
@@ -1260,7 +1343,7 @@ def _flash_core_bwd(has_seg, has_bias, bias_grad, causal, scale, block_q,
         lse, seg if has_seg else None, seg if has_seg else None,
         bias if has_bias else None, bias_grad,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret, window=window,
+        interpret=interpret, window=window, blocks=blocks,
     )
     if bias_grad:
         dbias = res_bwd[3].astype(bias.dtype)  # already BHQK
@@ -1284,7 +1367,7 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 # the host: without it 72 kernels a step, traced again by every program
 # that holds the model); XLA inlines the calls, so the compiled step is
 # the same program, each kernel under its call site's ``op_name``.
-_flash_call = jax.jit(_flash_core, static_argnums=tuple(range(5, 14)))
+_flash_call = jax.jit(_flash_core, static_argnums=tuple(range(5, 15)))
 
 
 def flash_attention(
@@ -1301,6 +1384,8 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    causal_block: int = 1,
+    causal_strict: bool = False,
 ) -> jax.Array:
     """Flash attention on ``[B, T, H, D]`` inputs, Pallas forward AND
     backward (both VMEM-blocked; the score matrix never exists in HBM in
@@ -1334,6 +1419,18 @@ def flash_attention(
     exception: ``bias_grad=True`` forces the dk/dv kernel back to the
     full grid (its dbias output must tile every (iq, ik)).
 
+    ``causal_block`` / ``causal_strict`` make the causal mask one by
+    blocks of ``causal_block`` positions (block diffusion's): query ``i``
+    sees key ``j`` where ``i // bl >= j // bl``, its own block whole, or
+    with ``causal_strict`` where ``i // bl > j // bl``, earlier blocks
+    alone. Both need ``causal=True`` and no ``window``. A tile the stair
+    leaves dark is skipped as one above the diagonal is (``flash_tiles``
+    counts by the same rule), and the mask is built on the sub-tile the
+    stair crosses. A strict mask's first block of queries sees nothing:
+    its output and every gradient of it are zero. The defaults (1, not
+    strict) are the causal mask, and the kernels are then the same
+    programs as without the two arguments.
+
     ``block_q`` / ``block_k`` of None (the default) are derived from the
     lengths and the mask kind (:func:`_geometry`); numbers are taken as
     given, halved until they divide the lengths.
@@ -1364,6 +1461,11 @@ def flash_attention(
                              "window is defined over past positions)")
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
+    blocks = _blocks(causal_block, causal_strict)
+    if blocks is not None and (not causal or window is not None):
+        raise ValueError("causal_block / causal_strict describe a causal "
+                         "mask without a window: pass causal=True and no "
+                         "window")
     if has_bias:
         if bias.ndim != 4 or bias.shape[0] not in (1, q.shape[0]) \
                 or bias.shape[1] not in (1, q.shape[2]) \
@@ -1383,54 +1485,75 @@ def flash_attention(
                    k.shape[1], causal=causal, window=window,
                    bare=not (has_seg or has_bias), has_bias=has_bias,
                    row_bytes=q.shape[3] * q.dtype.itemsize,
-                   block_q=block_q, block_k=block_k)
+                   block_q=block_q, block_k=block_k, blocks=blocks)
     return _flash_call(q, k, v, seg, b, has_seg, has_bias, bias_grad,
-                       causal, scale, block_q, block_k, interpret, window)
+                       causal, scale, block_q, block_k, interpret, window,
+                       blocks)
 
 
 # ---------------------------------------------------------------------------
 # Block-level entry points for ring attention
 # ---------------------------------------------------------------------------
 
+def _block_entry_mask(causal, window, q_offset, causal_block, causal_strict):
+    """:func:`_blocks` of a block entry point's arguments."""
+    blocks = _blocks(causal_block, causal_strict)
+    if blocks is not None and (not causal or window is not None
+                               or q_offset):
+        raise ValueError("a causal mask by blocks takes causal=True, no "
+                         "window and q_offset 0")
+    return blocks
+
+
 def flash_block_fwd(q, k_blk, v_blk, *, causal, scale, block_q, block_k,
                     interpret, seg_q=None, seg_kv=None, window=None,
-                    q_offset=0):
+                    q_offset=0, causal_block=1, causal_strict=False):
     """One ring step's forward: full flash over the resident Q shard and ONE
     arriving K/V block, returning BTHD output + ``[B, H, Tq]`` LSE. The ring
     merges successive blocks' (out, lse) partials in log space
     (:func:`chainermn_tpu.parallel.ring_attention.merge_partials`).
     ``seg_q``/``seg_kv`` are the per-shard segment-id slices (the kv ids
-    travel with their block around the ring)."""
+    travel with their block around the ring). ``causal_block`` /
+    ``causal_strict``: :func:`flash_attention`'s mask by blocks (block
+    diffusion merges a strict call's partial with its in-block part as
+    the ring merges its steps')."""
+    blocks = _block_entry_mask(causal, window, q_offset, causal_block,
+                               causal_strict)
     lay = _Layout.of(q.shape, k_blk.shape)
     _publish_tiles((train_path.FLASH_FWD,), lay, q.shape[1], k_blk.shape[1],
                    causal=causal, window=window, q_offset=q_offset,
-                   block_q=block_q, block_k=block_k)
+                   block_q=block_q, block_k=block_k, blocks=blocks)
     out, lse = _flash_fwd(
         lay, lay.enter(q), lay.enter(k_blk), lay.enter(v_blk), seg_q, seg_kv,
         causal=causal, window=window, q_offset=q_offset,
         scale=scale, block_q=block_q, block_k=block_k, interpret=interpret,
+        blocks=blocks,
     )
     return lay.leave(out), lse[:, :, 0]
 
 
 def flash_block_bwd(q, k_blk, v_blk, do, lse, out, *, causal, scale,
                     block_q, block_k, interpret, seg_q=None, seg_kv=None,
-                    window=None, q_offset=0):
+                    window=None, q_offset=0, causal_block=1,
+                    causal_strict=False, grad_dtype=jnp.float32):
     """One ring step's backward: (dq, dk_blk, dv_blk) contributions for one
     K/V block, BTHD, in float32 whatever the operands': the ring adds
-    them up over its steps. ``lse`` (``[B, H, Tq]``) and ``out`` (BTHD)
-    are the ring's merged log-sum-exp and output."""
+    them up over its steps (``grad_dtype`` None: in the operands' own,
+    rounded once, as the op hands them back). ``lse`` (``[B, H, Tq]``) and
+    ``out`` (BTHD) are the ring's merged log-sum-exp and output."""
+    blocks = _block_entry_mask(causal, window, q_offset, causal_block,
+                               causal_strict)
     lay = _Layout.of(q.shape, k_blk.shape)
     _publish_tiles((train_path.FLASH_BWD_DQ, train_path.FLASH_BWD_DKV), lay,
                    q.shape[1], k_blk.shape[1], causal=causal, window=window,
                    q_offset=q_offset, bare=seg_q is None,
                    row_bytes=q.shape[3] * q.dtype.itemsize,
-                   block_q=block_q, block_k=block_k)
+                   block_q=block_q, block_k=block_k, blocks=blocks)
     grads = _flash_bwd(
         lay, lay.enter(q), lay.enter(k_blk), lay.enter(v_blk),
         lay.enter(out), lay.enter(do), lse[:, :, None], seg_q, seg_kv,
         causal=causal, scale=scale, window=window, q_offset=q_offset,
         block_q=block_q, block_k=block_k, interpret=interpret,
-        grad_dtype=jnp.float32,
+        grad_dtype=grad_dtype, blocks=blocks,
     )
     return tuple(lay.leave(x) for x in grads)

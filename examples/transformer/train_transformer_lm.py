@@ -43,9 +43,12 @@ from chainermn_tpu.models import (
     MODEL_CONFIGS,
     ROUTER_STATE,
     TransformerLM,
+    diffusion_noise_key,
+    diffusion_noise_state,
     head_table,
     lm_from_config,
     lm_loss,
+    lm_loss_block_diffusion,
     lm_loss_fused,
     lm_loss_looped,
     lm_loss_moe,
@@ -308,6 +311,10 @@ def run_named_model(args, comm, compute_dtype, rng):
         raise SystemExit(f"{args.model} has {model.max_len} positions")
     global_batch = args.batchsize * comm.size
     tokens0 = synthetic_tokens(rng, global_batch, args.seq_len)
+    block_diffusion = bool(model.arch.diffusion_block)
+    if block_diffusion:
+        # its training pass reads a clean and a noised copy of every row
+        tokens0 = np.tile(tokens0, (1, 2))
     variables = jax.jit(model.init)(
         jax.random.key(0), jnp.asarray(tokens0[:1]))
     params = variables["params"]
@@ -315,7 +322,21 @@ def run_named_model(args, comm, compute_dtype, rng):
     # rides the train state as model_state, here at its initial zero
     router_state = variables.get(ROUTER_STATE, ())
 
-    if model.expert_layers:
+    if block_diffusion:
+        # SDAR's: the step draws its noise itself, from a key the train
+        # state counts up as model_state; the synthetic ids lie below
+        # VOCAB, and the mask token is the vocabulary's last
+        if model.vocab_size <= VOCAB:
+            raise SystemExit(f"{args.model}: the mask token needs an id "
+                             f"above the data's {VOCAB}")
+        router_state = diffusion_noise_state(0)
+
+        def loss_fn(params, tokens, noise_state):
+            key, noise_state = diffusion_noise_key(noise_state)
+            loss, metrics = lm_loss_block_diffusion(
+                model, params, tokens, key, mask_id=model.vocab_size - 1)
+            return loss, (metrics, noise_state)
+    elif model.expert_layers:
         # a sigmoid router has no auxiliary losses
         coefs = {} if model.arch.router_score == "softmax" else dict(
             load_balance_coef=0.0, z_loss_coef=0.0)
@@ -353,7 +374,8 @@ def run_named_model(args, comm, compute_dtype, rng):
             extra = "".join(
                 f" {k.split('/')[1]}={float(v):.3f}"
                 for k, v in sorted(metrics.items())
-                if k.startswith(("moe/", "loop/")) and jnp.ndim(v) == 0)
+                if k.startswith(("moe/", "loop/", "bd/"))
+                and jnp.ndim(v) == 0)
             print(
                 f"iter {it + 1}/{args.iterations} "
                 f"loss={float(metrics['loss']):.4f}{extra} "

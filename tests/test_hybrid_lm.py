@@ -261,9 +261,10 @@ def test_the_comparison_catches(name, tiny, ref, monkeypatch):
 
 # -- the share ---------------------------------------------------------------
 
-def _expert_layer(held, params, state, x, whole_config):
+def _expert_layer(held, params, state, x, whole_config, positions=None):
     """One attention-and-experts block holding experts ``held`` of the
-    whole layer's, applied to ``x``."""
+    whole layer's, applied to ``x`` (``state``: the router's selection
+    bias, where the router has one)."""
     lo, hi = held
     arch = dataclasses.replace(
         Architecture.from_config(whole_config), experts_held=(lo, hi))
@@ -272,44 +273,96 @@ def _expert_layer(held, params, state, x, whole_config):
         attention_fn=_attn, arch=arch, layer_index=0)
     share = {**params, "moe_w_gate_up": params["moe_w_gate_up"][lo:hi],
              "moe_w_down": params["moe_w_down"][lo:hi]}
-    out, _ = block.apply(
-        {"params": share, ROUTER_STATE: state}, x, None,
-        jnp.arange(x.shape[1]), mutable=["moe_aux"])
+    variables = {"params": share}
+    if state is not None:
+        variables[ROUTER_STATE] = state
+    if positions is None:
+        positions = jnp.arange(x.shape[1])
+    out, _ = block.apply(variables, x, None, positions, mutable=["moe_aux"])
     return out
 
 
-@pytest.mark.parametrize("side", ["system", "reference"])
-def test_the_four_shares_add_up_to_the_uncut_layer(side, ref):
-    """The expert outputs of the four shares, summed, are the uncut
-    reference's for the whole layer (no shared expert here, so nothing is
-    counted once): ``sum_s (y_s - r) = y - r`` with ``r`` the residual
-    stream after the mixer, which every chip computes alike."""
+def _lfm2_share_case(ref):
+    """LFM2's layer: four shares of 2 of 8 experts, sigmoid top-2 with a
+    selection bias."""
     config = {**_whole(TINY), "num_hidden_layers": 1,
               "layer_types": ["full_attention"], "num_dense_layers": 0}
     params, state, _ = _init(config, bias_std=0.05)  # every share chosen
     p, s = params["block_0"], state["block_0"]
     x = jax.random.normal(jax.random.key(4), (2, T, 64))
     eps = config["norm_eps"]
+    return dict(
+        config=config, p=p, state=s, x=x, positions=None,
+        shares=[(0, 2), (2, 4), (4, 6), (6, 8)],
+        mixed=lambda h: ref.by_row(
+            lambda row: ref.attention(row, p, config), h),
+        norm=lambda y, name: ref.rms_norm(y, p[name], eps),
+        experts=lambda h, p_, c: ref.experts(
+            h, p_, s["moe_router_bias"], c))
+
+
+def _sdar_share_case(_ref):
+    """SDAR's layer under block diffusion: eight shares of 16 of 128
+    experts, softmax top-8 renormalised, over the rows ``[x ; x~]``."""
+    ref = _load("benchmark/reference/block_diffusion_moe_lm.py",
+                "reference_block_diffusion_moe_lm")
+    config = dict(
+        MODEL_CONFIGS["sdar-30b-a3b"], num_hidden_layers=1, hidden_size=64,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        moe_intermediate_size=16, vocab_size=128,
+        max_position_embeddings=64)
+    model = lm_from_config(config, compute_dtype=jnp.float32,
+                           return_hidden=True)
+    p = model.init(jax.random.key(1), jnp.zeros((1, 2 * T), jnp.int32))[
+        "params"]["block_0"]
+    # a router far from uniform, so that the eight chosen differ by token
+    p = {**p, "moe_router": p["moe_router"] * 20}
+    x = jax.random.normal(jax.random.key(4), (2, 2 * T, 64))
+    eps = config["rms_norm_eps"]
+    return dict(
+        config=config, p=p, state=None, x=x,
+        positions=jnp.tile(jnp.arange(T), 2),
+        shares=[(16 * i, 16 * i + 16) for i in range(8)],
+        mixed=lambda h: ref.attention(h, p, config),
+        norm=lambda y, name: ref.rms_norm(y, p[name], eps),
+        experts=lambda h, p_, c: ref.experts(h, p_, c)[0])
+
+
+SHARE_CASES = {"lfm2_four_shares_of_2": _lfm2_share_case,
+               "sdar_eight_shares_of_16": _sdar_share_case}
+
+
+@pytest.mark.parametrize("side", ["system", "reference"])
+@pytest.mark.parametrize("family", sorted(SHARE_CASES))
+def test_the_four_shares_add_up_to_the_uncut_layer(family, side, ref):
+    """The expert outputs of the shares, summed, are the uncut
+    reference's for the whole layer (no shared expert here, so nothing is
+    counted once): ``sum_s (y_s - r) = y - r`` with ``r`` the residual
+    stream after the mixer, which every chip computes alike. LFM2's four
+    shares of 2 experts, and SDAR's eight shares of 16 under its mask by
+    blocks."""
+    case = SHARE_CASES[family](ref)
+    config, p, x = case["config"], case["p"], case["x"]
 
     def r_and_h():
-        r = x + ref.by_row(lambda row: ref.attention(row, p, config),
-                           ref.rms_norm(x, p["RMSNorm_0"], eps))
-        return r, ref.rms_norm(r, p["RMSNorm_1"], eps).reshape(-1, 64)
+        r = x + case["mixed"](case["norm"](x, "RMSNorm_0"))
+        return r, case["norm"](r, "RMSNorm_1").reshape(-1, 64)
 
     r, h = _highest(r_and_h)
-    uncut = _highest(ref.experts, h, p, s["moe_router_bias"], config)
-    shares = [(0, 2), (2, 4), (4, 6), (6, 8)]
+    uncut = _highest(case["experts"], h, p, config)
+    shares = case["shares"]
     if side == "system":
-        parts = [_expert_layer(held, p, s, x, config) - r
+        parts = [_expert_layer(held, p, case["state"], x, config,
+                               case["positions"]) - r
                  for held in shares]
     else:
         parts = [_highest(
-            ref.experts, h,
+            case["experts"], h,
             {**p, "moe_w_gate_up": p["moe_w_gate_up"][lo:hi],
              "moe_w_down": p["moe_w_down"][lo:hi]},
-            s["moe_router_bias"],
-            {**config, "num_experts": 2, "experts_published": 8,
-             "experts_held_range": [lo, hi]}).reshape(2, T, 64)
+            {**config, "num_experts": hi - lo,
+             "experts_published": shares[-1][1],
+             "experts_held_range": [lo, hi]}).reshape(x.shape)
             for lo, hi in shares]
     assert all(float(jnp.linalg.norm(part)) > 0.05 * float(
         jnp.linalg.norm(uncut)) for part in parts)

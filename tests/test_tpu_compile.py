@@ -133,3 +133,31 @@ def test_mosaic_compiles_the_short_conv_kernels(kernel, shape, dtype, topo):
     text = lowered.compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert f"short_conv/{kernel}" in text
+
+
+@pytest.mark.parametrize("strict", [False, True], ids=["inclusive", "strict"])
+def test_mosaic_compiles_the_flash_kernels_under_a_mask_by_blocks(strict,
+                                                                  topo):
+    """The SDAR cell's attention shape (8192 positions, 32 / 4 heads of
+    128, bf16, blocks of 4), forward and the two backward kernels, under
+    the inclusive mask (clean on clean) and the strict one (noised on
+    clean): the stair's mask is a column of block starts compared along
+    the keys, which the interpreter cannot vouch for."""
+    from jax.sharding import SingleDeviceSharding
+
+    from chainermn_tpu.ops.flash_attention import flash_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, causal_block=4, causal_strict=strict,
+            interpret=False).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
+    ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
