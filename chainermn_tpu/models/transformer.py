@@ -875,8 +875,10 @@ class TransformerBlock(nn.Module):
         into :data:`MOE_AUX`. With a share of the experts held
         (``arch.experts_held``) the leaves and the groups are the held
         experts', and the rows of the absent ones, which lie behind the
-        last group, come out of the grouped matmuls as zeros."""
-        from chainermn_tpu.ops.grouped_matmul import grouped_matmul
+        last group, come out of the grouped matmuls as zeros: written, not
+        multiplied (their tiles are counted, ``tail_tiles``, and sown
+        beside the router's statistics)."""
+        from chainermn_tpu.ops.grouped_matmul import grouped_matmul, tail_tiles
         from chainermn_tpu.parallel import moe as _moe
 
         E, F = arch.n_experts, arch.expert_width
@@ -912,6 +914,8 @@ class TransformerBlock(nn.Module):
                 routing, arch.router_score == "softmax").items():
             self.sow(MOE_AUX, name, value)
         rows = _moe.dispatch(tokens, routing)
+        self.sow(MOE_AUX, "tail_tiles", tail_tiles(
+            routing.group_sizes, rows.shape[0]).astype(jnp.float32))
         gate_up = grouped_matmul(rows, w_gate_up, routing.group_sizes)
         with jax.named_scope(train_path.MOE_EXPERTS):
             act = nn.silu(gate_up[:, :F]) * gate_up[:, F:]
@@ -1829,7 +1833,11 @@ def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
     softmax router's), ``moe/expert_load_max_over_mean`` (the busiest held
     expert's rows over the mean, all layers together), ``moe/rows_held``
     (the rows whose expert this program holds, summed over the layers:
-    all ``tokens * k`` a layer unless it holds a share), ``moe/dropped``
+    all ``tokens * k`` a layer unless it holds a share),
+    ``moe/tail_tiles`` (the expert section's row tiles behind the last held
+    group, summed over the layers, which the grouped matmuls write as
+    zeros without multiplying: 0 where every expert is held and the rows
+    fill their tiles), ``moe/dropped``
     (rows routed to a held expert that lie in no expert's group, counted
     from each layer's group sizes: 0 while the dropless path keeps its
     word) and the vector ``moe/expert_load`` (rows a held expert received,
@@ -1860,6 +1868,7 @@ def _with_router_aux(loss, model: "TransformerLM", sown, load_balance_coef,
     metrics = {
         "moe/expert_load_max_over_mean": load.max() / load.mean(),
         "moe/rows_held": over_layers("rows_held", jnp.sum),
+        train_path.MOE_TAIL_TILES: over_layers("tail_tiles", jnp.sum),
         "moe/dropped": over_layers("dropped", jnp.sum),
         "moe/expert_load": load,
     }

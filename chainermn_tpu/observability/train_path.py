@@ -148,6 +148,11 @@ MOE_EXPERTS_TOTAL = "moe_experts_total"
 #: gauge set beside them: the experts whose weights the layer holds (all
 #: of them, or the share ``Architecture.experts_held`` names)
 MOE_EXPERTS_HELD = "moe_experts_held"
+#: key of a MoE loss's metrics beside ``moe/rows_held``: the row tiles of
+#: the expert section that lie wholly behind the last held group, which
+#: the grouped matmul's row products write as zeros without multiplying
+#: (``ops.grouped_matmul.tail_tiles``), summed over the expert layers
+MOE_TAIL_TILES = "moe/tail_tiles"
 #: gauge set while a ``TransformerLM`` is traced, label ``kind``
 #: (``attention`` / ``short_conv`` / ``dense_ffn`` / ``expert_ffn``): the
 #: layers of the stack that have a mixer or a feed-forward of that kind

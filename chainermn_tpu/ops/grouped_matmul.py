@@ -16,6 +16,11 @@ Two Pallas kernels serve the forward product and both gradients:
   output block. A tile that straddles a group boundary is visited once a
   group, each visit masked to the group's rows and accumulated into the
   tile's output block, which stays in VMEM while the tile does not change.
+  The rows behind the last group (a share's absent experts' rows, a
+  receive buffer's padding) are one more group, the *tail*, whose items
+  multiply nothing and fetch nothing: a tile wholly behind the groups is
+  written as zeros, and a tile the tail shares with the last groups' rows
+  is closed with what those accumulated. A tail tile costs its zero write.
 * ``drhs[e] = lhs_e^T @ dout_e`` walks the same items with the output
   block following the *group*: every visit adds the tile's rows of that
   group, contracted over the rows.
@@ -23,7 +28,9 @@ Two Pallas kernels serve the forward product and both gradients:
 The items are computed from ``group_sizes`` on the device (``_plan``) and
 reach the kernel as scalar-prefetch operands, so block indices follow them
 and no shape depends on the routing. An empty group still has one (fully
-masked) item, which zeroes its ``drhs`` block.
+masked) item, which zeroes its ``drhs`` block. :func:`tail_tiles` counts
+the tiles wholly behind the groups (``moe/tail_tiles`` in a model's
+metrics).
 """
 
 from __future__ import annotations
@@ -65,12 +72,17 @@ def _tile(size: int, want: int, align: int) -> int:
     return size
 
 
+def _tile_m(m: int, want: int) -> int:
+    """Rows of a row tile: ``want``, or where there are fewer rows all of
+    them, rounded up to 8."""
+    return want if m >= want else -(-m // 8) * 8
+
+
 def _row_tiles(want: int, *arrays):
-    """``(tile_m, rows, padded arrays)``: row tiles of ``want`` (one tile of
-    the rows rounded up to 8 where there are fewer), the arrays' rows
-    padded with zeros to a whole number of tiles."""
+    """``(tile_m, rows, padded arrays)``: row tiles of :func:`_tile_m`, the
+    arrays' rows padded with zeros to a whole number of tiles."""
     m = arrays[0].shape[0]
-    tile_m = want if m >= want else -(-m // 8) * 8
+    tile_m = _tile_m(m, want)
     rows = -(-m // tile_m) * tile_m
     if rows != m:
         arrays = [jnp.pad(a, ((0, rows - m), (0, 0))) for a in arrays]
@@ -85,9 +97,11 @@ def _plan(group_sizes, rows: int, tile_m: int, *, cover_tail: bool):
     group ``group_of[i]``, whose rows are ``lo[g] <= r < hi[g]``. Items are
     ordered by group and, within a group, by tile, so both indices never
     decrease; items from ``total`` on repeat the last one and do nothing.
-    With ``cover_tail`` a last group of no rows (``lo == hi``) is planned
-    over the tiles past ``sum(group_sizes)``, so that every tile is visited
-    and rows outside every group read zero.
+    With ``cover_tail`` a last group of no rows (``lo == hi``), the
+    *tail*, is planned over the tiles past ``sum(group_sizes)``, so that
+    every tile is visited and rows outside every group read zero: its
+    items are the ones with ``group_of == len(group_sizes)``, which a
+    kernel answers by writing zeros (it takes no product for them).
     """
     n_tiles = rows // tile_m
     sizes = group_sizes.astype(jnp.int32)
@@ -95,7 +109,7 @@ def _plan(group_sizes, rows: int, tile_m: int, *, cover_tail: bool):
     starts = ends - sizes
     lo, hi = starts, ends
     if cover_tail:
-        # planned over [sum, rows), masked as an empty range
+        # the tail: planned over [sum, rows), an empty range of rows
         starts = jnp.concatenate([starts, ends[-1:]])
         ends = jnp.concatenate([ends, jnp.full((1,), rows, jnp.int32)])
         lo = jnp.concatenate([lo, jnp.zeros((1,), jnp.int32)])
@@ -113,6 +127,27 @@ def _plan(group_sizes, rows: int, tile_m: int, *, cover_tail: bool):
     return group_of, tile_of, lo, hi, total[None]
 
 
+def _tiles_read(group_of, tile_of, n_groups: int):
+    """The row tile of ``lhs`` each item of a ``cover_tail`` plan has in
+    VMEM: its own, and for the tail's items (which read nothing) the last
+    group's last, so that the block does not change and nothing is
+    fetched for them."""
+    held = group_of < n_groups
+    # tiles never decrease, so the groups' last is their largest
+    return jnp.where(held, tile_of, jnp.max(jnp.where(held, tile_of, 0)))
+
+
+def tail_tiles(group_sizes, rows: int):
+    """How many row tiles of a ``[rows, K]`` ``lhs`` lie wholly behind the
+    last group, int32 ``[]``: the tiles the row products write as zeros
+    without a product or a read (:func:`_plan`'s tail, less the one tile
+    it may share with the last groups' rows). 0 where the groups fill the
+    rows."""
+    tile_m = _tile_m(rows, _TILE_M)
+    held = group_sizes.astype(jnp.int32).sum()
+    return -(-rows // tile_m) - -(-held // tile_m)
+
+
 def _row_mask(tile, tile_m, lo, hi, shape, axis):
     rows = tile * tile_m + lax.broadcasted_iota(jnp.int32, shape, axis)
     return (rows >= lo) & (rows < hi)
@@ -123,12 +158,15 @@ def _inside(tile, tile_m, lo, hi):
     return (lo <= tile * tile_m) & ((tile + 1) * tile_m <= hi)
 
 
-def _gmm_body(group_of, tile_of, lo, hi, total, lhs_ref, rhs_ref, out_ref,
-              acc_ref, *, tile_m, n_items, transpose_rhs):
+def _gmm_body(group_of, tile_of, lo, hi, total, lhs_tile_of, lhs_ref,
+              rhs_ref, out_ref, acc_ref, *, tile_m, n_items, n_groups,
+              transpose_rhs):
+    del lhs_tile_of  # the index maps' (``_gmm``)
     i = pl.program_id(1)
     g, t = group_of[i], tile_of[i]
     contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
     active = i < total[0]
+    tail = g == n_groups  # rows behind every group: nothing to multiply
     opens = (i == 0) | (tile_of[jnp.maximum(i - 1, 0)] != t)
     closes = (i == total[0] - 1) | \
         (tile_of[jnp.minimum(i + 1, n_items - 1)] != t)
@@ -144,7 +182,17 @@ def _gmm_body(group_of, tile_of, lo, hi, total, lhs_ref, rhs_ref, out_ref,
     def _whole_tile():  # the common case: straight to the output block
         out_ref[...] = product().astype(out_ref.dtype)
 
-    @pl.when(active & jnp.logical_not(alone))
+    @pl.when(active & tail)
+    def _tail_tile():  # one item a tile, so it always closes its tile
+        @pl.when(opens)
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        @pl.when(jnp.logical_not(opens))  # the last groups' rows, as summed
+        def _():
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+    @pl.when(active & jnp.logical_not(alone | tail))
     def _shared_tile():
         prod = product()
         prod = jnp.where(
@@ -173,21 +221,23 @@ def _gmm(lhs, rhs, group_sizes, *, transpose_rhs, interpret):
     tile_n = _tile(n, _TILE_N, 128)
     plan = _plan(group_sizes, rows, tile_m, cover_tail=True)
     n_items = plan[0].shape[0]
+    # a tail item reads nothing: both operands' blocks stay where the last
+    # item of a group left them, so no copy is made
+    lhs_tile_of = _tiles_read(*plan[:2], e)
 
     def rhs_index(j, i, group_of, *_):
-        g = jnp.minimum(group_of[i], e - 1)  # the tail's items read any
+        g = jnp.minimum(group_of[i], e - 1)
         return (g, j, 0) if transpose_rhs else (g, 0, j)
 
     out = pl.pallas_call(
         functools.partial(_gmm_body, tile_m=tile_m, n_items=n_items,
-                          transpose_rhs=transpose_rhs),
+                          n_groups=e, transpose_rhs=transpose_rhs),
         name=train_path.MOE_EXPERTS,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=6,
             grid=(n // tile_n, n_items),
             in_specs=[
-                pl.BlockSpec((tile_m, k),
-                             lambda j, i, g_of, t_of, *_: (t_of[i], 0)),
+                pl.BlockSpec((tile_m, k), lambda j, i, *plan: (plan[5][i], 0)),
                 pl.BlockSpec((1, tile_n, k) if transpose_rhs
                              else (1, k, tile_n), rhs_index),
             ],
@@ -200,7 +250,7 @@ def _gmm(lhs, rhs, group_sizes, *, transpose_rhs, interpret):
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(*plan, lhs, rhs)
+    )(*plan, lhs_tile_of, lhs, rhs)
     return out[:m] if rows != m else out
 
 

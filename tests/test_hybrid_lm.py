@@ -179,7 +179,7 @@ def test_loss_and_every_gradient_leaf_match_the_reference(held, ref):
     assert params["block_1"]["moe_router"].shape == (64, 8)
     assert float(metrics["moe/dropped"]) == 0.0
     assert set(metrics) == {"moe/dropped", "moe/rows_held",
-                            "moe/expert_load",
+                            "moe/tail_tiles", "moe/expert_load",
                             "moe/expert_load_max_over_mean"}
 
 
@@ -445,15 +445,36 @@ TAILS = {
     "a_quarter_live": (4096, 128, 256, [300, 0, 500, 224]),
     "nothing_live": (2048, 128, 256, [0, 0, 0, 0]),
     "groups_end_inside_a_tile": (3000, 128, 256, [513, 511, 1, 0, 700]),
+    # where the tail opens (ISSUE 44): on a tile boundary; in a tile an
+    # earlier group ends in, behind an empty last group; in the one tile
+    # there is, of fewer rows than 512
+    "the_tail_opens_on_a_tile_boundary": (2560, 128, 256, [512, 0, 512]),
+    "an_empty_last_group_in_the_tails_first_tile":
+        (2048, 128, 256, [200, 400, 0, 0]),
+    "fewer_rows_than_a_tile": (200, 128, 256, [30, 0, 50]),
 }
 
 
+def _by_group(a, b, sizes):
+    out, start = [], 0
+    for e, size in enumerate(sizes):
+        out.append(a[start:start + size] @ b[e])
+        start += size
+    out.append(jnp.zeros((a.shape[0] - start, b.shape[2]), a.dtype))
+    return jnp.concatenate(out)
+
+
+@pytest.mark.parametrize("tail", ["random", "nan_and_inf"])
 @pytest.mark.parametrize("what", ["forward", "grad_lhs", "grad_rhs"])
 @pytest.mark.parametrize("case", sorted(TAILS))
-def test_rows_past_the_held_groups_are_multiplied_by_nothing(case, what):
+def test_rows_past_the_held_groups_are_multiplied_by_nothing(case, what,
+                                                             tail):
     """The grouped matmul under a share: the rows behind the last held
     group come out zero, hand back a zero gradient and add nothing to any
-    expert's."""
+    expert's, **whatever they hold** (NaN and inf in ``lhs`` and in the
+    cotangent: they are written as zeros, not multiplied and masked), and
+    a held row's result is the bits of the call that ends with the last
+    live tile."""
     from chainermn_tpu.ops.grouped_matmul import grouped_matmul
 
     m, k, n, sizes = TAILS[case]
@@ -461,49 +482,81 @@ def test_rows_past_the_held_groups_are_multiplied_by_nothing(case, what):
     rhs = jax.random.normal(jax.random.key(10), (len(sizes), k, n))
     weight = jax.random.normal(jax.random.key(11), (m, n))
     gs, live = jnp.array(sizes, jnp.int32), sum(sizes)
+    if tail == "nan_and_inf":
+        behind = (jnp.arange(m) >= live)[:, None]
+        bad = jnp.where(jnp.arange(m)[:, None] % 2, jnp.nan, jnp.inf)
+        lhs = jnp.where(behind, bad, lhs)
+        if what == "grad_lhs":  # the weights' gradient contracts a tile's
+            # cotangent rows against zeros (``_tgmm`` masks ``lhs`` alone)
+            weight = jnp.where(behind, bad, weight)
+    arg = {"forward": None, "grad_lhs": 0, "grad_rhs": 1}[what]
 
-    def by_group(a, b):
-        out, start = [], 0
-        for e, size in enumerate(sizes):
-            out.append(a[start:start + size] @ b[e])
-            start += size
-        out.append(jnp.zeros((m - start, n), a.dtype))
-        return jnp.concatenate(out)
+    def run(fn, a, w):
+        if arg is None:
+            return fn(a, rhs)
+        # the cotangent is ``w``: the rows behind the groups take theirs
+        # as it comes, NaN and all
+        out, vjp = jax.vjp(fn, a, rhs)
+        return vjp(w.astype(out.dtype))[arg]
 
-    if what == "forward":
-        got, want = grouped_matmul(lhs, rhs, gs), by_group(lhs, rhs)
+    got = run(lambda a, b: grouped_matmul(a, b, gs), lhs, weight)
+    # the reference never touches a row behind the groups: a clean tail
+    clean = [jnp.where((jnp.arange(m) < live)[:, None], x, 0.0)
+             for x in (lhs, weight)]
+    want = run(lambda a, b: _by_group(a, b, sizes), *clean)
+    assert bool(jnp.isfinite(got).all())
+    if what != "grad_rhs":
         assert float(jnp.abs(got[live:]).max()) == 0.0
-    else:
-        arg = 0 if what == "grad_lhs" else 1
-        got = jax.grad(lambda a, b: (grouped_matmul(a, b, gs) * weight).sum(),
-                       arg)(lhs, rhs)
-        want = jax.grad(lambda a, b: (by_group(a, b) * weight).sum(),
-                        arg)(lhs, rhs)
-        if what == "grad_lhs":
-            assert float(jnp.abs(got[live:]).max()) == 0.0
+        # cut off at the end of the last live tile: the same bits
+        tile = min(512, -(-m // 8) * 8)
+        cut = -(-live // tile) * tile
+        if 0 < cut < m:
+            short = run(lambda a, b: grouped_matmul(a, b, gs), lhs[:cut],
+                        weight[:cut])
+            assert jnp.array_equal(got[:live], short[:live])
+            assert float(jnp.abs(short[live:]).max(initial=0.0)) == 0.0
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
 
 
+@pytest.mark.parametrize("case", sorted(TAILS))
+def test_tail_tiles_is_the_tiles_wholly_behind_the_groups(case):
+    from chainermn_tpu.ops.grouped_matmul import tail_tiles
+
+    m, _, _, sizes = TAILS[case]
+    tile = min(512, -(-m // 8) * 8)
+    behind = [t for t in range(-(-m // tile)) if t * tile >= sum(sizes)]
+    assert int(tail_tiles(jnp.array(sizes, jnp.int32), m)) == len(behind)
+
+
+@pytest.mark.parametrize("batch", [2, 16])
 def test_the_losss_metrics_count_the_rows_that_reached_a_held_expert(
-        tiny, ref):
+        batch, tiny):
     """``moe/rows_held`` is the rows whose expert is held, summed over the
-    four expert layers, as the reference's own routing counts them;
-    ``moe/dropped`` counts a held row that falls out of its group."""
-    params, state, tokens = tiny
+    four expert layers, as each layer's own routing counts them;
+    ``moe/dropped`` counts a held row that falls out of its group;
+    ``moe/tail_tiles`` the row tiles behind the last held group (with 16
+    sequences a layer routes 2,048 rows, four tiles of 512, about half of
+    them to a held expert)."""
+    params, state, _ = tiny
+    tokens = jax.random.randint(jax.random.key(0), (batch, T), 0,
+                                TINY["vocab_size"])
     _, metrics = _system_loss(params, state, tokens)
-    want = 0
-    eps = TINY["norm_eps"]
-    # the reference's choice, layer by layer, on the system's own states
-    model = _model()
-    _, sown = model.apply({"params": params, ROUTER_STATE: state}, tokens,
-                          mutable=["moe_aux"])
+    _, sown = _model().apply({"params": params, ROUTER_STATE: state}, tokens,
+                             mutable=["moe_aux"])
+    rows = batch * T * 2
+    tile = min(512, rows)
+    want = tiles = 0
     for i in range(1, 5):
-        want += float(sown["moe_aux"][f"block_{i}"]["rows_held"][0])
+        held = float(sown["moe_aux"][f"block_{i}"]["rows_held"][0])
+        want += held
+        tiles += rows // tile - -(-int(held) // tile)
     assert float(metrics["moe/rows_held"]) == want
-    assert 0 < want < 4 * 2 * T * 2
+    assert 0 < want < 4 * rows
     assert float(metrics["moe/expert_load"].sum()) == want
     assert metrics["moe/expert_load"].shape == (4,)
     assert float(metrics["moe/dropped"]) == 0.0
+    assert float(metrics[train_path.MOE_TAIL_TILES]) == tiles
+    assert (tiles > 0) == (batch == 16)
 
 
 @pytest.mark.parametrize("n", [1, 3])

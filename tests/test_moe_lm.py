@@ -22,7 +22,7 @@ from chainermn_tpu.models import (
     lm_loss_moe,
 )
 from chainermn_tpu.observability import train_path
-from chainermn_tpu.ops.grouped_matmul import grouped_matmul
+from chainermn_tpu.ops.grouped_matmul import grouped_matmul, tail_tiles
 from chainermn_tpu.parallel import moe
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -103,6 +103,8 @@ def test_loss_and_every_gradient_leaf_match_the_reference(tiny, ref):
     assert float(metrics["moe/dropped"]) == 0.0
     assert float(metrics["moe/expert_load"].sum()) == 2 * 2 * 32 * 2
     assert metrics["moe/expert_load_max_over_mean"] >= 1.0
+    # every expert held and the rows fill their one tile: no tail
+    assert float(metrics[train_path.MOE_TAIL_TILES]) == 0.0
 
 
 def _bf16_router(real):
@@ -286,6 +288,14 @@ GROUPS = {
     "one_group_takes_all": (1024, 64, 128, [1024, 0]),
     "rows_past_the_groups": (1030, 64, 128, [500, 500]),
     "single_rows": (16, 8, 8, [1] * 16),
+    # a tail behind the groups (ISSUE 44): its tiles are written, not
+    # multiplied; opening on a tile boundary, inside the last group's tile,
+    # behind an empty last group, behind nothing, inside the one tile
+    "tail_opens_on_a_tile_boundary": (2048, 64, 128, [512, 512]),
+    "tail_shares_the_last_groups_tile": (2048, 64, 128, [300, 400]),
+    "tail_behind_an_empty_last_group": (2048, 64, 128, [700, 0]),
+    "every_group_empty": (1536, 64, 128, [0, 0, 0]),
+    "fewer_rows_than_a_tile": (100, 16, 24, [7, 20]),
 }
 
 
@@ -308,6 +318,38 @@ def test_grouped_matmul_against_a_loop_over_groups(case, what):
                         arg)(lhs, rhs)
     assert got.shape == want.shape and got.dtype == want.dtype
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("case", sorted(GROUPS))
+def test_tail_tiles_counts_the_tiles_behind_the_last_group(case):
+    m, _, _, sizes = GROUPS[case]
+    tile = 512 if m >= 512 else -(-m // 8) * 8
+    behind = [t for t in range(-(-m // tile)) if t * tile >= sum(sizes)]
+    got = tail_tiles(jnp.array(sizes, jnp.int32), m)
+    assert got.dtype == jnp.int32 and int(got) == len(behind)
+
+
+@pytest.mark.parametrize("case", sorted(GROUPS))
+def test_a_tail_item_fetches_no_tile_of_lhs(case):
+    """The block of ``lhs`` follows an item's tile while the item is a
+    group's; over the tail's items it stays on the groups' last tile, so
+    Pallas copies nothing for them (the block index does not change)."""
+    from chainermn_tpu.ops import grouped_matmul as gm
+
+    m, _, _, sizes = GROUPS[case]
+    tile = 512 if m >= 512 else -(-m // 8) * 8
+    rows = -(-m // tile) * tile
+    group_of, tile_of, _, _, total = gm._plan(
+        jnp.array(sizes, jnp.int32), rows, tile, cover_tail=True)
+    read = np.asarray(gm._tiles_read(group_of, tile_of, len(sizes)))
+    group_of, tile_of = np.asarray(group_of), np.asarray(tile_of)
+    held = group_of < len(sizes)
+    assert (read[held] == tile_of[held]).all()
+    assert held[0] and (read[~held] == tile_of[held][-1]).all()
+    # every tile is still visited, the tail's once each, in order
+    assert sorted(set(tile_of[:int(total[0])])) == list(range(rows // tile))
+    tails = tile_of[:int(total[0])][~held[:int(total[0])]]
+    assert (np.diff(tails) == 1).all() and tails[-1] == rows // tile - 1
 
 
 def test_grouped_matmul_bf16_operands_keep_f32_weights_gradient():

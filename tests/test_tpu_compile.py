@@ -135,6 +135,36 @@ def test_mosaic_compiles_the_short_conv_kernels(kernel, shape, dtype, topo):
     assert f"short_conv/{kernel}" in text
 
 
+@pytest.mark.parametrize("shape", [
+    (65536, 2048, 3584, 8),    # the LFM2 cell's gate|up: a quarter live
+    (131072, 768, 2048, 16),   # the SDAR cell's down: an eighth live
+    (131072, 2048, 2048, 64),  # the OLMoE cell's gate|up: no tail
+], ids=["lfm2_gate_up", "sdar_down", "olmoe_gate_up"])
+def test_mosaic_compiles_the_grouped_matmuls_kernels(shape, topo):
+    """Forward and both gradients at the three cells' shapes, bf16 rows on
+    float32 masters: the row products' tail items (a zero write under a
+    ``pl.when``, a block of ``lhs`` that stays put) are Mosaic's to take,
+    which the interpreter cannot vouch for."""
+    from jax.sharding import SingleDeviceSharding
+
+    from chainermn_tpu.ops.grouped_matmul import _grouped_matmul
+
+    m, k, n, e = shape
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    lhs = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((e, k, n), jnp.float32, sharding=one_chip)
+    gs = jax.ShapeDtypeStruct((e,), jnp.int32, sharding=one_chip)
+
+    def loss(a, b, sizes):
+        out = _grouped_matmul(a, b, sizes, False)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(lhs, rhs, gs).compile(
+    ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert " conditional(" not in text
+
+
 @pytest.mark.parametrize("strict", [False, True], ids=["inclusive", "strict"])
 def test_mosaic_compiles_the_flash_kernels_under_a_mask_by_blocks(strict,
                                                                   topo):

@@ -536,20 +536,24 @@ def _short_conv_timings(iters: int = 20):
 
 def _grouped_matmul_timings(iters: int = 5):
     """The grouped matmul at the OLMoE cell's shapes (131,072 rows of
-    uneven groups over 64 experts, bf16 rows, float32 master weights):
-    forward, and forward + both gradients, ms a call, chained in one
-    program like the flash timings."""
+    uneven groups over 64 experts, bf16 rows, float32 master weights) and
+    at the LFM2 cell's gate|up under its share (65,536 rows of which a
+    quarter reach the 8 held experts; the other 96 row tiles are the tail
+    the row products write as zeros): forward, and forward + both
+    gradients, ms a call, chained in one program like the flash timings."""
     import jax
     import jax.numpy as jnp
 
     from chainermn_tpu.ops.grouped_matmul import grouped_matmul
 
     rows = []
-    for k_dim, n_dim in ((2048, 2048), (1024, 2048)):
+    for m, live, e, k_dim, n_dim in ((131072, 131072, 64, 2048, 2048),
+                                     (131072, 131072, 64, 1024, 2048),
+                                     (65536, 16384, 8, 2048, 3584)):
         keys = jax.random.split(jax.random.PRNGKey(25), 3)
-        lhs = jax.random.normal(keys[0], (131072, k_dim), jnp.bfloat16)
-        rhs = jax.random.normal(keys[1], (64, k_dim, n_dim), jnp.float32)
-        sizes = _group_sizes(jax.random.normal(keys[2], (64,)), 131072)
+        lhs = jax.random.normal(keys[0], (m, k_dim), jnp.bfloat16)
+        rhs = jax.random.normal(keys[1], (e, k_dim, n_dim), jnp.float32)
+        sizes = _group_sizes(jax.random.normal(keys[2], (e,)), live)
 
         # the weights and the sizes are arguments: closed over they would
         # be a gigabyte of constants in the program
@@ -571,7 +575,8 @@ def _grouped_matmul_timings(iters: int = 5):
             return jax.lax.scan(step, x, None, length=iters)[0] \
                 .astype(jnp.float32).sum()
 
-        rows.append({"shape": f"M131072xE64xK{k_dim}xN{n_dim}_bf16",
+        rows.append({"shape": f"M{m}xE{e}xK{k_dim}xN{n_dim}_bf16"
+                              + (f"_live{live}" if live < m else ""),
                      "gmm_fwd_ms": _chained_ms(jax.jit(fwd), iters, lhs,
                                                rhs, sizes),
                      "gmm_fwdbwd_ms": _chained_ms(jax.jit(fwdbwd), iters,
