@@ -59,8 +59,8 @@ EXIT_GATE = "exit_gate"
 #: a block-diffusion model's training pass: round the draw of a noise
 #: level a block and a mask a token, the noised copy, the concatenation
 #: ``[x ; x~]`` and its positions; round the masked attention over the
-#: two copies (the flash kernels' clean-on-clean and noised-on-clean
-#: calls, the in-block part and the merge by log-sum-exps)
+#: two copies (the flash kernels' clean-on-clean, noised-on-clean and
+#: in-block calls and the merge by log-sum-exps)
 BD_NOISE = "bd_noise"
 BD_ATTENTION = "bd_attention"
 
@@ -166,6 +166,10 @@ LOOP_PASSES = "loop_passes"
 #: noised copy; the head sees half of them)
 BD_BLOCK_LENGTH = "bd_block_length"
 BD_ROWS_PER_STEP = "bd_rows_per_step"
+#: gauge set by a block-diffusion model's attention while it is traced:
+#: the diagonal tiles of one sequence and head its in-block call visits
+#: (``L / t``; the call has no tile off the diagonal)
+BD_IN_BLOCK_TILES = "bd_in_block_tiles"
 #: gauge set while the fused LM head is traced: 1 where the trace made the
 #: head's gradient inside its forward loop (it was differentiated), 0
 #: where it made the loss alone (evaluation)

@@ -1,5 +1,6 @@
 """Block diffusion on the trained path (SDAR's): the flash kernels' causal
-mask by blocks against a dense-mask attention, the attention over ``[x ;
+mask by blocks against a dense-mask attention, the in-block call on the
+diagonal tiles against a dense same-block mask, the attention over ``[x ;
 x~]`` assembled from its three parts, ``lm_loss_block_diffusion`` against
 the benchmark's plain reference, what a row may depend on, the noise, and
 the entry points that refuse the model."""
@@ -27,6 +28,7 @@ from chainermn_tpu.models import (
 )
 from chainermn_tpu.observability import train_path
 from chainermn_tpu.observability.metrics import registry
+from chainermn_tpu.ops import block_diffusion as bd
 from chainermn_tpu.ops.block_diffusion import block_diffusion_attention
 from chainermn_tpu.ops.flash_attention import flash_attention
 
@@ -224,6 +226,106 @@ def test_the_three_parts_are_the_two_copy_mask(L, H, Hkv, D, bl, ref):
         np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
     assert registry().snapshot()[train_path.BD_BLOCK_LENGTH]["values"][0][
         "value"] == float(bl)
+
+
+# -- the in-block call: a noised row's own block on the diagonal tiles -------
+
+def _same_block_mask(L, bl):
+    return jnp.arange(L)[:, None] // bl == jnp.arange(L)[None, :] // bl
+
+
+def _dense_lse(q, k, allowed, scale):
+    g = q.shape[2] // k.shape[2]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, g, axis=2)) * scale
+    return jax.nn.logsumexp(jnp.where(allowed, s, -jnp.inf), axis=-1)
+
+
+@pytest.mark.parametrize("tile_blocks", [2, 4])
+@pytest.mark.parametrize("bl", [4, 6], ids=["bl4", "bl6_divides_no_lane_tile"])
+@pytest.mark.parametrize("H,Hkv,D", [(2, 2, 16), (8, 1, 128)],
+                         ids=["group_of_1", "group_of_8"])
+def test_the_in_block_call_against_a_dense_same_block_mask(H, Hkv, D, bl,
+                                                           tile_blocks):
+    """Output, log-sum-exp and the three gradients of the call alone, at
+    two tile lengths ``t`` (the tiles are ``L / t`` sequences of their
+    own: nothing of a neighbouring tile may leak, and a block that ends
+    where a tile ends sees its own keys only)."""
+    L, t, scale = 48, bl * tile_blocks, D ** -0.5
+    q, k, v, do = _operands((2, L, H, D), (2, L, Hkv, D), seed=5)
+    allowed = _same_block_mask(L, bl)
+    out, lse = bd.in_block_fwd(q, k, v, bl=bl, t=t, scale=scale,
+                               interpret=True)
+    np.testing.assert_allclose(
+        out, _dense_attention(q, k, v, allowed, scale), atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, _dense_lse(q, k, allowed, scale),
+                               atol=2e-5, rtol=2e-5)
+    grads = bd.in_block_bwd(q, k, v, do, lse, out, bl=bl, t=t, scale=scale,
+                            interpret=True)
+    wants = jax.grad(lambda *a: (_dense_attention(*a, allowed, scale)
+                                 * do).sum(), (0, 1, 2))(q, k, v)
+    for got, want in zip(grads, wants):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_a_first_blocks_rows_are_their_in_block_part(direction):
+    """A noised row of the first block sees no clean key: the strict
+    call's partial for it is ``(0, NEG_INF)``, the merged pair is the
+    in-block call's, and in the backward nothing reaches the clean copy
+    through it and nothing is NaN."""
+    L, H, Hkv, D, bl = 32, 4, 2, 16, 4
+    scale = D ** -0.5
+    q, kc, vc, do = _operands((2, L, H, D), (2, L, Hkv, D), seed=6)
+    _, kn, vn, _ = _operands((2, L, H, D), (2, L, Hkv, D), seed=7)
+    out, res = bd._noised_rows_fwd(q, kc, vc, kn, vn, bl, scale, True)
+    own, own_lse = bd.in_block_fwd(q, kn, vn, bl=bl, t=L, scale=scale,
+                                   interpret=True)
+    if direction == "forward":
+        _, strict_lse = bd.flash_block_fwd(
+            q, kc, vc, scale=scale, interpret=True, causal_block=bl,
+            **bd._STRICT)
+        assert float(strict_lse[:, :, :bl].max()) <= fa.NEG_INF
+        assert bool(jnp.isfinite(res[-1]).all())
+        np.testing.assert_array_equal(np.asarray(res[-1][:, :, :bl]),
+                                      np.asarray(own_lse[:, :, :bl]))
+        np.testing.assert_array_equal(np.asarray(out[:, :bl]),
+                                      np.asarray(own[:, :bl]))
+        # and a later row's pair is the softmax over both key sets
+        assert float(jnp.abs(out[:, bl:] - own[:, bl:]).max()) > 1e-3
+        return
+    first = do.at[:, bl:].set(0.0)  # a cotangent on the first block alone
+    grads = bd._noised_rows_bwd(bl, scale, True, res, first)
+    assert all(bool(jnp.isfinite(g).all()) for g in grads)
+    dq, dk_clean, dv_clean, dk_noised, dv_noised = grads
+    assert float(jnp.abs(dk_clean).max()) == 0.0
+    assert float(jnp.abs(dv_clean).max()) == 0.0
+    for g in (dq, dk_noised, dv_noised):
+        assert float(jnp.abs(g[:, bl:]).max()) == 0.0
+    wants = jax.grad(lambda *a: (_dense_attention(
+        *a, _same_block_mask(L, bl), scale) * first).sum(), (0, 1, 2))(
+        q, kn, vn)
+    for got, want in zip((dq, dk_noised, dv_noised), wants):
+        np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("L,bl,tiles", [
+    (8192, 4, 16), (1536, 4, 3), (96, 32, 1), (2048, 1024, 2), (2036, 4, 509)],
+    ids=["the_cells", "three_tiles_of_512", "one_tile", "a_block_a_tile",
+         "a_prime_count_of_blocks"])
+def test_the_gauge_says_which_diagonal_tiles_were_visited(L, bl, tiles):
+    """``bd_in_block_tiles`` is ``L / t`` of the last attention traced: the
+    in-block call's ``L / t`` sequences are one tile each (``t`` the most
+    whole blocks that divide ``L`` within the kernels' 512-row tile), so
+    what it visits is the diagonal and nothing else."""
+    t = bd.in_block_tile(L, bl)
+    assert L % t == 0 and t % bl == 0 and L // t == tiles
+    assert t <= max(fa._TILES[0], bl)
+    q = jax.ShapeDtypeStruct((1, 2 * L, 2, 16), jnp.float32)
+    jax.eval_shape(lambda q, k, v: block_diffusion_attention(
+        q, k, v, block_length=bl), q, q, q)
+    assert registry().snapshot()[train_path.BD_IN_BLOCK_TILES]["values"][0][
+        "value"] == float(tiles)
 
 
 def test_rows_that_are_no_two_copies_of_whole_blocks_are_refused():

@@ -5,10 +5,17 @@ libtpu knows, and under it the default gradient reduction's all_to_alls
 come out as asynchronous pairs. A libtpu that renames or drops the
 option fails here, and not in a user's step. And Mosaic takes the gated
 short convolution's two kernels at the LFM2 cell's shape, which the
-interpreter's tests cannot say (it accepts layouts Mosaic refuses).
+interpreter's tests cannot say (it accepts layouts Mosaic refuses); so
+it does the grouped matmul's and the flash kernels' under the masks block
+diffusion calls them with, and a small SDAR step holds nine Mosaic calls
+a layer under ``bd_attention`` and no lane reduction of the in-block
+part's old spelling.
 
 One file, one fixture: only one process may load the TPU's library, so
 the topology is described inside the fixture and nowhere at import."""
+
+import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -191,3 +198,88 @@ def test_mosaic_compiles_the_flash_kernels_under_a_mask_by_blocks(strict,
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
     ).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_mosaic_compiles_the_in_block_calls_kernels(topo):
+    """The SDAR cell's noised copy (8192 positions, 32 / 4 heads of 128,
+    bf16, blocks of 4) as the in-block call sees it: 16 sequences of one
+    512-row tile each, no causal mask, segment ids a row's block within
+    its tile; forward, dq and dk/dv."""
+    from jax.sharding import SingleDeviceSharding
+
+    from chainermn_tpu.ops import block_diffusion as bd
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    L, bl = 8192, 4
+    kw = dict(bl=bl, t=bd.in_block_tile(L, bl), scale=128 ** -0.5,
+              interpret=False)
+    assert kw["t"] == 512
+    q = jax.ShapeDtypeStruct((1, L, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, L, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((1, 32, L), jnp.float32, sharding=one_chip)
+    call = 'custom_call_target="tpu_custom_call"'
+    fwd = jax.jit(lambda q, k, v: bd.in_block_fwd(q, k, v, **kw)).lower(
+        q, kv, kv).compile().as_text()
+    assert fwd.count(call) == 1
+    bwd = jax.jit(lambda *a: bd.in_block_bwd(*a, **kw)).lower(
+        q, kv, kv, q, lse, q).compile().as_text()
+    assert bwd.count(call) == 2
+
+
+def test_a_small_sdar_step_holds_nine_mosaic_calls_a_layer(topo,
+                                                           monkeypatch):
+    """The gradient of a two-layer SDAR of heads of 128, 8 query heads a
+    key-value head, at ``L`` 1024 under remat ``dots``: under
+    ``bd_attention`` three forward kernels a layer (clean on clean,
+    noised on clean, in-block) and six backward ones, none run again by
+    the recomputation; and no float32 sum over the head width of a
+    ``[B, L, Hkv, G, D]`` array, which is what the in-block part's
+    products were before they went through the kernels."""
+    from jax.sharding import SingleDeviceSharding
+
+    from chainermn_tpu.models import (
+        MODEL_CONFIGS,
+        lm_from_config,
+        lm_loss_block_diffusion,
+    )
+
+    for name in ("flash_attention", "grouped_matmul", "block_diffusion"):
+        monkeypatch.setattr(
+            importlib.import_module(f"chainermn_tpu.ops.{name}"),
+            "_use_interpret", lambda: False)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    L, layers = 1024, 2
+    config = dict(
+        MODEL_CONFIGS["sdar-30b-a3b"], num_hidden_layers=layers,
+        hidden_size=256, num_attention_heads=8, num_key_value_heads=1,
+        head_dim=128, moe_intermediate_size=128, num_experts=4,
+        experts_published=8, experts_held_range=[2, 6],
+        num_experts_per_tok=2, vocab_size=512, mask_token_id=511,
+        max_position_embeddings=L)
+    model = lm_from_config(config, compute_dtype=jnp.bfloat16,
+                           return_hidden=True, remat=True)
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(lambda: model.init(
+        jax.random.key(1), jnp.zeros((1, 2 * L), jnp.int32))["params"]))
+    lowered = jax.jit(jax.grad(lambda p, b, k: lm_loss_block_diffusion(
+        model, p, b, k, mask_id=511, n_chunks=2)[0])).lower(
+        params, placed(jax.ShapeDtypeStruct((1, L), jnp.int32)),
+        placed(jax.eval_shape(lambda: jax.random.key(0))))
+    calls = [line for line in lowered.compile().as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "bd_attention" in line]
+    assert len(calls) == 9 * layers
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(f"/{kernel}/pallas_call" in line
+                   for line in calls) == 3 * layers, kernel
+    lane_sums = [line for line in lowered.as_text().splitlines()
+                 if "stablehlo.reduce" in line
+                 and re.search(r"dimensions = \[4\] : \(tensor<1x1024x1x8x128xf32>",
+                               line)]
+    assert not lane_sums, lane_sums[:2]

@@ -55,6 +55,13 @@ Checked kernels:
   4 taps, run against the plain ``jax.numpy`` spelling in float32
   (``rel_err`` under ``_TOL``); ``timings`` carries the kernels' and the
   plain spelling's forward and forward + backward at the cell's shape
+- block diffusion's in-block call (``ops/block_diffusion.py``): the flash
+  kernels without a causal mask under segment ids that are a row's block
+  within its tile, at the SDAR cell's shape (8192 noised rows as 16
+  sequences of one 512-row tile, 32 / 4 heads of 128, blocks of 4),
+  forward (output and log-sum-exp) and the three gradients, run against
+  the band computed block by block in float32; ``timings`` carries the
+  call's forward + backward there
 - fused paged decode (ISSUE 19): plain tick T=1, verify span T>1,
   window, and the dense-cache wrapper — the ``(1, bs, 1, D)`` KV block
   (second-to-last dim 1 over the kv-head axis) is exactly the kind of
@@ -64,6 +71,7 @@ Usage (through the chip tool; needs the chip)::
 
     python tools/kernel_compile_check.py
     python tools/kernel_compile_check.py --json chiprun_out/kernels.json
+    python tools/kernel_compile_check.py --only bd_in_block   # no timings
 
 On CPU every case fails fast with the honest explanation (Mosaic
 lowering needs a TPU backend). Exit code: number of failed cases
@@ -309,6 +317,32 @@ def _cases():
         return dot_product_attention(q_, k_ext, v_ext, causal=True,
                                      q_offset=tail, bias=bias)
 
+    # Block diffusion's in-block call at the SDAR cell's shape. The
+    # reference computes the band a block at a time in float32: a dense
+    # [32, 8192, 8192] score array does not fit.
+    from chainermn_tpu.ops import block_diffusion as bd
+
+    in_block, sdar = _sdar_in_block()
+
+    def in_block_ref(q_, k_, v_):
+        bl, (B_, L_, H_, D_) = in_block["bl"], q_.shape
+
+        def blocks(x):
+            x = jnp.repeat(x, H_ // x.shape[2], axis=2)
+            return x.reshape(B_, L_ // bl, bl, H_, D_).astype(jnp.float32)
+
+        s = jnp.einsum("bnqhd,bnkhd->bnhqk", blocks(q_), blocks(k_),
+                       precision="highest") * in_block["scale"]
+        out = jnp.einsum("bnhqk,bnkhd->bnqhd", jax.nn.softmax(s, -1),
+                         blocks(v_), precision="highest")
+        lse = jax.nn.logsumexp(s, -1).transpose(0, 2, 1, 3)
+        return out.reshape(q_.shape), lse.reshape(B_, H_, L_)
+
+    def in_block_grads(q_, k_, v_):
+        out, lse = bd.in_block_fwd(q_, k_, v_, **in_block)
+        return bd.in_block_bwd(q_, k_, v_, jnp.ones_like(out), lse, out,
+                               **in_block)
+
     # Paged decode at the accel serving shape (bench._bench_serving):
     # slots=16, max_len=512, bs=32 — pool of 257 blocks (scratch + all).
     S, L, bs = 16, 512, 32
@@ -405,6 +439,11 @@ def _cases():
          grads(xla_window64(4096))),
         ("sp_window_ext_fwd", sp_window_ext_fwd, (qs, seg),
          per_example(sp_window_ext_ref)),
+        ("bd_in_block_fwd",
+         lambda q_, k_, v_: bd.in_block_fwd(q_, k_, v_, **in_block),
+         sdar, in_block_ref),
+        ("bd_in_block_grads", in_block_grads, sdar,
+         grads(lambda *a: in_block_ref(*a)[0])),
         paged("paged_decode_t1", 1),
         paged("paged_decode_verify_t4", 4),
         paged("paged_decode_window", 1, window=128),
@@ -414,6 +453,21 @@ def _cases():
         ("remat_dots_block_grads", remat_block_grads,
          (remat_params, remat_tokens), None),
     ]
+
+
+def _sdar_in_block():
+    """``(static arguments, (q, k, v) specs)`` of block diffusion's
+    in-block call at the SDAR cell's shape: 8192 noised rows, 32 / 4
+    heads of 128, bf16, blocks of 4."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu.ops.block_diffusion import in_block_tile
+
+    kw = dict(bl=4, t=in_block_tile(8192, 4), scale=128 ** -0.5,
+              interpret=False)
+    return kw, tuple(jax.ShapeDtypeStruct((1, 8192, h, 128), jnp.bfloat16)
+                     for h in (32, 4, 4))
 
 
 def _seeded(specs):
@@ -480,7 +534,8 @@ def _timings():
             samples.append((time.perf_counter() - t0) / iters * 1e3)
         rows.append({"shape": f"B{B}xT{T}xH{H}xD{D}_bf16_causal",
                      "flash_fwdbwd_ms": [round(x, 4) for x in samples]})
-    return rows + _grouped_matmul_timings() + _short_conv_timings()
+    return rows + _in_block_timings() + _grouped_matmul_timings() \
+        + _short_conv_timings()
 
 
 def _chained_ms(fn, iters: int, *args):
@@ -493,6 +548,37 @@ def _chained_ms(fn, iters: int, *args):
         float(fn(*args))
         samples.append((time.perf_counter() - t0) / iters * 1e3)
     return [round(x, 4) for x in samples]
+
+
+def _in_block_timings(iters: int = 10):
+    """Block diffusion's in-block call at the SDAR cell's shape (8192
+    noised rows, 32 / 4 heads of 128, blocks of 4): the forward kernel,
+    and forward + dq + dk/dv, ms a call."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu.ops import block_diffusion as bd
+
+    kw, specs = _sdar_in_block()
+    q, k, v = _seeded(specs)
+
+    def fwd(qc, k, v):
+        return bd.in_block_fwd(qc, k, v, **kw)[0]
+
+    def fwdbwd(qc, k, v):
+        out, lse = bd.in_block_fwd(qc, k, v, **kw)
+        dq, dk, dv = bd.in_block_bwd(qc, k, v, out, lse, out, **kw)
+        return (qc + 0.0001 * (dq + (dk + dv).sum(2, keepdims=True))
+                ).astype(qc.dtype)
+
+    def chained(step):
+        return jax.jit(lambda q, k, v: jax.lax.scan(
+            lambda qc, _: (step(qc, k, v), ()), q, None, length=iters,
+        )[0].astype(jnp.float32).sum())
+
+    return [{"shape": "bd_in_block_L8192xH32/4xD128_bl4_t512_bf16",
+             "fwd_ms": _chained_ms(chained(fwd), iters, q, k, v),
+             "fwdbwd_ms": _chained_ms(chained(fwdbwd), iters, q, k, v)}]
 
 
 def _short_conv_timings(iters: int = 20):
@@ -588,6 +674,8 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None,
                     help="also write the result rows to this path")
+    ap.add_argument("--only", default=None,
+                    help="run the cases whose name holds this, no timings")
     args = ap.parse_args()
 
     import jax
@@ -595,6 +683,8 @@ def main() -> int:
     backend = jax.devices()[0].platform
     rows = []
     for name, fn, specs, ref in _cases():
+        if args.only and args.only not in name:
+            continue
         _note(f"compiling {name} (backend={backend})")
         t0 = time.perf_counter()
         row = {"kernel": name}
@@ -625,10 +715,10 @@ def main() -> int:
         "failures": failures,
         "results": rows,
     }
-    if backend == "tpu":
+    if backend == "tpu" and not args.only:
         _note("timing flash forward+backward")
         out["timings"] = _timings()
-    else:
+    elif backend != "tpu":
         out["note"] = (
             "non-TPU backend: Mosaic never ran, failures here say "
             "nothing about the chip — run it through the chip tool"
