@@ -457,13 +457,15 @@ class CommunicatorBase:
         derives the topology-aware schedule). Subclasses may pin an explicit
         algorithm (:class:`TwoDimensionalCommunicator`).
 
-        ``schedule`` overrides the strategy with a pinned one from
-        :mod:`chainermn_tpu.parallel.reduction_schedule` (``'flat'`` =
-        bucketed packed allreduce, ``'two_level'`` = reduce-scatter ->
-        shard allreduce -> allgather per bucket); the optimizer wrapper's
-        ``reduction_schedule=`` is the normal front door — this knob
-        exists for hand-rolled steps that call the communicator directly.
-        Outside the named-axis context both forms degrade identically."""
+        ``schedule`` overrides the strategy with one of the two bucketed
+        schedules of
+        :func:`chainermn_tpu.parallel.reduction_schedule.reduce_tree`
+        (``'flat'`` = one ``pmean`` per packed bucket, ``'two_level'`` =
+        reduce-scatter -> shard allreduce -> allgather per bucket); the
+        optimizer wrapper's ``reduction_schedule=`` is the normal front
+        door — this knob exists for hand-rolled steps that call the
+        communicator directly. Outside the named-axis context both forms
+        degrade identically."""
         from chainermn_tpu.optimizers import allreduce_gradients
 
         if compress_dtype is None:

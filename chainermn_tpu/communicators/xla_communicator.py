@@ -162,8 +162,9 @@ class TwoDimensionalCommunicator(HierarchicalCommunicator):
         of the shard -> intra ``all_gather``. An int8 compress dtype
         selects the quantized wire at the ONLY stage where compression
         pays — the shard crossing inter/DCN — with the intra reduction
-        exact. Trace-time ``pack`` + per-bucket ``wire`` events record
-        the layout and the bucket decision's provenance."""
+        exact. Trace-time events record the layout: one ``pack`` with
+        the bucket decision's provenance, one ``wire`` per bucket per
+        stage."""
         from chainermn_tpu.parallel.collectives import axes_bound
         from chainermn_tpu.parallel.reduction_schedule import reduce_tree
 

@@ -421,69 +421,31 @@ def read_jsonl(path: str) -> list[dict]:
 
 def summarize_overlap(events: Iterable[Mapping[str, Any]]) -> Optional[dict]:
     """Comm/compute-overlap rollup from ``wire`` + ``overlap_config``
-    events (ISSUE 3: the consumer side of the per-bucket wire events;
-    one owner shared by ``tools/trace_report.py`` and bench).
+    events (the consumer side of the per-bucket wire events; one owner
+    shared by ``tools/trace_report.py`` and bench).
 
     Two wire-event flavours feed it:
 
-    - trace-time layout events (in-jit bucketed schedules; no
-      ``dur_s``): counted per schedule with their ``overlapped`` flag —
-      what the compiled program COMMITTED to. Events carrying a
-      ``composition`` signature (ISSUE 12: one event per bucket per
-      STAGE) group under ``compositions`` instead, keyed by signature
-      with a per-stage bytes/time table — the consumer side of the
-      composed schedules' stage events. Stage events carrying a
-      ``slice`` address (ISSUE 15: sliced compositions emit one event
-      per stage PER SLICE) additionally group under the stage row's
-      ``slices`` sub-table (``s<i>`` -> n/bytes and, when measured,
-      ``dur_ms``/``blocked_ms``), while the stage row keeps the
-      across-slice totals — per-slice columns without disturbing
-      unsliced rows;
+    - trace-time layout events (``reduce_tree``'s in-jit bucketed
+      schedules, one event per bucket per stage; no ``dur_s``): counted
+      per schedule under ``schedules`` — buckets (a bucket's first
+      stage, ``stage_index`` 0, counts it and its ``overlapped`` flag)
+      and the bytes its stages carry — what the compiled program
+      COMMITTED to;
     - measured events (the eager ``OverlappedBucketReducer``; ``dur_s``
       = dispatch->ready, ``blocked_s`` = wait actually paid at
       collect): aggregated into comm time total vs comm time hidden
       behind compute, and the ``hidden_fraction`` between them.
 
-    ``sched_search`` events (ISSUE 16: the cost-model schedule search's
-    audit record — predicted prices for every ranked arm, measured ms
-    for the arms actually timed, the model error vs the measurement
-    spread) land under ``sched_search``: per-signature
-    predicted/measured rows plus the mode/provenance/error header the
-    report's loud-flag rule keys on — and each matching composition row
-    above gains a ``predicted_ms`` column.
-
     Returns None when the trace carries none (section omitted)."""
     configs: list[dict] = []
     layout: dict = {}
-    composed: dict = {}
-    search: Optional[dict] = None
     n_measured = 0
     comm_s = 0.0
     blocked_s = 0.0
     for ev in events:
         kind = ev.get("kind")
-        if kind == "sched_search":
-            rows: dict = {}
-            pred = ev.get("predicted_ms") or {}
-            meas = ev.get("measured_ms") or {}
-            for sig in sorted(set(pred) | set(meas)):
-                row: dict = {}
-                if sig in pred:
-                    row["predicted_ms"] = round(float(pred[sig]), 4)
-                if sig in meas:
-                    row["measured_ms"] = round(float(meas[sig]), 4)
-                else:
-                    row["skipped"] = True
-                rows[sig] = row
-            search = {
-                "mode": ev.get("mode"),
-                "provenance": ev.get("provenance"),
-                "rows": rows,
-            }
-            for k in ("err_pct", "spread_pct"):
-                if ev.get(k) is not None:
-                    search[k] = float(ev[k])
-        elif kind == "overlap_config":
+        if kind == "overlap_config":
             configs.append({
                 k: ev.get(k)
                 for k in ("double_buffering", "staleness", "schedule",
@@ -491,59 +453,15 @@ def summarize_overlap(events: Iterable[Mapping[str, Any]]) -> Optional[dict]:
             })
         elif kind == "wire":
             dur = ev.get("dur_s")
-            if ev.get("composition"):
-                sig = str(ev["composition"])
-                row = composed.setdefault(sig, {
-                    "schedule": str(ev.get("schedule", sig)),
-                    "buckets": 0, "nbytes": 0, "overlapped": 0,
-                    "stages": {},
-                })
-                # stage_index 0 marks a bucket's first stage event —
-                # one bucket, not one per stage
-                if not ev.get("stage_index"):
-                    row["buckets"] += 1
-                    row["overlapped"] += 1 if ev.get("overlapped") else 0
-                row["nbytes"] += int(ev.get("nbytes") or 0)
-                st = row["stages"].setdefault(
-                    str(ev.get("stage", "?")),
-                    {"op": ev.get("stage_op"), "n": 0, "nbytes": 0},
-                )
-                st["n"] += 1
-                st["nbytes"] += int(ev.get("nbytes") or 0)
-                if dur is not None:
-                    # a measured composed event (eager executors):
-                    # per-stage time lands in the table too
-                    st["dur_ms"] = round(
-                        st.get("dur_ms", 0.0) + float(dur) * 1e3, 4
-                    )
-                b = ev.get("blocked_s")
-                if b is not None:
-                    st["blocked_ms"] = round(
-                        st.get("blocked_ms", 0.0) + float(b) * 1e3, 4
-                    )
-                if ev.get("slice") is not None:
-                    # ISSUE 15: the per-slice column of the stage table
-                    sl = st.setdefault("slices", {}).setdefault(
-                        f"s{int(ev['slice'])}", {"n": 0, "nbytes": 0}
-                    )
-                    sl["n"] += 1
-                    sl["nbytes"] += int(ev.get("nbytes") or 0)
-                    if dur is not None:
-                        sl["dur_ms"] = round(
-                            sl.get("dur_ms", 0.0) + float(dur) * 1e3, 4
-                        )
-                    if b is not None:
-                        sl["blocked_ms"] = round(
-                            sl.get("blocked_ms", 0.0) + float(b) * 1e3, 4
-                        )
-            elif dur is None:
+            if dur is None:
                 key = str(ev.get("schedule", "?"))
                 row = layout.setdefault(
                     key, {"buckets": 0, "nbytes": 0, "overlapped": 0}
                 )
-                row["buckets"] += 1
+                if not ev.get("stage_index"):
+                    row["buckets"] += 1
+                    row["overlapped"] += 1 if ev.get("overlapped") else 0
                 row["nbytes"] += int(ev.get("nbytes") or 0)
-                row["overlapped"] += 1 if ev.get("overlapped") else 0
             else:
                 n_measured += 1
                 comm_s += float(dur)
@@ -551,8 +469,7 @@ def summarize_overlap(events: Iterable[Mapping[str, Any]]) -> Optional[dict]:
                 # FULLY-HIDDEN bucket and must count as such.
                 b = ev.get("blocked_s")
                 blocked_s += float(dur if b is None else b)
-    if (not configs and not layout and not composed and not n_measured
-            and search is None):
+    if not configs and not layout and not n_measured:
         return None
     out: dict = {}
     if configs:
@@ -561,18 +478,6 @@ def summarize_overlap(events: Iterable[Mapping[str, Any]]) -> Optional[dict]:
         out["schedules"] = {
             k: layout[k] for k in sorted(layout)
         }
-    if composed:
-        if search is not None:
-            # the predicted-vs-measured column on the composition rows
-            for sig, row in composed.items():
-                p = search["rows"].get(sig, {}).get("predicted_ms")
-                if p is not None:
-                    row["predicted_ms"] = p
-        out["compositions"] = {
-            k: composed[k] for k in sorted(composed)
-        }
-    if search is not None:
-        out["sched_search"] = search
     if n_measured:
         hidden_s = max(0.0, comm_s - blocked_s)
         out["measured"] = {

@@ -222,14 +222,16 @@ class MultiNodeOptimizer:
     (:mod:`chainermn_tpu.parallel.reduction_schedule`; see
     docs/parallelism.md "Gradient-reduction schedules"):
 
-    - ``None`` (default): the communicator's own strategy — base: fused
-      pmean; two_dimensional: its packed two-level pipeline. Exactly
-      the pre-schedule behaviour.
-    - ``'flat'``: the packed flat allreduce, pinned (the reference's
-      ``_memory_utility.pack_params`` (dagger) discipline).
+    - ``None`` (default): the communicator's own strategy — base:
+      :func:`allreduce_gradients` (every large matrix reduced where it
+      lies, the rest one fused pmean); two_dimensional: its packed
+      two-level pipeline.
+    - ``'flat'``: the packed flat allreduce, one ``pmean`` per ~64 MB
+      bucket (the reference's ``_memory_utility.pack_params`` (dagger)
+      discipline).
     - ``'two_level'``: intra reduce-scatter -> inter allreduce on the
-      shard -> allgather, per ~64 MB bucket (HiCCL-style composition,
-      arXiv:2408.05962).
+      shard -> allgather, per ~64 MB bucket (the reference's
+      ``two_dimensional_communicator.py`` (dagger) pipeline).
     - ``'zero'``: reduce-scatter + SHARDED update + allgather — the
       inner optimizer runs on 1/n of the parameters with 1/n of its
       state (arXiv:2004.13336), fused with
@@ -238,9 +240,8 @@ class MultiNodeOptimizer:
       through ``shard_map`` with :meth:`opt_state_spec`
       (``make_train_step`` does this automatically). Incompatible with
       ``double_buffering``, ``error_feedback`` and the int8 wire.
-    - ``'auto'``: resolved once per optimizer instance through the
-      decision registry (decision ``'reduction_schedule'``, keyed
-      device_kind x world-shape x payload-MB bucket).
+
+    Anything else raises a ``ValueError``.
 
     ``double_buffering=True`` is the OVERLAPPED mode: the update
     consumes the PREVIOUS step's banked buckets while this step's
@@ -295,38 +296,15 @@ class MultiNodeOptimizer:
                 "(allreduce_grad_dtype=jnp.int8) — other dtypes lose "
                 "nothing systematic to feed back"
             )
-        from chainermn_tpu.parallel.composition import (
-            Composition,
-            CompositionError,
-            compile_schedule,
-        )
         from chainermn_tpu.parallel.reduction_schedule import SCHEDULES
 
-        if reduction_schedule not in (None, "auto") + SCHEDULES:
-            # Beyond the menu: a composition signature string or a
-            # Composition instance (ISSUE 12) — validated against this
-            # communicator's mesh axes NOW, so a broken pipeline fails
-            # at construction, not inside the compiled step.
-            try:
-                comp = compile_schedule(
-                    reduction_schedule, communicator.grad_axes
-                )
-            except CompositionError as e:
-                raise ValueError(
-                    f"reduction_schedule must be one of "
-                    f"{(None, 'auto') + SCHEDULES}, a composition "
-                    f"signature, or a Composition; got "
-                    f"{reduction_schedule!r} ({e})"
-                ) from None
-            if comp.has_update:
-                raise ValueError(
-                    f"reduction_schedule composition "
-                    f"{comp.signature()!r} carries a sharded_update "
-                    "stage — spell the structural form as "
-                    "reduction_schedule='zero'"
-                )
-            if isinstance(reduction_schedule, Composition):
-                reduction_schedule = comp  # normalized+validated
+        if not (reduction_schedule is None
+                or isinstance(reduction_schedule, str)
+                and reduction_schedule in SCHEDULES):
+            raise ValueError(
+                "reduction_schedule must be None, 'flat', 'two_level' or "
+                f"'zero'; got {reduction_schedule!r}"
+            )
         if error_feedback and reduction_schedule not in (None, "flat"):
             raise ValueError(
                 "error_feedback owns its reduction (the flat or the "
@@ -348,26 +326,6 @@ class MultiNodeOptimizer:
                     "compression or the flat/two_level schedules"
                 )
         self.reduction_schedule = reduction_schedule
-        #: candidates an ``'auto'`` resolution may pick: the DERIVED
-        #: choice set for this mesh's axis count (menu names + the
-        #: compositions the menu cannot express, by signature —
-        #: chainermn_tpu.parallel.composition.schedule_candidates).
-        #: ``'zero'`` is eligible only when nothing structurally
-        #: incompatible is on; beyond-menu compositions only on a
-        #: lossless/bf16 wire (the int8 two-phase wire has flat and
-        #: two-level renderings only).
-        from chainermn_tpu.parallel.composition import schedule_candidates
-
-        self._auto_candidates = tuple(
-            s for s in schedule_candidates(len(communicator.grad_axes))
-            if not (s == "zero" and (double_buffering or error_feedback
-                                     or self._int8_wire()))
-            and not (s not in SCHEDULES and self._int8_wire())
-        )
-        #: the one-shot 'auto' resolution (first need wins — init and
-        #: update must agree on the state layout) + its registry record.
-        self._auto_resolved: str | None = None
-        self._schedule_provenance: dict | None = None
         # One resolution per optimizer instance: init's residual
         # allocation and update's reduction must see the same bucket
         # layout. The table-default 64 MB resolves to None —
@@ -403,38 +361,13 @@ class MultiNodeOptimizer:
     def _zero_n(self) -> int:
         return int(self.communicator.mesh.shape[self._zero_axis()])
 
-    def _effective_schedule(self, tree: PyTree | None = None) -> str | None:
-        """The schedule this update runs: the explicit choice, the
-        one-shot ``'auto'`` resolution (payload taken from ``tree``),
-        or — for the default ``None`` — the communicator's own strategy,
-        EXCEPT under double buffering, where the overlapped mode runs
-        the bucketed pipeline so each in-flight bucket is a separately
+    def _effective_schedule(self) -> str | None:
+        """The schedule this update runs: the explicit choice, or — for
+        the default ``None`` — the communicator's own strategy, EXCEPT
+        under double buffering, where the overlapped mode runs the
+        bucketed pipeline so each in-flight bucket is a separately
         schedulable (and separately traced) collective."""
         s = self.reduction_schedule
-        if s == "auto":
-            if self._auto_resolved is None:
-                from chainermn_tpu.parallel.reduction_schedule import (
-                    resolve_schedule,
-                )
-
-                payload = sum(
-                    leaf.size * jnp.dtype(leaf.dtype).itemsize
-                    for leaf in jax.tree.leaves(tree)
-                ) if tree is not None else 0
-                comm = self.communicator
-                winner, rec = resolve_schedule(
-                    comm.device_kind, payload,
-                    tuple(int(v) for v in comm.mesh.shape.values()),
-                    candidates=self._auto_candidates,
-                    # comp_slices (ISSUE 15): slice the winner where a
-                    # measured capture adopted an interleave — except
-                    # on the int8 wire, whose two-phase scheme has no
-                    # sliced rendering.
-                    slices=(None if self._int8_wire() else "auto"),
-                )
-                self._auto_resolved = winner
-                self._schedule_provenance = rec
-            return self._auto_resolved
         if s is None and self.double_buffering:
             return ("two_level"
                     if getattr(self.communicator, "two_level_axes", None)
@@ -462,7 +395,6 @@ class MultiNodeOptimizer:
             compress_dtype=self.compress_dtype,
             bucket_bytes=self._bucket_bytes,
             overlapped=self.double_buffering,
-            provenance=self._schedule_provenance,
             size=comm.size,
         )
 
@@ -472,21 +404,10 @@ class MultiNodeOptimizer:
         shards every (stacked) state leaf over the scatter axis;
         everything else is replicated. ``make_train_step`` consumes
         this automatically; hand-rolled steps pass it as the state's
-        ``in_specs``/``out_specs`` entry.
-
-        An unresolved ``'auto'`` is resolved HERE (payload unknown —
-        the 1 MB key bucket) rather than silently reported replicated:
-        the resolution is one-shot, so whichever of init()/this runs
-        first fixes the schedule and the other agrees — never a spec
-        that contradicts the state layout. Call ``init`` (or
-        ``create_train_state``) first when the payload-keyed cache
-        entry should decide."""
+        ``in_specs``/``out_specs`` entry."""
         from jax.sharding import PartitionSpec as P
 
-        sched = self.reduction_schedule
-        if sched == "auto":
-            sched = self._effective_schedule(None)
-        if sched == "zero":
+        if self.reduction_schedule == "zero":
             return _ZeroShardState(inner=P(self._zero_axis()))
         return P()
 
@@ -502,8 +423,17 @@ class MultiNodeOptimizer:
         over the full stacked state — elementwise inner transforms make
         that exactly the full-parameter update, so eager/pjit callers
         see identical numerics with zero collectives."""
-        from chainermn_tpu.parallel.collectives import axes_bound, axes_size
-        from chainermn_tpu.parallel.zero import _chunk_rows, _unchunk
+        from chainermn_tpu.parallel.collectives import (
+            axes_bound,
+            axes_size,
+            publish_grad_wire,
+        )
+        from chainermn_tpu.parallel.zero import (
+            _chunk_rows,
+            _unchunk,
+            zero_gather_updates,
+            zero_grad_scatter,
+        )
 
         inner = self.actual_optimizer
         comm = self.communicator
@@ -542,28 +472,21 @@ class MultiNodeOptimizer:
         n_tot = axes_size(names)
         idx = lax.axis_index(ax)
 
-        # The 'zero' schedule IS a composition instance (ISSUE 12):
-        # rs(fast) > [ar(rest)] > sharded_update > ag(fast) — the
-        # reduce prefix and gather suffix run through the one staged
-        # executor, with the inner optimizer fused between them.
-        from chainermn_tpu.parallel.composition import (
-            run_gather_suffix,
-            run_reduce_prefix,
-            zero_composition,
-        )
+        def mean_chunk(g):
+            # reduce-scatter over the last axis, all-reduce of the shard
+            # over the others, on the compressed wire where there is one
+            wire = g.reshape(-1)
+            if compress is not None and jnp.issubdtype(g.dtype,
+                                                       jnp.floating):
+                wire = wire.astype(compress)
+            return zero_grad_scatter(
+                wire, ax, extra_axes=names[:-1], total=n_tot
+            ).astype(g.dtype)
 
-        from chainermn_tpu.parallel.collectives import publish_grad_wire
-
-        pre, post = zero_composition(names).split_update()
         leaves = jax.tree.leaves(grads)
         publish_grad_wire(leaves, compress, names, len(leaves))
         with jax.named_scope(train_path.GRAD_REDUCE):
-            gchunks = jax.tree.map(
-                lambda g: run_reduce_prefix(
-                    g, pre, total=n_tot, wire_dtype=compress
-                ),
-                grads,
-            )
+            gchunks = jax.tree.map(mean_chunk, grads)
         pchunks = (jax.tree.map(
             lambda p: lax.dynamic_index_in_dim(
                 _chunk_rows(p, n), idx, keepdims=False
@@ -575,15 +498,14 @@ class MultiNodeOptimizer:
         inner_state = jax.tree.map(lambda e: e[None], schunk)
 
         updates = jax.tree.map(
-            lambda u, g: run_gather_suffix(u, g, post, pre),
-            uchunks, grads,
+            lambda u, g: zero_gather_updates(u, g, ax), uchunks, grads
         )
         return updates, _ZeroShardState(inner=inner_state)
 
     # -- optax protocol ----------------------------------------------------
 
     def init(self, params: PyTree):
-        if self._effective_schedule(params) == "zero":
+        if self._effective_schedule() == "zero":
             # 1/n state per shard, stacked [n, ...] (scalar counters
             # tiled) so ONE prefix spec shards the whole subtree — the
             # layout _zero_update and opt_state_spec() both key on.
@@ -766,7 +688,7 @@ class MultiNodeOptimizer:
                     grads, ef_state.residual
                 )
         else:
-            schedule = self._effective_schedule(grads)
+            schedule = self._effective_schedule()
             if schedule == "zero":
                 return self._zero_update(grads, state, params)
 
@@ -1000,7 +922,7 @@ def create_multi_node_optimizer(
     systematic rounding bias (the cumulative applied gradient tracks the
     exact mean to one-step noise instead of drifting linearly).
     ``reduction_schedule`` picks the reduction algorithm
-    ('flat'/'two_level'/'zero'/'auto'; see
+    (None/'flat'/'two_level'/'zero'; see
     :class:`MultiNodeOptimizer` and docs/parallelism.md)."""
     return MultiNodeOptimizer(
         actual_optimizer,

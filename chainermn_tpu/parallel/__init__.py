@@ -57,19 +57,11 @@ def __getattr__(name):
         from chainermn_tpu.parallel import plan_specs as _pspec
 
         return getattr(_pspec, name)
-    if name in ("reduce_tree", "resolve_schedule", "bucket_partition",
+    if name in ("reduce_tree", "bucket_partition",
                 "OverlappedBucketReducer", "SCHEDULES"):
         from chainermn_tpu.parallel import reduction_schedule as _rs
 
         return getattr(_rs, name)
-    if name in ("Composition", "CompositionError", "Stage",
-                "compile_schedule", "derive_compositions",
-                "parse_signature", "predicted_collectives",
-                "reduce_composed", "schedule_candidates",
-                "validate_composition", "zero_composition"):
-        from chainermn_tpu.parallel import composition as _comp
-
-        return getattr(_comp, name)
     if name in ("moe_layer_local", "top1_route", "topk_route",
                 "load_balancing_loss", "make_expert_params",
                 "moe_capacity", "routing_stats",
@@ -129,21 +121,9 @@ __all__ = [
     "AxisSpec",
     "CANONICAL_AXES",
     "reduce_tree",
-    "resolve_schedule",
     "bucket_partition",
     "OverlappedBucketReducer",
     "SCHEDULES",
-    "Composition",
-    "CompositionError",
-    "Stage",
-    "compile_schedule",
-    "derive_compositions",
-    "parse_signature",
-    "predicted_collectives",
-    "reduce_composed",
-    "schedule_candidates",
-    "validate_composition",
-    "zero_composition",
     "moe_layer_local",
     "top1_route",
     "topk_route",

@@ -365,10 +365,11 @@ def decomposed_allreduce(x: jax.Array, axes, *, op: str = "mean") -> jax.Array:
     ``TwoDimensionalCommunicator`` pipeline
     (``two_dimensional_communicator.py`` (dagger)); on a flat mesh it
     pins the reduce-scatter -> all-gather schedule XLA would otherwise
-    be free to fuse back into one all-reduce — the explicit form the
+    be free to fuse back into one all-reduce. A bucket of the
     ``'two_level'`` reduction schedule
-    (:mod:`chainermn_tpu.parallel.reduction_schedule`) compiles to,
-    HiCCL-style hierarchy-aware composition (arXiv:2408.05962)."""
+    (:func:`chainermn_tpu.parallel.reduction_schedule.reduce_tree`) is
+    one call of this (hierarchy-aware reduction, HiCCL,
+    arXiv:2408.05962)."""
     if op not in ("sum", "mean"):
         raise ValueError(f"op must be 'sum' or 'mean', got {op!r}")
     names = _names_tuple(axes)
@@ -484,35 +485,9 @@ def two_level_shard_len(size: int, n_intra: int) -> int:
     return -(-size // n_intra)
 
 
-# ---------------------------------------------------------------------------
-# Staged primitives over MERGED axis tuples — the composition layer's
-# vocabulary (chainermn_tpu.parallel.composition): each is one stage of
-# a composed reduction pipeline, one XLA collective over the flattened
-# product of its axis group.
-# ---------------------------------------------------------------------------
-
-
 def _merged_axes_arg(axes):
     names = _names_tuple(axes)
     return names if len(names) > 1 else names[0]
-
-
-def staged_reduce_scatter(flat: jax.Array, axes) -> jax.Array:
-    """One composition stage: ceil-pad the flat buffer into
-    ``[n, c]`` rows over the MERGED axis group ``axes`` (``n`` = the
-    product of their sizes, ``c`` = :func:`two_level_shard_len`) and
-    ``psum_scatter`` it — this member's exactly-summed 1/n shard. The
-    padding rule is the two-level frame's, so a single-axis stage is
-    byte-identical to the pinned ``decomposed_allreduce`` scatter."""
-    names = _names_tuple(axes)
-    n = 1
-    for a in names:
-        n *= lax.axis_size(a)
-    c = two_level_shard_len(flat.size, n)
-    rows = jnp.pad(flat, (0, n * c - flat.size)).reshape(n, c)
-    return lax.psum_scatter(
-        rows, _merged_axes_arg(names), scatter_dimension=0, tiled=False
-    )
 
 
 #: the XLA:TPU option under which an ``all_to_all`` compiles to an
@@ -595,62 +570,6 @@ def all_to_all_mean(x: jax.Array, axes, axis: int = 0) -> jax.Array:
     out = lax.all_to_all(jnp.broadcast_to(mean, x.shape), names, axis, axis)
     out = out.reshape(shape)
     return lax.slice_in_dim(out, 0, size, axis=axis) if pad else out
-
-
-def staged_allreduce(x: jax.Array, axes) -> jax.Array:
-    """One composition stage: ``psum`` over the merged axis group."""
-    return lax.psum(x, _names_tuple(axes))
-
-
-def staged_allgather(shard: jax.Array, axes, orig_size: int) -> jax.Array:
-    """One composition stage: the conjugate gather of
-    :func:`staged_reduce_scatter` — ``all_gather`` the shard rows back
-    over the merged group and un-pad to ``orig_size`` elements."""
-    rows = lax.all_gather(shard, _merged_axes_arg(axes), axis=0, tiled=False)
-    return rows.reshape(-1)[:orig_size]
-
-
-def staged_broadcast(
-    x: jax.Array, axes, *, radix: int = 2, root: int = 0
-) -> jax.Array:
-    """One composition stage (ISSUE 16): multicast-tree broadcast of
-    the ``root`` member's buffer over the MERGED axis group — every
-    member returns the root's ``x``. The tree is ``ceil(log_radix(n))``
-    ``ppermute`` rounds of holder-doubling: non-holders carry zeros, so
-    each round's ``cur + ppermute(cur)`` either delivers the payload or
-    adds zero, and round d multiplies the holder set by ``radix``
-    (holder s sends to ``s + j*holders`` for ``j in 1..radix-1``). The
-    HLO carries exactly ``tree_depth(n, radix)`` collective-permutes —
-    the count :func:`chainermn_tpu.parallel.composition
-    .predicted_collectives` pins and the serving tree push's donor
-    depth mirrors (multicast-tree collectives, arXiv:2605.22428)."""
-    names = _names_tuple(axes)
-    n = axes_size(names)
-    r = int(radix)
-    if r < 2:
-        raise ValueError(f"multicast radix must be >= 2, got {radix}")
-    if n == 1:
-        return x
-    idx = axes_index(names)
-    rk = int(root) % n
-    # Relabel so the root is position 0 in tree coordinates.
-    pos = lambda s: (s + rk) % n  # noqa: E731 — tree coord -> rank
-    cur = jnp.where(idx == rk, x, jnp.zeros_like(x))
-    arg = _merged_axes_arg(names)
-    holders = 1
-    while holders < n:
-        # ppermute sources must be unique, so a radix-r round is r-1
-        # ppermutes (sub-send j: holder s -> s + j*holders); the
-        # destination sets are disjoint and sources never receive, so
-        # sequential accumulation within a round is exact. Op count =
-        # composition.tree_sends (the structural pin).
-        for j in range(1, r):
-            perm = [(pos(s), pos(s + j * holders))
-                    for s in range(holders) if s + j * holders < n]
-            if perm:
-                cur = cur + lax.ppermute(cur, arg, perm)
-        holders = min(n, holders * r)
-    return cur
 
 
 def int8_two_level_allreduce_mean_with_feedback(

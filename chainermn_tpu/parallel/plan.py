@@ -135,26 +135,12 @@ class ParallelPlan:
         ICI-topology-aware via :func:`~chainermn_tpu.parallel.mesh.
         make_mesh` — on a pod slice the 2-D ``(dcn, ici)`` factorisation
         falls out of the canonical order.
-      grad_reduction: optional schedule for the data-parallel gradient
-        reduction of the non-ZeRO update groups — a menu name, a
-        composition signature, or a
-        :class:`~chainermn_tpu.parallel.composition.Composition` over
-        exactly this plan's dp axes (``data`` [+ ``zero``]), validated
-        at construction (ISSUE 12). Default ``None`` keeps the fused
-        ``pmean`` (byte-identical to the pre-composition plan; the
-        single-stage ``ar(all)`` composition compiles to the same
-        program). A composition with stages acts as a SPEC PROVIDER:
-        the affected axes' owed collectives in :meth:`describe` come
-        from its stage list
-        (:func:`~chainermn_tpu.parallel.plan_specs.
-        composition_collectives`).
       zero_stacked_groups: chunk the STACKED groups' (``model``/``pipe``)
         optimizer state over the ``zero`` axis too (ISSUE 13 — TP x ZeRO
         per arXiv:2004.13336): their dp gradient mean becomes the zero
-        composition's rs > ar > sharded-update > ag per leaf (same wire
+        group's rs > ar > sharded-update > ag per leaf (same wire
         bytes), state leaves stack ``[n_stack, n_zero, ...]``. Requires
-        a ``zero`` axis and at least one stacked axis; mutually
-        exclusive with ``grad_reduction=``.
+        a ``zero`` axis and at least one stacked axis.
     """
 
     def __init__(
@@ -162,7 +148,6 @@ class ParallelPlan:
         axes: Mapping[str, int] | Sequence[str],
         *,
         devices=None,
-        grad_reduction=None,
         zero_stacked_groups: bool = False,
     ) -> None:
         if devices is None:
@@ -225,40 +210,6 @@ class ParallelPlan:
                     "('model'/'pipe') whose state it can chunk — a plain "
                     "zero plan already chunks everything"
                 )
-            if grad_reduction is not None:
-                raise ValueError(
-                    "zero_stacked_groups and grad_reduction= are "
-                    "mutually exclusive: the stacked groups' reduction "
-                    "IS the zero composition (rs > ar > update > ag)"
-                )
-        self._grad_comp = None
-        if grad_reduction is not None:
-            from chainermn_tpu.parallel.composition import compile_schedule
-
-            if not self.dp_axes:
-                raise ValueError(
-                    "grad_reduction= needs a data-parallel axis "
-                    "('data'/'zero') to reduce over; this plan has none"
-                )
-            comp = compile_schedule(grad_reduction, self.dp_axes)
-            if comp.has_update:
-                raise ValueError(
-                    f"grad_reduction composition {comp.signature()!r} "
-                    "carries a sharded_update stage — the sharded update "
-                    "is the 'zero' AXIS's job (add zero to the plan's "
-                    "axes); grad_reduction takes pure reductions"
-                )
-            self._grad_comp = comp
-            # The composition is the spec provider for the plain data
-            # axis: its owed collectives come from the stage list. The
-            # 'zero' axis keeps its own provider entry — the sharded
-            # update's per-leaf rs/ag is that axis's job regardless of
-            # how the replicated groups' gradients reduce.
-            owed = _ps.composition_collectives(comp)
-            if "data" in owed and "data" in self.axes:
-                self.axes["data"] = dataclasses.replace(
-                    self.axes["data"], collectives=owed["data"]
-                )
 
     # -- topology accessors -------------------------------------------------
 
@@ -289,16 +240,12 @@ class ParallelPlan:
 
     def describe(self) -> dict:
         """Axis sizes + the collectives each spec provider owes the step
-        (the dryrun/bench provenance line). A composed gradient
-        reduction reports its signature — the provenance names the
-        pipeline, not a menu label."""
+        (the dryrun/bench provenance line)."""
         out = {
             "mesh": {a: s.size for a, s in self.axes.items()},
             "collectives": _ps.owed_collectives(self.axes),
             "batch_spec": str(self.batch_spec()),
         }
-        if self._grad_comp is not None:
-            out["grad_reduction"] = self._grad_comp.signature()
         if self._zsg:
             out["zero_stacked_groups"] = True
         if self._seq_impl is not None:
@@ -692,13 +639,11 @@ class ParallelPlan:
                     pipeline):
         from jax import shard_map
 
-        from chainermn_tpu.parallel.composition import (
-            reduce_composed_tree,
-            run_gather_suffix,
-            run_reduce_prefix,
-            zero_composition,
+        from chainermn_tpu.parallel.zero import (
+            zero_gather_updates,
+            zero_grad_scatter,
+            zero_param_chunk,
         )
-        from chainermn_tpu.parallel.zero import zero_param_chunk
         from chainermn_tpu.training.train_step import (
             TrainState,
             normalize_loss_fn,
@@ -712,12 +657,15 @@ class ParallelPlan:
         n_expert = self.axis_size("expert")
         red_axes = (dp_axes + (("seq",) if has_seq else ())
                     + (("expert",) if has_expert else ()))
-        grad_comp = self._grad_comp
         zsg = self._zsg
-        # the zero group's structural composition (scatter axis last in
-        # dp order — 'zero' — the other dp axes reduce the shard)
-        zero_comp = (zero_composition(dp_axes)
-                     if "zero" in self.axes else None)
+        #: the dp axes a zero chunk is all-reduced over after the
+        #: reduce-scatter over 'zero'
+        zero_extra = tuple(a for a in dp_axes if a != "zero")
+
+        def zero_mean_chunk(g):
+            return zero_grad_scatter(g, "zero", extra_axes=zero_extra,
+                                     total=dp_total)
+
         spec_tree = self.param_specs(params, param_specs)
         treedef = jax.tree.structure(params)
         flat_specs = jax.tree.leaves(spec_tree)
@@ -859,15 +807,13 @@ class ParallelPlan:
             new_opt = {}
 
             # Stacked groups + plain replicated: the dp-axes gradient
-            # reduction — the plan's grad_reduction composition when
-            # one is set, else the fused pmean (TP/pipe leaves included
-            # — those axes are extra data parallelism for them; the
-            # model/pipe axes themselves are never reduced, the
-            # tensor/pipeline composition rule). With
+            # reduction is one fused pmean (TP/pipe leaves included —
+            # those axes are extra data parallelism for them; the
+            # model/pipe axes themselves are never reduced). With
             # zero_stacked_groups the stacked groups run the zero
-            # composition instead: rs(zero) > ar(other dp) > 1/z-chunk
-            # update > ag(zero) per leaf — same wire bytes as the fused
-            # pmean they replace, state 1/z per TP/pipe shard.
+            # group's pipeline instead: rs(zero) > ar(other dp) >
+            # 1/z-chunk update > ag(zero) per leaf — same wire bytes as
+            # the fused pmean they replace, state 1/z per TP/pipe shard.
             for grp, idx in groups.items():
                 if grp == "zero" or not idx:
                     continue
@@ -876,11 +822,7 @@ class ParallelPlan:
                 p_sub = [flat_p[i] for i in idx]
                 st = state.opt_state[grp]
                 if depth and zsg:
-                    zpre, zpost = zero_comp.split_update()
-                    gch = [
-                        run_reduce_prefix(gi, zpre, total=dp_total)
-                        for gi in g
-                    ]
+                    gch = [zero_mean_chunk(gi) for gi in g]
                     pch = [zero_param_chunk(pi, "zero") for pi in p_sub]
                     stc = jax.tree.map(
                         lambda e: _peel(e, depth + 1), st
@@ -890,16 +832,11 @@ class ParallelPlan:
                         lambda e: _wrap(e, depth + 1), st_out
                     )
                     for i, uc, pi in zip(idx, uch, p_sub):
-                        flat_u[i] = run_gather_suffix(
-                            uc, pi, zpost, zpre
-                        )
+                        flat_u[i] = zero_gather_updates(uc, pi, "zero")
                     new_opt[grp] = st_out
                     continue
                 if dp_axes:
-                    if grad_comp is not None:
-                        g = reduce_composed_tree(g, grad_comp)
-                    else:
-                        g = lax.pmean(g, dp_axes)
+                    g = lax.pmean(g, dp_axes)
                 new_in = st
                 if depth:
                     new_in = jax.tree.map(lambda e: _peel(e, depth), st)
@@ -912,18 +849,11 @@ class ParallelPlan:
                     flat_u[i] = ui
                 new_opt[grp] = st_out
 
-            # ZeRO group: the composition rs(zero) > ar(other dp) >
-            # sharded_update > ag(zero) — the derived instance the
-            # hand-wired zero_grad_scatter/zero_gather_updates pair
-            # used to spell (identical primitives, identical counts),
-            # with the inner optimizer fused at the split point.
+            # ZeRO group: rs(zero) > ar(other dp) > the inner update on
+            # the 1/z chunk > ag(zero), per leaf.
             idx = groups.get("zero")
             if idx:
-                zpre, zpost = zero_comp.split_update()
-                gch = [
-                    run_reduce_prefix(flat_g[i], zpre, total=dp_total)
-                    for i in idx
-                ]
+                gch = [zero_mean_chunk(flat_g[i]) for i in idx]
                 pch = [zero_param_chunk(flat_p[i], "zero") for i in idx]
                 st = jax.tree.map(
                     lambda e: e[0], state.opt_state["zero"]
@@ -931,9 +861,7 @@ class ParallelPlan:
                 uch, st_out = inner.update(gch, st, pch)
                 new_opt["zero"] = jax.tree.map(lambda e: e[None], st_out)
                 for i, uc in zip(idx, uch):
-                    flat_u[i] = run_gather_suffix(
-                        uc, flat_p[i], zpost, zpre
-                    )
+                    flat_u[i] = zero_gather_updates(uc, flat_p[i], "zero")
 
             updates_c = jax.tree.unflatten(treedef, flat_u)
             params_c2 = optax.apply_updates(params_c, updates_c)

@@ -294,23 +294,3 @@ def group_stack_axes(group: str) -> tuple[str, ...]:
 def owed_collectives(axes: Mapping[str, AxisSpec]) -> dict[str, tuple]:
     """Per-axis collective vocabulary — what the structural tests count."""
     return {name: spec.collectives for name, spec in axes.items()}
-
-
-def composition_collectives(comp) -> dict[str, tuple]:
-    """A :class:`~chainermn_tpu.parallel.composition.Composition` as a
-    SPEC PROVIDER: per mesh axis, the HLO collectives its stages owe
-    the compiled step (stage order preserved) — what
-    :class:`~chainermn_tpu.parallel.plan.ParallelPlan` substitutes for
-    the ``data`` provider's fixed ``('all-reduce',)`` when a derived
-    schedule drives the gradient reduction (ISSUE 12). The structural
-    tests count against this, same as every other provider."""
-    from chainermn_tpu.parallel.composition import STAGE_HLO
-
-    out: dict[str, list] = {}
-    for st in comp.stages:
-        hlo = STAGE_HLO.get(st.primitive)
-        if hlo is None:
-            continue
-        for a in st.axes:
-            out.setdefault(a, []).append(hlo)
-    return {a: tuple(v) for a, v in out.items()}

@@ -28,11 +28,14 @@ Usage (inside the shard_map'd train step, like every in-jit collective):
 
 ``axis_name`` may also be a TUPLE of mesh axes: the state then shards
 over their flattened product (ravelled index, product size) — the
-layout the data-parallel wrapper's ``reduction_schedule='zero'``
-(:mod:`chainermn_tpu.parallel.reduction_schedule`,
-:class:`chainermn_tpu.optimizers.MultiNodeOptimizer`) builds on, where
-the reduce-scatter, the 1/n update, and the allgather fuse into the
-gradient-reduction hot path itself (arXiv:2004.13336).
+layout :func:`zero_shard_optimizer` shards its state in. The
+data-parallel wrapper's ``reduction_schedule='zero'``
+(:class:`chainermn_tpu.optimizers.MultiNodeOptimizer`) and the
+:class:`~chainermn_tpu.parallel.plan.ParallelPlan`'s zero group run
+:func:`zero_grad_scatter`, the inner update on the chunk and
+:func:`zero_gather_updates`: the reduce-scatter, the 1/n update and
+the allgather in the gradient-reduction path itself
+(arXiv:2004.13336).
 """
 
 from __future__ import annotations
@@ -45,9 +48,9 @@ import optax
 from jax import lax
 
 # Multi-axis group helpers: ONE owner of the flattened ravelled-index
-# convention (collectives) — the 'zero' reduction schedule depends on
-# the scatter chunk index and the state shard index agreeing, so no
-# second copy of the axis-order rule may live here.
+# convention (collectives) — the sharded update depends on the scatter
+# chunk index and the state shard index agreeing, so no second copy of
+# the axis-order rule may live here.
 from chainermn_tpu.parallel.collectives import (
     _names_tuple as _names,
     axes_index as _group_index,
@@ -61,15 +64,12 @@ PyTree = Any
 def _chunk_rows(x: jax.Array, n: int) -> jax.Array:
     """Flatten ``x`` and pad so it splits into ``n`` equal rows [n, c].
 
-    The row length comes from ``collectives.two_level_shard_len`` — the
-    ONE owner of the ceil-pad rule, shared with the staged composition
-    primitives (``staged_reduce_scatter``): the ZeRO path pairs grad
-    chunks from the composed scatter with param chunks from here, and
-    the pairing is only correct while both read the same rule."""
+    The row length comes from ``collectives.two_level_shard_len``, the
+    ONE owner of the ceil-pad rule: gradient chunks, parameter chunks
+    and the state's rows pair up only while all read the same rule."""
     flat = x.reshape(-1)
     c = _shard_len(flat.size, n)
     return jnp.pad(flat, (0, n * c - flat.size)).reshape(n, c)
-
 
 
 

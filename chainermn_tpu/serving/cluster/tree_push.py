@@ -4,10 +4,8 @@ The serving plane's one-to-many paths — pushing a tenant adapter to N
 replicas, warming N prefix tries from one prefilled donor — previously
 cost the donor ``N-1`` sequential ``send_obj`` calls: the donor's
 egress is the bottleneck and delivery latency is linear in the fleet.
-A radix-``r`` multicast tree (the host-plane rendering of the ``bc``
-stage in :mod:`chainermn_tpu.parallel.composition` — same
-holder-doubling walk, same :func:`~chainermn_tpu.parallel.composition.
-tree_depth`/:func:`~chainermn_tpu.parallel.composition.tree_sends`
+A radix-``r`` multicast tree (multicast-tree collectives,
+arXiv:2605.22428; :func:`tree_depth` / :func:`tree_sends` are its
 arithmetic) delivers in ``ceil(log_r N)`` rounds: every member that
 already holds the payload forwards it to up to ``r-1`` new members per
 round, so the donor pays at most ``(r-1)·ceil(log_r N)`` sends — O(log
@@ -36,11 +34,8 @@ import numpy as np
 
 from chainermn_tpu.observability import journey as _journey
 from chainermn_tpu.observability import trace as _trace
-from chainermn_tpu.parallel.composition import (
-    DEFAULT_RADIX,
-    tree_depth,
-    tree_sends,
-)
+#: Default multicast-tree radix (binary tree: doubling rounds).
+DEFAULT_RADIX = 2
 
 
 def tree_rounds(
@@ -51,8 +46,7 @@ def tree_rounds(
     topologically ordered (every ``src`` holds the payload before round
     ``t`` starts). ``len(rounds) == tree_depth(n, radix)`` and the
     total pair count is ``n - 1`` (each non-root receives exactly
-    once) — the same walk :func:`~chainermn_tpu.parallel.collectives.
-    staged_broadcast` compiles to ppermutes."""
+    once)."""
     n, r = int(n), int(radix)
     if r < 2:
         raise ValueError(f"radix must be >= 2, got {radix}")
@@ -68,6 +62,22 @@ def tree_rounds(
         rounds.append(pairs)
         holders *= r
     return rounds
+
+
+def tree_depth(n: int, radix: int = DEFAULT_RADIX) -> int:
+    """Rounds a radix-``radix`` multicast tree needs to cover ``n``
+    members from one root, ``ceil(log_radix(n))``: the donor-send depth
+    of the serving tree push."""
+    return len(tree_rounds(n, radix))
+
+
+def tree_sends(n: int, radix: int = DEFAULT_RADIX) -> int:
+    """Sends the root of a radix-``radix`` multicast over ``n`` members
+    makes: up to ``radix - 1`` a round — at radix 2 this equals
+    :func:`tree_depth`; a larger radix trades rounds for per-round
+    sends (``(r-1)*ceil(log_r(n))`` at full occupancy)."""
+    return sum(src == 0 for pairs in tree_rounds(n, radix)
+               for src, _ in pairs)
 
 
 def tree_push(

@@ -34,18 +34,11 @@ DEFAULT_TABLE: dict = {
     "attention": {"cpu": "xla", "tpu": "flash", "*": "flash"},
     # The same under a sliding window: R2's windowed model.
     "attention_windowed": {"cpu": "xla", "tpu": "windowed", "*": "windowed"},
-    # The gradient path, down to `comp_slices` and `sched_search`: the
-    # cell is `gpt2m-podshare-dp4` (710 MB over ICI). Wire f32|bf16|int8.
+    # The gradient path's two: the cell is `gpt2m-podshare-dp4` (710 MB
+    # over ICI). Wire f32|bf16|int8 where a caller asks for `auto`.
     "allreduce_wire": {"*": "bf16"},
-    # MB a bucket (`none` = one fused buffer).
+    # MB a bucket of the packed schedules (`none` = one fused buffer).
     "allreduce_bucket_mb": {"*": "64"},
-    # Staleness-1 overlap of the all-reduce with the next step.
-    "double_buffering": {"*": "off"},
-    # The fused pmean | the pinned two_level / zero / derived pipelines
-    # (parallel/reduction_schedule.py, composition.py; D6 after S2).
-    "reduction_schedule": {"*": "flat"},
-    # Slices a composed schedule's stages interleave over (D6).
-    "comp_slices": {"*": "1"},
     # Serving, from here on: no cell yet; what names no other item
     # waits for R1's. Paged | dense per-slot KV cache.
     "decode_impl": {"*": "paged"},
@@ -65,9 +58,6 @@ DEFAULT_TABLE: dict = {
     # Ring (n-1 ppermutes a layer) | Ulysses (all-to-alls; heads must
     # divide): R5d's long-context cell, with `prefill_seq_parallel`.
     "seq_attn_impl": {"*": "ring"},
-    # A composed-schedule sweep times the cost model's top-k | every arm
-    # (parallel/cost_model.py; D6).
-    "sched_search": {"*": "topk"},
     # Adapter rows gathered in the forward | one tenant merged into the
     # weights: no cell in sight (serving/adapters.py).
     "adapter_impl": {"*": "gather"},

@@ -64,11 +64,8 @@ def main(argv=None):
                         "can diverge)")
     p.add_argument("--allreduce-grad-dtype", default=None)
     p.add_argument("--reduction-schedule", default=None,
-                   metavar="SCHED",
-                   help="gradient-reduction schedule: flat | two_level "
-                        "| zero | auto | a composition signature, "
-                        "sliced forms included (e.g. "
-                        "'rs(data)[s0..3]>ag(data)'); default: the "
+                   choices=("flat", "two_level", "zero"),
+                   help="gradient-reduction schedule; default: the "
                         "communicator's own strategy")
     p.add_argument("--error-feedback", action="store_true",
                    help="EF-SGD residual feedback over the int8 wire "

@@ -46,15 +46,20 @@ def _golden_trace_lines():
          "flagged_ranks": [3],
          "phases": {"compute": {"median_s": 0.01, "worst_rank": 3,
                                 "worst_rel_dev": 0.8, "flagged": [3]}}},
-        # ISSUE 3: overlap configuration + per-bucket wire events — one
-        # trace-time layout event (no dur) and two MEASURED eager-
-        # reducer events (dur = dispatch->ready, blocked = wait paid at
-        # collect; the 4 ms gap on bucket 0 is comm hidden by compute).
+        # Overlap configuration + wire events. Trace-time layout events
+        # as reduce_tree records them, one a bucket a stage, no dur: a
+        # flat bucket of the double-buffered mode, and a two_level
+        # bucket on a 2x4 mesh (the scatter and the gather carry the
+        # bucket, the shard's all-reduce a quarter of it). Then two
+        # MEASURED eager-reducer events (dur = dispatch->ready, blocked
+        # = wait paid at collect; the 4 ms gap on bucket 0 is comm
+        # hidden by compute).
         {"schema": 1, "kind": "overlap_config", "t": 1.8, "pid": 1,
          "rank": 0, "double_buffering": True, "staleness": 1,
          "schedule": "two_level", "donate": True},
         {"schema": 1, "kind": "wire", "t": 1.9, "pid": 1, "rank": 0,
-         "schedule": "two_level", "bucket": 0, "n_buckets": 1,
+         "schedule": "flat", "stage": "ar(inter+intra)", "stage_index": 0,
+         "bucket": 0, "n_buckets": 1,
          "nbytes": 1000, "wire_dtype": "bfloat16", "overlapped": True},
         {"schema": 1, "kind": "wire", "t": 2.0, "pid": 1, "rank": 0,
          "schedule": "overlap_eager", "bucket": 0, "n_buckets": 2,
@@ -64,78 +69,18 @@ def _golden_trace_lines():
          "schedule": "overlap_eager", "bucket": 1, "n_buckets": 2,
          "nbytes": 4096, "dur_s": 0.003, "blocked_s": 0.003,
          "overlapped": False},
-        # ISSUE 12: one composed-schedule bucket — per-STAGE wire
-        # events carrying the composition signature (rs -> ar -> ag:
-        # the scatter and gather carry the full bucket, the shard
-        # allreduce 1/4 of it), grouped by signature in the overlap
-        # section's per-stage table. The rs/ag stages additionally
-        # carry MEASURED dur_s (ISSUE 13: the eager
-        # MeasuredComposedReducer pattern) — the stage rows then gain a
-        # dur_ms column; the ar stage stays layout-only (no dur), so
-        # the table renders mixed measured/unmeasured rows.
         {"schema": 1, "kind": "wire", "t": 2.12, "pid": 1, "rank": 0,
-         "schedule": "two_level", "composition": "rs(a1)>ar(a0)>ag(a1)",
-         "stage": "rs(a1)", "stage_index": 0, "stage_op": "reduce-scatter",
+         "schedule": "two_level", "stage": "rs(intra)", "stage_index": 0,
          "bucket": 0, "n_buckets": 1, "nbytes": 2048,
-         "wire_dtype": "bfloat16", "overlapped": False,
-         "dur_s": 0.0015},
+         "wire_dtype": "bfloat16", "overlapped": False},
         {"schema": 1, "kind": "wire", "t": 2.13, "pid": 1, "rank": 0,
-         "schedule": "two_level", "composition": "rs(a1)>ar(a0)>ag(a1)",
-         "stage": "ar(a0)", "stage_index": 1, "stage_op": "all-reduce",
+         "schedule": "two_level", "stage": "ar(inter)", "stage_index": 1,
          "bucket": 0, "n_buckets": 1, "nbytes": 512,
          "wire_dtype": "bfloat16", "overlapped": False},
         {"schema": 1, "kind": "wire", "t": 2.14, "pid": 1, "rank": 0,
-         "schedule": "two_level", "composition": "rs(a1)>ar(a0)>ag(a1)",
-         "stage": "ag(a1)", "stage_index": 2, "stage_op": "all-gather",
+         "schedule": "two_level", "stage": "ag(intra)", "stage_index": 2,
          "bucket": 0, "n_buckets": 1, "nbytes": 2048,
-         "wire_dtype": "bfloat16", "overlapped": False,
-         "dur_s": 0.0005},
-        # ISSUE 15: a SLICED composition (S=2) — one event per stage
-        # per slice in the skewed interleave order, each carrying its
-        # slice address. The rs/ag slice rows are MEASURED (dur_s +
-        # blocked_s, the eager sliced reducer), the ar rows layout-only
-        # — so the per-signature stage table renders mixed
-        # sliced/unsliced, measured/unmeasured rows side by side.
-        {"schema": 1, "kind": "wire", "t": 2.15, "pid": 1, "rank": 0,
-         "schedule": "composed_eager",
-         "composition": "rs(a1)[s0..1]>ar(a0)>ag(a1)",
-         "stage": "rs(a1)", "stage_index": 0, "stage_op": "reduce-scatter",
-         "bucket": 0, "n_buckets": 1, "nbytes": 1024, "slice": 0,
-         "n_slices": 2, "overlapped": True,
-         "dur_s": 0.001, "blocked_s": 0.0002},
-        {"schema": 1, "kind": "wire", "t": 2.16, "pid": 1, "rank": 0,
-         "schedule": "composed_eager",
-         "composition": "rs(a1)[s0..1]>ar(a0)>ag(a1)",
-         "stage": "rs(a1)", "stage_index": 1, "stage_op": "reduce-scatter",
-         "bucket": 0, "n_buckets": 1, "nbytes": 1024, "slice": 1,
-         "n_slices": 2, "overlapped": False,
-         "dur_s": 0.0008, "blocked_s": 0.0001},
-        {"schema": 1, "kind": "wire", "t": 2.17, "pid": 1, "rank": 0,
-         "schedule": "composed_eager",
-         "composition": "rs(a1)[s0..1]>ar(a0)>ag(a1)",
-         "stage": "ar(a0)", "stage_index": 2, "stage_op": "all-reduce",
-         "bucket": 0, "n_buckets": 1, "nbytes": 256, "slice": 0,
-         "n_slices": 2, "overlapped": False},
-        {"schema": 1, "kind": "wire", "t": 2.18, "pid": 1, "rank": 0,
-         "schedule": "composed_eager",
-         "composition": "rs(a1)[s0..1]>ar(a0)>ag(a1)",
-         "stage": "ar(a0)", "stage_index": 3, "stage_op": "all-reduce",
-         "bucket": 0, "n_buckets": 1, "nbytes": 256, "slice": 1,
-         "n_slices": 2, "overlapped": False},
-        {"schema": 1, "kind": "wire", "t": 2.19, "pid": 1, "rank": 0,
-         "schedule": "composed_eager",
-         "composition": "rs(a1)[s0..1]>ar(a0)>ag(a1)",
-         "stage": "ag(a1)", "stage_index": 4, "stage_op": "all-gather",
-         "bucket": 0, "n_buckets": 1, "nbytes": 1024, "slice": 0,
-         "n_slices": 2, "overlapped": False,
-         "dur_s": 0.0004, "blocked_s": 0.0004},
-        {"schema": 1, "kind": "wire", "t": 2.195, "pid": 1, "rank": 0,
-         "schedule": "composed_eager",
-         "composition": "rs(a1)[s0..1]>ar(a0)>ag(a1)",
-         "stage": "ag(a1)", "stage_index": 5, "stage_op": "all-gather",
-         "bucket": 0, "n_buckets": 1, "nbytes": 1024, "slice": 1,
-         "n_slices": 2, "overlapped": True,
-         "dur_s": 0.0006, "blocked_s": 0.0},
+         "wire_dtype": "bfloat16", "overlapped": False},
         # ISSUE 4: one request through the serving scheduler — queue
         # wait, bucketed prefill (its sampled token counts as generated;
         # ttft_s = submit -> first token, ISSUE 5), three decode steps
@@ -236,7 +181,7 @@ def test_trace_report_contract(tmp_path):
         "schema_versions": [1],
         "meta": {"started_at": "2026-08-03T00:00:00Z", "sync": False,
                  "source": "bench"},
-        "n_events": 37,  # torn tail line skipped, not fatal
+        "n_events": 31,  # torn tail line skipped, not fatal
         "collectives": [
             {"op": "allreduce_grad", "plane": "device", "n": 2,
              "total_bytes": 2000, "total_s": 0.004, "mean_ms": 2.0,
@@ -263,69 +208,12 @@ def test_trace_report_contract(tmp_path):
         "overlap": {
             "config": [{"double_buffering": True, "staleness": 1,
                         "schedule": "two_level", "donate": True}],
-            "schedules": {"two_level": {"buckets": 1, "nbytes": 1000,
-                                        "overlapped": 1}},
-            # ISSUE 12: the composed bucket's per-stage table, grouped
-            # by composition signature (2048 + 512 + 2048 wire bytes
-            # over the three stages of one bucket).
-            # ISSUE 13: stage rows carry dur_ms where measured events
-            # (dur_s — the eager MeasuredComposedReducer) exist; a
-            # layout-only stage row simply has no dur_ms key.
-            "compositions": {
-                "rs(a1)>ar(a0)>ag(a1)": {
-                    "schedule": "two_level", "buckets": 1,
-                    "nbytes": 4608, "overlapped": 0,
-                    "stages": {
-                        "rs(a1)": {"op": "reduce-scatter", "n": 1,
-                                   "nbytes": 2048, "dur_ms": 1.5},
-                        "ar(a0)": {"op": "all-reduce", "n": 1,
-                                   "nbytes": 512},
-                        "ag(a1)": {"op": "all-gather", "n": 1,
-                                   "nbytes": 2048, "dur_ms": 0.5},
-                    },
-                },
-                # ISSUE 15: the sliced composition's stage rows carry
-                # across-slice totals plus the per-slice sub-table
-                # (dur_ms/blocked_ms only where the slice was
-                # measured — the ar rows are layout-only).
-                "rs(a1)[s0..1]>ar(a0)>ag(a1)": {
-                    "schedule": "composed_eager", "buckets": 1,
-                    "nbytes": 4608, "overlapped": 1,
-                    "stages": {
-                        "rs(a1)": {
-                            "op": "reduce-scatter", "n": 2,
-                            "nbytes": 2048, "dur_ms": 1.8,
-                            "blocked_ms": 0.3,
-                            "slices": {
-                                "s0": {"n": 1, "nbytes": 1024,
-                                       "dur_ms": 1.0,
-                                       "blocked_ms": 0.2},
-                                "s1": {"n": 1, "nbytes": 1024,
-                                       "dur_ms": 0.8,
-                                       "blocked_ms": 0.1},
-                            },
-                        },
-                        "ar(a0)": {
-                            "op": "all-reduce", "n": 2, "nbytes": 512,
-                            "slices": {
-                                "s0": {"n": 1, "nbytes": 256},
-                                "s1": {"n": 1, "nbytes": 256},
-                            },
-                        },
-                        "ag(a1)": {
-                            "op": "all-gather", "n": 2, "nbytes": 2048,
-                            "dur_ms": 1.0, "blocked_ms": 0.4,
-                            "slices": {
-                                "s0": {"n": 1, "nbytes": 1024,
-                                       "dur_ms": 0.4,
-                                       "blocked_ms": 0.4},
-                                "s1": {"n": 1, "nbytes": 1024,
-                                       "dur_ms": 0.6,
-                                       "blocked_ms": 0.0},
-                            },
-                        },
-                    },
-                },
+            # a bucket is counted at its first stage; nbytes is what
+            # its stages carry together (2048 + 512 + 2048)
+            "schedules": {
+                "flat": {"buckets": 1, "nbytes": 1000, "overlapped": 1},
+                "two_level": {"buckets": 1, "nbytes": 4608,
+                              "overlapped": 0},
             },
             "measured": {"n": 2, "comm_ms_total": 8.0,
                          "comm_ms_blocked": 4.0, "comm_ms_hidden": 4.0,
@@ -409,7 +297,7 @@ def test_trace_report_contract(tmp_path):
     }, summary
     # chrome export emitted alongside
     chrome = _json.loads(chrome_file.read_text())
-    assert len(chrome["traceEvents"]) == 36  # meta excluded
+    assert len(chrome["traceEvents"]) == 30  # meta excluded
     # and the human rendering mentions the essentials
     proc2 = subprocess.run(
         [sys.executable, os.path.join(_REPO, "tools", "trace_report.py"),
@@ -419,21 +307,8 @@ def test_trace_report_contract(tmp_path):
     assert proc2.returncode == 0
     for token in ("allreduce_grad", "STRAGGLER", "allreduce_wire=bf16",
                   "comm/compute overlap", "50.0% hidden",
-                  "composed rs(a1)>ar(a0)>ag(a1) [two_level]: "
-                  "1 bucket(s), 4.5 KiB wire",
-                  "rs(a1) [reduce-scatter]: n=1, 2.0 KiB, 1.500 ms",
-                  "ar(a0) [all-reduce]: n=1, 512 B",
-                  "ag(a1) [all-gather]: n=1, 2.0 KiB, 0.500 ms",
-                  # ISSUE 15: the sliced composition's per-slice rows
-                  "composed rs(a1)[s0..1]>ar(a0)>ag(a1) "
-                  "[composed_eager]: 1 bucket(s), 4.5 KiB wire",
-                  "rs(a1) [reduce-scatter]: n=2, 2.0 KiB, 1.800 ms",
-                  "s0: n=1, 1.0 KiB, 1.000 ms (0.200 ms blocked)",
-                  "s1: n=1, 1.0 KiB, 0.800 ms (0.100 ms blocked)",
-                  "ar(a0) [all-reduce]: n=2, 512 B",
-                  "s0: n=1, 256 B",
-                  "ag(a1) [all-gather]: n=2, 2.0 KiB, 1.000 ms",
-                  "s1: n=1, 1.0 KiB, 0.600 ms (0.000 ms blocked)",
+                  "flat: 1 bucket(s), 1000 B wire, 1 overlapped",
+                  "two_level: 1 bucket(s), 4.5 KiB wire, 0 overlapped",
                   "serving (continuous batching)", "tokens/s: 227.27",
                   "p50 4.000 ms, p99 6.000 ms", "33.3% mean",
                   "TTFT: p50 12.000 ms, p99 12.000 ms",
@@ -458,6 +333,30 @@ def test_trace_report_contract(tmp_path):
                   "layers: [0, 1]",
                   "expert load: e0=62.5% e1=37.5%"):
         assert token in proc2.stdout, (token, proc2.stdout)
+
+
+def test_overlap_summary_has_schedules_and_no_compositions():
+    """The overlap rollup groups layout events by schedule name and by
+    nothing else, a bucket counted once however many stages it has; a
+    trace of an older build, whose events also carry ``composition`` and
+    ``slice``, is grouped the same way."""
+    import json as _json
+
+    from chainermn_tpu.observability.trace import summarize_overlap
+
+    events = []
+    for line in _golden_trace_lines():
+        try:
+            events.append(_json.loads(line))
+        except ValueError:
+            pass  # the fixture's torn tail
+    ov = summarize_overlap(events)
+    assert set(ov) == {"config", "schedules", "measured"}
+    assert set(ov["schedules"]) == {"flat", "two_level"}
+    older = [dict(e, composition="rs(a1)[s0..1]>ar(a0)>ag(a1)", slice=0,
+                  n_slices=2) if e.get("kind") == "wire" else e
+             for e in events]
+    assert summarize_overlap(older) == ov
 
 
 def _golden_journey_lines():

@@ -423,7 +423,11 @@ def test_the_controls_tool_takes_every_reading_at_the_tiny_size(
     import json
     import sys
 
-    monkeypatch.setattr(sys, "path", list(sys.path))  # the tool adds to it
+    # the path the tool's helper (tools/_controls.py) sets where this
+    # process imports it first; an earlier file's tool may have, under a
+    # path that is restored by now
+    monkeypatch.setattr(sys, "path", [os.path.join(REPO, "benchmark"), REPO]
+                        + list(sys.path))
     # and would turn the persistent compile cache on for this process
     from chainermn_tpu.utils import compile_cache
     monkeypatch.setattr(compile_cache, "use_compile_cache", lambda: "")

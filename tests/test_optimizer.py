@@ -1498,6 +1498,91 @@ def test_which_leaves_the_default_reduction_sends_as_all_to_alls(
     assert ("psum" in text) != moved
 
 
+#: leaf kind -> (shape, dtype, whether allreduce_gradients moves it by
+#: all_to_alls); the row and the vector and the integers take ``pmean``
+_DEFAULT_LEAVES = {
+    "matrix_n_divides": ((1024, 512), np.float32, True),     # 2 MiB
+    "matrix_padded": ((1023, 513), np.float32, True),  # no dim n divides
+    "row_under_1mib": ((4, 300), np.float32, False),
+    "vector_2mib": ((1 << 19,), np.float32, False),
+    "integer_matrix": ((1024, 512), np.int32, False),
+}
+
+
+@pytest.mark.parametrize("n", [2, 4, 8])
+@pytest.mark.parametrize("leaf,wire", [
+    *((k, None) for k in _DEFAULT_LEAVES),
+    ("matrix_n_divides", jnp.bfloat16), ("matrix_padded", jnp.bfloat16),
+])
+def test_the_default_reduction_is_the_mean_with_the_same_bits_on_every_member(
+        leaf, wire, n):
+    """``allreduce_gradients`` as the four-chip cell runs it, executed:
+    every member ends with the same bits (what the benchmark's check (e)
+    stands on), and they are the float32 mean rounded once to the wire
+    dtype. A leaf that goes by ``all_to_all`` is summed in member order,
+    so random values are held to the bit; a ``pmean`` may sum in any
+    order, so those leaves are eighths, whose sums are exact."""
+    import ml_dtypes
+
+    shape, dtype, moved = _DEFAULT_LEAVES[leaf]
+    comm = create_communicator("xla", devices=jax.devices("cpu")[:n],
+                               allreduce_grad_dtype=wire)
+    axes = comm.grad_axes
+    rs = np.random.RandomState(n)
+    if dtype == np.int32:
+        x = (rs.randint(-8, 9, (n,) + shape) * 8).astype(np.int32)
+    elif moved:
+        x = rs.randn(n, *shape).astype(np.float32)
+    else:
+        x = (rs.randint(-8, 9, (n,) + shape) / 8.0).astype(np.float32)
+    grads = {"leaf": jnp.asarray(x),
+             "bias": jnp.asarray(rs.randint(-8, 9, (n, 5)) / 8.0,
+                                 jnp.float32)}
+    text = str(jax.make_jaxpr(
+        lambda g: allreduce_gradients(g, comm),
+        axis_env=[(axes[0], n)])(jax.tree.map(lambda l: l[0], grads)))
+    assert ("all_to_all" in text) == moved
+
+    def local(g):
+        out = allreduce_gradients(jax.tree.map(lambda l: l[0], g), comm)
+        return jax.tree.map(lambda l: l[None], out)
+
+    spec = jax.tree.map(lambda l: P(axes, *([None] * (l.ndim - 1))), grads)
+    out = jax.device_get(jax.jit(shard_map(
+        local, mesh=comm.mesh, in_specs=(spec,), out_specs=spec,
+        check_vma=False))(grads))
+
+    for k, got in out.items():
+        assert got.dtype == np.asarray(grads[k]).dtype
+        for i in range(1, n):
+            np.testing.assert_array_equal(got[i], got[0])
+    np.testing.assert_array_equal(
+        out["bias"][0], np.asarray(grads["bias"]).mean(0))
+    on_wire = x.astype(ml_dtypes.bfloat16) if wire is not None else x
+    total = on_wire[0].astype(np.float32)
+    for j in range(1, n):
+        total = total + on_wire[j].astype(np.float32)
+    mean = (total / np.float32(n)).astype(on_wire.dtype).astype(dtype)
+    np.testing.assert_array_equal(out["leaf"][0], mean)
+
+
+@pytest.mark.parametrize("value", [
+    "auto", "rs(data)>ag(data)", "ar(data)[s0..3]", "ring", object(),
+], ids=["auto", "signature", "sliced_signature", "ring", "object"])
+def test_reduction_schedule_takes_the_four_names(comm, value):
+    """``None``, ``'flat'``, ``'two_level'``, ``'zero'`` and nothing
+    else: no resolution by table, no pipeline spelled as a string or an
+    object; the error names the four."""
+    with pytest.raises(ValueError,
+                       match="None, 'flat', 'two_level' or 'zero'"):
+        create_multi_node_optimizer(optax.sgd(0.1), comm,
+                                    reduction_schedule=value)
+    for name in (None, "flat", "two_level", "zero"):
+        opt = create_multi_node_optimizer(optax.sgd(0.1), comm,
+                                          reduction_schedule=name)
+        assert opt.reduction_schedule == name
+
+
 def test_the_default_reduction_outside_any_axis_is_the_identity():
     """Outside shard_map there is nothing to reduce over, whatever the
     leaf's size: the gradient passes (through the wire dtype's rounding)

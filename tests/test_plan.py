@@ -570,11 +570,15 @@ class TestPlanStructural:
 # ---------------------------------------------------------------------------
 
 
+_ZSG_LOWERED_COUNTS = {"reduce_scatter": 3, "all_gather": 3,
+                       "all_reduce": 5, "collective_permute": 0}
+
+
 class TestZeroStackedGroups:
     """``zero_stacked_groups=True``: the stacked groups' optimizer state
     chunks over the zero axis too (arXiv:2004.13336 applied per TP
     shard) — dist == single values AND grads, state 1/z per shard,
-    and the stacked groups' dp reduction becomes the zero composition's
+    and the stacked groups' dp reduction becomes the zero group's
     rs/ag (pinned in the compiled HLO)."""
 
     def _workload(self):
@@ -669,10 +673,38 @@ class TestZeroStackedGroups:
         with pytest.raises(ValueError, match="stacked axis"):
             ParallelPlan({"data": 2, "zero": 4},
                          devices=_devices(), zero_stacked_groups=True)
-        with pytest.raises(ValueError, match="mutually exclusive"):
-            ParallelPlan({"data": 2, "zero": 2, "model": 2},
-                         devices=_devices(), zero_stacked_groups=True,
-                         grad_reduction="flat")
+
+    def test_zero_stacked_groups_program_is_the_parents(self):
+        """The step as it is traced, before XLA combines anything: a
+        reduce-scatter over ``zero``, an all-reduce of the chunk over
+        ``data`` and an all-gather over ``zero`` for each of the three
+        leaves, one all-reduce over ``model`` for the row-parallel
+        product and one for the metrics, no permute: the numbers PR 45's
+        tree lowers to."""
+        w1, w2, b2, x, y, loss_fn = self._workload()
+        plan, params, specs = self._plan_and_params(w1, w2, b2)
+        inner = optax.adamw(1e-2)
+        state = plan.create_train_state(params, inner, param_specs=specs)
+        step = plan.compile_train_step(loss_fn, inner, params,
+                                       param_specs=specs)
+        txt = step.lower(state, (x, y)).as_text()
+        counts = {op: txt.count(f"stablehlo.{op}") for op in
+                  ("reduce_scatter", "all_gather", "all_reduce",
+                   "collective_permute")}
+        assert counts == _ZSG_LOWERED_COUNTS, counts
+
+
+def test_plan_takes_no_grad_reduction():
+    """The plan's data-parallel reduction is its fused ``pmean``; there
+    is no schedule to hand it and nothing in ``describe()`` names one."""
+    import inspect
+
+    assert "grad_reduction" not in inspect.signature(
+        ParallelPlan.__init__).parameters
+    with pytest.raises(TypeError, match="grad_reduction"):
+        ParallelPlan({"data": 8}, devices=_devices(), grad_reduction="flat")
+    assert "grad_reduction" not in ParallelPlan(
+        {"data": 8}, devices=_devices()).describe()
 
 
 class TestPipeModelComposed:

@@ -1,22 +1,24 @@
-"""ISSUE 3 — the overlapped hierarchical gradient-reduction pipeline.
+"""The named gradient-reduction schedules and their double-buffered mode.
 
 Covers, per the repo's conventions (dist==single equivalence for every
-distributed feature; structural/HLO-level assertions for communication
-claims; measured, not asserted in prose):
+distributed feature; structural assertions for communication claims;
+measured, not asserted in prose):
 
 - bucket-partition edge contract (zero-size leaves, sub-bucket
-  payloads, oversized leaves — the satellite fix's unit cases);
-- dist == single equivalence (values AND gradients) for all three
-  schedules (flat / two_level / zero), through the real train step;
+  payloads, oversized leaves);
+- ``flat`` and ``two_level`` on meshes of 8, 2x4 and 2x2x2 devices and
+  the float32, bf16 and int8 wires: the mean, and the collectives a
+  bucket is written as;
+- dist == single through the real train step for ``None``, ``flat``,
+  ``two_level`` and ``zero`` on the three meshes;
+- the ``zero`` update: state 1/n a shard, a reduce-scatter and an
+  all-gather a leaf, updates equal to the replicated update;
 - double-buffered mode bit-matches a hand-rolled one-step-stale
   reference loop (the reference ``double_buffering_optimizer.py``
   (dagger) semantics, as an executable model rather than prose);
-- compiled-HLO collective counts pinned per schedule (the
-  ppermute-count convention);
-- per-bucket ``wire`` trace events (layout + overlapped flag) and the
-  eager :class:`OverlappedBucketReducer`'s measured events feeding
-  ``summarize_overlap``;
-- the ``'auto'`` schedule resolution through the tuning registry.
+- ``wire`` trace events a bucket a stage, and the eager
+  :class:`OverlappedBucketReducer`'s measured events feeding
+  ``summarize_overlap``.
 """
 
 import os
@@ -27,16 +29,15 @@ import numpy as np
 import optax
 import pytest
 from jax import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from chainermn_tpu import create_communicator, create_multi_node_optimizer
+from chainermn_tpu.communicators.xla_communicator import XlaCommunicator
 from chainermn_tpu.observability import trace
 from chainermn_tpu.parallel.reduction_schedule import (
-    SCHEDULES,
     OverlappedBucketReducer,
     bucket_partition,
     reduce_tree,
-    resolve_schedule,
 )
 
 N = 8
@@ -45,6 +46,30 @@ N = 8
 @pytest.fixture(scope="module")
 def comm():
     return create_communicator("naive")
+
+
+#: the meshes the named schedules are held to: one axis, the reference's
+#: (inter, intra), and three levels (mesh order: slowest axis first)
+MESHES = {"8": ((8,), ("data",)),
+          "2x4": ((2, 4), ("inter", "intra")),
+          "2x2x2": ((2, 2, 2), ("dcn", "y", "x"))}
+WIRES = {"none": None, "bf16": jnp.bfloat16, "int8": jnp.int8}
+
+
+@pytest.fixture(scope="module")
+def mesh_comm():
+    """``mesh name -> communicator`` over the eight CPU devices, made
+    once a mesh."""
+    made = {}
+
+    def get(name):
+        if name not in made:
+            shape, names = MESHES[name]
+            devs = np.array(jax.devices("cpu")[:N]).reshape(shape)
+            made[name] = XlaCommunicator(mesh=Mesh(devs, names))
+        return made[name]
+
+    return get
 
 
 @pytest.fixture(autouse=True)
@@ -123,7 +148,7 @@ class TestBucketPartition:
 
 
 # ----------------------------------------------------------------------
-# dist == single equivalence, all schedules (values AND gradients)
+# The trainer's problem, and what 'zero' refuses
 # ----------------------------------------------------------------------
 
 
@@ -160,59 +185,6 @@ class TestScheduleEquivalence:
         x = jnp.asarray(rs.randn(16, 5), jnp.float32)
         y = jnp.asarray(np.arange(16) % 3, np.int32)
         return params, (x, y)
-
-    @pytest.mark.parametrize("schedule", SCHEDULES)
-    def test_dist_equals_single_values_and_gradients(
-        self, comm, problem, schedule
-    ):
-        """The suite's core invariant, per schedule: the 8-slot
-        distributed trajectory (gradients reduced by THIS schedule)
-        equals the single-slot one and the legacy default."""
-        params, batch = problem
-        dist_p, dist_l = _train(comm, params, batch,
-                                reduction_schedule=schedule)
-        single_p, single_l = _train(comm.sub_communicator([0]), params,
-                                    batch, reduction_schedule=schedule)
-        legacy_p, legacy_l = _train(comm, params, batch)
-        for k in params:
-            np.testing.assert_allclose(dist_p[k], single_p[k],
-                                       rtol=1e-5, atol=1e-6)
-            np.testing.assert_allclose(dist_p[k], legacy_p[k],
-                                       rtol=1e-5, atol=1e-6)
-        assert abs(dist_l - single_l) < 1e-6
-        assert abs(dist_l - legacy_l) < 1e-6
-
-    def test_two_level_matches_on_two_axis_mesh(self, problem):
-        from jax.sharding import Mesh
-        from chainermn_tpu.communicators.xla_communicator import (
-            HierarchicalCommunicator,
-        )
-
-        devs = np.array(jax.devices("cpu")[:8]).reshape(2, 4)
-        c2 = HierarchicalCommunicator(mesh=Mesh(devs, ("inter", "intra")))
-        params, batch = problem
-        p2, l2 = _train(c2, params, batch, reduction_schedule="two_level")
-        p1, l1 = _train(c2, params, batch)  # legacy fused pmean
-        for k in params:
-            np.testing.assert_allclose(p2[k], p1[k], rtol=1e-5, atol=1e-6)
-        assert abs(l2 - l1) < 1e-6
-
-    def test_zero_schedule_state_is_sharded_1_over_n(self, comm, problem):
-        """The point of 'zero': each shard holds 1/n of the adam state
-        (stacked [n, ceil(size/n)] leaves, sharded over the data axis)."""
-        from chainermn_tpu.training.train_step import create_train_state
-
-        params, _ = problem
-        opt = create_multi_node_optimizer(
-            optax.adam(1e-2), comm, reduction_schedule="zero"
-        )
-        state = create_train_state(params, opt, comm)
-        mu = state.opt_state.inner[0].mu
-        for k, leaf in params.items():
-            chunk = -(-leaf.size // N)
-            assert mu[k].shape == (N, chunk), (k, mu[k].shape)
-        spec = opt.opt_state_spec()
-        assert spec.inner == P(comm.grad_axes[-1])
 
     def test_zero_schedule_eager_degrade_matches_full_update(
         self, comm, problem
@@ -274,10 +246,6 @@ class TestScheduleEquivalence:
                 optax.sgd(0.1), comm, reduction_schedule="two_level",
                 allreduce_grad_dtype=jnp.int8, error_feedback=True,
             )
-        with pytest.raises(ValueError, match="reduction_schedule"):
-            create_multi_node_optimizer(
-                optax.sgd(0.1), comm, reduction_schedule="ring"
-            )
 
 
 # ----------------------------------------------------------------------
@@ -334,140 +302,242 @@ def test_double_buffer_matches_stale_update_reference_model(comm):
 
 
 # ----------------------------------------------------------------------
-# Structural: compiled-HLO collective counts per schedule
+# flat / two_level / zero over the three meshes and the three wires
 # ----------------------------------------------------------------------
 
 
-def _compiled_counts(comm, fn, tree, spec_tree=None):
-    """Compile fn under shard_map over comm's mesh; count collectives."""
-    axes = comm.grad_axes
-
-    def local(t):
-        sq = jax.tree.map(lambda l: l[0], t)
-        out = fn(sq)
-        return jax.tree.map(lambda l: l[None], out)
-
-    spec = jax.tree.map(
-        lambda l: P(axes, *([None] * (l.ndim - 1))), tree
-    )
-    f = jax.jit(shard_map(local, mesh=comm.mesh, in_specs=(spec,),
-                          out_specs=spec, check_vma=False))
-    txt = f.lower(tree).compile().as_text()
-    return {op: txt.count(op) for op in
-            ("reduce-scatter(", "all-gather(", "all-reduce(")}
+def _stacked_spec(axes, tree):
+    return jax.tree.map(lambda l: P(axes, *([None] * (l.ndim - 1))), tree)
 
 
-class TestStructural:
-    def test_flat_schedule_is_one_allreduce_per_bucket(self, comm):
-        tree = {"w": jnp.ones((N, 64, 32)), "b": jnp.ones((N, 32))}
-        counts = _compiled_counts(
-            comm,
-            lambda t: reduce_tree(t, schedule="flat", axes=comm.grad_axes,
-                                  compress_dtype=jnp.bfloat16),
-            tree,
-        )
-        assert counts == {"reduce-scatter(": 0, "all-gather(": 0,
-                          "all-reduce(": 1}, counts
+def _collectives(txt, ops):
+    return {op: txt.count(op) for op in ops}
 
-    def test_two_level_on_flat_mesh_is_rs_plus_ag(self, comm):
-        """On a 1-axis mesh the two_level schedule pins the decomposed
-        reduce-scatter -> all-gather form: NO all-reduce survives."""
-        tree = {"w": jnp.ones((N, 64, 32)), "b": jnp.ones((N, 32))}
-        counts = _compiled_counts(
-            comm,
-            lambda t: reduce_tree(t, schedule="two_level",
-                                  axes=comm.grad_axes,
-                                  compress_dtype=jnp.bfloat16),
-            tree,
-        )
-        assert counts == {"reduce-scatter(": 1, "all-gather(": 1,
-                          "all-reduce(": 0}, counts
 
-    def test_two_level_on_two_axis_mesh_is_rs_ar_ag(self):
-        """2-axis mesh: intra reduce-scatter -> inter all-reduce of the
-        shard -> intra all-gather, exactly once per bucket (the existing
-        TwoDimensionalCommunicator pins, now via the shared layer)."""
-        from jax.sharding import Mesh
-        from chainermn_tpu.communicators.xla_communicator import (
-            TwoDimensionalCommunicator,
-        )
+_LOWERED = ("stablehlo.all_reduce", "stablehlo.reduce_scatter",
+            "stablehlo.all_gather", "stablehlo.all_to_all")
 
-        devs = np.array(jax.devices("cpu")[:8]).reshape(2, 4)
-        c2 = TwoDimensionalCommunicator(
-            mesh=Mesh(devs, ("inter", "intra"))
-        )
-        tree = {"w": jnp.ones((8, 16, 8)), "b": jnp.ones((8, 8))}
+
+def _bucket_as_written(schedule, n_axes, int8):
+    """The collectives ONE bucket is written as (the lowered program,
+    before XLA combines or splits anything)."""
+    ar, rs, ag, a2a = _LOWERED
+    if int8 and (schedule == "flat" or n_axes == 1):
+        # the two-phase wire: int8 chunks out, scales, int8 shards and
+        # their scales back
+        return {ar: 0, rs: 0, ag: 3, a2a: 1}
+    if int8:
+        # exact scatter and gather over the last axis, the two-phase
+        # wire over the others
+        return {ar: 0, rs: 1, ag: 4, a2a: 1}
+    if schedule == "flat":
+        return {ar: 1, rs: 0, ag: 0, a2a: 0}
+    return {ar: 1 if n_axes > 1 else 0, rs: 1, ag: 1, a2a: 0}
+
+
+class TestNamedSchedules:
+    @pytest.mark.parametrize("wire", sorted(WIRES))
+    @pytest.mark.parametrize("mesh", sorted(MESHES))
+    @pytest.mark.parametrize("schedule", ["flat", "two_level"])
+    def test_mean_and_collective_counts(self, mesh_comm, schedule, mesh,
+                                        wire):
+        """A bucket is the collectives its schedule's name says, and
+        what comes back is the mean: to the bit on the float32 wire
+        (eighths, whose sums are exact in any order), inside the wire's
+        stated error on the others (bf16: the input rounded once and a
+        bf16 sum; int8: two roundings of 1/254 of the largest value
+        each)."""
+        c = mesh_comm(mesh)
+        axes = c.grad_axes
+        rs = np.random.RandomState(11)
+        if wire == "none":
+            vals = lambda *sh: rs.randint(-8, 9, sh) / 8.0  # noqa: E731
+        else:
+            vals = lambda *sh: rs.randn(*sh)  # noqa: E731
+        tree = {"w": jnp.asarray(vals(N, 37, 5), jnp.float32),
+                "b": jnp.asarray(vals(N, 5), jnp.float32),
+                "e": jnp.zeros((N, 0), jnp.float32)}
 
         def local(t):
             sq = jax.tree.map(lambda l: l[0], t)
-            out = reduce_tree(sq, schedule="two_level", axes=c2.grad_axes,
-                              compress_dtype=jnp.bfloat16)
+            out = reduce_tree(sq, schedule=schedule, axes=axes,
+                              compress_dtype=WIRES[wire])
             return jax.tree.map(lambda l: l[None], out)
 
-        spec = jax.tree.map(
-            lambda l: P(("inter", "intra"), *([None] * (l.ndim - 1))),
-            tree,
-        )
-        f = jax.jit(shard_map(local, mesh=c2.mesh, in_specs=(spec,),
+        spec = _stacked_spec(axes, tree)
+        f = jax.jit(shard_map(local, mesh=c.mesh, in_specs=(spec,),
                               out_specs=spec, check_vma=False))
-        txt = f.lower(tree).compile().as_text()
-        counts = {op: txt.count(op) for op in
-                  ("reduce-scatter(", "all-gather(", "all-reduce(")}
-        assert counts == {"reduce-scatter(": 1, "all-gather(": 1,
-                          "all-reduce(": 1}, counts
+        lowered = f.lower(tree)
+        # (the zero-size leaf takes its own exact path and sends nothing)
+        want = _bucket_as_written(schedule, len(axes), wire == "int8")
+        assert _collectives(lowered.as_text(), _LOWERED) == want
+        if wire != "int8":
+            # and what XLA's CPU compiler leaves of them: it neither
+            # fuses a scatter and a gather back into an all-reduce nor
+            # splits one
+            compiled = _collectives(
+                lowered.compile().as_text(),
+                ("all-reduce(", "reduce-scatter(", "all-gather("))
+            assert list(compiled.values()) == list(want.values())[:3], \
+                compiled
 
-    def test_zero_schedule_is_rs_plus_ag_per_leaf_no_allreduce(self, comm):
-        """The sharded-update pipeline: one reduce-scatter in, one
-        all-gather out per parameter leaf, and NO gradient all-reduce
-        anywhere in the reduction+update program."""
-        from chainermn_tpu.testing import count_primitives
+        out = jax.device_get(f(tree))
+        for k in ("w", "b"):
+            got = out[k]
+            mean = np.asarray(tree[k], np.float64).mean(0)
+            # every member holds the mean
+            assert all((got[i] == got[0]).all() for i in range(N))
+            if wire == "none":
+                np.testing.assert_array_equal(got[0], mean.astype(np.float32))
+            else:
+                amax = float(np.abs(np.asarray(tree[k])).max())
+                atol = {"bf16": amax * 2.0 ** -6,
+                        "int8": amax / 127 * 1.01}[wire]
+                for i in range(N):
+                    np.testing.assert_allclose(got[i], mean, rtol=0,
+                                               atol=atol)
+        assert out["e"].shape == (N, 0)
 
-        params = {"w": jnp.ones((5, 3), jnp.float32),
-                  "b": jnp.ones((3,), jnp.float32)}
+    @pytest.mark.parametrize("mesh", sorted(MESHES))
+    @pytest.mark.parametrize("schedule", [None, "flat", "two_level", "zero"])
+    def test_trainer_dist_equals_single(self, mesh_comm, schedule, mesh):
+        """The suite's core invariant, per schedule and mesh: three Adam
+        steps of the real train step, gradients reduced by THIS
+        schedule, against three steps on the whole batch on one device
+        (values, and through Adam's first step the gradients)."""
+        rs = np.random.RandomState(0)
+        params = {"w": jnp.asarray(rs.randn(5, 3), jnp.float32),
+                  "b": jnp.asarray(rs.randn(3), jnp.float32)}
+        batch = (jnp.asarray(rs.randn(16, 5), jnp.float32),
+                 jnp.asarray(np.arange(16) % 3, np.int32))
+        dist_p, dist_l = _train(mesh_comm(mesh), params, batch,
+                                reduction_schedule=schedule)
+
+        inner = optax.adam(1e-2)
+        p, st = params, inner.init(params)
+        for _ in range(3):
+            loss, g = jax.value_and_grad(_loss_fn)(p, batch)
+            u, st = inner.update(g, st, p)
+            p = optax.apply_updates(p, u)
+        for k in params:
+            np.testing.assert_allclose(dist_p[k], np.asarray(p[k]),
+                                       rtol=1e-5, atol=1e-6)
+        assert abs(dist_l - float(loss)) < 1e-6
+
+    @pytest.mark.parametrize("inner_name", ["sgdm", "adamw"])
+    @pytest.mark.parametrize("wire", ["none", "bf16"])
+    @pytest.mark.parametrize("mesh", sorted(MESHES))
+    def test_zero_update(self, mesh_comm, mesh, wire, inner_name):
+        """``'zero'``: each shard of the last axis holds 1/n of the
+        inner state (stacked ``[n, ceil(size/n)]``, sharded over that
+        axis); a leaf is one reduce-scatter over it in and one
+        all-gather out, with no all-reduce on one axis and one of the
+        chunk over the others on several; the updates are the inner
+        optimizer's on the mean gradient (eighths: exact on both
+        wires)."""
+        c = mesh_comm(mesh)
+        axes = c.grad_axes
+        n_last = c.mesh.shape[axes[-1]]
+        make = {"sgdm": lambda: optax.sgd(0.5, momentum=0.5),
+                "adamw": lambda: optax.adamw(1e-2)}[inner_name]
+        rs = np.random.RandomState(5)
+        params = {"w": jnp.asarray(rs.randint(-8, 9, (37, 5)) / 8.0,
+                                   jnp.float32),
+                  "b": jnp.asarray(rs.randint(-8, 9, (5,)) / 8.0,
+                                   jnp.float32)}
+        grads = {"w": jnp.asarray(rs.randint(-8, 9, (N, 37, 5)) / 8.0,
+                                  jnp.float32),
+                 "b": jnp.asarray(rs.randint(-8, 9, (N, 5)) / 8.0,
+                                  jnp.float32)}
         opt = create_multi_node_optimizer(
-            optax.adam(1e-2), comm, reduction_schedule="zero"
-        )
-        full = opt.init(params)
-        sliced = jax.tree.map(lambda e: e[:1], full)
-        g = jax.tree.map(jnp.ones_like, params)
-        counts = count_primitives(
-            lambda gg: opt.update(gg, sliced, params)[0], g,
-            axis_env=[(comm.axis_name, N)],
-        )
-        assert counts.get("reduce_scatter") == 2    # one per leaf
-        assert counts.get("all_gather") == 2
-        assert not counts.get("psum")               # no grad all-reduce
+            make(), c, reduction_schedule="zero",
+            allreduce_grad_dtype=WIRES[wire])
+        state = opt.init(params)
+        for leaf in jax.tree.leaves(state.inner):
+            if leaf.ndim >= 2:
+                assert leaf.shape[0] == n_last
+                assert leaf.shape[1] in (-(-185 // n_last),
+                                         -(-5 // n_last))
+        sspec = opt.opt_state_spec()
+        assert sspec.inner == P(axes[-1])
 
-    def test_wire_events_record_bucket_layout_and_overlap_flag(self, comm):
-        """Per-bucket, per-STAGE trace-time wire events: schedule label,
-        composition signature, stage payload bytes, and overlapped=True
-        exactly under double buffering."""
+        def local(g, st, p):
+            return opt.update(jax.tree.map(lambda l: l[0], g), st, p)
+
+        f = jax.jit(shard_map(
+            local, mesh=c.mesh,
+            in_specs=(_stacked_spec(axes, grads), sspec, P()),
+            out_specs=(P(), sspec), check_vma=False))
+        counts = _collectives(f.lower(grads, state, params).as_text(),
+                              _LOWERED)
+        assert counts == {
+            "stablehlo.all_reduce": 2 if len(axes) > 1 else 0,
+            "stablehlo.reduce_scatter": 2, "stablehlo.all_gather": 2,
+            "stablehlo.all_to_all": 0,
+        }, counts
+
+        ref = make()
+        rstate = ref.init(params)
+        mean = jax.tree.map(lambda g: g.mean(0), grads)
+        for _ in range(2):
+            u, state = f(grads, state, params)
+            ru, rstate = ref.update(mean, rstate, params)
+            jax.tree.map(
+                lambda a, b: np.testing.assert_allclose(
+                    np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-7),
+                u, ru)
+
+    @pytest.mark.parametrize("mesh", ["8", "2x4"])
+    @pytest.mark.parametrize("schedule", ["flat", "two_level"])
+    def test_wire_events_name_their_stage_and_bytes(self, mesh_comm,
+                                                    schedule, mesh):
+        """Trace-time ``wire`` events: one a bucket a stage, the stage
+        named by its collective and axes, its bytes what that stage
+        carries (a shard's all-reduce is the bucket over the intra
+        size), ``overlapped`` exactly under double buffering."""
         from chainermn_tpu.testing import count_primitives
 
+        c = mesh_comm(mesh)
+        axes = c.grad_axes
+        env = [(a, c.mesh.shape[a]) for a in axes]
         rec = trace.enable(None)
-        tree = {"w": jnp.zeros((64, 32)), "b": jnp.zeros((32,))}
-        env = [(comm.axis_name, N)]
+        tree = {"w": jnp.zeros((64, 32)), "b": jnp.zeros((30,))}
         count_primitives(
-            lambda t: reduce_tree(t, schedule="two_level",
-                                  axes=comm.grad_axes,
-                                  compress_dtype=jnp.bfloat16),
+            lambda t: reduce_tree(t, schedule=schedule, axes=axes,
+                                  compress_dtype=jnp.bfloat16,
+                                  bucket_bytes=64 * 32 * 2),
             tree, axis_env=env,
         )
         wires = [e for e in rec.events if e["kind"] == "wire"]
-        # on the flat mesh two_level IS rs(data)>ag(data): one wire
-        # event per stage, both carrying the composition signature
-        assert len(wires) == 2
-        assert [w["stage"] for w in wires] == ["rs(data)", "ag(data)"]
-        assert all(w["schedule"] == "two_level" for w in wires)
-        assert all(w["composition"] == "rs(data)>ag(data)" for w in wires)
-        # both stages carry the full bucket payload (in / out of the
-        # scatter frame) on the bf16 wire
-        assert all(w["nbytes"] == (64 * 32 + 32) * 2 for w in wires)
-        assert all(w["overlapped"] is False for w in wires)
+        last, rest = axes[-1], "+".join(axes[:-1])
+        stages = {
+            ("flat", "8"): ["ar(data)"],
+            ("flat", "2x4"): ["ar(inter+intra)"],
+            ("two_level", "8"): ["rs(data)", "ag(data)"],
+            ("two_level", "2x4"): ["rs(intra)", "ar(inter)", "ag(intra)"],
+        }[schedule, mesh]
+        assert [(w["bucket"], w["stage"]) for w in wires] == [
+            (b, st) for b in (0, 1) for st in stages]
+        buckets = [30 * 2, 64 * 32 * 2]  # 'b' sorts first; the bf16 wire
+        n_intra = c.mesh.shape[last]
+        for w in wires:
+            assert w["schedule"] == schedule and w["n_buckets"] == 2
+            assert w["stage_index"] == stages.index(w["stage"])
+            assert w["wire_dtype"] == "bfloat16"
+            assert w["overlapped"] is False
+            assert not {"composition", "slice", "n_slices"} & set(w)
+            whole = buckets[w["bucket"]]
+            if w["stage"] == f"ar({rest})":
+                assert w["nbytes"] == -(-whole // 2 // n_intra) * 2
+            else:
+                assert w["nbytes"] == whole
+        pack = [e for e in rec.events if e["kind"] == "pack"][-1]
+        assert pack["n_buckets"] == 2 and pack["nbytes"] == sum(buckets)
 
         # the double-buffered optimizer tags its buckets overlapped
         opt = create_multi_node_optimizer(
-            optax.sgd(1.0), comm, double_buffering=True
+            optax.sgd(1.0), c, double_buffering=True,
+            reduction_schedule=schedule,
         )
         state = opt.init(jnp.zeros((8,)))
         count_primitives(
@@ -476,7 +546,7 @@ class TestStructural:
         )
         wires = [e for e in rec.events if e["kind"] == "wire"]
         assert wires[-1]["overlapped"] is True
-        assert wires[-1]["schedule"] == "flat"
+        assert wires[-1]["schedule"] == schedule
 
     def test_recorder_does_not_change_the_scheduled_program(self, comm):
         """The observability invariant holds for the new schedules:
@@ -497,51 +567,6 @@ class TestStructural:
         trace.enable(None)
         on = {s: counts(s) for s in ("flat", "two_level")}
         assert on == off
-
-
-# ----------------------------------------------------------------------
-# 'auto' resolution + provenance
-# ----------------------------------------------------------------------
-
-
-class TestAutoResolution:
-    def test_table_default_is_flat_with_provenance(self, comm):
-        winner, rec = resolve_schedule("cpu", 3 << 20, (8,))
-        assert winner == "flat"
-        assert rec["name"] == "reduction_schedule"
-        assert rec["source"] == "table"
-        assert rec["key"].endswith("|sched")
-
-    def test_forced_override_reaches_the_optimizer(self, comm, monkeypatch):
-        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE_FORCE",
-                           "reduction_schedule=zero")
-        opt = create_multi_node_optimizer(
-            optax.adam(1e-2), comm, reduction_schedule="auto"
-        )
-        params = {"w": jnp.ones((6,), jnp.float32)}
-        state = opt.init(params)
-        from chainermn_tpu.optimizers import _ZeroShardState
-
-        assert isinstance(state, _ZeroShardState)
-        assert opt._auto_resolved == "zero"
-        assert opt._schedule_provenance["source"] == "forced"
-        # resolution is one-shot: spec agrees with the state layout
-        assert opt.opt_state_spec().inner == P(comm.grad_axes[-1])
-
-    def test_auto_excludes_zero_under_double_buffering(
-        self, comm, monkeypatch
-    ):
-        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE_FORCE",
-                           "reduction_schedule=zero")
-        opt = create_multi_node_optimizer(
-            optax.sgd(0.1), comm, reduction_schedule="auto",
-            double_buffering=True,
-        )
-        assert "zero" not in opt._auto_candidates
-        # the forced override names a non-candidate -> loud error, not
-        # a silently wrong layout
-        with pytest.raises(ValueError):
-            opt.init({"w": jnp.ones((4,))})
 
 
 # ----------------------------------------------------------------------
@@ -591,56 +616,6 @@ class TestOverlappedBucketReducer:
         red.collect()
         with pytest.raises(RuntimeError, match="no dispatched"):
             red.collect()
-
-    def test_measured_composed_reducer(self, comm):
-        """ISSUE 13 satellite (PR 11 follow-up): the eager per-STAGE
-        composed executor — mean correct for every derived pipeline,
-        one measured ``wire`` event per stage carrying the composition
-        signature + ``dur_s``, and the overlap rollup's per-signature
-        stage rows gain the measured ``dur_ms`` column."""
-        from chainermn_tpu.parallel.reduction_schedule import (
-            MeasuredComposedReducer,
-        )
-
-        rec = trace.enable(None)
-        rs = np.random.RandomState(5)
-        stacked = {
-            "a": jnp.asarray(rs.randn(N, 33), jnp.float32),
-            "b": jnp.asarray(rs.randn(N, 4, 2), jnp.float32),
-        }
-        for sched, n_stages in (("flat", 1), ("two_level", 2)):
-            red = MeasuredComposedReducer(comm, schedule=sched)
-            out = red.reduce(stacked)
-            jax.tree.map(
-                lambda o, g: np.testing.assert_allclose(
-                    np.asarray(o), np.asarray(g).mean(0),
-                    rtol=1e-5, atol=1e-6,
-                ),
-                out, stacked,
-            )
-            sig = red.comp.signature()
-            wires = [e for e in rec.events
-                     if e["kind"] == "wire"
-                     and e.get("composition") == sig]
-            assert len(wires) == n_stages, (sig, wires)
-            for i, w in enumerate(wires):
-                assert w["schedule"] == "composed_eager"
-                assert w["stage_index"] == i
-                assert w["dur_s"] >= 0
-                assert w["nbytes"] > 0
-        ov = trace.summarize_overlap(rec.events)
-        for sig, row in ov["compositions"].items():
-            for st, srow in row["stages"].items():
-                assert srow.get("dur_ms") is not None, (sig, st)
-
-    def test_measured_composed_refuses_update_stage(self, comm):
-        from chainermn_tpu.parallel.composition import CompositionError
-        from chainermn_tpu.parallel.reduction_schedule import (
-            MeasuredComposedReducer,
-        )
-
-        with pytest.raises(CompositionError, match="sharded_update"):
-            MeasuredComposedReducer(comm, schedule="zero")
 
     def test_staleness_one_loop_matches_reference(self, comm):
         """The reducer's intended double-buffered usage: dispatch step
@@ -704,260 +679,3 @@ def test_trainer_emits_overlap_config(comm):
     assert cfgs[0]["double_buffering"] is True
     assert cfgs[0]["staleness"] == 1
     assert cfgs[0]["schedule"] == "two_level"
-
-
-# ----------------------------------------------------------------------
-# ISSUE 15: sliced eager reducers + the comp_slices decision
-# ----------------------------------------------------------------------
-
-
-class TestSlicedEagerReducers:
-    def test_overlapped_reducer_sliced_mean_and_slice_events(self, comm):
-        """slices=4: one collective flies PER SLICE (the real async
-        interleave), each wire event carries its slice address beside
-        dur_s/blocked_s, the mean is exact, and the rollup still
-        yields a hidden_fraction."""
-        rec = trace.enable(None)
-        rs = np.random.RandomState(2)
-        stacked = {
-            "a": jnp.asarray(rs.randn(N, 100), jnp.float32),
-            "b": jnp.asarray(rs.randn(N, 7, 3), jnp.float32),
-            "empty": jnp.zeros((N, 0), jnp.float32),
-        }
-        red = OverlappedBucketReducer(comm, bucket_bytes=100 * 4,
-                                      slices=4)
-        n_buckets = red.dispatch(stacked)
-        assert n_buckets == 2
-        out = red.collect()
-        for k in ("a", "b"):
-            np.testing.assert_allclose(
-                np.asarray(out[k]), np.asarray(stacked[k]).mean(0),
-                rtol=1e-5, atol=1e-6,
-            )
-        assert out["empty"].shape == (0,)
-        wires = [e for e in rec.events if e["kind"] == "wire"]
-        assert len(wires) == 8  # 2 buckets x 4 slices
-        for w in wires:
-            assert w["schedule"] == "overlap_eager"
-            assert w["n_slices"] == 4 and 0 <= w["slice"] < 4
-            assert w["dur_s"] >= w["blocked_s"] >= 0
-        ov = trace.summarize_overlap(rec.events)
-        assert ov["measured"]["n"] == 8
-        assert 0.0 <= ov["measured"]["hidden_fraction"] <= 1.0
-
-    def test_overlapped_reducer_slice_degrade(self, comm):
-        """A 3-element bucket under slices=8 flies 3 collectives —
-        min(S, elements), never a zero-size one (the zero-leaf
-        contract on the eager path)."""
-        rec = trace.enable(None)
-        red = OverlappedBucketReducer(comm, slices=8)
-        red.dispatch({"g": jnp.ones((N, 3), jnp.float32)})
-        out = red.collect()
-        np.testing.assert_allclose(np.asarray(out["g"]),
-                                   np.ones(3), rtol=1e-6)
-        wires = [e for e in rec.events if e["kind"] == "wire"]
-        assert len(wires) == 3
-        assert all(w["n_slices"] == 3 and w["nbytes"] > 0
-                   for w in wires)
-        with pytest.raises(ValueError, match="slices"):
-            OverlappedBucketReducer(comm, slices=0)
-
-    def test_measured_composed_reducer_sliced(self, comm):
-        """The sliced measured executor: 3 stages x 4 slices of wire
-        events in skewed order, every one carrying slice address +
-        dur_s + blocked_s, the mean exact, and summarize_overlap's
-        per-signature stage rows growing the per-slice sub-table with
-        measured dur_ms/blocked_ms."""
-        from chainermn_tpu.parallel.reduction_schedule import (
-            MeasuredComposedReducer,
-        )
-
-        rec = trace.enable(None)
-        rs = np.random.RandomState(6)
-        stacked = {
-            "a": jnp.asarray(rs.randn(N, 33), jnp.float32),
-            "b": jnp.asarray(rs.randn(N, 4, 2), jnp.float32),
-        }
-        red = MeasuredComposedReducer(comm, schedule="two_level",
-                                      slices=4)
-        sig = red.comp.signature()
-        assert "[s0..3]" in sig
-        out = red.reduce(stacked)
-        jax.tree.map(
-            lambda o, g: np.testing.assert_allclose(
-                np.asarray(o), np.asarray(g).mean(0),
-                rtol=1e-5, atol=1e-6,
-            ),
-            out, stacked,
-        )
-        wires = [e for e in rec.events
-                 if e["kind"] == "wire" and e.get("composition") == sig]
-        n_stages = len(red.comp.stages)
-        assert len(wires) == n_stages * 4
-        for i, w in enumerate(wires):
-            assert w["stage_index"] == i
-            assert w["n_slices"] == 4 and 0 <= w["slice"] < 4
-            assert w["dur_s"] >= 0 and w["blocked_s"] >= 0
-            assert w["nbytes"] > 0
-        # skew: slice 1's rs event precedes slice 0's inter-level ar
-        stages_in_order = [(w["stage"], w["slice"]) for w in wires]
-        rs_name = red.comp.stages[0].signature()
-        ar_name = red.comp.stages[1].signature()
-        assert stages_in_order.index((rs_name, 1)) < \
-            stages_in_order.index((ar_name, 0))
-        ov = trace.summarize_overlap(rec.events)
-        row = ov["compositions"][sig]
-        for st, srow in row["stages"].items():
-            assert srow["n"] == 4, (st, srow)
-            slices = srow["slices"]
-            assert set(slices) == {"s0", "s1", "s2", "s3"}
-            for sl in slices.values():
-                assert sl.get("dur_ms") is not None
-                assert sl.get("blocked_ms") is not None
-
-    def test_measured_composed_reducer_zigzag(self, comm):
-        """ISSUE 16: the eager measured executor honors the zigzag cut
-        — strided slice membership on the way in, comb reassembly on
-        the way out, mean still exact."""
-        from chainermn_tpu.parallel.reduction_schedule import (
-            MeasuredComposedReducer,
-        )
-
-        rs = np.random.RandomState(16)
-        stacked = {"a": jnp.asarray(rs.randn(N, 37), jnp.float32)}
-        sig = "rs(a0)[z0..3]>ag(a0)"
-        red = MeasuredComposedReducer(comm, schedule=sig)
-        assert red.comp.slice_layout == "zigzag"
-        out = red.reduce(stacked)
-        np.testing.assert_allclose(
-            np.asarray(out["a"]), np.asarray(stacked["a"]).mean(0),
-            rtol=1e-5, atol=1e-6,
-        )
-
-    def test_measured_composed_sliced_degrade(self, comm):
-        from chainermn_tpu.parallel.reduction_schedule import (
-            MeasuredComposedReducer,
-        )
-
-        rec = trace.enable(None)
-        red = MeasuredComposedReducer(comm, schedule="two_level",
-                                      slices=8)
-        out = red.reduce({"g": jnp.ones((N, 3), jnp.float32)})
-        np.testing.assert_allclose(np.asarray(out["g"]), np.ones(3),
-                                   rtol=1e-6)
-        wires = [e for e in rec.events
-                 if e["kind"] == "wire" and e.get("composition")]
-        # min(8, 3) slices x the pipeline's stages (2 on a flat mesh)
-        assert len(wires) == 3 * len(red.comp.stages)
-        assert all(w["n_slices"] == 3 for w in wires)
-
-
-class TestCompSlicesDecision:
-    def test_table_default_is_one(self):
-        from chainermn_tpu.parallel.reduction_schedule import (
-            resolve_comp_slices,
-        )
-
-        assert resolve_comp_slices("cpu", 3 << 20, (2, 2, 2)) == 1
-        # ...and the auto schedule resolution stays unsliced
-        winner, rec = resolve_schedule("cpu", 3 << 20, (2, 2, 2),
-                                       slices="auto")
-        assert winner == "flat"
-        assert "comp_slices" not in (rec or {})
-
-    def test_forced_slices_slice_the_auto_winner(self, monkeypatch):
-        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE_FORCE",
-                           "comp_slices=4")
-        winner, rec = resolve_schedule("cpu", 3 << 20, (2, 2, 2),
-                                       slices="auto")
-        assert winner == "ar(a0+a1+a2)[s0..3]"
-        assert rec["comp_slices"] == 4
-        assert rec["composition"] == winner
-        # an explicit integer pins without consulting the registry
-        winner2, rec2 = resolve_schedule("cpu", 3 << 20, (2, 2, 2),
-                                         slices=2)
-        assert winner2 == "ar(a0+a1+a2)[s0..1]"
-        # slices=None (the default) is the pre-ISSUE-15 behaviour
-        winner3, _ = resolve_schedule("cpu", 3 << 20, (2, 2, 2))
-        assert winner3 == "flat"
-
-    def test_sliced_auto_winner_runs_through_the_optimizer(
-        self, comm, monkeypatch
-    ):
-        """End to end: a forced comp_slices=2 'auto' optimizer reduces
-        a dyadic tree identically to the flat schedule — the sliced
-        winner compiles and runs through the standard update path."""
-        monkeypatch.setenv("CHAINERMN_TPU_AUTOTUNE_FORCE",
-                           "comp_slices=2")
-        opt = create_multi_node_optimizer(
-            optax.sgd(0.5), comm, reduction_schedule="auto"
-        )
-        params = {"w": jnp.asarray(
-            np.arange(N * 24).reshape(N, 24) % 8, jnp.float32) / 8.0}
-
-        def local(p):
-            sq = {"w": p["w"][0]}
-            sched = opt._effective_schedule(sq)
-            out = opt._reduce_scheduled(sq, sched)
-            return {"w": out["w"][None]}
-
-        from jax import shard_map
-        from jax.sharding import PartitionSpec as P
-
-        f = jax.jit(shard_map(
-            local, mesh=comm.mesh,
-            in_specs=({"w": P(comm.grad_axes, None)},),
-            out_specs={"w": P(comm.grad_axes, None)},
-            check_vma=False,
-        ))
-        out = jax.device_get(f(params))
-        assert "[s0..1]" in opt._auto_resolved
-        assert opt._schedule_provenance["comp_slices"] == 2
-        ref = np.asarray(params["w"]).reshape(N, -1).mean(0)
-        np.testing.assert_array_equal(out["w"].reshape(N, -1)[0], ref)
-
-
-def test_sliced_wire_events_and_pack_degrade_note(comm):
-    """ISSUE 15: trace-time events of a SLICED in-jit schedule — one
-    wire event per stage per slice (slice/n_slices fields, per-slice
-    payloads summing to the unsliced stage bytes), and the pack event
-    carrying the requested slice count plus the LOUD min(S, elements)
-    degrade provenance when a bucket is smaller than S."""
-    from chainermn_tpu.testing import count_primitives
-
-    rec = trace.enable(None)
-    tree = {"w": jnp.zeros((64, 32)), "b": jnp.zeros((32,))}
-    env = [(comm.axis_name, N)]
-    sig = "rs(data)[s0..3]>ag(data)"
-    count_primitives(
-        lambda t: reduce_tree(t, schedule=sig, axes=comm.grad_axes,
-                              compress_dtype=jnp.bfloat16),
-        tree, axis_env=env,
-    )
-    wires = [e for e in rec.events if e["kind"] == "wire"]
-    assert len(wires) == 8  # 2 stages x 4 slices
-    assert all(w["composition"] == sig for w in wires)
-    assert all(w["n_slices"] == 4 and 0 <= w["slice"] < 4
-               for w in wires)
-    per_stage: dict = {}
-    for w in wires:
-        per_stage[w["stage"]] = per_stage.get(w["stage"], 0) + w["nbytes"]
-    total = (64 * 32 + 32) * 2  # the unsliced bucket on the bf16 wire
-    assert per_stage == {"rs(data)": total, "ag(data)": total}
-    pack = [e for e in rec.events if e["kind"] == "pack"][-1]
-    assert pack["comp_slices"] == 4
-    assert "comp_slices_degraded" not in pack  # 2080 elems >> 4
-
-    # degrade: a 3-element payload under S=4 → 3 slices, loud note
-    rec2 = trace.enable(None)
-    count_primitives(
-        lambda t: reduce_tree(t, schedule=sig, axes=comm.grad_axes),
-        {"b": jnp.zeros((3,))}, axis_env=env,
-    )
-    pack2 = [e for e in rec2.events if e["kind"] == "pack"][-1]
-    assert pack2["comp_slices"] == 4
-    assert pack2["comp_slices_degraded"] == {0: 3}
-    assert "min(S, elements)" in pack2["comp_slices_note"]
-    wires2 = [e for e in rec2.events if e["kind"] == "wire"]
-    assert len(wires2) == 6  # 2 stages x min(4, 3) slices
-    assert all(w["n_slices"] == 3 and w["nbytes"] > 0 for w in wires2)

@@ -2,8 +2,8 @@
 
 Pins the tree-push contract end to end:
 
-- the send schedule is the composition DSL's broadcast walk
-  (``tree_depth`` rounds, ``n-1`` total sends, every source a holder);
+- the send schedule is the holder-doubling walk (``tree_depth`` rounds,
+  ``n-1`` total sends, every source a holder);
 - :func:`tree_push` delivers over the loopback hub with O(log N)
   donor sends (vs the N-1 sequential baseline) and emits the
   ``tree_push`` trace event;
@@ -21,7 +21,6 @@ import pytest
 
 from chainermn_tpu.models.transformer import TransformerLM
 from chainermn_tpu.observability import trace
-from chainermn_tpu.parallel.composition import tree_depth, tree_sends
 from chainermn_tpu.serving import Scheduler, ServingEngine
 from chainermn_tpu.serving.adapters import AdapterBank, random_adapter
 from chainermn_tpu.serving.cluster import (
@@ -32,6 +31,7 @@ from chainermn_tpu.serving.cluster import (
     tree_rounds,
     warm_prefix_trie,
 )
+from chainermn_tpu.serving.cluster.tree_push import tree_depth, tree_sends
 
 VOCAB = 32
 
@@ -93,6 +93,23 @@ class TestTreeSchedule:
     def test_radix_validation(self):
         with pytest.raises(ValueError, match="radix"):
             tree_rounds(4, 1)
+        with pytest.raises(ValueError, match="radix"):
+            tree_depth(4, 1)
+        with pytest.raises(ValueError, match="radix"):
+            tree_sends(4, 1)
+
+    @pytest.mark.parametrize("n,radix,depth,sends", [
+        (1, 2, 0, 0), (2, 2, 1, 1), (5, 2, 3, 3), (8, 2, 3, 3),
+        (8, 4, 2, 4), (9, 4, 2, 5), (64, 4, 3, 9),
+    ])
+    def test_tree_depth_and_sends(self, n, radix, depth, sends):
+        """Rounds to reach ``n`` members and the root's sends: radix - 1
+        a full round, fewer in a last round that needs fewer."""
+        assert tree_depth(n, radix) == depth
+        assert tree_sends(n, radix) == sends
+        assert len(tree_rounds(n, radix)) == depth
+        assert sum(s == 0 for rnd in tree_rounds(n, radix)
+                   for s, _ in rnd) == sends
 
 
 class TestTreePush:

@@ -12,6 +12,7 @@ Covers the subsystem's contracts hermetically (no hardware):
   mesh is the sort path (scatter in the lowering, decision recorded).
 """
 
+import functools
 import json
 
 import jax
@@ -90,10 +91,6 @@ class TestRegistry:
 _SIDES = {
     "allreduce_wire": ("bf16", "int8"),
     "allreduce_bucket_mb": ("64", "256"),
-    "double_buffering": ("off", "on"),
-    "reduction_schedule": ("flat", "two_level"),
-    "comp_slices": ("1", "4"),
-    "sched_search": ("topk", "exhaustive"),
     "attention": ("flash", "xla"),
     "attention_windowed": ("windowed", "xla"),
     "moe_dispatch": ("sort", "einsum"),
@@ -117,7 +114,6 @@ def _call_sites():
     program, where it has one that is not a constructor."""
     from chainermn_tpu.ops.attention import resolve_attention_impl
     from chainermn_tpu.parallel import collectives, moe
-    from chainermn_tpu.parallel import reduction_schedule as rs
     from chainermn_tpu.parallel.plan import ParallelPlan
     from chainermn_tpu.serving import engine
 
@@ -150,10 +146,6 @@ def _call_sites():
             kind, 4),
         "allreduce_bucket_mb": lambda kind: collectives.tuned_bucket_bytes(
             kind, 4),
-        "reduction_schedule": lambda kind: rs.resolve_schedule(
-            kind, 1 << 20, (8,)),
-        "comp_slices": lambda kind: rs.resolve_comp_slices(
-            kind, 1 << 20, (2, 2, 2)),
         "seq_attn_impl": plan_seq,
         **served,
     }
@@ -200,6 +192,56 @@ def test_decision_resolves_from_its_table_entry(name, tmp_path, monkeypatch):
             assert rec["source"] == "table", (kind, rec)
 
 
+@functools.lru_cache(maxsize=None)
+def _decisions_read_by_the_package():
+    """Every decision name that is the first argument of a ``choice``
+    call under ``chainermn_tpu/``: a string literal, or a name the same
+    file assigns string literals to (``ops/attention.py`` picks between
+    two)."""
+    import ast
+    import os
+
+    import chainermn_tpu
+
+    read = set()
+    for folder, _, files in os.walk(os.path.dirname(chainermn_tpu.__file__)):
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            with open(os.path.join(folder, f)) as fh:
+                tree = ast.parse(fh.read())
+            assigned: dict = {}
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Assign):
+                    literals = {c.value for c in ast.walk(node.value)
+                                if isinstance(c, ast.Constant)
+                                and isinstance(c.value, str)}
+                    for t in node.targets:
+                        if isinstance(t, ast.Name):
+                            assigned.setdefault(t.id, set()).update(literals)
+            for node in ast.walk(tree):
+                if not (isinstance(node, ast.Call) and node.args):
+                    continue
+                fn = node.func
+                if (fn.attr if isinstance(fn, ast.Attribute)
+                        else getattr(fn, "id", None)) != "choice":
+                    continue
+                first = node.args[0]
+                if isinstance(first, ast.Constant):
+                    read.add(first.value)
+                elif isinstance(first, ast.Name):
+                    read |= assigned.get(first.id, set())
+    return read
+
+
+@pytest.mark.parametrize("decision", sorted(tuning.DEFAULT_TABLE))
+def test_every_table_key_has_a_reader(decision):
+    """A table key is a path choice some code takes: a key no ``choice``
+    call in the package names is a constant with a table entry, and goes
+    (``double_buffering`` had none from PR 28 to PR 46)."""
+    assert decision in _decisions_read_by_the_package()
+
+
 def test_choice_opens_no_file_and_reads_no_clock(monkeypatch):
     """Resolving a decision is pure Python over the arguments, the
     override and the table: with ``open`` and the clocks taken away the
@@ -214,7 +256,7 @@ def test_choice_opens_no_file_and_reads_no_clock(monkeypatch):
     monkeypatch.setattr(builtins, "open", refuse)
     monkeypatch.setattr(time, "perf_counter", refuse)
     monkeypatch.setattr(time, "time", refuse)
-    for name in ("attention", "allreduce_bucket_mb", "reduction_schedule",
+    for name in ("attention", "allreduce_bucket_mb", "allreduce_wire",
                  "decode_impl"):
         sites[name]("cpu")
     assert {r["source"] for r in tuning.decisions_taken()} == {"table"}

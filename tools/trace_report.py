@@ -24,11 +24,9 @@ Sections:
   (name=winner(source), keyed).
 - **overlap** — comm/compute overlap (ISSUE 3): the step's overlap
   configuration (``overlap_config`` events — double-buffering
-  staleness, reduction schedule, donation), the per-bucket ``wire``
-  layout the compiled schedules committed to, the COMPOSED schedules
-  grouped by composition signature with a per-stage bytes/time table
-  (ISSUE 12: wire events carrying ``composition``/``stage`` fields —
-  one row per ``rs``/``ar``/``ag`` stage of the derived pipeline), and
+  staleness, reduction schedule, donation), the ``wire`` layout the
+  compiled schedules committed to (buckets and the bytes their stages
+  carry, per schedule), and
   — where measured wire events exist (the eager
   ``OverlappedBucketReducer``; dur = dispatch->ready, blocked = wait
   actually paid at collect) — per-step comm time vs comm time hidden
@@ -411,37 +409,6 @@ def render_text(s: dict) -> str:
                 f"{_fmt_bytes(row['nbytes'])} wire, "
                 f"{row['overlapped']} overlapped"
             )
-        for sig, row in ov.get("compositions", {}).items():
-            pred = (f", predicted {row['predicted_ms']:.3f} ms"
-                    if row.get("predicted_ms") is not None else "")
-            lines.append(
-                f"  composed {sig} [{row['schedule']}]: "
-                f"{row['buckets']} bucket(s), "
-                f"{_fmt_bytes(row['nbytes'])} wire, "
-                f"{row['overlapped']} overlapped{pred}"
-            )
-            for st, srow in row.get("stages", {}).items():
-                dur = (f", {srow['dur_ms']:.3f} ms"
-                       if srow.get("dur_ms") is not None else "")
-                lines.append(
-                    f"    {st} [{srow.get('op')}]: n={srow['n']}, "
-                    f"{_fmt_bytes(srow['nbytes'])}{dur}"
-                )
-                # ISSUE 15: the per-slice column — one sub-row per
-                # bucket slice with its measured dur beside the layout
-                # bytes (unsliced stages carry no 'slices' table).
-                for s_key, sl in sorted(
-                    srow.get("slices", {}).items(),
-                    key=lambda kv: int(kv[0][1:]),
-                ):
-                    sdur = (f", {sl['dur_ms']:.3f} ms"
-                            if sl.get("dur_ms") is not None else "")
-                    sblk = (f" ({sl['blocked_ms']:.3f} ms blocked)"
-                            if sl.get("blocked_ms") is not None else "")
-                    lines.append(
-                        f"      {s_key}: n={sl['n']}, "
-                        f"{_fmt_bytes(sl['nbytes'])}{sdur}{sblk}"
-                    )
         m = ov.get("measured")
         if m:
             lines.append(
@@ -450,32 +417,6 @@ def render_text(s: dict) -> str:
                 f"({m['hidden_fraction'] * 100:.1f}% hidden, "
                 f"{m['n']} bucket events)"
             )
-        # ISSUE 16: the cost-model schedule search's audit — predicted
-        # beside measured per arm, skipped arms still priced (no silent
-        # coverage loss), and a LOUD flag when the model's error blew
-        # past the measurement spread (the exhaustive-fallback gate).
-        ss = ov.get("sched_search")
-        if ss:
-            err, spread = ss.get("err_pct"), ss.get("spread_pct")
-            loud = (err is not None and spread is not None
-                    and err > spread)
-            head = f"  schedule search [{ss.get('mode')}] " \
-                   f"({ss.get('provenance')})"
-            if err is not None:
-                head += f": model err {err:.1f}%"
-                if spread is not None:
-                    head += (f" > spread {spread:.1f}% !! MODEL PAST "
-                             f"GATE — exhaustive fallback" if loud else
-                             f" <= spread {spread:.1f}%")
-            lines.append(head)
-            for sig, row in ss.get("rows", {}).items():
-                p = (f"predicted {row['predicted_ms']:>9.3f} ms"
-                     if row.get("predicted_ms") is not None
-                     else " " * 22)
-                mm = (f"  measured {row['measured_ms']:>9.3f} ms"
-                      if row.get("measured_ms") is not None
-                      else "  (skipped)")
-                lines.append(f"    {sig}: {p}{mm}")
     if s.get("serving"):
         sv = s["serving"]
         lines.append("")
