@@ -15,6 +15,7 @@ ring_attention_local(q, k, v, 'seq', causal=causal, scale=scale)``).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -27,8 +28,64 @@ from chainermn_tpu.ops.attention import blockwise_attention
 
 #: the token mixers and feed-forwards a layer of the stack may have
 #: (:attr:`Architecture.layers`)
-MIXERS = ("attention", "short_conv")
+MIXERS = ("attention", "short_conv", "latent_attention")
 FFNS = ("dense", "experts")
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's numbers (arXiv:2309.00071, as DeepSeek-V2's ``rope_scaling``
+    spells them): rotary frequencies blended between the base's own and
+    those a ``factor`` times slower, by how many turns a dimension makes
+    over the ``original_max_position`` positions the model was first
+    trained on; and the two ``mscale``s of the attention's temperature."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def correction_range(self, dim: int, base: float) -> tuple:
+        """``(low, high)``: the dimension pairs ``i <= low`` keep the
+        base's frequency, ``i >= high`` take the slowed one, those
+        between a linear blend. ``corr(n)`` is the pair that makes ``n``
+        turns over the original positions."""
+        def corr(turns):
+            return dim * math.log(self.original_max_position
+                                  / (turns * 2 * math.pi)) \
+                / (2 * math.log(base))
+
+        return (max(math.floor(corr(self.beta_fast)), 0),
+                min(math.ceil(corr(self.beta_slow)), dim - 1))
+
+    def frequencies(self, dim: int, base: float):
+        """The ``dim // 2`` float32 frequencies of a rotary part of
+        ``dim`` values at ``base``."""
+        i = jnp.arange(dim // 2, dtype=jnp.float32)
+        extra = base ** (-2.0 * i / dim)
+        low, high = self.correction_range(dim, base)
+        ramp = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return extra / self.factor * ramp + extra * (1.0 - ramp)
+
+    def _m(self, t: float) -> float:
+        if self.factor <= 1.0:
+            return 1.0
+        return 0.1 * t * math.log(self.factor) + 1.0
+
+    @property
+    def rotation_scale(self) -> float:
+        """What cos and sin are multiplied by:
+        ``m(mscale) / m(mscale_all_dim)``."""
+        return self._m(self.mscale) / self._m(self.mscale_all_dim)
+
+    @property
+    def softmax_scale(self) -> float:
+        """The factor on a head's ``width ** -0.5``:
+        ``m(mscale_all_dim) ** 2`` (1 where ``mscale_all_dim`` is 0)."""
+        return self._m(self.mscale_all_dim) ** 2 \
+            if self.mscale_all_dim else 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,6 +166,26 @@ class Architecture:
     #: mask by blocks, and its loss :func:`lm_loss_block_diffusion`.
     #: Training only
     diffusion_block: int = 0
+    #: a ``'latent_attention'`` mixer's sizes (DeepSeek-V2's MLA, queries
+    #: projected directly): keys and values come through one normed
+    #: latent of ``latent_rank`` values a token; a head's query and key
+    #: are ``qk_nope_dim`` values without position and ``qk_rope_dim``
+    #: rotated ones, the rotated key one row shared by all heads; its
+    #: values ``v_head_dim`` wide. Training only
+    latent_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    #: scaled RoPE (:class:`Yarn`; ``None``: the base's own frequencies),
+    #: read by the latent attention's rotary part
+    rope_scaling: Optional[Yarn] = None
+    #: width of the shared expert beside the routed ones (0: none): one
+    #: gated feed-forward every token passes, added unweighted; under a
+    #: share of the routed experts it is computed whole
+    shared_expert_width: int = 0
+    #: the router also sows the per-sequence form of the balance loss
+    #: (DeepSeek-V2's ``seq_aux``; :func:`lm_loss_moe`'s ``seq_aux_coef``)
+    seq_aux: bool = False
 
     def __post_init__(self):
         if self.qk_norm is True:
@@ -147,6 +224,14 @@ class Architecture:
                     f"experts_held={self.experts_held} is no range of "
                     f"{self.n_experts} experts")
             object.__setattr__(self, "experts_held", (int(lo), int(hi)))
+        if self.rope_scaling is not None and not (self.layers and all(
+                m == "latent_attention" for m, _ in self.layers)):
+            raise ValueError(
+                "scaled RoPE is built for latent attention alone: "
+                "another mixer would read the base's own frequencies")
+        if (self.shared_expert_width or self.seq_aux) and not self.n_experts:
+            raise ValueError("a shared expert and the per-sequence "
+                             "balance loss belong to a router: n_experts")
         if self.layers is not None:
             layers = tuple(tuple(pair) for pair in self.layers)
             object.__setattr__(self, "layers", layers)
@@ -158,6 +243,16 @@ class Architecture:
                         f"{FFNS}, got {pair!r}")
             if self.has_short_conv and self.conv_width < 1:
                 raise ValueError("a short_conv layer needs conv_width >= 1")
+            if self.has_latent_attention and not (
+                    self.latent_rank > 0 and self.qk_nope_dim > 0
+                    and self.qk_rope_dim > 0 and self.qk_rope_dim % 2 == 0
+                    and 0 < self.v_head_dim
+                    <= self.qk_nope_dim + self.qk_rope_dim
+                    and self.positions == "rope" and not self.qk_norm):
+                raise ValueError(
+                    "a latent_attention layer needs latent_rank, "
+                    "qk_nope_dim, an even qk_rope_dim, a v_head_dim no "
+                    "wider than a key, RoPE positions and no qk_norm")
             if any(f == "experts" for _, f in layers) \
                     and not self.n_experts:
                 raise ValueError("a layer of experts needs n_experts")
@@ -175,6 +270,10 @@ class Architecture:
     @property
     def has_short_conv(self) -> bool:
         return any(m == "short_conv" for m, _ in self.layers or ())
+
+    @property
+    def has_latent_attention(self) -> bool:
+        return any(m == "latent_attention" for m, _ in self.layers or ())
 
     @property
     def n_experts_held(self) -> int:
@@ -244,25 +343,92 @@ class Architecture:
             return cls._from_lfm2_moe(config)
         if kind == "sdar_moe":
             return cls._from_sdar_moe(config)
+        if kind == "deepseek_v2":
+            return cls._from_deepseek_v2(config)
         raise ValueError(f"no block is described for model_type {kind!r}")
 
     @staticmethod
-    def _experts_share(config: dict) -> tuple:
+    def _experts_share(config: dict, key: str = "num_experts") -> tuple:
         """``(router width, held range or None)`` of a file that may hold
-        a chip's share of the experts: the experts it holds as
-        ``num_experts`` and ``experts_held_range``, the router's width as
-        ``experts_published``."""
-        n_experts = int(config.get("experts_published",
-                                   config["num_experts"]))
+        a chip's share of the experts: the experts it holds under the
+        family's own ``key`` and as ``experts_held_range``, the router's
+        width as ``experts_published``."""
+        n_experts = int(config.get("experts_published", config[key]))
         held = config.get("experts_held_range")
-        if (held is None) != (n_experts == config["num_experts"]) or (
-                held is not None
-                and held[1] - held[0] != config["num_experts"]):
+        if (held is None) != (n_experts == config[key]) or (
+                held is not None and held[1] - held[0] != config[key]):
             raise ValueError(
-                "a share of the experts is spelled num_experts (held), "
+                f"a share of the experts is spelled {key} (held), "
                 "experts_published (the router's width) and "
-                "experts_held_range [lo, hi) of num_experts entries")
+                f"experts_held_range [lo, hi) of {key} entries")
         return n_experts, tuple(held) if held is not None else None
+
+    @classmethod
+    def _from_deepseek_v2(cls, config: dict) -> "Architecture":
+        """DeepSeek-V2 as its Lite model spells it: latent attention with
+        queries projected directly in every layer, RoPE on a part of each
+        head (under YaRN where ``rope_scaling`` says so), the first
+        ``first_k_dense_replace`` layers dense and every later one top-k
+        of ``n_routed_experts`` behind a softmax router beside
+        ``n_shared_experts`` shared ones (one feed-forward of their summed
+        width), the balance loss per sequence with ``seq_aux``."""
+        scaling = config.get("rope_scaling")
+        unbuilt = [why for bad, why in (
+            (config.get("q_lora_rank") is not None,
+             "low-rank queries (q_lora_rank)"),
+            (config.get("n_group", 1) > 1
+             or config.get("topk_method", "greedy") != "greedy",
+             "group-limited routing (n_group > 1 or a topk_method other "
+             "than greedy)"),
+            (config.get("scoring_func", "softmax") != "softmax",
+             "a scoring_func other than softmax"),
+            (config.get("moe_layer_freq", 1) != 1,
+             "moe_layer_freq other than 1"),
+            (scaling is not None and scaling.get("type") != "yarn",
+             "a rope_scaling type other than yarn"),
+            (config.get("hidden_act", "silu") != "silu"
+             or config.get("attention_bias"),
+             "another activation or attention biases"),
+            (config.get("num_key_value_heads")
+             not in (None, config["num_attention_heads"]),
+             "fewer key-value heads than heads (the latent is shared by "
+             "all of them already)"),
+        ) if bad]
+        if unbuilt:
+            raise ValueError("a deepseek_v2 config with "
+                             + "; ".join(unbuilt) + " is not built here")
+        n_experts, held = cls._experts_share(config, "n_routed_experts")
+        dense = int(config["first_k_dense_replace"])
+        yarn = None if scaling is None else Yarn(
+            factor=float(scaling["factor"]),
+            original_max_position=int(
+                scaling["original_max_position_embeddings"]),
+            beta_fast=float(scaling.get("beta_fast", 32)),
+            beta_slow=float(scaling.get("beta_slow", 1)),
+            mscale=float(scaling.get("mscale", 1)),
+            mscale_all_dim=float(scaling.get("mscale_all_dim", 0)))
+        return cls(
+            norm="rmsnorm", norm_eps=float(config["rms_norm_eps"]),
+            ffn="gated_silu", positions="rope",
+            rope_base=float(config["rope_theta"]), rope_scaling=yarn,
+            tied_head=bool(config["tie_word_embeddings"]),
+            n_experts=n_experts,
+            experts_per_token=int(config["num_experts_per_tok"]),
+            expert_width=int(config["moe_intermediate_size"]),
+            renormalise_gates=bool(config["norm_topk_prob"]),
+            routed_scaling=float(config.get("routed_scaling_factor", 1)),
+            experts_held=held,
+            shared_expert_width=int(config.get("n_shared_experts") or 0)
+            * int(config["moe_intermediate_size"]),
+            seq_aux=bool(config.get("seq_aux")),
+            layers=tuple(
+                ("latent_attention", "dense" if i < dense else "experts")
+                for i in range(int(config["num_hidden_layers"]))),
+            latent_rank=int(config["kv_lora_rank"]),
+            qk_nope_dim=int(config["qk_nope_head_dim"]),
+            qk_rope_dim=int(config["qk_rope_head_dim"]),
+            v_head_dim=int(config["v_head_dim"]),
+        )
 
     @classmethod
     def _from_sdar_moe(cls, config: dict) -> "Architecture":
@@ -407,6 +573,26 @@ MODEL_CONFIGS = {
         # default block length
         "block_length": 4,
     },
+    "deepseek-v2-lite": {
+        "model_type": "deepseek_v2", "num_hidden_layers": 27,
+        "hidden_size": 2048, "num_attention_heads": 16,
+        "num_key_value_heads": 16, "q_lora_rank": None,
+        "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64, "v_head_dim": 128,
+        "intermediate_size": 10944, "moe_intermediate_size": 1408,
+        "first_k_dense_replace": 1, "moe_layer_freq": 1,
+        "n_routed_experts": 64, "n_shared_experts": 2,
+        "num_experts_per_tok": 6, "norm_topk_prob": False,
+        "scoring_func": "softmax", "topk_method": "greedy", "n_group": 1,
+        "topk_group": 1, "routed_scaling_factor": 1, "seq_aux": True,
+        "hidden_act": "silu", "rms_norm_eps": 1e-6, "rope_theta": 10000,
+        "rope_scaling": {
+            "type": "yarn", "factor": 40,
+            "original_max_position_embeddings": 4096, "beta_fast": 32,
+            "beta_slow": 1, "mscale": 0.707, "mscale_all_dim": 0.707},
+        "attention_bias": False, "tie_word_embeddings": False,
+        "vocab_size": 102400, "max_position_embeddings": 163840,
+    },
 }
 
 
@@ -418,8 +604,11 @@ def _norm_layer(arch: Architecture, dtype, name=None):
                name=name)
 
 
-def apply_rope(x, positions, base: float = 10000.0):
-    """Rotary position embedding on ``[B, T, H, Dh]`` (half-split pairing).
+def apply_rope(x, positions, base: float = 10000.0,
+               scaling: Optional[Yarn] = None):
+    """Rotary position embedding on ``[B, T, H, Dh]`` (half-split pairing),
+    at ``base``'s own frequencies or, with ``scaling``, at YaRN's blend
+    of them with cos and sin times its ``rotation_scale``.
 
     ``positions``: ``[T]`` GLOBAL positions — sequence-parallel shards pass
     their own offsets, so rotations agree across shards (rotation commutes
@@ -428,14 +617,22 @@ def apply_rope(x, positions, base: float = 10000.0):
     engine's slot array, where every slot sits at a different depth.
     """
     half = x.shape[-1] // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        m = 1.0
+    else:
+        freqs, m = scaling.frequencies(2 * half, base), \
+            scaling.rotation_scale
     ang = positions.astype(jnp.float32)[..., None] * freqs  # [..., T, half]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if m != 1.0:
+        cos, sin = cos * m, sin * m
     if ang.ndim == 2:  # [T, half]: shared across the batch
-        cos = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
-        sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
+        cos = cos[None, :, None, :].astype(x.dtype)
+        sin = sin[None, :, None, :].astype(x.dtype)
     else:  # [B, T, half]: per-row slot positions
-        cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)
-        sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
+        cos = cos[:, :, None, :].astype(x.dtype)
+        sin = sin[:, :, None, :].astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                            axis=-1)
@@ -877,7 +1074,10 @@ class TransformerBlock(nn.Module):
         experts', and the rows of the absent ones, which lie behind the
         last group, come out of the grouped matmuls as zeros: written, not
         multiplied (their tiles are counted, ``tail_tiles``, and sown
-        beside the router's statistics)."""
+        beside the router's statistics). A shared expert
+        (``arch.shared_expert_width``) is one more gated feed-forward that
+        every token passes outside the routing, added unweighted and,
+        under a share, computed whole (:data:`train_path.MOE_SHARED`)."""
         from chainermn_tpu.ops.grouped_matmul import grouped_matmul, tail_tiles
         from chainermn_tpu.parallel import moe as _moe
 
@@ -913,6 +1113,11 @@ class TransformerBlock(nn.Module):
         for name, value in _moe.dropless_aux(
                 routing, arch.router_score == "softmax").items():
             self.sow(MOE_AUX, name, value)
+        if arch.seq_aux:
+            with jax.named_scope(train_path.MOE_ROUTE):
+                self.sow(MOE_AUX, "seq_aux", sequence_balance_loss(
+                    routing.logits.reshape(B, T, E),
+                    routing.experts.reshape(B, T, -1)))
         rows = _moe.dispatch(tokens, routing)
         self.sow(MOE_AUX, "tail_tiles", tail_tiles(
             routing.group_sizes, rows.shape[0]).astype(jnp.float32))
@@ -920,7 +1125,89 @@ class TransformerBlock(nn.Module):
         with jax.named_scope(train_path.MOE_EXPERTS):
             act = nn.silu(gate_up[:, :F]) * gate_up[:, F:]
         out = grouped_matmul(act, w_down, routing.group_sizes)
-        return _moe.combine(out, routing).reshape(B, T, D)
+        out = _moe.combine(out, routing).reshape(B, T, D)
+        if arch.shared_expert_width:
+            out = out + self._shared_expert(h, arch.shared_expert_width)
+        return out
+
+    def _shared_expert(self, h, width):
+        """``down(silu(gate(h)) * up(h))`` at ``width``, gate and up one
+        matrix (gate's columns first) as the routed experts' are."""
+        from chainermn_tpu.observability.metrics import registry
+
+        registry().gauge(
+            train_path.MOE_SHARED_WIDTH,
+            "width of the shared expert every token passes beside the "
+            "routed ones, at the last layer traced",
+        ).set(float(width))
+        with jax.named_scope(train_path.MOE_SHARED):
+            gate_up = nn.Dense(
+                2 * width, use_bias=False, dtype=self.compute_dtype,
+                param_dtype=jnp.float32, name="shared_gate_up")(h)
+            act = nn.silu(gate_up[..., :width]) * gate_up[..., width:]
+            return nn.Dense(
+                h.shape[-1], use_bias=False, dtype=self.compute_dtype,
+                param_dtype=jnp.float32, name="shared_down")(act)
+
+    def _latent_attention(self, h, arch, rope_positions, causal, **kw):
+        """DeepSeek-V2's latent attention, queries projected directly:
+        ``q = h W_q`` (a head ``[q_nope ; q_pe]``), ``[c ; k_pe] = h
+        W_kva``, ``[k_nope ; v] = RMSNorm(c) W_kvb`` a head, RoPE (YaRN's
+        frequencies where the description scales them) on ``q_pe`` and on
+        the one ``k_pe`` all heads share, which is broadcast to them and
+        concatenated behind ``k_nope``; causal softmax at ``(nope +
+        rope) ** -0.5`` times YaRN's ``softmax_scale`` with values of
+        their own width; the output projection. Everything between the
+        block's norm and the residual is under
+        :data:`train_path.MLA_ATTENTION`."""
+        from chainermn_tpu.observability.metrics import registry
+        from chainermn_tpu.ops.flash_attention import flash_attention
+
+        H, nope, rope = self.num_heads, arch.qk_nope_dim, arch.qk_rope_dim
+        dv, rank = arch.v_head_dim, arch.latent_rank
+        for name, value, what in (
+                (train_path.MLA_LATENT_RANK, rank,
+                 "values of the normed latent a token's keys and values "
+                 "are projected from"),
+                (train_path.MLA_QK_WIDTH, nope + rope,
+                 "width of a head's query and key (without position + "
+                 "rotated)"),
+                (train_path.MLA_V_WIDTH, dv, "width of a head's values")):
+            registry().gauge(
+                name, what + ", at the last latent attention traced",
+            ).set(float(value))
+        # the blockwise reference knows one head width
+        attn = self.attention_fn or flash_attention
+        B, T, D = h.shape
+        cd = self.compute_dtype
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=cd,
+                            param_dtype=jnp.float32, name=name)
+
+        with jax.named_scope(train_path.MLA_ATTENTION):
+            q = dense(H * (nope + rope), "q_proj")(h) \
+                .reshape(B, T, H, nope + rope)
+            kva = dense(rank + rope, "kv_a")(h)
+            c = _norm_layer(arch, cd, "kv_a_norm")(kva[..., :rank])
+            kv = dense(H * (nope + dv), "kv_b")(c) \
+                .reshape(B, T, H, nope + dv)
+            k_pe = kva[..., None, rank:]  # [B, T, 1, rope]
+            if rope_positions is not None:
+                q_pe = apply_rope(q[..., nope:], rope_positions,
+                                  arch.rope_base, arch.rope_scaling)
+                q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+                k_pe = apply_rope(k_pe, rope_positions, arch.rope_base,
+                                  arch.rope_scaling)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_pe, (B, T, H, rope))], axis=-1)
+            scale = (nope + rope) ** -0.5
+            if arch.rope_scaling is not None:
+                scale *= arch.rope_scaling.softmax_scale
+            o = attn(q, k, kv[..., nope:], causal=causal, scale=scale,
+                     **kw)
+            return dense(D, "proj")(o.reshape(B, T, H * dv))
 
     def _short_conv(self, h, arch):
         """LFM2's gated short convolution: ``(B, C, x) = split3(h W_in)``,
@@ -1089,6 +1376,20 @@ class TransformerBlock(nn.Module):
                     "slot), adapters, tensor parallelism, captured keys "
                     "or segment ids yet")
             o = self._short_conv(h, arch)
+        elif mixer == "latent_attention":
+            if decode or adapters is not None or self.sow_kv \
+                    or self.tp_axis is not None or self.window is not None \
+                    or self.head_dim is not None or \
+                    self.num_kv_heads not in (None, self.num_heads):
+                raise ValueError(
+                    "latent attention is the training path: no decode "
+                    "(the paged cache stores whole heads, not a latent), "
+                    "adapters, tensor parallelism, captured keys, window, "
+                    "head_dim override or fewer key-value heads yet")
+            o = self._latent_attention(
+                h, arch, rope_positions, self.causal,
+                **({} if segment_ids is None
+                   else {"segment_ids": segment_ids}))
         else:
             o = attention(h)
 
@@ -1211,6 +1512,13 @@ def refuse_unbuilt_decode(model, what: str):
             "over several denoising passes and the cache is written when "
             "a block is final; the training path "
             "(lm_loss_block_diffusion) is")
+    if arch.has_latent_attention:
+        raise NotImplementedError(
+            f"{what} of a model with latent_attention layers is not "
+            "built: its cache is a latent of "
+            f"{arch.latent_rank} + {arch.qk_rope_dim} values a token "
+            "that every head reads through its own up-projection, and "
+            "the paged cache stores whole heads; the training path is")
     if arch.has_short_conv:
         raise NotImplementedError(
             f"{what} of a model with short_conv layers is not built: it "
@@ -1237,6 +1545,7 @@ def _publish_stack_kinds(arch: Architecture, num_layers: int):
     kinds = [arch.layer(i) for i in range(num_layers)]
     for label, where, name in (("attention", 0, "attention"),
                                ("short_conv", 0, "short_conv"),
+                               ("latent_attention", 0, "latent_attention"),
                                ("dense_ffn", 1, "dense"),
                                ("expert_ffn", 1, "experts")):
         gauge.set(float(sum(k[where] == name for k in kinds)), kind=label)
@@ -1817,7 +2126,7 @@ def _lm_loss_fused(hidden, emb_table, tokens, n_chunks, compute_dtype,
 
 def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
                 load_balance_coef=0.01, z_loss_coef=0.001,
-                router_state=None):
+                seq_aux_coef=0.0, router_state=None):
     """Loss of a model whose description has experts (build it with
     ``return_hidden=True``): next-token cross-entropy through the fused
     head plus the router's two auxiliary losses, each the mean over the
@@ -1825,7 +2134,10 @@ def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
     loss and ``z_loss_coef`` x the router z-loss
     (:func:`chainermn_tpu.parallel.moe.dropless_aux`). Both are a softmax
     router's: a sigmoid router has neither, and its coefficients must be
-    0. ``router_state`` is the :data:`ROUTER_STATE` collection of a router
+    0. ``seq_aux_coef`` x the per-sequence balance loss
+    (:func:`sequence_balance_loss`, the mean over those layers) where the
+    description's ``seq_aux`` has the router sow it. ``router_state`` is
+    the :data:`ROUTER_STATE` collection of a router
     with a selection bias.
 
     Returns ``(loss, metrics)`` as :func:`~chainermn_tpu.training.
@@ -1834,6 +2146,7 @@ def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
     expert's rows over the mean, all layers together), ``moe/rows_held``
     (the rows whose expert this program holds, summed over the layers:
     all ``tokens * k`` a layer unless it holds a share),
+    ``moe/seq_aux`` (with the description's ``seq_aux``),
     ``moe/tail_tiles`` (the expert section's row tiles behind the last held
     group, summed over the layers, which the grouped matmuls write as
     zeros without multiplying: 0 where every expert is held and the rows
@@ -1849,11 +2162,27 @@ def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
     hidden, sown = model.apply(variables, tokens, mutable=[MOE_AUX])
     ce = lm_loss_fused(hidden, head_table(params, model.arch), tokens,
                        n_chunks=n_chunks, compute_dtype=model.compute_dtype)
-    return _with_router_aux(ce, model, sown, load_balance_coef, z_loss_coef)
+    return _with_router_aux(ce, model, sown, load_balance_coef, z_loss_coef,
+                            seq_aux_coef)
+
+
+def sequence_balance_loss(logits, experts):
+    """DeepSeek-V2's balance loss in its per-sequence form (``seq_aux``):
+    with ``p = softmax(logits [B, T, E])`` and ``experts [B, T, k]`` a
+    token's choice, ``f_{b,e} = E / (k T) x`` the tokens of sequence ``b``
+    that chose ``e``, ``P_{b,e}`` the mean of ``p_e`` over ``b``'s tokens,
+    and the loss ``mean_b sum_e f_{b,e} P_{b,e}``; differentiable through
+    ``P``, not through the choice."""
+    B, T, E = logits.shape
+    k = experts.shape[-1]
+    chose = jax.nn.one_hot(experts, E, dtype=jnp.float32).sum(2)
+    f = chose.sum(1) * (E / (k * T))
+    mean_p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).mean(1)
+    return (f * mean_p).sum(-1).mean()
 
 
 def _with_router_aux(loss, model: "TransformerLM", sown, load_balance_coef,
-                     z_loss_coef):
+                     z_loss_coef, seq_aux_coef=0.0):
     """``(loss + the router's auxiliary losses, metrics)`` from what the
     expert layers of one ``apply`` sowed into :data:`MOE_AUX`
     (:func:`lm_loss_moe` says which metrics)."""
@@ -1873,14 +2202,16 @@ def _with_router_aux(loss, model: "TransformerLM", sown, load_balance_coef,
         "moe/expert_load": load,
     }
     for name, coef in (("load_balance", load_balance_coef),
-                       ("z_loss", z_loss_coef)):
+                       ("z_loss", z_loss_coef),
+                       ("seq_aux", seq_aux_coef)):
         if name in layers[0]:
             metrics["moe/" + name] = over_layers(name, jnp.mean)
             loss = loss + coef * metrics["moe/" + name]
         elif coef:
             raise ValueError(
-                f"{name} is a softmax router's auxiliary loss; this "
-                f"model's router has none (coefficient {coef})")
+                f"{name} is an auxiliary loss this model's router does "
+                f"not sow (a softmax router's, or the description's "
+                f"seq_aux; coefficient {coef})")
     return loss, metrics
 
 

@@ -63,6 +63,15 @@ EXIT_GATE = "exit_gate"
 #: in-block calls and the merge by log-sum-exps)
 BD_NOISE = "bd_noise"
 BD_ATTENTION = "bd_attention"
+#: a latent-attention mixer whole, between the block's norm and the
+#: residual: the four projections (queries, the down-projection to the
+#: latent and the rope key, the up-projection to keys and values, the
+#: output), the latent's norm, RoPE, the rope key's broadcast to the heads
+#: and the three flash kernels, each under its own scope inside this one
+MLA_ATTENTION = "mla_attention"
+#: the shared expert beside the routed ones: its two matmuls (gate|up as
+#: one, down) and the SiLU gate
+MOE_SHARED = "moe_shared"
 
 #: how JAX marks the transposed (backward) and the recomputed code of a
 #: scope in ``op_name``
@@ -170,6 +179,14 @@ BD_ROWS_PER_STEP = "bd_rows_per_step"
 #: the diagonal tiles of one sequence and head its in-block call visits
 #: (``L / t``; the call has no tile off the diagonal)
 BD_IN_BLOCK_TILES = "bd_in_block_tiles"
+#: gauges set while a latent-attention mixer is traced: the latent's rank,
+#: a head's key width (without position + rotated) and value width (the
+#: kernels take a value width of their own)
+MLA_LATENT_RANK = "mla_latent_rank"
+MLA_QK_WIDTH = "mla_qk_width"
+MLA_V_WIDTH = "mla_v_width"
+#: gauge set while a shared expert is traced: its width
+MOE_SHARED_WIDTH = "moe_shared_width"
 #: gauge set while the fused LM head is traced: 1 where the trace made the
 #: head's gradient inside its forward loop (it was differentiated), 0
 #: where it made the loss alone (evaluation)

@@ -62,7 +62,13 @@ one head a grid step; heads that divide a lane tile (64: two) share one
 goes through the same kernels as ``[B * H, T, D]``, one head a "batch"
 row, and for those the wrapper
 transposes as it always did. The gauge ``flash_heads_per_block`` (label
-``kernel``) says which: 2, 1, or 0 for the transposed form. dq, dk, dv
+``kernel``) says which: 2, 1, or 0 for the transposed form. The values'
+heads may be narrower than the keys' (latent attention's 128 under keys
+of 128 + 64): ``PV``, the output, ``dO``, ``dv`` and ``delta`` then run
+at the values' width, ``QK^T``, ``dq`` and ``dk`` at the keys'; both
+whole lane tiles keep the projections' rows, one head a step, anything
+else the transposed form. At one width nothing of a kernel changes.
+dq, dk, dv
 leave the kernels rounded once, from the float32 scratch they are summed
 in, to their operand's dtype (the ring's partial gradients to float32;
 with GQA dk/dv are float32 a q head until the group sum).
@@ -505,21 +511,34 @@ class _Layout(NamedTuple):
     Hkv: int
     D: int
     heads: int
+    #: the values' head width, which is the output's and its cotangent's
+    #: (``value=True`` below); ``D`` unless the values have one of their
+    #: own
+    Dv: int
 
     @classmethod
-    def of(cls, q_shape, k_shape):
-        """The layout of a call with ``q`` and ``k`` of these BTHD
-        shapes."""
+    def of(cls, q_shape, k_shape, v_shape=None):
+        """The layout of a call with ``q``, ``k`` (and ``v``, where its
+        heads have a width of their own) of these BTHD shapes. Values
+        narrower than the keys (latent attention's 128 under keys of
+        128 + 64) keep the projections' rows where both widths are whole
+        lane tiles, and go through the transposed form otherwise; two
+        heads share a lane tile only at one width."""
         B, _, H, D = q_shape
         Hkv = k_shape[2]
-        if D % _LANES == 0:
+        Dv = D if v_shape is None else v_shape[3]
+        if Dv > D:
+            raise ValueError(
+                f"values wider than the keys ({Dv} > {D}) are not built: "
+                "the kernels' tiles are chosen from the keys' row")
+        if D % _LANES == 0 and Dv % _LANES == 0:
             heads = 1
-        elif _group(H, Hkv) == 1 and _LANES % D == 0 \
+        elif Dv == D and _group(H, Hkv) == 1 and _LANES % D == 0 \
                 and (H * D) % _LANES == 0:
             heads = _LANES // D
         else:
             heads = 0
-        return cls(B, H, Hkv, D, heads)
+        return cls(B, H, Hkv, D, heads, Dv)
 
     @property
     def step_heads(self) -> int:
@@ -528,8 +547,13 @@ class _Layout(NamedTuple):
 
     @property
     def width(self) -> int:
-        """Lanes of a step's block of q, k, v, dO or a result."""
+        """Lanes of a step's block of q, k, dq or dk."""
         return self.step_heads * self.D
+
+    @property
+    def v_width(self) -> int:
+        """Lanes of a step's block of v, the output, dO or dv."""
+        return self.step_heads * self.Dv
 
     @property
     def group(self) -> int:
@@ -542,27 +566,34 @@ class _Layout(NamedTuple):
             return x.reshape(B, T, h * D)
         return x.transpose(0, 2, 1, 3).reshape(B * h, T, D)
 
-    def leave(self, x):
-        """A kernel's ``q``-, ``k``- or ``v``-shaped array as
+    def leave(self, x, value=False):
+        """A kernel's ``q``-, ``k``- or (``value``) ``v``-shaped array as
         ``[B, T, h, D]``."""
         if self.heads:
-            return x.reshape(*x.shape[:2], x.shape[2] // self.D, self.D)
+            D = self.Dv if value else self.D
+            return x.reshape(*x.shape[:2], x.shape[2] // D, D)
         return x.reshape(self.B, x.shape[0] // self.B, *x.shape[1:]) \
             .transpose(0, 2, 1, 3)
 
-    def spec(self, rows, row_of, shared=False):
+    def spec(self, rows, row_of, shared=False, value=False):
         """BlockSpec of ``rows`` rows (block ``row_of(i, j)`` of the
         grid's last two ids) of a step's heads; ``shared``: of K or V,
-        whose head ``h // g`` serves q head ``h``."""
+        whose head ``h // g`` serves q head ``h``; ``value``: at the
+        values' width (V, the output, dO, dv)."""
         g = self.group if shared else 1
         if self.heads:
             return pl.BlockSpec(
-                (1, rows, self.width),
+                (1, rows, self.v_width if value else self.width),
                 lambda b, h, i, j: (b, row_of(i, j), h // g))
         n = self.H // g
         return pl.BlockSpec(
-            (1, rows, self.D),
+            (1, rows, self.Dv if value else self.D),
             lambda b, h, i, j: (b * n + h // g, row_of(i, j), 0))
+
+    def value_shape(self, q_shape):
+        """The shape of the output (and dO) beside ``q`` of ``q_shape``
+        as the kernels take it."""
+        return (*q_shape[:2], q_shape[2] // self.D * self.Dv)
 
     def row_spec(self, block_q, row_of):
         """BlockSpec of a step's rows of statistics in ``[B, H, 1, Tq]``:
@@ -570,14 +601,16 @@ class _Layout(NamedTuple):
         return pl.BlockSpec((1, self.step_heads, 1, block_q),
                             lambda b, h, i, j: (b, h, 0, row_of(i, j)))
 
-    def group_sum(self, x):
-        """Per-q-head dk or dv summed over each KV head's group."""
+    def group_sum(self, x, value=False):
+        """Per-q-head dk or (``value``) dv summed over each KV head's
+        group."""
         g, T = self.group, x.shape[1]
+        D = self.Dv if value else self.D
         if self.heads:
-            return x.reshape(self.B, T, self.Hkv, g, self.D).sum(axis=3) \
-                .reshape(self.B, T, self.Hkv * self.D)
-        return x.reshape(self.B, self.Hkv, g, T, self.D).sum(axis=2) \
-            .reshape(self.B * self.Hkv, T, self.D)
+            return x.reshape(self.B, T, self.Hkv, g, D).sum(axis=3) \
+                .reshape(self.B, T, self.Hkv * D)
+        return x.reshape(self.B, self.Hkv, g, T, D).sum(axis=2) \
+            .reshape(self.B * self.Hkv, T, D)
 
 
 def _head_lanes(head: int, D: int):
@@ -672,10 +705,10 @@ def _walk(iq, ik, block_q, block_k, sub, causal, window, q_offset,
 
 
 def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
-              acc_ref, m_ref, l_ref, *, heads: int, D: int, scale: float,
-              causal: bool, block_q: int, block_k: int, sub: int,
-              num_k_blocks: int, window=None, band_lo=None, nk_total=None,
-              q_offset: int = 0, blocks=None):
+              acc_ref, m_ref, l_ref, *, heads: int, D: int, Dv: int,
+              scale: float, causal: bool, block_q: int, block_k: int,
+              sub: int, num_k_blocks: int, window=None, band_lo=None,
+              nk_total=None, q_offset: int = 0, blocks=None):
     iq = pl.program_id(2)
     j = pl.program_id(3)
     # Banded grid: slot j covers TRUE k block band_lo(iq) + j; slots
@@ -686,7 +719,7 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
     single = num_k_blocks == 1
 
     def _emit(p, acc, m, l):
-        o_ref[0, :, _head_lanes(p, D)] = jnp.where(
+        o_ref[0, :, _head_lanes(p, Dv)] = jnp.where(
             l > 0, acc / jnp.maximum(l, 1e-37), 0.0
         ).astype(o_ref.dtype)
         # LSE in the scaled-score domain; fully-masked rows stay NEG_INF.
@@ -710,7 +743,7 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
 
     def _accumulate(pieces):
         def head(p):
-            lanes = _head_lanes(p, D)
+            lanes, v_lanes = _head_lanes(p, D), _head_lanes(p, Dv)
             q = q_ref[0, :, lanes]
             scores = [
                 _scores(q, k_ref[0, a:b, lanes], bias_ref, seg_refs, masked,
@@ -732,7 +765,7 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
             for s, (a, b, _) in zip(scores, pieces):
                 pr = jnp.exp(s - m_sub)
                 l_new += jnp.sum(pr, axis=1, keepdims=True)
-                v = v_ref[0, a:b, lanes]
+                v = v_ref[0, a:b, v_lanes]
                 acc += jax.lax.dot_general(
                     pr.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
@@ -741,7 +774,7 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
                 _emit(p, acc, m_new, l_new)
                 return
             corr = jnp.exp(m_prev - m_new)  # [block_q, 1]
-            acc_ref[:, lanes] = acc_ref[:, lanes] * corr + acc
+            acc_ref[:, v_lanes] = acc_ref[:, v_lanes] * corr + acc
             m_ref[p] = jnp.broadcast_to(m_new, m_ref.shape[1:])
             l_ref[p] = jnp.broadcast_to(l_ref[p, :, 0:1] * corr + l_new,
                                         l_ref.shape[1:])
@@ -761,7 +794,7 @@ def _fwd_body(q_ref, k_ref, v_ref, seg_refs, bias_ref, o_ref, lse_ref,
     @pl.when(j == num_k_blocks - 1)
     def _finalize():
         _each_head(heads, lambda p: _emit(
-            p, acc_ref[:, _head_lanes(p, D)], m_ref[p, :, 0:1],
+            p, acc_ref[:, _head_lanes(p, Dv)], m_ref[p, :, 0:1],
             l_ref[p, :, 0:1]))
 
 
@@ -840,13 +873,15 @@ def _flash_fwd(lay, q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
     k_block = _k_slot(band_lo, nk, block_q, block_k, causal, q_offset,
                       blocks)
 
-    params = dict(heads=heads, D=lay.D, scale=scale, causal=causal,
-                  block_q=block_q, block_k=block_k, sub=sub,
+    params = dict(heads=heads, D=lay.D, Dv=lay.Dv, scale=scale,
+                  causal=causal, block_q=block_q, block_k=block_k, sub=sub,
                   num_k_blocks=grid_k, window=window, band_lo=band_lo,
                   nk_total=nk, q_offset=q_offset, blocks=blocks)
     q_spec = lay.spec(block_q, lambda iq, j: iq)
-    kv_spec = lay.spec(block_k, k_block, shared=True)
-    in_specs = [q_spec, kv_spec, kv_spec]
+    o_spec = lay.spec(block_q, lambda iq, j: iq, value=True)
+    k_spec = lay.spec(block_k, k_block, shared=True)
+    v_spec = lay.spec(block_k, k_block, shared=True, value=True)
+    in_specs = [q_spec, k_spec, v_spec]
     args = (q, k, v)
     if has_segments:
         in_specs += [
@@ -876,13 +911,13 @@ def _flash_fwd(lay, q, k, v, seg_q=None, seg_k=None, bias=None, *, causal,
             grid=(lay.B, lay.H // heads, nq, grid_k),
             compiler_params=_GRID_SEMANTICS,
             in_specs=in_specs,
-            out_specs=[q_spec, lay.row_spec(block_q, lambda iq, j: iq)],
+            out_specs=[o_spec, lay.row_spec(block_q, lambda iq, j: iq)],
             out_shape=[
-                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct(lay.value_shape(q.shape), q.dtype),
                 jax.ShapeDtypeStruct((lay.B, lay.H, 1, Tq), jnp.float32),
             ],
             scratch_shapes=[] if grid_k == 1 else [
-                pltpu.VMEM((block_q, lay.width), jnp.float32),        # acc
+                pltpu.VMEM((block_q, lay.v_width), jnp.float32),      # acc
                 pltpu.VMEM((heads, block_q, _LANES), jnp.float32),    # m
                 pltpu.VMEM((heads, block_q, _LANES), jnp.float32),    # l
             ],
@@ -921,7 +956,7 @@ def _delta(do, o):
 
 
 def _bwd_dq_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
-                 bias_ref, dq_ref, dq_acc, *, heads: int, D: int,
+                 bias_ref, dq_ref, dq_acc, *, heads: int, D: int, Dv: int,
                  scale: float, causal: bool, block_q: int, block_k: int,
                  sub: int, num_k_blocks: int, window=None, band_lo=None,
                  nk_total=None, q_offset: int = 0, blocks=None):
@@ -942,11 +977,11 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
 
     def _accumulate(pieces):
         def head(p):
-            lanes = _head_lanes(p, D)
+            lanes, v_lanes = _head_lanes(p, D), _head_lanes(p, Dv)
             q = q_ref[0, :, lanes]
-            do = do_ref[0, :, lanes].astype(jnp.float32)
+            do = do_ref[0, :, v_lanes].astype(jnp.float32)
             lse = _column(lse_ref, p, blocks)
-            delta = _delta(do, o_ref[0, :, lanes])
+            delta = _delta(do, o_ref[0, :, v_lanes])
             dq = 0.0
             for a, b, masked in pieces:
                 k = k_ref[0, a:b, lanes]
@@ -958,7 +993,7 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
                 # nothing.
                 pr = jnp.exp(s - lse)
                 dp = jax.lax.dot_general(
-                    do, v_ref[0, a:b, lanes], (((1,), (1,)), ((), ())),
+                    do, v_ref[0, a:b, v_lanes], (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )  # [block_q, b - a]
                 ds = pr * (dp - delta) * scale
@@ -993,7 +1028,7 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
 
 def _bwd_dkv_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
                   bias_ref, dk_ref, dv_ref, dbias_ref, dk_acc, dv_acc, *,
-                  heads: int, D: int, scale: float, causal: bool,
+                  heads: int, D: int, Dv: int, scale: float, causal: bool,
                   block_q: int, block_k: int, num_q_blocks: int, window=None,
                   band_lo=None, nq_total=None, q_offset: int = 0,
                   blocks=None):
@@ -1026,11 +1061,11 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
         (_, _, masked), = pieces  # the whole tile, masked or not
 
         def head(p):
-            lanes = _head_lanes(p, D)
+            lanes, v_lanes = _head_lanes(p, D), _head_lanes(p, Dv)
             q = q_ref[0, :, lanes]
             k = k_ref[0, :, lanes]
-            v = v_ref[0, :, lanes]
-            do = do_ref[0, :, lanes].astype(jnp.float32)
+            v = v_ref[0, :, v_lanes]
+            do = do_ref[0, :, v_lanes].astype(jnp.float32)
             s = _scores(q, k, bias_ref, seg_refs, masked, q0, k0,
                         slice(None), scale=scale, window=window, head=p,
                         blocks=blocks)
@@ -1045,7 +1080,7 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
                 preferred_element_type=jnp.float32,
             )
             # d loss / d s_total
-            ds_unscaled = pr * (dp - _delta(do, o_ref[0, :, lanes]))
+            ds_unscaled = pr * (dp - _delta(do, o_ref[0, :, v_lanes]))
             if dbias_ref is not None:
                 # dbias tile == ds before the qk-scale factor (the bias
                 # adds AFTER the scale multiplies q·k).
@@ -1058,10 +1093,10 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, seg_refs,
             )
             if single:
                 dk_ref[0, :, lanes] = dk.astype(dk_ref.dtype)
-                dv_ref[0, :, lanes] = dv.astype(dv_ref.dtype)
+                dv_ref[0, :, v_lanes] = dv.astype(dv_ref.dtype)
             else:
                 dk_acc[:, lanes] += dk
-                dv_acc[:, lanes] += dv
+                dv_acc[:, v_lanes] += dv
 
         _each_head(heads, head, looped=True)
 
@@ -1130,14 +1165,17 @@ def _flash_bwd(lay, q, k, v, out, do, lse, seg_q=None, seg_k=None,
             band_lo, grid_k = lo_k, span_k
     k_block = _k_slot(band_lo, nk, block_q, block_k, causal, q_offset,
                       blocks)
-    dq_params = dict(heads=heads, D=lay.D, scale=scale, causal=causal,
+    dq_params = dict(heads=heads, D=lay.D, Dv=lay.Dv, scale=scale,
+                     causal=causal,
                      block_q=block_q, block_k=block_k, sub=sub,
                      window=window, q_offset=q_offset, num_k_blocks=grid_k,
                      band_lo=band_lo, nk_total=nk, blocks=blocks)
     q_spec = lay.spec(block_q, lambda i, j: i)
+    o_spec = lay.spec(block_q, lambda i, j: i, value=True)
     row_spec = lay.row_spec(block_q, lambda i, j: i)
-    kv_spec = lay.spec(block_k, k_block, shared=True)
-    dq_in_specs = [q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec]
+    k_spec = lay.spec(block_k, k_block, shared=True)
+    v_spec = lay.spec(block_k, k_block, shared=True, value=True)
+    dq_in_specs = [q_spec, k_spec, v_spec, o_spec, o_spec, row_spec]
     if has_segments:
         dq_in_specs += [
             pl.BlockSpec((1, block_q, 1), lambda b, h, i, j: (b, i, 0)),
@@ -1187,15 +1225,17 @@ def _flash_bwd(lay, q, k, v, out, do, lse, seg_q=None, seg_k=None,
             band_lo, grid_q = lo_q, span_q
     q_block = _q_slot(band_lo, nq, block_q, block_k,
                       causal and not want_dbias, q_offset, blocks)
-    dkv_params = dict(heads=heads, D=lay.D, scale=scale, causal=causal,
+    dkv_params = dict(heads=heads, D=lay.D, Dv=lay.Dv, scale=scale,
+                      causal=causal,
                       block_q=block_q, block_k=block_k, window=window,
                       q_offset=q_offset, num_q_blocks=grid_q,
                       band_lo=band_lo, nq_total=nq, blocks=blocks)
-    k_spec_in = lay.spec(block_k, lambda i, j: i, shared=True)
-    k_spec_out = lay.spec(block_k, lambda i, j: i)
     q_spec_in = lay.spec(block_q, q_block)
+    o_spec_in = lay.spec(block_q, q_block, value=True)
     row_spec_in = lay.row_spec(block_q, q_block)
-    dkv_in_specs = [q_spec_in, k_spec_in, k_spec_in, q_spec_in, q_spec_in,
+    k_spec_in = lay.spec(block_k, lambda i, j: i, shared=True)
+    v_spec_in = lay.spec(block_k, lambda i, j: i, shared=True, value=True)
+    dkv_in_specs = [q_spec_in, k_spec_in, v_spec_in, o_spec_in, o_spec_in,
                     row_spec_in]
     if has_segments:
         dkv_in_specs += [
@@ -1211,9 +1251,11 @@ def _flash_bwd(lay, q, k, v, out, do, lse, seg_q=None, seg_k=None,
 
     # a q head each: k's rows at q's width (the same array without GQA)
     kv_dtype = jnp.float32 if lay.group > 1 else grad_dtype or k.dtype
-    dkv_shape = jax.ShapeDtypeStruct((q.shape[0], Tk, q.shape[2]), kv_dtype)
-    out_specs = [k_spec_out, k_spec_out]
-    out_shape = [dkv_shape, dkv_shape]
+    dk_shape = (q.shape[0], Tk, q.shape[2])
+    out_specs = [lay.spec(block_k, lambda i, j: i),
+                 lay.spec(block_k, lambda i, j: i, value=True)]
+    out_shape = [jax.ShapeDtypeStruct(dk_shape, kv_dtype),
+                 jax.ShapeDtypeStruct(lay.value_shape(dk_shape), kv_dtype)]
     if want_dbias:
         out_specs.append(
             pl.BlockSpec((1, heads, block_q, block_k),
@@ -1243,7 +1285,7 @@ def _flash_bwd(lay, q, k, v, out, do, lse, seg_q=None, seg_k=None,
             out_shape=out_shape,
             scratch_shapes=[] if grid_q == 1 else [
                 pltpu.VMEM((block_k, lay.width), jnp.float32),
-                pltpu.VMEM((block_k, lay.width), jnp.float32),
+                pltpu.VMEM((block_k, lay.v_width), jnp.float32),
             ],
             interpret=interpret,
         )(q, k, v, out, do, lse, *seg_args, *bias_args)
@@ -1254,7 +1296,7 @@ def _flash_bwd(lay, q, k, v, out, do, lse, seg_q=None, seg_k=None,
         dbias = None
     if lay.group > 1:
         dk = lay.group_sum(dk).astype(grad_dtype or k.dtype)
-        dv = lay.group_sum(dv).astype(grad_dtype or v.dtype)
+        dv = lay.group_sum(dv, value=True).astype(grad_dtype or v.dtype)
     if want_dbias:
         # Reduce to the bias's broadcast shape.
         if bias.shape[1] == 1:
@@ -1314,7 +1356,7 @@ def _flash_core(q, k, v, seg, bias, has_seg, has_bias, bias_grad, causal,
 def _flash_core_fwd(q, k, v, seg, bias, has_seg, has_bias, bias_grad,
                     causal, scale, block_q, block_k, interpret, window,
                     blocks=None):
-    lay = _Layout.of(q.shape, k.shape)
+    lay = _Layout.of(q.shape, k.shape, v.shape)
     out, lse = _flash_fwd(
         lay, lay.enter(q), lay.enter(k), lay.enter(v),
         seg if has_seg else None, seg if has_seg else None,
@@ -1331,13 +1373,13 @@ def _flash_core_fwd(q, k, v, seg, bias, has_seg, has_bias, bias_grad,
     # read both as they are.
     out = checkpoint_name(out, train_path.FLASH_OUT)
     lse = checkpoint_name(lse, train_path.FLASH_LSE)
-    return lay.leave(out), (q, k, v, seg, bias, out, lse)
+    return lay.leave(out, value=True), (q, k, v, seg, bias, out, lse)
 
 
 def _flash_core_bwd(has_seg, has_bias, bias_grad, causal, scale, block_q,
                     block_k, interpret, window, blocks, res, g):
     q, k, v, seg, bias, out, lse = res
-    lay = _Layout.of(q.shape, k.shape)
+    lay = _Layout.of(q.shape, k.shape, v.shape)
     res_bwd = _flash_bwd(
         lay, lay.enter(q), lay.enter(k), lay.enter(v), out, lay.enter(g),
         lse, seg if has_seg else None, seg if has_seg else None,
@@ -1352,8 +1394,9 @@ def _flash_core_bwd(has_seg, has_bias, bias_grad, causal, scale, block_q,
         # callers training a bias must pass bias_grad=True.
         dbias = jnp.zeros_like(bias)
     # dq, dk, dv: rounded to their operand's dtype by the kernels
+    dq, dk, dv = res_bwd[:3]
     return (
-        *(lay.leave(x) for x in res_bwd[:3]),
+        lay.leave(dq), lay.leave(dk), lay.leave(dv, value=True),
         None,  # integer segment ids carry no gradient
         dbias,
     )
@@ -1393,7 +1436,10 @@ def flash_attention(
 
     ``k``/``v`` may carry fewer heads than ``q`` (GQA/MQA — q heads must be
     a multiple of kv heads; kv blocks are shared via the kernel's index map,
-    never materialized per-group). ``segment_ids`` is an optional ``[B, T]``
+    never materialized per-group). ``v``'s heads may be narrower than
+    ``q``'s and ``k``'s (``[B, T, Hkv, Dv]``, ``Dv <= D``): the output and
+    ``dv`` are ``Dv`` wide, and a default ``scale`` is still ``D ** -0.5``.
+    ``segment_ids`` is an optional ``[B, T]``
     int array for packed sequences: attention is confined to positions with
     equal ids (composes with ``causal``).
 
@@ -1481,8 +1527,8 @@ def flash_attention(
     # Here and not in the kernels' builders, which `_flash_call` traces
     # once a shape: the backward's entries are what this call's gradient
     # runs, whether or not one is taken.
-    _publish_tiles(_WALKS, _Layout.of(q.shape, k.shape), q.shape[1],
-                   k.shape[1], causal=causal, window=window,
+    _publish_tiles(_WALKS, _Layout.of(q.shape, k.shape, v.shape),
+                   q.shape[1], k.shape[1], causal=causal, window=window,
                    bare=not (has_seg or has_bias), has_bias=has_bias,
                    row_bytes=q.shape[3] * q.dtype.itemsize,
                    block_q=block_q, block_k=block_k, blocks=blocks)
@@ -1519,7 +1565,7 @@ def flash_block_fwd(q, k_blk, v_blk, *, causal, scale, block_q, block_k,
     the ring merges its steps')."""
     blocks = _block_entry_mask(causal, window, q_offset, causal_block,
                                causal_strict)
-    lay = _Layout.of(q.shape, k_blk.shape)
+    lay = _Layout.of(q.shape, k_blk.shape, v_blk.shape)
     _publish_tiles((train_path.FLASH_FWD,), lay, q.shape[1], k_blk.shape[1],
                    causal=causal, window=window, q_offset=q_offset,
                    block_q=block_q, block_k=block_k, blocks=blocks)
@@ -1529,7 +1575,7 @@ def flash_block_fwd(q, k_blk, v_blk, *, causal, scale, block_q, block_k,
         scale=scale, block_q=block_q, block_k=block_k, interpret=interpret,
         blocks=blocks,
     )
-    return lay.leave(out), lse[:, :, 0]
+    return lay.leave(out, value=True), lse[:, :, 0]
 
 
 def flash_block_bwd(q, k_blk, v_blk, do, lse, out, *, causal, scale,
@@ -1543,17 +1589,17 @@ def flash_block_bwd(q, k_blk, v_blk, do, lse, out, *, causal, scale,
     ``out`` (BTHD) are the ring's merged log-sum-exp and output."""
     blocks = _block_entry_mask(causal, window, q_offset, causal_block,
                                causal_strict)
-    lay = _Layout.of(q.shape, k_blk.shape)
+    lay = _Layout.of(q.shape, k_blk.shape, v_blk.shape)
     _publish_tiles((train_path.FLASH_BWD_DQ, train_path.FLASH_BWD_DKV), lay,
                    q.shape[1], k_blk.shape[1], causal=causal, window=window,
                    q_offset=q_offset, bare=seg_q is None,
                    row_bytes=q.shape[3] * q.dtype.itemsize,
                    block_q=block_q, block_k=block_k, blocks=blocks)
-    grads = _flash_bwd(
+    dq, dk, dv = _flash_bwd(
         lay, lay.enter(q), lay.enter(k_blk), lay.enter(v_blk),
         lay.enter(out), lay.enter(do), lse[:, :, None], seg_q, seg_kv,
         causal=causal, scale=scale, window=window, q_offset=q_offset,
         block_q=block_q, block_k=block_k, interpret=interpret,
         grad_dtype=grad_dtype, blocks=blocks,
     )
-    return tuple(lay.leave(x) for x in grads)
+    return lay.leave(dq), lay.leave(dk), lay.leave(dv, value=True)

@@ -337,9 +337,13 @@ def run_named_model(args, comm, compute_dtype, rng):
                 model, params, tokens, key, mask_id=model.vocab_size - 1)
             return loss, (metrics, noise_state)
     elif model.expert_layers:
-        # a sigmoid router has no auxiliary losses
+        # a sigmoid router has no auxiliary losses; DeepSeek-V2's is the
+        # balance loss a sequence at a time, alone
         coefs = {} if model.arch.router_score == "softmax" else dict(
             load_balance_coef=0.0, z_loss_coef=0.0)
+        if model.arch.seq_aux:
+            coefs = dict(load_balance_coef=0.0, z_loss_coef=0.0,
+                         seq_aux_coef=0.001)
 
         def loss_fn(params, tokens, router_state=()):
             loss, metrics = lm_loss_moe(

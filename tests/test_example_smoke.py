@@ -107,14 +107,15 @@ def test_mnist_example_local_sgd_smoke():
 
 
 @pytest.mark.parametrize("model", ["olmoe-tiny", "gpt2-tiny", "lfm2-tiny",
-                                   "sdar-tiny"])
+                                   "sdar-tiny", "dsv2-tiny"])
 def test_transformer_example_named_model_smoke(model, monkeypatch, capsys):
     """``--model <name> --layers N`` builds a published architecture
     through the model description (ISSUE 25); here three tiny stand-ins
     under the published ones' ``model_type``s (LFM2's first three layers:
     two short convolutions and an attention layer, the third with
     experts; SDAR's trained by block diffusion, its noise drawn inside
-    the step)."""
+    the step; DeepSeek-V2's first two: latent attention under a dense
+    layer, then under routed experts beside the shared one)."""
     ex = _load_example("transformer", "train_transformer_lm.py")
     monkeypatch.setitem(ex.MODEL_CONFIGS, "olmoe-tiny", dict(
         ex.MODEL_CONFIGS["olmoe-1b-7b"], num_hidden_layers=4,
@@ -134,12 +135,20 @@ def test_transformer_example_named_model_smoke(model, monkeypatch, capsys):
         num_attention_heads=2, num_key_value_heads=1, head_dim=16,
         moe_intermediate_size=16, num_experts=4, num_experts_per_tok=2,
         vocab_size=1025, max_position_embeddings=64))
-    layers = 3 if model == "lfm2-tiny" else 1
+    monkeypatch.setitem(ex.MODEL_CONFIGS, "dsv2-tiny", dict(
+        ex.MODEL_CONFIGS["deepseek-v2-lite"], hidden_size=32,
+        num_attention_heads=2, num_key_value_heads=2, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        intermediate_size=48, moe_intermediate_size=16, n_routed_experts=4,
+        num_experts_per_tok=2, vocab_size=1024,
+        max_position_embeddings=64))
+    layers = {"lfm2-tiny": 3, "dsv2-tiny": 2}.get(model, 1)
     ex.main(["--iterations", "10", "--batchsize", "8", "--seq-len", "32",
              "--model", model, "--layers", str(layers)])
     out = capsys.readouterr().out
     assert f"done ({model}, {layers} layers)" in out
-    assert ("load_balance=" in out) == (model in ("olmoe-tiny",
-                                                  "sdar-tiny"))
+    assert ("load_balance=" in out) == (model in (
+        "olmoe-tiny", "sdar-tiny", "dsv2-tiny"))  # a softmax router's
+    assert ("seq_aux=" in out) == (model == "dsv2-tiny")
     assert ("masked_share=" in out) == (model == "sdar-tiny")
     assert ("rows_held=" in out) == (model != "gpt2-tiny")

@@ -261,7 +261,8 @@ def test_the_comparison_catches(name, tiny, ref, monkeypatch):
 
 # -- the share ---------------------------------------------------------------
 
-def _expert_layer(held, params, state, x, whole_config, positions=None):
+def _expert_layer(held, params, state, x, whole_config, positions=None,
+                  num_kv_heads=2):
     """One attention-and-experts block holding experts ``held`` of the
     whole layer's, applied to ``x`` (``state``: the router's selection
     bias, where the router has one)."""
@@ -269,8 +270,9 @@ def _expert_layer(held, params, state, x, whole_config, positions=None):
     arch = dataclasses.replace(
         Architecture.from_config(whole_config), experts_held=(lo, hi))
     block = TransformerBlock(
-        num_heads=4, num_kv_heads=2, d_ff=96, compute_dtype=jnp.float32,
-        attention_fn=_attn, arch=arch, layer_index=0)
+        num_heads=4, num_kv_heads=num_kv_heads, d_ff=96,
+        compute_dtype=jnp.float32, attention_fn=_attn, arch=arch,
+        layer_index=0)
     share = {**params, "moe_w_gate_up": params["moe_w_gate_up"][lo:hi],
              "moe_w_down": params["moe_w_down"][lo:hi]}
     variables = {"params": share}
@@ -328,19 +330,61 @@ def _sdar_share_case(_ref):
         experts=lambda h, p_, c: ref.experts(h, p_, c)[0])
 
 
+def _dsv2_share_case(_ref):
+    """DeepSeek-V2's layer: eight shares of 2 of 16 routed experts,
+    softmax top-3 not renormalised, under latent attention, beside a
+    shared expert that every share computes whole and the sum counts
+    once."""
+    ref = _load("benchmark/reference/latent_moe_lm.py",
+                "reference_latent_moe_lm")
+    config = dict(
+        MODEL_CONFIGS["deepseek-v2-lite"], num_hidden_layers=1,
+        first_k_dense_replace=0, hidden_size=64, num_attention_heads=4,
+        num_key_value_heads=4, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, intermediate_size=96,
+        moe_intermediate_size=16, n_routed_experts=16,
+        num_experts_per_tok=3, vocab_size=128, max_position_embeddings=64)
+    model = lm_from_config(config, compute_dtype=jnp.float32,
+                           return_hidden=True, attention_fn=_attn)
+    p = jax.jit(model.init)(jax.random.key(1), jnp.zeros((1, T), jnp.int32))[
+        "params"]["block_0"]
+    # a router far from uniform, so that the three chosen differ by token
+    p = {**p, "moe_router": p["moe_router"] * 20}
+    x = jax.random.normal(jax.random.key(4), (2, T, 64))
+    eps = config["rms_norm_eps"]
+
+    def shared(h):
+        return ref.gated(h, p["shared_gate_up"]["kernel"],
+                         p["shared_down"]["kernel"])
+
+    return dict(
+        config=config, p=p, state=None, x=x, positions=None,
+        shares=[(2 * i, 2 * i + 2) for i in range(8)],
+        experts_key="n_routed_experts", block=dict(num_kv_heads=4),
+        alike=shared,
+        mixed=lambda h: ref.attention(h, p, config),
+        norm=lambda y, name: ref.rms_norm(y, p[name], eps),
+        experts=lambda h, p_, c: ref.experts(
+            h.reshape(2, T, 64), p_, c)[0].reshape(-1, 64))
+
+
 SHARE_CASES = {"lfm2_four_shares_of_2": _lfm2_share_case,
-               "sdar_eight_shares_of_16": _sdar_share_case}
+               "sdar_eight_shares_of_16": _sdar_share_case,
+               "dsv2_eight_shares_of_2_and_a_shared_expert":
+               _dsv2_share_case}
 
 
 @pytest.mark.parametrize("side", ["system", "reference"])
 @pytest.mark.parametrize("family", sorted(SHARE_CASES))
 def test_the_four_shares_add_up_to_the_uncut_layer(family, side, ref):
     """The expert outputs of the shares, summed, are the uncut
-    reference's for the whole layer (no shared expert here, so nothing is
-    counted once): ``sum_s (y_s - r) = y - r`` with ``r`` the residual
-    stream after the mixer, which every chip computes alike. LFM2's four
-    shares of 2 experts, and SDAR's eight shares of 16 under its mask by
-    blocks."""
+    reference's for the whole layer: ``sum_s (y_s - r) = y - r`` with
+    ``r`` the residual stream after the mixer, which every chip computes
+    alike. LFM2's four shares of 2 experts, SDAR's eight shares of 16
+    under its mask by blocks (no shared expert in either, so nothing is
+    counted once), and DeepSeek-V2's eight shares of 2 under latent
+    attention, whose shared expert every share computes whole (``alike``)
+    and the sum counts once."""
     case = SHARE_CASES[family](ref)
     config, p, x = case["config"], case["p"], case["x"]
 
@@ -353,20 +397,27 @@ def test_the_four_shares_add_up_to_the_uncut_layer(family, side, ref):
     shares = case["shares"]
     if side == "system":
         parts = [_expert_layer(held, p, case["state"], x, config,
-                               case["positions"]) - r
-                 for held in shares]
+                               case["positions"], **case.get("block", {}))
+                 - r for held in shares]
     else:
         parts = [_highest(
             case["experts"], h,
             {**p, "moe_w_gate_up": p["moe_w_gate_up"][lo:hi],
              "moe_w_down": p["moe_w_down"][lo:hi]},
-            {**config, "num_experts": hi - lo,
+            {**config, case.get("experts_key", "num_experts"): hi - lo,
              "experts_published": shares[-1][1],
              "experts_held_range": [lo, hi]}).reshape(x.shape)
             for lo, hi in shares]
     assert all(float(jnp.linalg.norm(part)) > 0.05 * float(
         jnp.linalg.norm(uncut)) for part in parts)
-    assert _rel(sum(parts).reshape(-1, 64), uncut) < 1e-5
+    total = sum(parts).reshape(-1, 64)
+    if "alike" in case:
+        # what every chip computes alike is in every part: counted once
+        alike = _highest(case["alike"], h)
+        assert float(jnp.linalg.norm(alike)) > 0.05 * float(
+            jnp.linalg.norm(uncut))
+        total = total - (len(shares) - 1) * alike
+    assert _rel(total, uncut) < 1e-5
 
 
 def test_a_bias_changes_the_choice_and_not_the_weights():
@@ -619,6 +670,7 @@ def test_the_short_conv_scope_and_the_new_gauges_appear(which, tiny):
     assert _gauge(train_path.MOE_EXPERTS_TOTAL) == {(): 8.0}
     assert _gauge(train_path.STACK_LAYERS_BY_KIND) == {
         (("kind", "attention"),): 1.0, (("kind", "short_conv"),): 4.0,
+        (("kind", "latent_attention"),): 0.0,
         (("kind", "dense_ffn"),): 1.0, (("kind", "expert_ffn"),): 4.0}
     # the projections are matmuls outside the scope
     assert not any("dot_general" in line and "/short_conv/" in line
@@ -652,6 +704,7 @@ def test_a_model_without_a_description_by_layer_counts_its_kinds():
                                      jnp.zeros((1, 8), jnp.int32)))
     assert _gauge(train_path.STACK_LAYERS_BY_KIND) == {
         (("kind", "attention"),): 3.0, (("kind", "short_conv"),): 0.0,
+        (("kind", "latent_attention"),): 0.0,
         (("kind", "dense_ffn"),): 3.0, (("kind", "expert_ffn"),): 0.0}
 
 

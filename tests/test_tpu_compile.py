@@ -7,7 +7,8 @@ option fails here, and not in a user's step. And Mosaic takes the gated
 short convolution's two kernels at the LFM2 cell's shape, which the
 interpreter's tests cannot say (it accepts layouts Mosaic refuses); so
 it does the grouped matmul's and the flash kernels' under the masks block
-diffusion calls them with, and a small SDAR step holds nine Mosaic calls
+diffusion calls them with and at latent attention's keys of 192 on values
+of 128, and a small SDAR step holds nine Mosaic calls
 a layer under ``bd_attention`` and no lane reduction of the in-block
 part's old spelling.
 
@@ -198,6 +199,39 @@ def test_mosaic_compiles_the_flash_kernels_under_a_mask_by_blocks(strict,
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
     ).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("widths", [(192, 128), (256, 128)],
+                         ids=["dsv2_192_128_transposed",
+                              "own_rows_256_128"])
+def test_mosaic_compiles_the_flash_kernels_at_a_value_width_of_their_own(
+        widths, topo):
+    """The DeepSeek-V2-Lite cell's attention shape (8192 positions, 16
+    heads, keys of 128 + 64 and values of 128, bf16), forward and the two
+    backward kernels: 192 lanes are one and a half tiles, so the op takes
+    the ``[B * H, T, D]`` form, a block spanning the array's last
+    dimension; and keys of 256 on values of 128 in the projections' own
+    rows, one head a grid step at two block widths."""
+    from jax.sharding import SingleDeviceSharding
+
+    from chainermn_tpu.ops.flash_attention import flash_attention
+
+    D, Dv = widths
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    qk = jax.ShapeDtypeStruct((1, 8192, 16, D), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 8192, 16, Dv), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=0.11472,
+                               interpret=False).astype(jnp.float32).sum()
+
+    grad = jax.grad(loss, (0, 1, 2))
+    text = jax.jit(grad).lower(qk, qk, v).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert [g.shape for g in jax.eval_shape(grad, qk, qk, v)] == [
+        qk.shape, qk.shape, v.shape]
 
 
 def test_mosaic_compiles_the_in_block_calls_kernels(topo):

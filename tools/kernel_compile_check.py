@@ -28,6 +28,8 @@ Checked kernels:
   shapes it rejected them
 - flash attention forward + backward at the OLMoE cell's shape (B 4,
   T 4096, 16 heads of 128)
+- flash attention forward + backward at latent attention's widths (keys
+  of 192, values of 128, 16 heads: the transposed form at two widths)
 - flash attention forward + backward at T 200 and T 576: the kernels
   pass the log-sum-exp as ``[B, H, 1, T]`` rows, whose block Mosaic
   takes as a multiple of 128 lanes or as the whole row, and these
@@ -217,6 +219,11 @@ def _cases():
     # GPT-2 cell's (two heads of 64 a block) and Ouro's (one head of 128)
     q_cell16 = jax.ShapeDtypeStruct((16, 1024, 16, 64), dt)
     q_ouro = jax.ShapeDtypeStruct((1, 4096, 16, 128), dt)
+    # latent attention's widths (DeepSeek-V2-Lite: 16 heads, keys of 128 +
+    # 64, values of 128): 192 lanes are no whole tiles, so the transposed
+    # form, each kernel at two widths
+    qk_mla = jax.ShapeDtypeStruct((1, 2048, 16, 192), dt)
+    v_mla = jax.ShapeDtypeStruct((1, 2048, 16, 128), dt)
     seg_cell = jax.ShapeDtypeStruct((4, 1024), jnp.int32)
     seg_olmoe = jax.ShapeDtypeStruct((4, 4096), jnp.int32)
 
@@ -390,6 +397,8 @@ def _cases():
         ("flash_pairs_b16_fwdbwd", grads(flash), (q_cell16,) * 3,
          grads(xla)),
         ("flash_columns_b1_fwdbwd", grads(flash), (q_ouro,) * 3, grads(xla)),
+        ("flash_mla_192_128_fwdbwd", grads(flash), (qk_mla, qk_mla, v_mla),
+         grads(xla)),
         ("flash_whole_row_t200_fwdbwd", grads(flash), (q_200,) * 3,
          grads(xla)),
         ("flash_whole_row_t576_fwdbwd", grads(flash), (q_576,) * 3,
@@ -514,25 +523,30 @@ def _timings():
         dq, dk, dv = jax.grad(
             lambda a, b, c: flash_attention(a, b, c, causal=True)
             .astype(jnp.float32).sum(), argnums=(0, 1, 2))(qc, k, v)
+        dv = jnp.pad(dv, [(0, 0)] * 3 + [(0, dq.shape[-1] - dv.shape[-1])])
         return (qc + 0.0001 * (dq + dk + dv)).astype(qc.dtype)
 
     rows = []
-    for (B, T, H, D), iters in (((4, 4096, 8, 128), 10),
-                                ((4, 1024, 16, 64), 40),
-                                ((16, 1024, 16, 64), 10),
-                                ((16, 2048, 16, 64), 5)):
+    # the last: the DeepSeek-V2-Lite cell's (keys of 192, values of 128)
+    for (B, T, H, D), iters, Dv in (((4, 4096, 8, 128), 10, 128),
+                                    ((4, 1024, 16, 64), 40, 64),
+                                    ((16, 1024, 16, 64), 10, 64),
+                                    ((16, 2048, 16, 64), 5, 64),
+                                    ((1, 8192, 16, 192), 10, 128)):
         many = jax.jit(lambda q, k, v: jax.lax.scan(
             lambda qc, _: (step(qc, k, v), ()), q, None, length=iters,
         )[0].astype(jnp.float32).sum())
-        q, k, v = _seeded([jax.ShapeDtypeStruct((B, T, H, D),
-                                                jnp.bfloat16)] * 3)
+        q, k, v = _seeded([
+            jax.ShapeDtypeStruct((B, T, H, d), jnp.bfloat16)
+            for d in (D, D, Dv)])
         float(many(q, k, v))  # compile + warm
         samples = []
         for _ in range(3):
             t0 = time.perf_counter()
             float(many(q, k, v))
             samples.append((time.perf_counter() - t0) / iters * 1e3)
-        rows.append({"shape": f"B{B}xT{T}xH{H}xD{D}_bf16_causal",
+        width = f"D{D}" if Dv == D else f"D{D}v{Dv}"
+        rows.append({"shape": f"B{B}xT{T}xH{H}x{width}_bf16_causal",
                      "flash_fwdbwd_ms": [round(x, 4) for x in samples]})
     return rows + _in_block_timings() + _grouped_matmul_timings() \
         + _short_conv_timings()
