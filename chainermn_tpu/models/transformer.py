@@ -1071,14 +1071,27 @@ class TransformerBlock(nn.Module):
         none is padded; the router's auxiliary losses and load are sown
         into :data:`MOE_AUX`. With a share of the experts held
         (``arch.experts_held``) the leaves and the groups are the held
-        experts', and the rows of the absent ones, which lie behind the
-        last group, come out of the grouped matmuls as zeros: written, not
-        multiplied (their tiles are counted, ``tail_tiles``, and sown
-        beside the router's statistics). A shared expert
+        experts', and the section from the gather into expert order to
+        the weighted sum back is :func:`chainermn_tpu.parallel.moe.
+        experts_in_rounds`: a **round** is ``R`` consecutive rows of the
+        expert-sorted order (``R`` from static shapes alone,
+        :func:`~chainermn_tpu.parallel.moe.rows_bound`: twice the share a
+        balanced router gives the chip), gathered, multiplied and gated by
+        themselves, and a loop runs ``ceil(rows_held / R)`` of them, one
+        while the held rows fit. The rows of the absent experts, which lie
+        behind the last group, are zeros: behind the last round run never
+        touched, inside it written by the grouped matmuls, not multiplied
+        (those tiles are counted, ``tail_tiles``, and sown with ``rounds``
+        beside the router's statistics). Such a layer keeps nothing of the
+        section for the backward but its inputs, so trained with no remat
+        policy it computes the section twice. Every expert held is one
+        round of all ``tokens * k`` rows by construction and stays the
+        straight-line spelling. A shared expert
         (``arch.shared_expert_width``) is one more gated feed-forward that
         every token passes outside the routing, added unweighted and,
         under a share, computed whole (:data:`train_path.MOE_SHARED`)."""
-        from chainermn_tpu.ops.grouped_matmul import grouped_matmul, tail_tiles
+        from chainermn_tpu.observability.metrics import registry
+        from chainermn_tpu.ops.grouped_matmul import tail_tiles
         from chainermn_tpu.parallel import moe as _moe
 
         E, F = arch.n_experts, arch.expert_width
@@ -1118,14 +1131,29 @@ class TransformerBlock(nn.Module):
                 self.sow(MOE_AUX, "seq_aux", sequence_balance_loss(
                     routing.logits.reshape(B, T, E),
                     routing.experts.reshape(B, T, -1)))
-        rows = _moe.dispatch(tokens, routing)
-        self.sow(MOE_AUX, "tail_tiles", tail_tiles(
-            routing.group_sizes, rows.shape[0]).astype(jnp.float32))
-        gate_up = grouped_matmul(rows, w_gate_up, routing.group_sizes)
-        with jax.named_scope(train_path.MOE_EXPERTS):
-            act = nn.silu(gate_up[:, :F]) * gate_up[:, F:]
-        out = grouped_matmul(act, w_down, routing.group_sizes)
-        out = _moe.combine(out, routing).reshape(B, T, D)
+        bound = _moe.rows_bound(routing.order.shape[0], held, E)
+        registry().gauge(
+            train_path.MOE_ROWS_BOUND,
+            "expert-sorted rows one round of a dropless MoE layer's expert "
+            "section takes (moe_rows_per_step where every expert is held), "
+            "at the last layer traced",
+        ).set(float(bound))
+        if held == E:
+            # one round of all the rows by construction: straight-line
+            rows = _moe.dispatch(tokens, routing)
+            stats = {"rounds": jnp.float32(1.0),
+                     "tail_tiles": tail_tiles(
+                         routing.group_sizes, rows.shape[0]
+                     ).astype(jnp.float32)}
+            out = _moe.gated_experts(rows, w_gate_up, w_down,
+                                     routing.group_sizes)
+            out = _moe.combine(out, routing)
+        else:
+            stats = _moe.rounds_aux(routing, bound)
+            out = _moe.experts_in_rounds(tokens, w_gate_up, w_down, routing)
+        for name, value in stats.items():
+            self.sow(MOE_AUX, name, value)
+        out = out.reshape(B, T, D)
         if arch.shared_expert_width:
             out = out + self._shared_expert(h, arch.shared_expert_width)
         return out
@@ -2150,7 +2178,13 @@ def lm_loss_moe(model: "TransformerLM", params, tokens, *, n_chunks=8,
     ``moe/tail_tiles`` (the expert section's row tiles behind the last held
     group, summed over the layers, which the grouped matmuls write as
     zeros without multiplying: 0 where every expert is held and the rows
-    fill their tiles), ``moe/dropped``
+    fill their tiles; under a share counted within the rounds run),
+    ``moe/rounds`` (rounds of the expert section run, summed over the
+    layers: a layer that holds a share runs its expert-sorted rows in
+    rounds of a static bound, :func:`chainermn_tpu.parallel.moe.
+    experts_in_rounds`, ``ceil(rows_held / bound)`` of them, so the sum is
+    the number of expert layers while every layer's held rows fit the
+    bound, and always where every expert is held), ``moe/dropped``
     (rows routed to a held expert that lie in no expert's group, counted
     from each layer's group sizes: 0 while the dropless path keeps its
     word) and the vector ``moe/expert_load`` (rows a held expert received,
@@ -2198,6 +2232,7 @@ def _with_router_aux(loss, model: "TransformerLM", sown, load_balance_coef,
         "moe/expert_load_max_over_mean": load.max() / load.mean(),
         "moe/rows_held": over_layers("rows_held", jnp.sum),
         train_path.MOE_TAIL_TILES: over_layers("tail_tiles", jnp.sum),
+        train_path.MOE_ROUNDS: over_layers("rounds", jnp.sum),
         "moe/dropped": over_layers("dropped", jnp.sum),
         "moe/expert_load": load,
     }

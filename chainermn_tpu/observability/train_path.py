@@ -162,6 +162,19 @@ MOE_EXPERTS_HELD = "moe_experts_held"
 #: the grouped matmul's row products write as zeros without multiplying
 #: (``ops.grouped_matmul.tail_tiles``), summed over the expert layers
 MOE_TAIL_TILES = "moe/tail_tiles"
+#: key beside it: the rounds the expert sections ran, summed over the
+#: expert layers. A layer that holds a share runs its expert-sorted rows
+#: in rounds of ``moe_rows_bound`` rows, ``ceil(rows_held / bound)`` of
+#: them (``parallel.moe.experts_in_rounds``); every expert held is one
+#: round. Equal to the number of expert layers while nothing overflows the
+#: bound; ``moe/tail_tiles`` counts within the rounds run
+MOE_ROUNDS = "moe/rounds"
+#: gauge set beside ``moe_rows_per_step`` while a dropless MoE layer is
+#: traced: the expert-sorted rows one round of its expert section takes
+#: (``parallel.moe.rows_bound``, from static shapes: twice the share a
+#: balanced router gives the chip, rounded up to the row tile;
+#: ``moe_rows_per_step`` where every expert is held)
+MOE_ROWS_BOUND = "moe_rows_bound"
 #: gauge set while a ``TransformerLM`` is traced, label ``kind``
 #: (``attention`` / ``short_conv`` / ``dense_ffn`` / ``expert_ffn``): the
 #: layers of the stack that have a mixer or a feed-forward of that kind

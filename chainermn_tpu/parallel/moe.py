@@ -28,7 +28,12 @@ routing. On one chip there is no collective; an expert axis puts its two
 experts and :func:`combine`. A caller that holds a share of the experts
 (``held``) gets the part of the result its own experts give: the choice
 and the gates are over all experts, the rows of the absent ones lie in no
-group and add nothing, and nothing stands in for the exchange.
+group and add nothing, and nothing stands in for the exchange. Such a
+caller's section from :func:`dispatch` to :func:`combine` is
+:func:`experts_in_rounds`: the same values from rounds of a static number
+of sorted rows, as many rounds as the held rows need, so the absent
+experts' rows cost their place in the sort and in the sum back and
+nothing between.
 
 Differentiable end to end: routing uses straight-through softmax gating
 (gradient flows through the gate probability, not the indices), and
@@ -37,6 +42,8 @@ Differentiable end to end: routing uses straight-through softmax gating
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -336,8 +343,6 @@ def moe_capacity(
     the same expert), the serving contract: routing decouples across
     co-resident rows, so streams stay bit-identical to sequential
     ``generate`` whatever else shares the batch."""
-    import math
-
     if capacity_factor is None:
         return max(1, tokens)
     if capacity_factor < 0:
@@ -535,7 +540,12 @@ class Routing(NamedTuple):
     experts is held, ``group_sizes`` has one entry a *held* expert, the
     rows routed to an absent one sort behind the last group and lie in
     none, and ``rows_held`` counts the rows whose expert is held, from
-    the choice and not from the groups."""
+    the choice and not from the groups. The held rows are therefore the
+    first ``rows_held`` of the sorted order, and a **round** of a share's
+    expert section (:func:`experts_in_rounds`) is ``R`` consecutive
+    positions of it, ``order[r * R : (r + 1) * R]``, with the groups
+    clipped to that window: ``ceil(rows_held / R)`` rounds cover every
+    held row whatever the routing."""
 
     gates: jax.Array        # [T, k] float32
     experts: jax.Array      # [T, k] int32
@@ -689,13 +699,203 @@ def combine(y, routing: Routing):
     """Sum the experts' outputs ``y [T * k, D]`` (expert order) back into
     ``[T, D]``, each row weighted by its gate, accumulated in float32.
     Differentiable in ``y`` and in the gates."""
-    tokens, k = routing.gates.shape
+    return _sum_back(y, routing.gates, routing.order, routing.inverse)
+
+
+def _sum_back(y, gates, order, inverse):
+    tokens, k = gates.shape
     with jax.named_scope(train_path.MOE_COMBINE):
-        back = _rows_from_experts(y, routing.order, routing.inverse)
+        back = _rows_from_experts(y, order, inverse)
         back = back.reshape(tokens, k, y.shape[-1])
-        out = jnp.einsum("tkd,tk->td", back, routing.gates.astype(y.dtype),
+        out = jnp.einsum("tkd,tk->td", back, gates.astype(y.dtype),
                          preferred_element_type=jnp.float32)
         return out.astype(y.dtype)
+
+
+def gated_experts(rows, w_gate_up, w_down, group_sizes):
+    """``down(silu(gate(rows)) * up(rows))`` expert by expert, for rows in
+    expert order: gate and up of an expert are one matrix (``w_gate_up
+    [held, D, 2F]``, gate's columns first), so one grouped matmul makes
+    both; ``w_down`` is ``[held, F, D]``. The straight-line core of the
+    expert section, differentiable in all three."""
+    from chainermn_tpu.ops.grouped_matmul import grouped_matmul
+
+    width = w_down.shape[1]
+    gate_up = grouped_matmul(rows, w_gate_up, group_sizes)
+    with jax.named_scope(train_path.MOE_EXPERTS):
+        act = jax.nn.silu(gate_up[:, :width]) * gate_up[:, width:]
+    return grouped_matmul(act, w_down, group_sizes)
+
+
+#: rows of a round of a share's expert section over the rows a balanced
+#: router gives the chip (``T * k * held / E``). At 2 the three cells that
+#: train a share run one round a layer (``rows_held`` a layer ~16k of
+#: 32,768 in LFM2, 7.5-17.5k of 32,768 in SDAR, ~6.1k of 12,288 in
+#: DeepSeek-V2-Lite). Read on the v5e against 1.5, two seeds each: LFM2
+#: 310.05 / 308.47 ms a step at 2 and 310.36 / 308.69 at 1.5, SDAR 553.29 /
+#: 549.68 and 569.94 / 564.39 (PERF.md section 6, PR 48): a smaller round
+#: buys nothing there, and the rounds make any value exact
+_ROUND_SHARE = 2
+
+
+def rows_bound(rows: int, held: int, n_experts: int) -> int:
+    """``R``: the expert-sorted rows one round of a share's expert section
+    takes, from static shapes alone: :data:`_ROUND_SHARE` times the
+    expected share of ``rows`` (token, slot) rows where ``held`` of
+    ``n_experts`` experts are held, rounded up to the grouped matmul's row
+    tile, and no more than ``rows`` (every expert held: one round of all
+    rows)."""
+    from chainermn_tpu.ops.grouped_matmul import _TILE_M
+
+    tiles = math.ceil(_ROUND_SHARE * rows * held / (n_experts * _TILE_M))
+    return min(rows, tiles * _TILE_M)
+
+
+def _round_sizes(group_sizes, r, bound: int):
+    """The groups' sizes inside round ``r``'s window of the sorted rows,
+    ``[r * bound, (r + 1) * bound)``: a group that straddles an edge is
+    split, one outside the window is empty, and the window's rows behind
+    the last group are the grouped matmul's tail."""
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    starts = ends - group_sizes.astype(jnp.int32)
+    lo = r * bound
+    return jnp.clip(ends, lo, lo + bound) - jnp.clip(starts, lo, lo + bound)
+
+
+def rounds_aux(routing: Routing, bound: int) -> dict:
+    """What a share's expert section does at ``bound`` rows a round:
+    ``rounds`` (rounds run, ``ceil(rows_held / bound)``: 1 while the held
+    rows fit the bound, 0 where none is held) and ``tail_tiles`` (the row
+    tiles the rounds run hand the grouped matmuls that lie wholly behind
+    the last held group: written as zeros, not multiplied), float32."""
+    from chainermn_tpu.ops.grouped_matmul import tail_tiles
+
+    rounds = -(-routing.rows_held // bound)
+    last = _round_sizes(routing.group_sizes, jnp.maximum(rounds - 1, 0), bound)
+    return {
+        "rounds": rounds.astype(jnp.float32),
+        "tail_tiles": jnp.where(rounds > 0, tail_tiles(last, bound), 0
+                                ).astype(jnp.float32),
+    }
+
+
+def _whole_rounds(order, bound: int):
+    """``order`` padded with row 0 to a whole number of rounds (the padding
+    lies behind every group)."""
+    rows = order.shape[0]
+    return jnp.pad(order, (0, -(-rows // bound) * bound - rows))
+
+
+def _round(r, bound: int, x, order_p, group_sizes, k: int):
+    """Round ``r``: its (token, slot) rows, their token rows gathered from
+    ``x``, and the groups' sizes in its window."""
+    idx = lax.dynamic_slice_in_dim(order_p, r * bound, bound)
+    with jax.named_scope(train_path.MOE_DISPATCH):
+        rows = x[idx // k]
+    return idx, rows, _round_sizes(group_sizes, r, bound)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _section(bound, x, w_gate_up, w_down, gates, order, inverse, group_sizes,
+             rows_held):
+    tokens, k = gates.shape
+    order_p = _whole_rounds(order, bound)
+
+    def one_round(r, y):
+        _, rows, sizes = _round(r, bound, x, order_p, group_sizes, k)
+        out = gated_experts(rows, w_gate_up, w_down, sizes)
+        with jax.named_scope(train_path.MOE_EXPERTS):
+            return lax.dynamic_update_slice_in_dim(y, out, r * bound, 0)
+
+    with jax.named_scope(train_path.MOE_EXPERTS):
+        # rows behind the rounds run: zeros, as the tail's are
+        y = jnp.zeros((order_p.shape[0], x.shape[1]), x.dtype)
+    y = lax.fori_loop(0, -(-rows_held // bound), one_round, y)
+    return _sum_back(y[:tokens * k], gates, order, inverse)
+
+
+def _section_fwd(bound, *args):
+    return _section(bound, *args), args  # the inputs alone are kept
+
+
+def _section_bwd(bound, res, g):
+    x, w_gate_up, w_down, gates, order, inverse, group_sizes, rows_held = res
+    tokens, k = gates.shape
+    order_p = _whole_rounds(order, bound)
+    gate_of = gates.reshape(-1)
+
+    def one_round(r, carry):
+        d_gate_up, d_down, d_rows, d_gate = carry
+        idx, rows, sizes = _round(r, bound, x, order_p, group_sizes, k)
+        out, vjp = jax.vjp(
+            lambda a, b, c: gated_experts(a, b, c, sizes), rows, w_gate_up,
+            w_down)
+        with jax.named_scope(train_path.MOE_COMBINE):
+            # the weighted sum's transpose for these rows alone: the
+            # cotangent of a row is its token's times its gate (rounded as
+            # the sum rounds it), and the gate's is <row's output, token's>
+            g_rows = g[idx // k]
+            gate = gate_of[idx].astype(out.dtype).astype(jnp.float32)
+            d_out = (g_rows.astype(jnp.float32) * gate[:, None]
+                     ).astype(out.dtype)
+            d_gate_r = jnp.einsum("rd,rd->r", out, g_rows,
+                                  preferred_element_type=jnp.float32)
+        d_rows_r, d_gate_up_r, d_down_r = vjp(d_out)
+        with jax.named_scope(train_path.MOE_EXPERTS):
+            return (d_gate_up + d_gate_up_r, d_down + d_down_r,
+                    lax.dynamic_update_slice_in_dim(
+                        d_rows, d_rows_r, r * bound, 0),
+                    lax.dynamic_update_slice_in_dim(
+                        d_gate, d_gate_r, r * bound, 0))
+
+    with jax.named_scope(train_path.MOE_EXPERTS):
+        carry = (jnp.zeros(w_gate_up.shape, jnp.float32),
+                 jnp.zeros(w_down.shape, jnp.float32),
+                 jnp.zeros((order_p.shape[0], x.shape[1]), x.dtype),
+                 jnp.zeros(order_p.shape, jnp.float32))
+    d_gate_up, d_down, d_rows, d_gate = lax.fori_loop(
+        0, -(-rows_held // bound), one_round, carry)
+    with jax.named_scope(train_path.MOE_DISPATCH):
+        d_x, _, _ = _rows_to_experts_bwd((order, inverse, tokens),
+                                         d_rows[:tokens * k])
+    with jax.named_scope(train_path.MOE_COMBINE):
+        d_gates = d_gate[:tokens * k][inverse].reshape(tokens, k)
+    return (d_x, d_gate_up.astype(w_gate_up.dtype),
+            d_down.astype(w_down.dtype), d_gates.astype(gates.dtype),
+            None, None, None, None)
+
+
+_section.defvjp(_section_fwd, _section_bwd)
+
+
+def experts_in_rounds(x, w_gate_up, w_down, routing: Routing):
+    """The expert section of a layer that holds a share of the experts:
+    :func:`dispatch`, :func:`gated_experts` and :func:`combine` of ``x [T,
+    D]`` as one function, everything of it that lies in expert order run
+    over **rounds** of ``R`` rows (:func:`rows_bound`, from the shapes
+    here: ``held`` is the weights' leading dimension, ``E`` the router's
+    width). Round ``r`` takes ``routing.order[r * R : (r + 1) * R]``,
+    gathers those ``R`` token rows and multiplies them with the groups
+    clipped to its window; a loop runs ``ceil(rows_held / R)`` rounds (a
+    traced count: 1 while the held rows fit ``R``, up to ``T * k / R``
+    where every row's expert is held), so the rows of absent experts
+    behind the last round are never gathered, multiplied or gated, and
+    nothing is dropped at any routing. With one round the values are those
+    of the straight-line spelling, bit for bit.
+
+    Differentiable in ``x``, both weights and ``routing.gates`` by a rule
+    of its own (a loop of a traced length has no reverse mode): the
+    backward runs the same rounds, each re-gathering its rows, computing
+    :func:`gated_experts` again and transposing it, the weights' gradients
+    summed over the rounds in float32. **Nothing but the inputs is kept
+    for the backward**, so under ``jax.checkpoint`` the replay of the
+    forward is dead code, and with no checkpoint the section is computed
+    twice where the straight-line spelling would keep its products."""
+    bound = rows_bound(routing.order.shape[0], w_gate_up.shape[0],
+                       routing.logits.shape[-1])
+    return _section(bound, x, w_gate_up, w_down, routing.gates,
+                    routing.order, routing.inverse, routing.group_sizes,
+                    routing.rows_held)
 
 
 def dropless_aux(routing: Routing, losses: bool = True) -> dict:

@@ -179,8 +179,11 @@ def test_loss_and_every_gradient_leaf_match_the_reference(held, ref):
     assert params["block_1"]["moe_router"].shape == (64, 8)
     assert float(metrics["moe/dropped"]) == 0.0
     assert set(metrics) == {"moe/dropped", "moe/rows_held",
-                            "moe/tail_tiles", "moe/expert_load",
+                            "moe/tail_tiles", "moe/rounds",
+                            "moe/expert_load",
                             "moe/expert_load_max_over_mean"}
+    # a round of twice the expected share holds what this router deals
+    assert float(metrics["moe/rounds"]) == 4.0
 
 
 def _gates_by_choice(real):

@@ -10,7 +10,9 @@ it does the grouped matmul's and the flash kernels' under the masks block
 diffusion calls them with and at latent attention's keys of 192 on values
 of 128, and a small SDAR step holds nine Mosaic calls
 a layer under ``bd_attention`` and no lane reduction of the in-block
-part's old spelling.
+part's old spelling; and a small step on a share of the experts holds its
+expert section once, in a loop's body, with the Mosaic calls the
+straight-line spelling has and no ``conditional``.
 
 One file, one fixture: only one process may load the TPU's library, so
 the topology is described inside the fixture and nowhere at import."""
@@ -317,3 +319,72 @@ def test_a_small_sdar_step_holds_nine_mosaic_calls_a_layer(topo,
                  and re.search(r"dimensions = \[4\] : \(tensor<1x1024x1x8x128xf32>",
                                line)]
     assert not lane_sums, lane_sums[:2]
+
+
+def _straight_line_section(x, w_gate_up, w_down, routing):
+    """A share's expert section as it was spelled before the rounds."""
+    from chainermn_tpu.parallel import moe
+
+    return moe.combine(moe.gated_experts(
+        moe.dispatch(x, routing), w_gate_up, w_down, routing.group_sizes),
+        routing)
+
+
+@pytest.mark.parametrize("spelling", ["rounds", "straight_line"])
+def test_a_small_share_step_holds_one_copy_of_the_expert_section(
+        spelling, topo, monkeypatch):
+    """The gradient of a three-layer LFM2 (two expert layers, 2 of 8
+    experts held, 2,048 rows a layer in rounds of 1,024) under remat
+    ``dots``: **eight** Mosaic calls named ``moe_experts`` a layer in either
+    spelling (straight-line: 2 forward, 2 recomputed, 4 backward; in
+    rounds: 2 in the forward's loop, 2 + 4 in the backward's, and the
+    replay of the forward is dead code), every one of the rounds' inside a
+    ``while``'s body, and no ``conditional`` anywhere in the step."""
+    from jax.sharding import SingleDeviceSharding
+
+    from chainermn_tpu.models import (
+        MODEL_CONFIGS,
+        ROUTER_STATE,
+        lm_from_config,
+        lm_loss_moe,
+    )
+    from chainermn_tpu.parallel import moe
+
+    for name in ("flash_attention", "grouped_matmul"):
+        monkeypatch.setattr(
+            importlib.import_module(f"chainermn_tpu.ops.{name}"),
+            "_use_interpret", lambda: False)
+    if spelling == "straight_line":
+        monkeypatch.setattr(moe, "experts_in_rounds", _straight_line_section)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    T, expert_layers = 1024, 2
+    config = dict(
+        MODEL_CONFIGS["lfm2-8b-a1b"], num_hidden_layers=3,
+        num_dense_layers=1, layer_types=["conv", "full_attention", "conv"],
+        hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
+        intermediate_size=256, moe_intermediate_size=128, num_experts=2,
+        experts_published=8, experts_held_range=[2, 4],
+        num_experts_per_tok=2, vocab_size=512, max_position_embeddings=T)
+    model = lm_from_config(config, compute_dtype=jnp.bfloat16,
+                           return_hidden=True, remat=True)
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    v = jax.eval_shape(lambda: model.init(
+        jax.random.key(1), jnp.zeros((1, T), jnp.int32)))
+    text = jax.jit(jax.grad(lambda p, s, b: lm_loss_moe(
+        model, p, b, n_chunks=2, load_balance_coef=0.0, z_loss_coef=0.0,
+        router_state=s)[0])).lower(
+        placed(v["params"]), placed(v[ROUTER_STATE]),
+        placed(jax.ShapeDtypeStruct((1, T), jnp.int32))).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "moe_experts" in line]
+    assert len(calls) == 8 * expert_layers
+    assert " conditional(" not in text
+    in_a_loop = sum("_moe_dropless/while/body/" in line for line in calls)
+    assert in_a_loop == (len(calls) if spelling == "rounds" else 0)
+    if spelling == "rounds":
+        assert not any("rematted_computation" in line for line in calls)
